@@ -341,8 +341,9 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--achievable-gbps", type=float, default=None,
                    help="measured achievable HBM streaming bandwidth for "
                         "the live achieved_over_achievable roofline gauges "
-                        "(default: 819 spec on TPU, a nominal DDR figure "
-                        "on CPU — indicative only)")
+                        "(default: the device's published peak, "
+                        "telemetry/roofline.py DEVICE_PEAKS; a device "
+                        "without one publishes no fraction)")
     p.add_argument("--no-save", action="store_true")
     p.add_argument("-v", "--verbose", action="store_true")
     return p
@@ -399,6 +400,8 @@ def config_from_args(args: argparse.Namespace) -> Config:
         updates["max_new_tokens"] = args.max_new_tokens
     if args.quick:
         updates["profiles_per_combo"] = 1
+        args.num_items = min(args.num_items, 10)
+        args.num_comparisons = min(args.num_comparisons, 6)
     if args.speculate or args.draft_len is not None or args.ngram_max is not None:
         from fairness_llm_tpu.config import SpeculationConfig
 
@@ -1204,9 +1207,61 @@ def rollout_cmd(argv) -> int:
     return 0 if ro.state == "complete" else 2
 
 
+def run_study_phase(phase: int, args: argparse.Namespace, config: Config,
+                    save: bool, p1: Optional[Dict] = None,
+                    backend=None) -> Dict:
+    """Run one study phase the way the CLI runs it — driver call, summary,
+    figures — and return its results dict. ``p1`` is phase 1's in-memory
+    result (phase 3 mitigates it; None = load or re-run). ``backend``, an
+    already-built backend for ``args.model``, replaces the name-resolved
+    one: how ``chip_smoke.py`` serves a full-width model from seeded random
+    weights (``backend_for(..., allow_random=True)``) through this exact
+    path."""
+    if phase == 1:
+        res = run_phase1(config, args.model, args.profiles, save=save,
+                         resume=args.resume, backend=backend)
+        print_phase1_summary(res)
+        if save:
+            from fairness_llm_tpu.reports import (
+                generate_phase1_figures,
+                generate_summary_report,
+            )
+
+            generate_phase1_figures(res, f"{config.results_dir}/visualizations")
+            generate_summary_report(
+                res, f"{config.results_dir}/phase1/phase1_summary_report.txt"
+            )
+    elif phase == 2:
+        res = run_phase2(config, args.models or ([args.model] if args.model else None),
+                         args.num_items, args.num_comparisons, save=save,
+                         corpus=args.corpus, num_queries=args.num_queries,
+                         backends={args.model: backend} if backend is not None else None)
+        print_phase2_summary(res)
+        if save:
+            from fairness_llm_tpu.reports import generate_phase2_figure
+
+            generate_phase2_figure(res, f"{config.results_dir}/visualizations")
+    else:
+        res = run_phase3(config, phase1_results=p1, model_name=args.model,
+                         num_profiles=args.profiles, variant=args.variant,
+                         strategy=args.strategy, save=save, backend=backend,
+                         calibration=args.calibration,
+                         confidence_mapping=args.confidence_mapping,
+                         confidence_temperature=args.confidence_temperature)
+        print_phase3_summary(res)
+        if save:
+            from fairness_llm_tpu.reports import generate_phase3_figure
+
+            generate_phase3_figure(res, f"{config.results_dir}/visualizations")
+    return res
+
+
 def main(argv=None) -> int:
     if argv is None:
         argv = sys.argv[1:]
+    from fairness_llm_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
     if argv and argv[0] == "telemetry-report":
         # Subcommand dispatch ahead of the study parser (whose --all/--phase
         # group is required and would reject it).
@@ -1269,10 +1324,6 @@ def main(argv=None) -> int:
     if tc.achievable_gbps:
         set_achievable_gbps(tc.achievable_gbps)
 
-    if args.quick:
-        args.num_items = min(args.num_items, 10)
-        args.num_comparisons = min(args.num_comparisons, 6)
-
     from fairness_llm_tpu.utils import maybe_trace, phase_timer
 
     drain_handler = None
@@ -1285,9 +1336,12 @@ def main(argv=None) -> int:
 
         drain_handler = GracefulDrain().install()
 
+    from fairness_llm_tpu.utils.failures import SweepFailed
+
     phases = [1, 2, 3] if args.all else [args.phase]
     timings: Dict[str, float] = {}
     p1 = None
+    failure: Optional[str] = None
     for phase in phases:
         if drain_handler is not None and drain_handler.requested:
             # A drain mid-phase already preempted/journaled that phase's
@@ -1296,43 +1350,21 @@ def main(argv=None) -> int:
             # Stop at the boundary and get to the snapshot/journal note.
             print(f"\ndrain requested — skipping phase {phase} and beyond")
             break
-        with phase_timer(f"phase {phase}", timings), maybe_trace(
-            config.profile_trace_dir, f"phase{phase}"
-        ):
+        try:
+            with phase_timer(f"phase {phase}", timings), maybe_trace(
+                config.profile_trace_dir, f"phase{phase}"
+            ):
+                results = run_study_phase(phase, args, config, save, p1)
             if phase == 1:
-                p1 = run_phase1(config, args.model, args.profiles, save=save, resume=args.resume)
-                print_phase1_summary(p1)
-                if save:
-                    from fairness_llm_tpu.reports import (
-                        generate_phase1_figures,
-                        generate_summary_report,
-                    )
-
-                    generate_phase1_figures(p1, f"{config.results_dir}/visualizations")
-                    generate_summary_report(
-                        p1, f"{config.results_dir}/phase1/phase1_summary_report.txt"
-                    )
-            elif phase == 2:
-                p2 = run_phase2(config, args.models or ([args.model] if args.model else None),
-                                args.num_items, args.num_comparisons, save=save,
-                                corpus=args.corpus, num_queries=args.num_queries)
-                print_phase2_summary(p2)
-                if save:
-                    from fairness_llm_tpu.reports import generate_phase2_figure
-
-                    generate_phase2_figure(p2, f"{config.results_dir}/visualizations")
-            else:
-                p3 = run_phase3(config, phase1_results=p1, model_name=args.model,
-                                num_profiles=args.profiles, variant=args.variant,
-                                strategy=args.strategy, save=save,
-                                calibration=args.calibration,
-                                confidence_mapping=args.confidence_mapping,
-                                confidence_temperature=args.confidence_temperature)
-                print_phase3_summary(p3)
-                if save:
-                    from fairness_llm_tpu.reports import generate_phase3_figure
-
-                    generate_phase3_figure(p3, f"{config.results_dir}/visualizations")
+                p1 = results
+        except SweepFailed as e:
+            # Per-row containment stops at the sweep: with every row lost
+            # there is no study to report, and the later phases would only
+            # repeat the failure. The diagnostics below still run; the exit
+            # code and the last lines say what happened.
+            failure = f"phase {phase}: {e}"
+            print(f"\nFAILED {failure}")
+            break
 
     if drain_handler is not None:
         drain_handler.uninstall()
@@ -1396,10 +1428,13 @@ def main(argv=None) -> int:
             logger.warning("timeline export unavailable: %s", e)
 
     print("\n" + "=" * 60)
-    print("RUN COMPLETE")
+    print("RUN FAILED" if failure else "RUN COMPLETE")
     for name, dt in timings.items():
         print(f"  {name}: {dt:.1f}s")
     print(f"results under: {config.results_dir}/")
+    if failure:
+        print(f"FAILED {failure}")
+        return 1
     return 0
 
 

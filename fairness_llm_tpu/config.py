@@ -417,8 +417,8 @@ class TelemetryConfig:
 
     trace_out: Optional[str] = None
     # Measured achievable streaming bandwidth for achieved_over_achievable
-    # (None = platform default: 819 GB/s v5e spec on TPU, a nominal DDR
-    # figure on the CPU harness — indicative only).
+    # (None = the device's published peak from roofline.DEVICE_PEAKS; a
+    # device without one publishes no fraction).
     achievable_gbps: Optional[float] = None
     # Fairness observability (telemetry/fairness.py, CLI --fairness-obs):
     # phases register their profile grid + counterfactual pairs with the
@@ -577,8 +577,11 @@ class Config:
     )
 
     def settings_for(self, model_name: str) -> ModelSettings:
+        # The derived ``<base>-int8`` serving variants (models/configs.py)
+        # decode with their base model's settings.
+        base_name = model_name.removesuffix("-int8")
         for name, settings in self.model_settings:
-            if name == model_name:
+            if name in (model_name, base_name):
                 if settings.max_tokens > self.max_new_tokens:
                     settings = dataclasses.replace(
                         settings, max_tokens=self.max_new_tokens

@@ -57,7 +57,7 @@ class ModelConfig:
     # profiles/s at batch 48 (round 3); int8-cache mode (dequant-in-tile,
     # round 4) 0.28x XLA at batch 192/360 — the per-step head-major cache
     # transposes dominate, and the native head-major layout was also
-    # measured and rejected (docs/PERFORMANCE.md). Kept oracle-tested; the
+    # measured and rejected (ROADMAP.md D4). Kept oracle-tested; the
     # bench A/Bs it every round. Applies only on TPU to single-token cached
     # steps with compatible shapes (no sliding window); all other paths use
     # XLA regardless.

@@ -206,7 +206,7 @@ class QuantDense(nn.Module):
     so HBM never holds a float copy of the weight — the property that lets
     llama3-70b tp=8 fit a v5e-8 (the naive dequant-at-use expression gets
     hoisted out of the decode loop by XLA and materializes the full bf16
-    tree; docs/PERFORMANCE.md round 3). When the enclosing ``with mesh:``
+    tree; ops/quant_matmul.py). When the enclosing ``with mesh:``
     context shards the kernel's logical axes, the matmul runs as a
     FULL-manual shard_map over every mesh axis (column-parallel local,
     row-parallel + f32 psum, batch sharding encoded in the specs — Mosaic
@@ -303,16 +303,25 @@ class Attention(nn.Module):
         """
         if not (self.config.use_flash_attention and left_padded) or seq_len <= 1:
             return False
-        from fairness_llm_tpu.ops.quant_matmul import _FORCE_PALLAS
-
-        if jax.default_backend() != "tpu" and not _FORCE_PALLAS.get():
-            return False
-        _, qh_ax, kv_ax = self._mesh_axes()
-        if qh_ax != kv_ax:
-            return False
         from fairness_llm_tpu.ops import flash_supported
+        from fairness_llm_tpu.ops.dispatch import note_kernel_path
+        from fairness_llm_tpu.ops.quant_matmul import lowering_for_tpu
 
-        return flash_supported(seq_len, self.config.head_dim)
+        # From here on the caller asked for the kernel; a refusal is a gate
+        # decision and is witnessed (ops/dispatch.py), never silent.
+        shape = (seq_len, self.config.head_dim)
+        _, qh_ax, kv_ax = self._mesh_axes()
+        if not lowering_for_tpu():
+            reason = "not_tpu"
+        elif qh_ax != kv_ax:
+            reason = "mesh_axes"
+        elif not flash_supported(*shape):
+            reason = "untileable"
+        else:
+            note_kernel_path("flash_attention", "pallas", shape)
+            return True
+        note_kernel_path("flash_attention", "xla", shape, reason)
+        return False
 
     def _mesh_axes(self):
         """(batch, q_heads, kv_heads) mesh axes actually sharded (>1) under

@@ -1,7 +1,7 @@
 """Fused decode-step attention (Pallas TPU): one kernel call per layer per step.
 
 Why a kernel when decode attention is tiny: the round-3 device trace
-(docs/PERFORMANCE.md) showed XLA lowering each layer's single-token attention
+(ROADMAP.md S1) showed XLA lowering each layer's single-token attention
 into several HBM-round-tripping fusions — scores written to HBM, read back
 for softmax, probabilities written again, read for the PV reduce — costing
 ~140 us/layer where the data (a few MB of KV in VMEM) supports ~20 us. This

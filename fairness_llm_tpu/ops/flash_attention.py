@@ -150,4 +150,5 @@ def flash_attention(
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="flash_attention",
     )(lengths.astype(jnp.int32), q, k, v)

@@ -3,8 +3,8 @@
 Why a kernel instead of ``x @ (wq * scale)``: XLA hoists loop-invariant
 computation out of decode loops. For int8-stored weights that "obvious"
 dequant-at-use expression materializes a full bf16 copy of the parameter
-tree in HBM (measured round 3: llama3-70b tp=8 — int8 args 8.84 GB/chip
-would fit a v5e, but 35.2 GB of hoisted bf16 temps; docs/PERFORMANCE.md).
+tree in HBM (an earlier compile of llama3-70b tp=8: the 8.84 GB/chip of int8
+arguments would fit a v5e, but not the 35.2 GB of hoisted bf16 temps).
 Inside a Pallas kernel the int8->bf16 conversion happens per [bk, bn] tile
 in VMEM, so HBM only ever holds the int8 tree: weight-only-quantized
 serving streams half the bytes AND fits models that bf16 cannot.
@@ -46,6 +46,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
+from fairness_llm_tpu.ops.dispatch import note_kernel_path
+
 __all__ = [
     "quantize_weight",
     "dequantize_weight",
@@ -53,6 +55,7 @@ __all__ = [
     "quant_matmul",
     "quant_matmul_sharded",
     "force_pallas",
+    "lowering_for_tpu",
 ]
 
 _LANE = 128  # TPU lane width: last-dim tiling granule for every dtype
@@ -68,6 +71,14 @@ _LANE = 128  # TPU lane width: last-dim tiling granule for every dtype
 _FORCE_PALLAS: contextvars.ContextVar[int] = contextvars.ContextVar(
     "quant_matmul_force_pallas", default=0
 )
+
+
+def lowering_for_tpu() -> bool:
+    """Is the program being traced headed for a TPU? True on a live TPU
+    backend and under :class:`force_pallas` (AOT lowering against a TPU
+    topology from a CPU process). The one predicate every Pallas gate and
+    the engine's flash-eligible sequence bucket share."""
+    return jax.default_backend() == "tpu" or bool(_FORCE_PALLAS.get())
 
 
 class force_pallas:
@@ -167,11 +178,11 @@ def _quant_matmul_pallas(x, wq, scale, interpret: bool, out_dtype):
         out_specs=pl.BlockSpec((bm_t, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bm, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm_t, bn), jnp.float32)],
-        # CompilerParams was TPUCompilerParams before jax 0.7.
-        compiler_params=getattr(
-            pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-        )(dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
         interpret=interpret,
+        name="int8_matmul",
     )(x, wq, scale[None, :])
     return out[:m]
 
@@ -191,9 +202,14 @@ def quant_matmul(
     then-scale) so both paths agree to float rounding, not just mathematically.
     """
     out_dtype = out_dtype or x.dtype
-    on_tpu = jax.default_backend() == "tpu" or bool(_FORCE_PALLAS.get())
+    on_tpu = lowering_for_tpu()
     if (on_tpu or interpret) and quant_tileable(*wq.shape):
+        note_kernel_path("int8_matmul", "pallas", wq.shape)
         return _quant_matmul_pallas(x, wq, scale, interpret, out_dtype)
+    # On the TPU this is the expression XLA hoists a bf16 copy of the weight
+    # for (module docstring) — never taken silently.
+    note_kernel_path("int8_matmul", "xla", wq.shape,
+                     "untileable" if on_tpu or interpret else "not_tpu")
     y = jnp.dot(x, wq.astype(x.dtype), preferred_element_type=jnp.float32)
     return (y * scale[None, :].astype(jnp.float32)).astype(out_dtype)
 

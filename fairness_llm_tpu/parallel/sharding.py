@@ -47,38 +47,21 @@ def make_mesh(mesh_config: MeshConfig, devices: Optional[List] = None) -> Mesh:
         )
     arr = np.asarray(devices[:n]).reshape(mesh_config.shape)
     # Auto axis types: we annotate weights/activations and let GSPMD propagate
-    # through gathers/scans (jax 0.9's Explicit mode would require per-gather
-    # out_sharding annotations inside the model). Older jax (< 0.6) has no
-    # AxisType and every mesh axis is implicitly Auto — same semantics.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return Mesh(arr, mesh_config.axis_names)
-    axis_types = (axis_type.Auto,) * len(mesh_config.axis_names)
+    # through gathers/scans (Explicit mode would require per-gather
+    # out_sharding annotations inside the model).
+    axis_types = (jax.sharding.AxisType.Auto,) * len(mesh_config.axis_names)
     return Mesh(arr, mesh_config.axis_names, axis_types=axis_types)
 
 
 def compat_shard_map(f, mesh: Mesh, in_specs, out_specs, axis_names=None):
-    """``jax.shard_map`` across the jax versions this repo meets.
-
-    New jax (>= 0.6): ``jax.shard_map(..., axis_names=..., check_vma=False)``.
-    Old jax (0.4.x, this container): ``jax.experimental.shard_map.shard_map``
-    with the complementary ``auto=`` set and ``check_rep=False`` (the same
-    "don't prove replication" escape hatch ``check_vma=False`` became).
-    ``axis_names`` is the set of MANUAL axes; None means all of them.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        if axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        return jax.shard_map(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    manual = frozenset(mesh.axis_names) if axis_names is None else frozenset(axis_names)
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False, auto=frozenset(mesh.axis_names) - manual,
-    )
+    """``jax.shard_map`` with ``check_vma=False`` (the "don't prove
+    replication" escape hatch every caller here needs). ``axis_names`` is
+    the set of MANUAL axes; None means all of them."""
+    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                  check_vma=False)
+    if axis_names is not None:
+        kwargs["axis_names"] = axis_names
+    return jax.shard_map(f, **kwargs)
 
 
 def current_mesh() -> Optional[Mesh]:

@@ -324,11 +324,21 @@ def backend_for(
             "(the KV slot scatter is not dp/sp-aware yet); use --tp N or "
             "drop --mesh"
         )
+    import dataclasses as _dc
+
+    if use_serving:
+        # A KV-slot row holds the prompt bucket plus the decode cap, so the
+        # cap is bounded by what requests can ask for (--max-new-tokens
+        # clamps every model's max_tokens) and by half the model's positions
+        # — the scheduler refuses a cap that leaves no prompt budget, which
+        # would fail every row of a small model's sweep on the DEFAULT cap.
+        cap = min(serving.max_new_tokens, config.max_new_tokens,
+                  model_config.max_seq_len // 2)
+        if cap != serving.max_new_tokens:
+            serving = _dc.replace(serving, max_new_tokens=cap)
     if getattr(config, "weight_quant", None) is not None:
         # Explicit override in EITHER direction: "int8" quantizes a float
         # config, "none" forces float serving for e.g. llama3-70b-int8.
-        import dataclasses as _dc
-
         model_config = _dc.replace(model_config, weight_quant=config.weight_quant)
     mesh = None
     if config.mesh.num_devices > 1:

@@ -72,9 +72,13 @@ def decode_sweep(
     entries already present in ``done`` (resume path).
     """
     from fairness_llm_tpu.pipeline.backends import shared_prefix_ids
-    from fairness_llm_tpu.utils import with_failure_containment
+    from fairness_llm_tpu.utils import (
+        require_some_decoded,
+        with_failure_containment,
+    )
 
     generate = with_failure_containment(backend.generate)
+    attempted: List[Optional[str]] = []  # this call's texts (None = failed)
     # Prefix-cache key computed over the FULL sweep (not per chunk), so
     # resumed and uninterrupted runs split attention identically.
     prefix_ids = shared_prefix_ids(backend, list(prompts))
@@ -107,6 +111,7 @@ def decode_sweep(
             keys=[k for k, _ in batch],
             prefix_ids=prefix_ids,
         )
+        attempted.extend(texts)
         mon = get_fairness_monitor()
         for (k, _), text in zip(batch, texts):
             if text is None:  # contained decode failure — see utils/failures.py
@@ -141,6 +146,9 @@ def decode_sweep(
         # A resume whose tail chunks were all cached leaves the bar mid-line;
         # finish it so subsequent stderr output starts on a fresh line.
         print_progress(len(keys), len(keys), prefix=f"{phase} ")
+    # Containment is per row; a sweep that lost every row it attempted is a
+    # failed run, not a study over empty lists (utils/failures.SweepFailed).
+    require_some_decoded(attempted, f"{phase} sweep")
     mon = get_fairness_monitor()
     if mon.active:
         # Backfill entries the stream never saw (a resume's cached

@@ -38,6 +38,7 @@ from fairness_llm_tpu.pipeline.parsing import (
     parse_ranking_indices_with_count,
 )
 from fairness_llm_tpu.pipeline.prompts import listwise_prompt, pairwise_prompt
+from fairness_llm_tpu.utils.failures import require_some_decoded
 
 logger = logging.getLogger(__name__)
 
@@ -113,9 +114,12 @@ def listwise_evaluation_batch(
     prompts = [listwise_prompt(items, query=q) for q in queries]
     keys = [f"listwise::{q}" for q in queries]
     texts = backend.generate(prompts, settings, seed=seed, keys=keys)
+    require_some_decoded(texts, "phase2 listwise sweep")
     rankings, parsed_counts = [], []
     for text in texts:
-        order, parsed = parse_ranking_indices_with_count(text, len(items))
+        # A serving backend returns None for a request that failed; it counts
+        # as a query with nothing parsed (reported in parse_failures).
+        order, parsed = parse_ranking_indices_with_count(text or "", len(items))
         rankings.append([items[i].id for i in order])
         parsed_counts.append(parsed)
     return rankings, parsed_counts
@@ -166,11 +170,13 @@ def pairwise_evaluation(
     pairs = [tuple(rng.choice(n, size=2, replace=False)) for _ in range(num_comparisons)]
     prompts = [pairwise_prompt(items[a], items[b]) for a, b in pairs]
     texts = backend.generate(prompts, settings, seed=seed)
+    require_some_decoded(texts, "phase2 pairwise sweep")
 
     comparisons = []
     wins: Dict[int, int] = {}
     for (a, b), text in zip(pairs, texts):
-        winner, parsed = parse_pairwise_answer_full(text)
+        # None = a failed serving request: an unparsed comparison.
+        winner, parsed = parse_pairwise_answer_full(text or "")
         comparisons.append(
             {
                 "item_a": items[a].id,
@@ -387,7 +393,6 @@ def run_phase2(
     catalog = [it.text for it in items]
 
     model_results = {}
-    known_settings = {n for n, _ in config.model_settings}
     groups = [it.protected_attribute for it in items]
     for name in models:
         backend = (backends or {}).get(name) or backend_for(
@@ -395,7 +400,10 @@ def run_phase2(
         )
         # Injected test doubles may carry names outside the settings table;
         # they take engine defaults, like the simulated backend.
-        settings = config.settings_for(name) if name in known_settings else None
+        try:
+            settings = config.settings_for(name)
+        except KeyError:
+            settings = None
         logger.info(
             "phase2: evaluating %s (%s corpus, %d items, %d listwise queries)",
             name, corpus, len(items), num_queries,

@@ -213,8 +213,8 @@ class DecodeEngine:
             )
         # The resolved STORAGE width. Note for byte accounting: the decode
         # loop streams params at the COMPUTE width regardless (XLA hoists the
-        # storage->compute cast out of the loop — see docs/PERFORMANCE.md
-        # round 3), so roofline models should use config.dtype, not this.
+        # storage->compute cast out of the loop, per an earlier device trace;
+        # ROADMAP.md S1), so roofline models should use config.dtype, not this.
         self.param_itemsize = 2 if param_dtype == jnp.bfloat16 else 4
         if self.mesh is not None:
             pb = shd.per_device_param_bytes(
@@ -294,10 +294,12 @@ class DecodeEngine:
         """Sequence bucket multiple: 128 only when this model can actually take
         the Pallas flash path (head_dim tiling + TPU); otherwise 64 to halve
         padding. Shared by decode prefill and scoring so both stay eligible."""
+        from fairness_llm_tpu.ops.quant_matmul import lowering_for_tpu
+
         flash_eligible = (
             self.config.use_flash_attention
             and self.config.head_dim % 64 == 0
-            and jax.default_backend() == "tpu"
+            and lowering_for_tpu()
         )
         return 128 if flash_eligible else 64
 

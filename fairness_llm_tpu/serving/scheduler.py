@@ -519,11 +519,9 @@ class ContinuousScheduler:
 
     def _donate(self):
         # Donate the cache + carried logits so each decode chunk updates
-        # in-place instead of copying the whole pool per call (jax >= 0.4.26
-        # implements donation on CPU too; measured ~4 ms/call of pure
-        # memcpy saved for the tiny-gpt2-study pool). The decode failure
-        # path must then REBUILD device state, which _decode's except
-        # branch does.
+        # in-place instead of copying the whole pool per call (donation is
+        # implemented on CPU too). The decode failure path must then
+        # REBUILD device state, which _decode's except branch does.
         return (1, 2)
 
     def _guard(self) -> bool:

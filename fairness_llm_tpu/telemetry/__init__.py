@@ -102,9 +102,10 @@ from fairness_llm_tpu.telemetry.fairness import (
     use_fairness_monitor,
 )
 from fairness_llm_tpu.telemetry.roofline import (
+    DEVICE_PEAKS,
     decode_step_bytes,
+    device_peaks,
     observe_decode,
-    reference_achievable_gbps,
     set_achievable_gbps,
 )
 from fairness_llm_tpu.telemetry.slo import (
@@ -270,7 +271,8 @@ __all__ = [
     "render_fairness_report",
     "decode_step_bytes",
     "observe_decode",
-    "reference_achievable_gbps",
+    "DEVICE_PEAKS",
+    "device_peaks",
     "set_achievable_gbps",
     "SLOEvaluator",
     "SLOTargets",

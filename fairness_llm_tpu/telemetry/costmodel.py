@@ -311,7 +311,7 @@ _SUBJAXPR_SCAN_KEYS = ("jaxpr", "call_jaxpr", "fun_jaxpr")
 
 
 def _walk(jaxpr, ledger: CostLedger, scope: str, repeat: int = 1) -> None:
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def inner(sub, sub_scope: str, sub_repeat: int = 1) -> None:
         if isinstance(sub, ClosedJaxpr):
@@ -413,14 +413,12 @@ def tp_collective_costs(model_config, tp: int, rows: int, tokens: int = 1,
 
 
 # -- reference rates -----------------------------------------------------------
-# Companions of roofline.reference_achievable_gbps: a compute roofline and a
-# nominal per-dispatch host overhead, so min-times and the dispatch term are
-# defined on any backend. Off-TPU figures are INDICATIVE, like the roofline's
-# CPU_NOMINAL_GBPS — the live decomposition's measured terms (wall, host
-# gap) are exact either way.
+# The compute roofline comes from the same per-device table as the streaming
+# one (``roofline.DEVICE_PEAKS``): a device without a row has no floor, and
+# the gauges that need one are not published there. The per-dispatch host
+# overhead is a nominal estimate (the report labels it so), not a peak; the
+# decomposition's measured terms (wall, host gap) are exact either way.
 
-V5E_BF16_GFLOPS = 197_000.0  # v5e spec peak bf16
-CPU_NOMINAL_GFLOPS = 100.0  # nominal multi-threaded XLA-CPU figure
 TPU_DISPATCH_S = 5e-5
 CPU_DISPATCH_S = 2e-4
 
@@ -438,23 +436,28 @@ def set_dispatch_s(seconds: Optional[float]) -> None:
     _dispatch_override = float(seconds) if seconds else None
 
 
-def _backend() -> str:
-    try:
-        return jax.default_backend()
-    except Exception:  # noqa: BLE001 — no backend, assume host
-        return "cpu"
+def _publishable_rates() -> Optional[Tuple[float, float]]:
+    """(GB/s, GFLOP/s) the floor is priced at, or None when the device has
+    no published peaks and no override covers the gap."""
+    from fairness_llm_tpu.telemetry.roofline import (
+        known_device_peaks,
+        publishable_gbps,
+    )
 
-
-def reference_achievable_gflops() -> float:
-    if _gflops_override is not None:
-        return _gflops_override
-    return V5E_BF16_GFLOPS if _backend() == "tpu" else CPU_NOMINAL_GFLOPS
+    gbps = publishable_gbps()
+    gflops = _gflops_override
+    if gflops is None:
+        peaks = known_device_peaks()
+        gflops = peaks.bf16_gflops if peaks is not None else None
+    if gbps is None or gflops is None:
+        return None
+    return gbps, gflops
 
 
 def reference_dispatch_s() -> float:
     if _dispatch_override is not None:
         return _dispatch_override
-    return TPU_DISPATCH_S if _backend() == "tpu" else CPU_DISPATCH_S
+    return TPU_DISPATCH_S if jax.default_backend() == "tpu" else CPU_DISPATCH_S
 
 
 # -- publication ---------------------------------------------------------------
@@ -505,23 +508,19 @@ def note_invocation(program: str, wall_s: float, steps: int = 0,
         # negative; compile dominates in practice.
         reg.gauge("cost_compile_s_total", **lbl).add(
             max(float(wall_s), 0.0))
-    gbps = _roofline_gbps()
-    gflops = reference_achievable_gflops()
-    reg.gauge("cost_reference_gbps", component="costmodel").set(gbps)
-    reg.gauge("cost_reference_gflops", component="costmodel").set(gflops)
     reg.gauge("cost_dispatch_s", component="costmodel").set(
         reference_dispatch_s())
+    rates = _publishable_rates()
+    if rates is None:
+        return  # no peaks for this device: no reference gauges, no floor
+    gbps, gflops = rates
+    reg.gauge("cost_reference_gbps", component="costmodel").set(gbps)
+    reg.gauge("cost_reference_gflops", component="costmodel").set(gflops)
     if ledger is not None:
         for comp, sec in ledger.min_times_s(
                 steps, gbps * 1e9, gflops * 1e9).items():
             reg.gauge("cost_component_min_s_total", program=program,
                       component=comp).add(sec)
-
-
-def _roofline_gbps() -> float:
-    from fairness_llm_tpu.telemetry.roofline import reference_achievable_gbps
-
-    return reference_achievable_gbps()
 
 
 # -- the compile-time hook -----------------------------------------------------
@@ -727,11 +726,18 @@ def render_cost_report(snap: Dict, width: int = 78) -> str:
                 return float(g.get("value", 0.0))
         return 0.0
 
-    lines.append(
-        f"references: {ref('cost_reference_gbps'):g} GB/s streaming, "
-        f"{ref('cost_reference_gflops'):g} GFLOP/s, "
-        f"{ref('cost_dispatch_s') * 1e6:g} us/dispatch (nominal)"
-    )
+    if ref("cost_reference_gbps"):
+        lines.append(
+            f"references: {ref('cost_reference_gbps'):g} GB/s streaming, "
+            f"{ref('cost_reference_gflops'):g} GFLOP/s, "
+            f"{ref('cost_dispatch_s') * 1e6:g} us/dispatch (nominal)"
+        )
+    else:
+        lines.append(
+            "references: none (the device has no published peaks, so no "
+            f"floor is priced); {ref('cost_dispatch_s') * 1e6:g} "
+            "us/dispatch (nominal)"
+        )
     for program, d in decomp.items():
         lines.append(f"\n[{program}]  calls={d['calls']:g} "
                      f"steps={d['steps']:g}"

@@ -34,7 +34,9 @@ Three layers, one module:
   ``compiled.memory_analysis()`` once per compiled program — the
   temp/argument/output/peak bytes XLA itself budgeted — as
   ``program_memory_bytes{program, kind}`` gauges, for every program label
-  in ``compiles_total`` including ``*_fused`` and ``@tpN``. This turns the
+  in ``compiles_total`` including ``*_fused`` and ``@tpN``, and counts the
+  Pallas custom calls and all-reduces in the compiled module as
+  ``program_hlo_ops{program, op}``. This turns the
   70B fit-proof tooling's static math (tools/prove_70b_int8_fit.py) into a
   live instrument. The capture pays a second XLA compile per program, so
   it arms with the exporters (``telemetry.configure``) or explicitly
@@ -60,6 +62,7 @@ See docs/OBSERVABILITY.md §Memory signals.
 from __future__ import annotations
 
 import logging
+import re
 from typing import Dict, List, Optional, Tuple
 
 from fairness_llm_tpu.telemetry.registry import get_registry
@@ -472,6 +475,40 @@ def publish_program_memory(program: str, argument: int, output: int,
                   program=program, kind=kind).set(max(int(val), 0))
 
 
+_ALL_REDUCE = re.compile(r"\ball-reduce(-start)?\(")
+
+
+def count_hlo_ops(hlo_text: str) -> Dict[str, int]:
+    """What a compiled module holds of the ops a run has to prove: the two
+    Pallas kernels, found by the ``name=`` their ``pallas_call`` carries into
+    the Mosaic custom call's op_name (ops/quant_matmul.py,
+    ops/flash_attention.py), and the all-reduces GSPMD inserts after
+    partitioning (invisible to the jaxpr walk)."""
+    counts = {"int8_matmul": 0, "flash_attention": 0, "all-reduce": 0}
+    for line in hlo_text.splitlines():
+        if "tpu_custom_call" in line:
+            for kernel in ("int8_matmul", "flash_attention"):
+                if f"{kernel}/pallas_call" in line:
+                    counts[kernel] += 1
+        elif "all-reduce" in line and _ALL_REDUCE.search(line):
+            counts["all-reduce"] += 1
+    return counts
+
+
+def publish_program_hlo_ops(program: str, hlo_text: str) -> None:
+    """``program_hlo_ops{program, op}`` gauges from :func:`count_hlo_ops` —
+    the witness that a kernel was not replaced by an XLA expression and that
+    a sharded program communicates. A program label compiled at several
+    shapes keeps the FEWEST of each op seen, so one shape that lost a kernel
+    cannot hide behind another."""
+    reg = get_registry()
+    for op, n in count_hlo_ops(hlo_text).items():
+        labels = dict(component="memory", program=program, op=op)
+        prev = reg.peek("program_hlo_ops", **labels)
+        reg.gauge("program_hlo_ops", **labels).set(
+            n if prev is None else min(prev.value, n))
+
+
 def capture_program_memory(jit_fn, pyfn, program: str, args) -> bool:
     """AOT-compile ``jit_fn`` at ``args``' shapes and publish what XLA
     budgeted for it. Called by ``InstrumentedJit`` once per program on its
@@ -483,7 +520,9 @@ def capture_program_memory(jit_fn, pyfn, program: str, args) -> bool:
     import jax
 
     lowered = jit_fn.lower(*args)
-    ma = lowered.compile().memory_analysis()
+    compiled = lowered.compile()
+    publish_program_hlo_ops(program, compiled.as_text())
+    ma = compiled.memory_analysis()
     if ma is not None and hasattr(ma, "temp_size_in_bytes"):
         publish_program_memory(
             program,

@@ -4,8 +4,8 @@ The decode step is memory-bound: its floor is (HBM bytes the step must
 stream) / (bandwidth the chip can actually sustain). That model existed
 only offline — ``bench.py`` computed ``decode_step_bytes`` per bench round
 and ``tools/account_decode_step.py`` classified a captured device trace —
-so the BENCH_r03-r05 headline (``achieved_over_achievable`` stuck at
-0.4-0.5) could not be watched during a run, per replica, per program. This
+so the bench's headline fraction (``achieved_over_achievable``) could not
+be watched during a run, per replica, per program. This
 module folds the same byte model into live gauges fed per decode chunk:
 
 - ``decode_step_bytes{program, ...}`` — HBM bytes one step of this compiled
@@ -14,53 +14,86 @@ module folds the same byte model into live gauges fed per decode chunk:
 - ``achieved_hbm_gbps{program, ...}`` — bytes * steps / wall for the last
   chunk, plus an ``achieved_hbm_gbps_dist`` histogram of the same;
 - ``achieved_over_achievable{program, ...}`` — the headline fraction
-  against this platform's reference streaming bandwidth.
+  against this device's reference streaming bandwidth (published only on a
+  device that has one).
 
-The reference bandwidth is the v5e spec roofline (819 GB/s) on TPU; off-TPU
-(the CPU test harness) a nominal DDR-class figure keeps the fraction
-defined — INDICATIVE only, the real gate stays the bench's in-run measured
-``achievable_gbps`` (``bench.measure_achievable_gbps``). Override with
-``set_achievable_gbps`` (``TelemetryConfig.achievable_gbps``) when a
-measured figure exists.
+The reference bandwidth comes from ``DEVICE_PEAKS``, the one table of
+published per-chip peaks keyed by ``device_kind``. A device that is not in
+the table has no reference: asking for one raises, and the fraction gauge is
+simply not published there (a CPU run never writes a device-rate fraction).
+Override with ``set_achievable_gbps`` (``TelemetryConfig.achievable_gbps``)
+when a measured figure exists.
 
 Gated, like the whole attribution layer, on ``timeline.attribution_on()``.
 """
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, Optional
+
+import jax
 
 from fairness_llm_tpu.telemetry.registry import get_registry
 from fairness_llm_tpu.telemetry.timeline import attribution_on
 
-V5E_HBM_GBPS = 819.0  # v5e spec HBM bandwidth — the TPU roofline reference
-# Off-TPU fallback so achieved_over_achievable stays defined on the CPU
-# harness: a nominal DDR4-class streaming figure. Indicative only.
-CPU_NOMINAL_GBPS = 16.0
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    hbm_gbps: float  # HBM streaming bandwidth, GB/s
+    bf16_gflops: float  # dense bf16 matmul peak, GFLOP/s
+
+
+# Published peaks of ONE chip, keyed by ``jax.devices()[0].device_kind``.
+# Every roofline/cost-model reference in the repo reads this table; a device
+# that is not listed is an error where a peak is asked for, never a default.
+DEVICE_PEAKS: Dict[str, DevicePeaks] = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 per chip,
+    # 16 GB HBM2e at 819 GB/s.
+    "TPU v5 lite": DevicePeaks(hbm_gbps=819.0, bf16_gflops=197_000.0),
+}
 
 _achievable_override: Optional[float] = None
 
 
+def known_device_peaks() -> Optional[DevicePeaks]:
+    """The running device's row of ``DEVICE_PEAKS``, or None when it has
+    none — the publishers' question ("is there a peak to divide by?")."""
+    return DEVICE_PEAKS.get(jax.devices()[0].device_kind)
+
+
+def device_peaks(device_kind: Optional[str] = None) -> DevicePeaks:
+    """Published peaks of ``device_kind`` (default: the running device).
+    Raises ``LookupError`` for a device that is not in the table."""
+    if device_kind is None:
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise LookupError(
+            f"no published peaks for device_kind {device_kind!r} (known: "
+            f"{sorted(DEVICE_PEAKS)}); add it to telemetry/roofline.py "
+            "DEVICE_PEAKS with its source, or install a measured reference "
+            "(set_achievable_gbps / --achievable-gbps)"
+        ) from None
+
+
 def set_achievable_gbps(gbps: Optional[float]) -> None:
     """Install a measured achievable-bandwidth reference (None restores the
-    platform default). ``TelemetryConfig.achievable_gbps`` routes here."""
+    device's published peak). ``TelemetryConfig.achievable_gbps`` routes
+    here."""
     global _achievable_override
     _achievable_override = float(gbps) if gbps else None
 
 
-def reference_achievable_gbps() -> float:
+def publishable_gbps() -> Optional[float]:
     """The denominator of ``achieved_over_achievable``: the override when
-    installed, else the platform default (v5e spec on TPU, nominal DDR
-    figure elsewhere)."""
+    installed, else the running device's published HBM bandwidth, else None
+    — an unlisted device publishes nothing that needs a peak."""
     if _achievable_override is not None:
         return _achievable_override
-    try:
-        import jax
-
-        backend = jax.default_backend()
-    except Exception:  # noqa: BLE001 — no jax, no platform hint
-        backend = "cpu"
-    return V5E_HBM_GBPS if backend == "tpu" else CPU_NOMINAL_GBPS
+    peaks = known_device_peaks()
+    return peaks.hbm_gbps if peaks is not None else None
 
 
 def decode_step_bytes(config, stats) -> int:
@@ -138,8 +171,8 @@ def observe_decode(config, stats: Dict, steps: int, wall_s: float,
     lbl = labels or {}
     step_bytes = decode_step_bytes(config, stats)
     gbps = step_bytes * steps / wall_s / 1e9
-    achievable = reference_achievable_gbps()
-    frac = gbps / achievable if achievable > 0 else 0.0
+    achievable = publishable_gbps()
+    frac = gbps / achievable if achievable else None
     reg = get_registry()
     reg.gauge("decode_step_bytes", component="roofline", program=program,
               **lbl).set(step_bytes)
@@ -147,8 +180,9 @@ def observe_decode(config, stats: Dict, steps: int, wall_s: float,
               **lbl).set(gbps)
     reg.histogram("achieved_hbm_gbps_dist", component="roofline",
                   program=program, **lbl).observe(gbps)
-    reg.gauge("achieved_over_achievable", component="roofline",
-              program=program, **lbl).set(frac)
+    if frac is not None:
+        reg.gauge("achieved_over_achievable", component="roofline",
+                  program=program, **lbl).set(frac)
     # Flight-recorder roofline ring (telemetry/flightrecorder.py): the
     # last-K per-chunk samples — a bundle shows whether bandwidth was
     # degrading INTO the incident, which the last-write gauge cannot.
@@ -158,7 +192,8 @@ def observe_decode(config, stats: Dict, steps: int, wall_s: float,
 
     get_flight_recorder().record(
         "roofline", program=program, steps=steps,
-        gbps=round(gbps, 3), fraction=round(frac, 4),
+        gbps=round(gbps, 3),
+        fraction=(round(frac, 4) if frac is not None else None),
         replica=lbl.get("replica"),
     )
     return {"step_bytes": step_bytes, "gbps": gbps,

@@ -5,6 +5,8 @@ from fairness_llm_tpu.utils.failures import (
     DecodeFault,
     HangFault,
     ScriptedFaultInjector,
+    SweepFailed,
+    require_some_decoded,
     with_failure_containment,
 )
 from fairness_llm_tpu.utils.progress import print_progress
@@ -16,6 +18,8 @@ __all__ = [
     "DecodeFault",
     "HangFault",
     "ScriptedFaultInjector",
+    "SweepFailed",
+    "require_some_decoded",
     "with_failure_containment",
     "print_progress",
     "RateLimiter",

@@ -3,11 +3,13 @@
 The reference wraps every API call in try/except returning empty-result
 sentinels so one failure doesn't kill a 45-call sweep
 (``phase1_bias_detection.py:202-211``, SURVEY.md §5.3) — but it has no
-retries. Local decode fails differently (compile OOM, tunnel hiccups, bad
+retries. Local decode fails differently (compile OOM, a device fault, bad
 checkpoint), and a whole CHUNK fails at once; this wrapper retries a failed
 chunk once (fresh attempt covers transient device errors) and then degrades
 to per-prompt empty sentinels, keeping the sweep alive and the failure
-visible in the results.
+visible in the results. Per-row containment stops at the sweep: a sweep that
+lost EVERY row raises :class:`SweepFailed` (``require_some_decoded``), so a
+compile rejection or an OOM can never pass for a completed study.
 """
 
 from __future__ import annotations
@@ -56,6 +58,25 @@ class NumericsFault(DecodeFault):
     ``None`` sentinels, and the breakers see a persistent numeric sickness
     as consecutive failures. The distinct type keys the telemetry labels
     (``kind="numerics"``) and the ``numerics_faults_total`` breakdown."""
+
+
+class SweepFailed(RuntimeError):
+    """Every row of one decode sweep failed, so there is nothing to compute
+    a study from. Per-row containment (``None`` sentinels, ``decode_failed``
+    rows) keeps a sweep alive through SOME failures; a sweep that lost
+    everything — a program the compiler rejected, an out-of-memory device, a
+    misconfigured server — must stop the run instead of reporting metrics
+    over empty lists."""
+
+
+def require_some_decoded(texts: Sequence[Optional[str]], what: str) -> None:
+    """Raise :class:`SweepFailed` when every entry of a non-empty ``texts``
+    is the ``None`` failure sentinel."""
+    if texts and all(t is None for t in texts):
+        raise SweepFailed(
+            f"{what}: all {len(texts)} row(s) failed to decode (see the "
+            "contained-failure log lines above for the cause)"
+        )
 
 
 class ScriptedFaultInjector:

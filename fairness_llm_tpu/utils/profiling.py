@@ -8,7 +8,7 @@ when unset (no-op context).
 
 ``summarize_trace(trace_dir)`` aggregates a captured trace's device events
 per op WITHOUT TensorBoard — the terminal-friendly analysis that produced
-the round-3 decode-step breakdown (docs/PERFORMANCE.md): total device time,
+the decode-step breakdown (ROADMAP.md S1): total device time,
 event counts, and the top ops by accumulated duration.
 """
 

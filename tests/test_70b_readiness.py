@@ -28,17 +28,6 @@ from fairness_llm_tpu.parallel import sharding as shd
 
 V5E_HBM_BYTES = 16 * 1024**3
 
-# The v5e-topology AOT proofs need the jax generation they were recorded on:
-# under jax 0.4.x jaxlib the TPU-topology compile SIGABRTs the whole test
-# process (observed on 0.4.37 — a fatal Mosaic/PJRT crash, not a Python
-# error, so it cannot be caught in-test). CPU-mesh AOT compiles are fine.
-_JAX_VERSION = tuple(int(x) for x in jax.__version__.split(".")[:2])
-needs_tpu_aot = pytest.mark.skipif(
-    _JAX_VERSION < (0, 6),
-    reason="jax 0.4 jaxlib hard-crashes on TPU-topology AOT compiles",
-)
-
-
 def _rules_for_shape(cfg, shape):
     """make_axis_rules only reads mesh.shape — a shim lets us probe mesh
     geometries (tp=16) larger than the 8 virtual devices can realize."""
@@ -169,7 +158,6 @@ def test_70b_decode_kv_cache_estimate():
     assert got == 2 * 80 * 48 * 1024 * 1 * 128 * 2
 
 
-@needs_tpu_aot
 def test_8b_flash_prefill_compiles_sharded_on_v5e_topology():
     """tp=8 serving prefill with the FLASH kernel engaged, through the real
     v5e compiler: the round-4 shard_map dispatch is what makes a Pallas
@@ -236,7 +224,6 @@ def test_8b_flash_prefill_compiles_sharded_on_v5e_topology():
     assert compiled.memory_analysis() is not None
 
 
-@needs_tpu_aot
 def test_70b_int8_layer_compiles_on_v5e_topology():
     """The int8 fit proof's LOWERING, at suite speed: a 2-layer model with
     llama3-70b's exact per-layer dimensions, int8 weights, tp=8, compiled by

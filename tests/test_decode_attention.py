@@ -1,7 +1,7 @@
 """Oracle tests for the fused decode-attention kernel (interpret mode).
 
 The kernel (``ops/decode_attention.py``) is OFF by default — measured ~8%
-slower than XLA's fusions on the sweep (docs/PERFORMANCE.md round 3) — but
+slower than XLA's fusions on the sweep (ROADMAP.md D4) — but
 stays in the tree as oracle-verified groundwork for a head-major cache
 layout. These tests pin its semantics against a dense reference: GQA head
 mapping, partial validity masks, the shared-prefix joint softmax (including

@@ -3,7 +3,7 @@
 The capability under test is the round-4 headline: serving weights stored
 int8 in HBM with dequantization inside the Pallas matmul tile (the naive
 dequant-at-use gets hoisted out of decode loops by XLA and materializes the
-float tree — docs/PERFORMANCE.md round 3). The reference has no local
+float tree — ops/quant_matmul.py). The reference has no local
 weights at all (its models are remote APIs, SURVEY.md §0); parity here is
 against our own float path, which is golden/HF-parity tested elsewhere.
 """

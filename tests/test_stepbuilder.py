@@ -401,7 +401,7 @@ def test_fused_program_publishes_own_telemetry(engine):
             assert any(m.value >= 1 for m in rows("compiles_total"))
             assert rows("cost_ledger_bytes"), \
                 "fused program must publish its own ledger"
-            assert rows("achieved_over_achievable"), \
+            assert rows("achieved_hbm_gbps"), \
                 "fused program must publish its own roofline gauges"
             gaps = rows("cost_host_gap_s_total")
             assert gaps and gaps[0].value > 0, \
@@ -580,7 +580,7 @@ def test_tp2_sharded_telemetry_owns_mesh_labels(tp2_engine):
             coll = rows("cost_ledger_bytes", component="collectives")
             assert coll and sum(m.value for m in coll) > 0, \
                 "sharded program must ledger its collectives traffic"
-            assert rows("achieved_over_achievable"), \
+            assert rows("achieved_hbm_gbps"), \
                 "sharded program must publish its own roofline gauges"
             # Nothing leaked into the unsharded label.
             assert not [m for m in reg.instruments()

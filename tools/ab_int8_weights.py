@@ -57,6 +57,9 @@ def run(model_name: str = "llama32-1b", reps: int = 3) -> dict:
 
 
 if __name__ == "__main__":
+    from fairness_llm_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
     name = sys.argv[1] if len(sys.argv) > 1 else "llama32-1b"
     reps = int(sys.argv[2]) if len(sys.argv) > 2 else 3
     print(json.dumps(run(name, reps)))

@@ -3,7 +3,7 @@
 The round-3 bf16 kernel lost to XLA's fusions (~8% at batch 48); the int8
 variant is the one kernel target with a byte-reduction story — at batch
 192/360 decode is KV-bound and int8-KV already wins +24% through plain XLA
-despite its dequant cost (docs/PERFORMANCE.md). This measures whether
+despite its dequant cost (ROADMAP.md S1). This measures whether
 dequant-in-tile beats XLA's fused dequant at the shapes that matter.
 
     python tools/ab_int8kv_kernel.py [model] [mults...]   # default gpt2-small 4 8
@@ -65,6 +65,9 @@ def run(model_name: str = "gpt2-small", mults=(4, 8)) -> dict:
 
 
 if __name__ == "__main__":
+    from fairness_llm_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
     name = sys.argv[1] if len(sys.argv) > 1 else "gpt2-small"
     mults = [int(a) for a in sys.argv[2:]] or [4, 8]
     print(json.dumps(run(name, mults)))

@@ -93,6 +93,9 @@ def run(model_name: str = "gpt2-small") -> dict:
 
 
 if __name__ == "__main__":
+    from fairness_llm_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
     model = sys.argv[1] if len(sys.argv) > 1 else "gpt2-small"
     res = run(model)
     if len(sys.argv) > 2:

@@ -374,4 +374,7 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from fairness_llm_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
     sys.exit(main())

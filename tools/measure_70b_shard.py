@@ -96,6 +96,9 @@ def run(batch: int = 8, new_tokens: int = 32) -> dict:
 
 
 if __name__ == "__main__":
+    from fairness_llm_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
     batch = int(sys.argv[1]) if len(sys.argv) > 1 else 8
     new = int(sys.argv[2]) if len(sys.argv) > 2 else 32
     print(json.dumps(run(batch, new)))

@@ -154,6 +154,9 @@ def run(num_items: int = 60, num_queries: int = 4, num_comparisons: int = 200,
 
 
 if __name__ == "__main__":
+    from fairness_llm_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
     logging.basicConfig(level=logging.INFO)
     quick = "--quick" in sys.argv
     res = run(

@@ -6,8 +6,11 @@ Measures what the emulated 70B shard (``tools/measure_70b_shard.py``, the
 ``llama70b_shard`` entry) deliberately omits: decode over an ACTUAL tp mesh
 with the collectives executed — every step program lowered as one SPMD
 computation, params + slot KV cache + carried logits sharded, XLA-inserted
-all-reduces on the wire. On the CPU harness the mesh is real too
-(``--xla_force_host_platform_device_count``), so this runs in CI.
+all-reduces on the wire. This worker is the CPU-mesh harness of that path
+and nothing else: it pins itself to the CPU platform with ``--tp`` forced
+host devices (``--xla_force_host_platform_device_count``), so it runs in CI,
+never touches a chip its parent may hold, and its record names the platform
+it ran on. The same path on real chips is ``chip_smoke.py --tp N``.
 
 Must be a subprocess of bench.py / CI, never imported into a live jax
 process: the forced host device count only takes effect when set BEFORE
@@ -42,7 +45,7 @@ if len(jax_flags := os.environ.get("XLA_FLAGS", "")) == 0 or \
         "host_platform_device_count" not in jax_flags:
     os.environ["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={args.tp} " + jax_flags)
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax  # noqa: E402
@@ -79,6 +82,8 @@ def run(tp: int, model_name: str, reps: int) -> dict:
             f"device count {jax.device_count()} not divisible by tp={tp}")
     cfg = get_model_config(model_name)
     out: dict = {"tp": tp, "model": model_name,
+                 "platform": jax.devices()[0].platform,
+                 "device_kind": jax.devices()[0].device_kind,
                  "devices": jax.device_count()}
 
     # Single-device greedy reference: the parity oracle AND the speed
@@ -185,5 +190,8 @@ def run(tp: int, model_name: str, reps: int) -> dict:
 
 
 if __name__ == "__main__":
+    from fairness_llm_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
     rec = run(args.tp, args.model, args.reps)
     print(json.dumps(rec))

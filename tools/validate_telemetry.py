@@ -27,7 +27,7 @@ terminal Result), and ``fleet_healthy_replicas`` back to
 ``fleet_replicas`` (the killed replica rejoined via its canary probe).
 ``--require-profile`` requires the performance-attribution signals
 (ISSUE 7): nonzero compile events (``compiles_total``), a populated
-``achieved_over_achievable`` roofline gauge, a nonzero ``step_gap_s``
+``achieved_hbm_gbps`` roofline gauge, a nonzero ``step_gap_s``
 histogram, and a schema-valid ``trace.json`` beside the snapshot
 containing prefill + decode spans and request lanes.
 ``--require-overload`` requires the overload-control signals the brownout
@@ -363,10 +363,15 @@ def _check_costmodel(snap: dict) -> list:
     if not any(v > 0 for v in walls.values()):
         problems.append("no nonzero cost_wall_s_total gauge (gap "
                         "attribution has no measured wall to decompose)")
+    # The floor is priced against the device's published peaks
+    # (telemetry/roofline.py DEVICE_PEAKS); a run on a device without any
+    # publishes no reference gauges and owes no floor.
+    priced = any(g.get("name") == "cost_reference_gbps"
+                 for g in snap.get("gauges", []))
     floors = [g for g in snap.get("gauges", [])
               if g.get("name") == "cost_component_min_s_total"
               and g.get("value", 0.0) > 0]
-    if not floors:
+    if priced and not floors:
         problems.append("cost_component_min_s_total is empty (no invocation "
                         "ever folded its ledger into the floor)")
     # Fused dispatch programs (ISSUE 14, runtime/stepbuilder.py): a
@@ -761,13 +766,16 @@ def _check_profile(path: str, snap: dict) -> list:
     if not sum(c["value"] for c in snap.get("counters", [])
                if c.get("name") == "compiles_total"):
         problems.append("compiles_total is zero (no compile event recorded)")
+    # achieved_hbm_gbps needs no peak, so every device publishes it; the
+    # achieved_over_achievable fraction exists only where the device has a
+    # published peak (or --achievable-gbps installed one).
     aoa = [g for g in snap.get("gauges", [])
-           if g.get("name") == "achieved_over_achievable"]
+           if g.get("name") == "achieved_hbm_gbps"]
     if not aoa:
-        problems.append("no achieved_over_achievable gauge (roofline "
-                        "accounting never ran)")
+        problems.append("no achieved_hbm_gbps gauge (roofline accounting "
+                        "never ran)")
     elif not any(g["value"] > 0 for g in aoa):
-        problems.append("achieved_over_achievable is zero everywhere")
+        problems.append("achieved_hbm_gbps is zero everywhere")
     gaps = [h for h in snap.get("histograms", [])
             if h.get("name") == "step_gap_s"]
     if not any(h.get("count") for h in gaps):
@@ -789,7 +797,7 @@ def _check_profile(path: str, snap: dict) -> list:
                    and g["value"] > 0 for g in aoa):
             problems.append(
                 f"fused program {prog!r} has no nonzero "
-                "achieved_over_achievable gauge (fused chunks must feed "
+                "achieved_hbm_gbps gauge (fused chunks must feed "
                 "the roofline under their own label)"
             )
     trace_dir = path if os.path.isdir(path) else os.path.dirname(path)
