@@ -57,7 +57,7 @@ from fairness_llm_tpu.runtime.stepbuilder import (
     compile_key,
 )
 from fairness_llm_tpu.telemetry import get_registry
-from fairness_llm_tpu.telemetry.compilestats import note_lookup, record_compile
+from fairness_llm_tpu.telemetry.compilestats import record_compile
 from fairness_llm_tpu.telemetry.costmodel import instrument_jit, note_invocation
 from fairness_llm_tpu.telemetry.roofline import observe_decode
 from fairness_llm_tpu.telemetry.timeline import get_timeline
@@ -311,7 +311,6 @@ class DecodeEngine:
         A ``stepbuilder`` composition, like every compiled program here."""
         key = compile_key("prefix", prefix_len=prefix_len)
         fn = self._compiled.get(key)
-        note_lookup("prefix", hit=fn is not None)
         if fn is not None:
             return fn
         fn = instrument_jit(
@@ -336,7 +335,6 @@ class DecodeEngine:
                           max_new=max_new, sampler=sampler_settings,
                           prefix_len=prefix_len, guard=guard)
         fn = self._compiled.get(key)
-        note_lookup("decode", hit=fn is not None)
         if fn is not None:
             return fn
         # The plain program is the builder's batch entry + the SHARED greedy
@@ -382,7 +380,6 @@ class DecodeEngine:
                           guard=guard, ngram_max=spec.ngram_max,
                           draft_len=spec.draft_len)
         fn = self._compiled.get(key)
-        note_lookup("spec_decode", hit=fn is not None)
         if fn is not None:
             return fn
         run = build_spec_decode(
@@ -843,7 +840,7 @@ class DecodeEngine:
         for key in set(self._compiled) - keys_before:
             if key[0] != "prefix_kv":  # cached KV arrays, not a program
                 record_compile(key[0], reason="shape", seconds=wall_mono,
-                               track="engine", key=key, t0=t0_mono)
+                               key=key)
         if use_spec:
             steps_done = spec_stats.verify_steps
         else:

@@ -53,6 +53,12 @@ last element of ``decode`` keys and sits mid-key on ``spec_decode`` keys
 fused dispatch each compile their own program and restoring reuses the
 original.
 
+Every builder names its closure before ``jax.jit`` sees it (the program's
+label plus its bucket: ``serve_step``, ``serve_prefill_8x256``,
+``paged_step_fused``, ``decode_4x64``), so the executable reads
+``jit_serve_step(...)`` on the device's ``XLA Modules`` line of a profiler
+trace and in JAX's compile logs, and not ``jit_run`` for all of them.
+
 Behavioral contract: every composition is token-for-token identical to the
 hand-threaded program it replaced — the whole pre-existing parity/golden/
 chaos test surface is the regression net, plus the dedicated harness in
@@ -295,6 +301,7 @@ def build_engine_decode(cfg, model, sampler: SamplerSettings, pad_id: int,
             return c[5], c[7]  # toks [B, max_new], finite
         return c[5]
 
+    run.__name__ = f"decode_{batch}x{prompt_len}"
     return run
 
 
@@ -456,6 +463,7 @@ def build_spec_decode(cfg, model, pad_id: int, eos_id: int, *, batch: int,
         )
         return gen[:, :max_new], out_len, counters
 
+    run.__name__ = f"spec_decode_{batch}x{prompt_len}"
     return run
 
 
@@ -485,6 +493,7 @@ def build_prefix(cfg, model, *, prefix_len: int):
                 out.append((layer.k[0], layer.v[0]))
         return tuple(out)
 
+    run.__name__ = f"prefix_{prefix_len}"
     return run
 
 
@@ -538,6 +547,7 @@ def build_serve_step(cfg, model, sampler: SamplerSettings, pad_id: int,
                 return arena, c[2], c[5], c[4], c[6], c[7]
             return arena, c[2], c[5], c[4], c[6]
 
+        run.__name__ = program_label("paged_step", fuse)
         return run
 
     def run(params, cache, prev_logits, row_seeds, emitted0, base, caps,
@@ -556,6 +566,7 @@ def build_serve_step(cfg, model, sampler: SamplerSettings, pad_id: int,
             return c[1], c[2], c[5], c[4], c[6], c[7]
         return c[1], c[2], c[5], c[4], c[6]
 
+    run.__name__ = program_label("serve_step", fuse)
     return run
 
 
@@ -618,6 +629,7 @@ def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
             )
         return new_cache, new_logits
 
+    run.__name__ = f"serve_prefill_{nb}x{P}"
     return run
 
 
@@ -694,4 +706,5 @@ def build_paged_prefill(model, *, nb: int, S: int, guard: bool,
             )
         return arena, new_logits
 
+    run.__name__ = f"paged_prefill_{nb}x{S}"
     return run

@@ -58,6 +58,7 @@ from fairness_llm_tpu.resilience.drain import ServingJournal
 from fairness_llm_tpu.serving.fleet import ReplicaSet
 from fairness_llm_tpu.serving.request import Request
 from fairness_llm_tpu.serving.scheduler import ContinuousScheduler
+from fairness_llm_tpu.telemetry.timeline import get_timeline
 from fairness_llm_tpu.telemetry import get_registry
 
 logger = logging.getLogger(__name__)
@@ -303,6 +304,29 @@ class ServingBackend:
             return list(out.texts)
         sched = self.scheduler_for(settings)
         self._maybe_canary(sched)
+        tl, track = get_timeline(), getattr(sched, "_track", "serving")
+        with tl.span("backend.submit", "backend", track, stage="build",
+                     rows=len(prompts)):
+            requests = self._build_requests(prompts, settings, seed, keys)
+        results = sched.serve(requests)
+        stats = sched.last_stats
+        if stats is not None:
+            self.serve_totals = (
+                stats if self.serve_totals is None
+                else self.serve_totals.merge(stats)
+            )
+        with tl.span("backend.collect", "backend", track,
+                     rows=len(results)):
+            self.last_output = self._collect(results, sched, stats)
+        # None (not "") for failed rows — the decode_sweep/failure-containment
+        # sentinel convention, so resumes retry them.
+        return [r.text if r.ok else None for r in results]
+
+    def _build_requests(self, prompts, settings: ModelSettings, seed: int,
+                        keys) -> List[Request]:
+        """One ``Request`` per prompt, with its stable id and row seed."""
+        from fairness_llm_tpu.pipeline.backends import _stable_hash
+
         # Study tags (telemetry/fairness.py): a phase that registered its
         # profile grid with the fairness monitor gets its sweep requests
         # stamped with (attribute, group, pair_id), so the serving layer's
@@ -330,19 +354,18 @@ class ServingBackend:
                 group=tags[1] if tags else None,
                 pair_id=tags[2] if tags else None,
             ))
-        results = sched.serve(requests)
-        stats = sched.last_stats
-        if stats is not None:
-            self.serve_totals = (
-                stats if self.serve_totals is None
-                else self.serve_totals.merge(stats)
-            )
+        return requests
+
+    def _collect(self, results, sched, stats):
+        """The call's results as the token matrix ``last_output`` holds."""
+        from fairness_llm_tpu.runtime.engine import GenerateOutput
+
         cap = max((len(r.tokens) for r in results), default=0)
         toks = np.full((len(results), cap), self.engine.tokenizer.pad_id,
                        np.int32)
         for i, r in enumerate(results):
             toks[i, : len(r.tokens)] = r.tokens
-        self.last_output = GenerateOutput(
+        return GenerateOutput(
             texts=[r.text if r.ok else "" for r in results],
             tokens=toks,
             steps=sched.serving.max_new_tokens,
@@ -357,6 +380,3 @@ class ServingBackend:
                 "serving": stats.as_dict() if stats is not None else None,
             },
         )
-        # None (not "") for failed rows — the decode_sweep/failure-containment
-        # sentinel convention, so resumes retry them.
-        return [r.text if r.ok else None for r in results]

@@ -130,7 +130,7 @@ from fairness_llm_tpu.telemetry import (
     emit_event,
     get_registry,
 )
-from fairness_llm_tpu.telemetry.compilestats import note_lookup, record_compile
+from fairness_llm_tpu.telemetry.compilestats import record_compile
 from fairness_llm_tpu.telemetry.costmodel import (
     instrument_jit,
     note_invocation,
@@ -139,6 +139,7 @@ from fairness_llm_tpu.telemetry.costmodel import (
 from fairness_llm_tpu.telemetry.fairness import get_fairness_monitor
 from fairness_llm_tpu.telemetry.flightrecorder import get_flight_recorder
 from fairness_llm_tpu.telemetry.incidents import maybe_trigger, record_decision
+from fairness_llm_tpu.telemetry.memory import get_memory_ledger
 from fairness_llm_tpu.telemetry.roofline import observe_decode
 from fairness_llm_tpu.telemetry.timeline import get_timeline
 from fairness_llm_tpu.integrity.numerics import check_finite
@@ -224,6 +225,11 @@ class ContinuousScheduler:
         # Timeline lane (telemetry/timeline.py): fleet replicas get their
         # own track; the single-engine path shares one "serving" lane.
         self._track = replica or "serving"
+        # The number of the iteration in flight (every span inside it
+        # carries it as ``seq``), and the time of the earliest dispatch
+        # since the last decode sync: the device is busy from then on.
+        self._iter_seq = 0
+        self._busy_from: Optional[float] = None
         self.sampler = SamplerSettings(
             temperature=self.settings.temperature,
             top_k=self.settings.top_k,
@@ -565,7 +571,6 @@ class ContinuousScheduler:
                           tp=self.tp)
         program = program_label("serve_prefill", tp=self.tp)
         fn = self._compiled.get(key)
-        note_lookup(program, hit=fn is not None, labels=self.labels)
         if fn is not None:
             return fn
         run = build_serve_prefill(
@@ -590,7 +595,6 @@ class ContinuousScheduler:
         key = self._step_key(guard)
         program = self._step_program()
         fn = self._compiled.get(key)
-        note_lookup(program, hit=fn is not None, labels=self.labels)
         if fn is not None:
             return fn
         run = build_serve_step(
@@ -618,7 +622,6 @@ class ContinuousScheduler:
                           tp=self.tp)
         program = program_label("paged_prefill", tp=self.tp)
         fn = self._compiled.get(key)
-        note_lookup(program, hit=fn is not None, labels=self.labels)
         if fn is not None:
             return fn
         run = build_paged_prefill(
@@ -713,6 +716,14 @@ class ContinuousScheduler:
         already queued via ``submit()`` decode alongside; their Results stay
         claimable through ``take_result``."""
         stats = ServingStats(num_slots=self.num_slots)
+        with get_timeline().span("backend.submit", "backend", self._track,
+                                 stage="intake", rows=len(requests)):
+            self._intake(requests)
+        self._run_loop(stats)
+        self.last_stats = stats
+        return [self._results.pop(r.id) for r in requests]
+
+    def _intake(self, requests: Sequence[Request]) -> None:
         # Validate the whole batch up front (same guard as submit()) so a
         # mismatched-sampler request fails loudly before any work starts,
         # and start every request's deadline/latency clock at intake.
@@ -734,9 +745,6 @@ class ContinuousScheduler:
             if self.journal is not None:
                 self.journal.record_submitted(r, version=self.journal_version)
         self._pending = deque(requests)
-        self._run_loop(stats)
-        self.last_stats = stats
-        return [self._results.pop(r.id) for r in requests]
 
     # -- internals ----------------------------------------------------------
 
@@ -1243,6 +1251,31 @@ class ContinuousScheduler:
         n = min(free, self.serving.prefill_group, len(self.queue))
         if n <= 0:
             return False
+        tl = get_timeline()
+        with tl.span("sched.admit.plan", "sched", self._track,
+                     queue_depth=len(self.queue),
+                     live_rows=self.pool.occupancy) as plan_span:
+            plan = self._plan_admission(n, stats)
+            if plan is None:
+                return False
+            admitted, injected_hang = plan
+            if self.paged:
+                groups = self._plan_paged(admitted)
+                plan_span.set(rows=sum(len(g) for g in groups.values()))
+            else:
+                call = self._plan_prefill(admitted)
+                plan_span.set(rows=len(admitted), nb=call["nb"], P=call["P"])
+        if self.paged:
+            for S in sorted(groups):
+                self._paged_prefill_group(groups[S], S, stats, injected_hang)
+            return bool(groups)
+        return self._dispatch_prefill(call, stats, injected_hang)
+
+    def _plan_admission(self, n: int, stats: ServingStats):
+        """Pop ``n`` requests and settle each one's fate before any device
+        work: expired, shed, faulted by script, or admitted with its token
+        ids and prompt bucket. Returns ``([(request, ids, P)],
+        injected_hang)``, or None when nothing is left to admit."""
         popped = self.queue.pop(n)
         tok = self.engine.tokenizer
         now = time.monotonic()
@@ -1315,10 +1348,13 @@ class ContinuousScheduler:
             )
             admitted.append((req, ids, P))
         if not admitted:
-            return False
-        if self.paged:
-            return self._admit_paged(admitted, stats, injected_hang)
+            return None
+        return admitted, injected_hang
 
+    def _plan_prefill(self, admitted) -> dict:
+        """Slots, bucket and padded host arrays for one contiguous prefill
+        call, on the device before the call is made."""
+        tok = self.engine.tokenizer
         # ONE prefill per admission batch, at the max prompt bucket of the
         # batch. Shorter rows pad up to it — numerically free (pad slots are
         # masked, contributing exact zeros to every reduction; parity tests
@@ -1355,29 +1391,51 @@ class ContinuousScheduler:
         guard = self._guard()
         pf_key = compile_key("serve_prefill", nb=nb, P=P, guard=guard,
                              tp=self.tp)
-        pf_program = program_label("serve_prefill", tp=self.tp)
         first_compile = pf_key not in self._compiled
-        fn = self._prefill_fn(nb, P, guard)
+        return dict(
+            nb=nb, P=P, reqs=reqs, slots=slots, guard=guard, key=pf_key,
+            first_compile=first_compile, fn=self._prefill_fn(nb, P, guard),
+            program=program_label("serve_prefill", tp=self.tp),
+            prompt_tokens=int(tb.lengths.sum()),
+            args=(jnp.asarray(tokens), jnp.asarray(valid),
+                  jnp.asarray(slot_ids)),
+        )
+
+    def _dispatch_prefill(self, call: dict, stats: ServingStats,
+                          injected_hang: float) -> bool:
+        """The one compiled call of a contiguous admission batch, as the
+        ``sched.prefill.dispatch`` span that names the requests it admits.
+        On an asynchronous backend the call returns once the work is
+        queued: the span, ``prefill_wall_s`` and ``note_invocation`` are the
+        DISPATCH; the device is known to be done only at the next
+        ``sched.decode.sync``."""
+        nb, P, reqs, slots = call["nb"], call["P"], call["reqs"], call["slots"]
+        fn, guard, first_compile = call["fn"], call["guard"], \
+            call["first_compile"]
         pf_t0 = time.monotonic()
         for req in reqs:
             self.tracer.record(req.id, "prefill_start", t=pf_t0)
         if self.watchdog is not None:
             self.watchdog.arm("prefill")
         try:
-            out = self._run_compiled(
-                fn,
-                self.engine.params, self._cache, self._prev_logits,
-                jnp.asarray(tokens), jnp.asarray(valid),
-                jnp.asarray(slot_ids),
-            )
-            if guard:
-                new_cache, new_logits, finite = out
-                # Checked BEFORE the state swap: prefill isn't donated, so a
-                # poisoned batch leaves the previous cache/logits untouched
-                # (the containment branch releases the new slots).
-                check_finite(finite, "serving", "prefill")
-            else:
-                new_cache, new_logits = out
+            with get_timeline().span(
+                    "sched.prefill.dispatch", "prefill", self._track,
+                    program=call["program"], nb=nb, P=P, rows=len(reqs),
+                    request_ids=[r.id for r in reqs]):
+                self._note_dispatch(pf_t0)
+                out = self._run_compiled(
+                    fn, self.engine.params, self._cache, self._prev_logits,
+                    *call["args"],
+                )
+                if guard:
+                    new_cache, new_logits, finite = out
+                    # Checked BEFORE the state swap: prefill isn't donated,
+                    # so a poisoned batch leaves the previous cache/logits
+                    # untouched (the containment branch releases the new
+                    # slots).
+                    check_finite(finite, "serving", "prefill")
+                else:
+                    new_cache, new_logits = out
             self._cache, self._prev_logits = new_cache, new_logits
             if self.watchdog is not None:
                 # Post-hoc hang classification (see resilience/watchdog.py):
@@ -1407,41 +1465,40 @@ class ContinuousScheduler:
             return True
         if self.breakers is not None:
             self.breakers.record_success("prefill")
-        pf_wall = time.monotonic() - pf_t0
-        get_registry().histogram(
-            "prefill_wall_s", component="serving", **self.labels
-        ).observe(pf_wall)
-        # Timeline span + compile accounting (telemetry/timeline.py,
-        # telemetry/compilestats.py): one span per compiled prefill batch on
-        # this scheduler's track; a first-use shape records its (compile-
-        # dominated) first-call wall under compiles_total/compile_seconds.
-        get_timeline().record_span(
-            f"prefill[{nb}x{P}]", "prefill", self._track, pf_t0, pf_wall,
-            rows=len(admitted),
-        )
-        # Busy-cursor mark: a prefill between two decode chunks must not
-        # count as the cost ledger's "host gap" (it is attributed to
-        # serve_prefill by note_invocation below).
-        get_timeline().note_busy(self._track, pf_t0, pf_wall)
-        if first_compile:
-            record_compile(pf_program, reason="shape", seconds=pf_wall,
-                           track=self._track, key=pf_key,
-                           labels=self.labels, t0=pf_t0)
-        note_invocation(pf_program, pf_wall,
-                        ledger=getattr(fn, "ledger", None),
-                        compiling=first_compile)
+        self._note_prefill(call["program"], time.monotonic() - pf_t0, fn,
+                           first_compile, call["key"])
         stats.prefill_batches += 1
-        stats.prefill_tokens += int(tb.lengths.sum())
-        stats.admitted += len(admitted)
+        stats.prefill_tokens += call["prompt_tokens"]
+        stats.admitted += len(reqs)
         return True
 
-    def _admit_paged(self, admitted, stats: ServingStats,
-                     injected_hang: float) -> bool:
+    def _note_dispatch(self, t0: float) -> None:
+        """The earliest dispatch since the last ``sched.decode.sync``: the
+        device is taken to be busy from here until that sync returns."""
+        if self._busy_from is None:
+            self._busy_from = t0
+
+    def _note_prefill(self, program: str, wall: float, fn,
+                      first_compile: bool, key) -> None:
+        """What a prefill call feeds once it has returned:
+        ``prefill_wall_s`` (``overload.DeadlineEstimator`` reads its p50 as
+        a lower bound on a prefill, which a dispatch wall still is),
+        ``compiles_total`` on a first use, and the cost ledger's wall."""
+        get_registry().histogram(
+            "prefill_wall_s", component="serving", **self.labels
+        ).observe(wall)
+        if first_compile:
+            record_compile(program, reason="shape", seconds=wall, key=key,
+                           labels=self.labels)
+        note_invocation(program, wall, ledger=getattr(fn, "ledger", None),
+                        compiling=first_compile)
+
+    def _plan_paged(self, admitted) -> Dict[int, list]:
         """Paged admission (--paged-kv): radix-match each popped row, claim
-        blocks (private tail + refs on the shared prefix chain), and prefill
-        ONLY the unmatched suffixes — grouped by suffix bucket so one
-        compiled shape serves each group and every row's bucketed write
-        window provably fits its slot extent.
+        blocks (private tail + refs on the shared prefix chain), and group
+        the rows that go ahead by the bucket of their UNMATCHED suffix —
+        only that is prefilled, one compiled shape per group, and every
+        row's bucketed write window provably fits its slot extent.
 
         Two deferral rules put rows back at the queue head (order
         preserved) instead of admitting them this iteration:
@@ -1486,8 +1543,6 @@ class ContinuousScheduler:
         for req in reversed(deferred):
             self.queue.requeue(req)
         self._note_block_pressure(exhausted, deferred)
-        if not planned:
-            return False
         groups: Dict[int, list] = {}
         for row in planned:
             S = self._bucket_len(row[4], self.engine.seq_bucket)
@@ -1496,17 +1551,93 @@ class ContinuousScheduler:
                 f"(matched {row[3].matched} + bucket {S} > {self.cache_len})"
             )
             groups.setdefault(S, []).append(row)
-        for S in sorted(groups):
-            self._paged_prefill_group(groups[S], S, stats, injected_hang)
-        return True
+        return groups
 
     def _paged_prefill_group(self, rows, S: int, stats: ServingStats,
                              injected_hang: float) -> None:
         """One compiled suffix-prefill call for rows sharing suffix bucket
-        ``S``; mirrors the non-paged batch prefill's telemetry, watchdog,
-        breaker, and containment discipline. A fault releases exactly this
-        group's slots (blocks freed before commit, so nothing leaks into
-        the radix index) and requeues each rider once."""
+        ``S``; mirrors the non-paged batch prefill's spans, telemetry,
+        watchdog, breaker, and containment discipline. A fault releases
+        exactly this group's slots (blocks freed before commit, so nothing
+        leaks into the radix index) and requeues each rider once."""
+        paged = self.pool.paged
+        tl = get_timeline()
+        with tl.span("sched.admit.plan", "sched", self._track, stage="pad",
+                     rows=len(rows), P=S) as plan_span:
+            nb, matched_tokens, args = self._pad_paged_group(rows, S)
+            guard = self._guard()
+            pf_key = compile_key("paged_prefill", nb=nb, P=S, guard=guard,
+                                 tp=self.tp)
+            pf_program = program_label("paged_prefill", tp=self.tp)
+            first_compile = pf_key not in self._compiled
+            fn = self._paged_prefill_fn(nb, S, guard)
+            plan_span.set(nb=nb)
+        pf_t0 = time.monotonic()
+        for req, *_ in rows:
+            self.tracer.record(req.id, "prefill_start", t=pf_t0)
+        if self.watchdog is not None:
+            self.watchdog.arm("prefill")
+        try:
+            # The span carries the matched-prefix total, so the timeline
+            # shows prefill work disappear under prefix reuse.
+            with tl.span("sched.prefill.dispatch", "prefill", self._track,
+                         program=pf_program, nb=nb, P=S, rows=len(rows),
+                         matched_prefix_tokens=matched_tokens,
+                         request_ids=[r[0].id for r in rows]):
+                self._note_dispatch(pf_t0)
+                out = self._run_compiled(
+                    fn, self.engine.params, self._arena, self._prev_logits,
+                    *args,
+                )
+                if guard:
+                    new_arena, new_logits, finite = out
+                    check_finite(finite, "serving", "prefill")
+                else:
+                    new_arena, new_logits = out
+            self._arena, self._prev_logits = new_arena, new_logits
+            if self.watchdog is not None:
+                self.watchdog.observe("prefill", extra_s=injected_hang,
+                                      classify=not first_compile)
+        except Exception as e:  # noqa: BLE001 — containment is the point
+            kind = ("hang" if isinstance(e, HangFault)
+                    else "numerics" if isinstance(e, NumericsFault)
+                    else "device")
+            logger.warning("paged prefill group (%d, %d) failed: %s",
+                           nb, S, e)
+            get_registry().counter(
+                "faults_total", component="serving",
+                kind=kind, stage="prefill", **self.labels,
+            ).inc()
+            self._note_fault("prefill", kind, [r[0].id for r in rows], e)
+            if self.breakers is not None:
+                self.breakers.record_failure("prefill")
+            for req, ids, slot, plan, real_s in rows:
+                self.pool.release(slot)
+                self._requeue_or_fail(req, f"prefill failed: {e}", stats,
+                                      cause=kind)
+            return
+        if self.breakers is not None:
+            self.breakers.record_success("prefill")
+        reg = get_registry()
+        for req, ids, slot, plan, real_s in rows:
+            # Commit AFTER the device call: the freshly-written full prompt
+            # blocks become matchable, and later rows (deferred above) find
+            # them in the index.
+            paged.commit(slot, ids)
+            reg.histogram(
+                "matched_prefix_len", component="paged_kv", **self.labels
+            ).observe(plan.matched)
+        self._note_prefill(pf_program, time.monotonic() - pf_t0, fn,
+                           first_compile, pf_key)
+        stats.prefill_batches += 1
+        # Suffix tokens only: the hit/miss counters hold the reuse story,
+        # and this total IS the measured prefill-token reduction.
+        stats.prefill_tokens += sum(r[4] for r in rows)
+        stats.admitted += len(rows)
+
+    def _pad_paged_group(self, rows, S: int):
+        """The padded host arrays of one suffix-prefill group, on the
+        device: ``(nb, matched prefix tokens, call arguments)``."""
         paged = self.pool.paged
         tok = self.engine.tokenizer
         cfg = self.engine.config
@@ -1541,94 +1672,16 @@ class ContinuousScheduler:
         # idiom); their write tables are all-drop and their slot id is out
         # of range, so nothing they compute lands anywhere.
         valid[len(rows):, 0] = True
-        guard = self._guard()
-        pf_key = compile_key("paged_prefill", nb=nb, P=S, guard=guard,
-                             tp=self.tp)
-        pf_program = program_label("paged_prefill", tp=self.tp)
-        first_compile = pf_key not in self._compiled
-        fn = self._paged_prefill_fn(nb, S, guard)
-        pf_t0 = time.monotonic()
-        for req, *_ in rows:
-            self.tracer.record(req.id, "prefill_start", t=pf_t0)
-        if self.watchdog is not None:
-            self.watchdog.arm("prefill")
-        try:
-            out = self._run_compiled(
-                fn,
-                self.engine.params, self._arena, self._prev_logits,
-                jnp.asarray(tokens), jnp.asarray(valid),
-                jnp.asarray(positions), jnp.asarray(tables),
-                jnp.asarray(wtables), jnp.asarray(cow_src),
-                jnp.asarray(cow_dst), jnp.asarray(matched),
-                jnp.asarray(slot_ids), jnp.asarray(last_idx),
-            )
-            if guard:
-                new_arena, new_logits, finite = out
-                check_finite(finite, "serving", "prefill")
-            else:
-                new_arena, new_logits = out
-            self._arena, self._prev_logits = new_arena, new_logits
-            if self.watchdog is not None:
-                self.watchdog.observe("prefill", extra_s=injected_hang,
-                                      classify=not first_compile)
-        except Exception as e:  # noqa: BLE001 — containment is the point
-            kind = ("hang" if isinstance(e, HangFault)
-                    else "numerics" if isinstance(e, NumericsFault)
-                    else "device")
-            logger.warning("paged prefill group (%d, %d) failed: %s",
-                           nb, S, e)
-            get_registry().counter(
-                "faults_total", component="serving",
-                kind=kind, stage="prefill", **self.labels,
-            ).inc()
-            self._note_fault("prefill", kind, [r[0].id for r in rows], e)
-            if self.breakers is not None:
-                self.breakers.record_failure("prefill")
-            for req, ids, slot, plan, real_s in rows:
-                self.pool.release(slot)
-                self._requeue_or_fail(req, f"prefill failed: {e}", stats,
-                                      cause=kind)
-            return
-        if self.breakers is not None:
-            self.breakers.record_success("prefill")
-        reg = get_registry()
-        for req, ids, slot, plan, real_s in rows:
-            # Commit AFTER the device call: the freshly-written full prompt
-            # blocks become matchable, and later rows (deferred above) find
-            # them in the index.
-            paged.commit(slot, ids)
-            reg.histogram(
-                "matched_prefix_len", component="paged_kv", **self.labels
-            ).observe(plan.matched)
-        pf_wall = time.monotonic() - pf_t0
-        reg.histogram(
-            "prefill_wall_s", component="serving", **self.labels
-        ).observe(pf_wall)
-        # Timeline span carries the per-prefill matched_prefix_len total, so
-        # the attribution layer (PR 7) can see prefill work disappear.
-        get_timeline().record_span(
-            f"prefill[{nb}x{S}]", "prefill", self._track, pf_t0, pf_wall,
-            rows=len(rows), matched_prefix_tokens=int(matched.sum()),
-        )
-        get_timeline().note_busy(self._track, pf_t0, pf_wall)
-        if first_compile:
-            record_compile(pf_program, reason="shape", seconds=pf_wall,
-                           track=self._track, key=pf_key,
-                           labels=self.labels, t0=pf_t0)
-        note_invocation(pf_program, pf_wall,
-                        ledger=getattr(fn, "ledger", None),
-                        compiling=first_compile)
-        stats.prefill_batches += 1
-        # Suffix tokens only: the hit/miss counters hold the reuse story,
-        # and this total IS the measured prefill-token reduction.
-        stats.prefill_tokens += sum(r[4] for r in rows)
-        stats.admitted += len(rows)
+        return nb, int(matched.sum()), tuple(
+            jnp.asarray(x) for x in (tokens, valid, positions, tables,
+                                     wtables, cow_src, cow_dst, matched,
+                                     slot_ids, last_idx))
 
-    def _decode(self, stats: ServingStats) -> bool:
-        """One compiled decode chunk over the live slots; evict finished
-        rows. Returns True when any decoding happened."""
-        if self.breakers is not None and not self.breakers.allow("decode"):
-            return False
+    def _prepare_decode(self, stats: ServingStats) -> Optional[dict]:
+        """Everything a decode chunk needs before its call: scripted
+        faults, the live rows' masks, seeds and caps as host arrays, the
+        step program, and the call's arguments on the device. None when no
+        row is live."""
         injected_hang = 0.0
         if self.fault_injector is not None:
             for slot in self.pool.live_slots():
@@ -1661,7 +1714,7 @@ class ContinuousScheduler:
                         self._prev_logits = self._prev_logits.at[slot].set(bad)
         live_ids = self.pool.live_slots()
         if not live_ids:
-            return False
+            return None
         # Released-slot invalidation rides on the step program's reset mask
         # (no separate dispatch). Slots released and REUSED before this
         # point never enter the mask — SlotPool.alloc cancels their pending
@@ -1698,26 +1751,42 @@ class ContinuousScheduler:
                 tables[slot] = paged.table_for(slot)
                 wtables[slot] = paged.write_table_for(slot)
         fn = self._step_fn()
+        if self.paged:
+            args = (self._arena, self._prev_logits,
+                    jnp.asarray(tables), jnp.asarray(wtables))
+        else:
+            args = (self._cache, self._prev_logits)
+        args += tuple(jnp.asarray(x) for x in
+                      (seeds, emitted, base, caps, live))
+        if not self.paged:
+            args += (jnp.asarray(reset),)
+        return dict(live_ids=live_ids, injected_hang=injected_hang, fn=fn,
+                    args=args, prog=prog, step_key=step_key, guard=guard,
+                    first_compile=first_compile)
+
+    def _decode(self, stats: ServingStats) -> bool:
+        """One compiled decode chunk over the live slots; evict finished
+        rows. Returns True when any decoding happened."""
+        if self.breakers is not None and not self.breakers.allow("decode"):
+            return False
+        tl = get_timeline()
+        with tl.span("sched.decode.prepare", "decode", self._track) as prep:
+            ready = self._prepare_decode(stats)
+            if ready is None:
+                return False
+            live_ids, prog = ready["live_ids"], ready["prog"]
+            prep.set(rows=len(live_ids), program=prog)
+        fn, args, guard = ready["fn"], ready["args"], ready["guard"]
+        step_key, first_compile = ready["step_key"], ready["first_compile"]
+        injected_hang = ready["injected_hang"]
         dc_t0 = time.monotonic()
         if self.watchdog is not None:
             self.watchdog.arm("decode")
         try:
-            if self.paged:
-                out = self._run_compiled(
-                    fn,
-                    self.engine.params, self._arena, self._prev_logits,
-                    jnp.asarray(tables), jnp.asarray(wtables),
-                    jnp.asarray(seeds), jnp.asarray(emitted),
-                    jnp.asarray(base), jnp.asarray(caps), jnp.asarray(live),
-                )
-            else:
-                out = self._run_compiled(
-                    fn,
-                    self.engine.params, self._cache, self._prev_logits,
-                    jnp.asarray(seeds), jnp.asarray(emitted),
-                    jnp.asarray(base), jnp.asarray(caps), jnp.asarray(live),
-                    jnp.asarray(reset),
-                )
+            with tl.span("sched.decode.dispatch", "decode", self._track,
+                         program=prog, rows=len(live_ids)):
+                self._note_dispatch(dc_t0)
+                out = self._run_compiled(fn, self.engine.params, *args)
             if guard:
                 (new_kv, self._prev_logits, toks, emitted_after,
                  counters, finite) = out
@@ -1728,17 +1797,22 @@ class ContinuousScheduler:
                 self._arena = new_kv
             else:
                 self._cache = new_kv
-            toks = np.asarray(jax.device_get(toks))
-            emitted_after = np.asarray(jax.device_get(emitted_after))
-            counters = np.asarray(jax.device_get(counters))
-            if guard:
-                # A tripped finite flag discards the whole chunk as a
-                # NumericsFault into the containment branch below — the
-                # donated cache was already consumed, so the rebuild there
-                # is mandatory, and every rider requeues for a fresh
-                # prefill (which re-derives all activations from the
-                # prompt, healing a transient flip).
-                check_finite(finite, "serving", "decode")
+            # Where the host learns that the chunk, and every prefill
+            # dispatched ahead of it, is done.
+            with tl.span("sched.decode.sync", "decode", self._track):
+                toks = np.asarray(jax.device_get(toks))
+                emitted_after = np.asarray(jax.device_get(emitted_after))
+                counters = np.asarray(jax.device_get(counters))
+                if guard:
+                    # A tripped finite flag discards the whole chunk as a
+                    # NumericsFault into the containment branch below — the
+                    # donated cache was already consumed, so the rebuild
+                    # there is mandatory, and every rider requeues for a
+                    # fresh prefill (which re-derives all activations from
+                    # the prompt, healing a transient flip).
+                    check_finite(finite, "serving", "decode")
+            synced = time.monotonic()
+            busy_from, self._busy_from = self._busy_from, None
             if self.watchdog is not None:
                 # Hang classification AFTER the host sees results: a chunk
                 # past max_step_seconds raises HangFault into the branch
@@ -1796,25 +1870,43 @@ class ContinuousScheduler:
             self._place_device_state()
             self._account_device_state()
             self.pool.take_invalidations()
+            self._busy_from = None
             return True
+        with tl.span("sched.decode.emit", "decode", self._track,
+                     rows=len(live_ids)):
+            self._emit_chunk(
+                stats, live_ids, toks, emitted_after, counters, fn=fn,
+                prog=prog, step_key=step_key, first_compile=first_compile,
+                dc_t0=dc_t0, now=synced, busy_from=busy_from)
+        return True
+
+    def _emit_chunk(self, stats: ServingStats, live_ids, toks, emitted_after,
+                    counters, *, fn, prog: str, step_key, first_compile: bool,
+                    dc_t0: float, now: float,
+                    busy_from: Optional[float]) -> None:
+        """What follows a chunk whose results the host holds (``now`` is the
+        end of its sync): the telemetry that reads its wall, then each
+        row's new tokens, first-token stamp, and eviction when done."""
         if self.breakers is not None:
             self.breakers.record_success("decode")
         steps = int(counters[0])
         stats.decode_steps += steps
         stats.occupancy_sum += int(counters[1])
-        now = time.monotonic()
         # Performance attribution (telemetry/): the chunk's span on this
         # scheduler's timeline track (the gap to the previous chunk feeds
         # the step_gap_s histogram — the per-step host sync ROADMAP item 3
         # wants to eliminate), first-use compiles under compiles_total, and
         # the live roofline gauges. The byte model streams the WHOLE pool's
         # KV per step (the compiled program does, live rows or not), so
-        # batch is num_slots, not len(live_ids).
+        # batch is num_slots, not len(live_ids). ``busy_from`` is the
+        # earliest dispatch since the previous sync (a prefill queued ahead
+        # of this chunk): the device was busy from there to ``now``, which
+        # is known only now that the sync has returned.
         dc_wall = now - dc_t0
         gap = get_timeline().decode_chunk(self._track, dc_t0, dc_wall, steps,
                                           labels=self.labels,
                                           rows=len(live_ids),
-                                          program=prog)
+                                          program=prog, busy_from=busy_from)
         # Flight-recorder chunk ring (telemetry/flightrecorder.py): the
         # last-K decode chunks with their step gaps — the high-rate recent
         # history an incident bundle snapshots but nothing persists.
@@ -1830,9 +1922,7 @@ class ContinuousScheduler:
                 reason=("decode_chunk"
                         if self.decode_chunk != self._base_decode_chunk
                         else "shape"),
-                seconds=dc_wall, track=self._track,
-                key=step_key,
-                labels=self.labels, t0=dc_t0,
+                seconds=dc_wall, key=step_key, labels=self.labels,
             )
         roof_stats = {"batch": self.num_slots, "cache_slots": self.cache_len,
                       "prefix_len": 0}
@@ -1879,9 +1969,27 @@ class ContinuousScheduler:
                 self._finish(slot, "length", stats)
             elif st.request.expired(now):
                 self._finish(slot, "deadline", stats)
-        return True
 
     def _iterate(self, stats: ServingStats) -> bool:
+        """One iteration, as one ``sched.iterate`` span whose children are
+        the pieces a stall can hide in (docs/OBSERVABILITY.md, "Spans
+        inside the iteration"): ``sched.expire``, then per admission batch
+        ``sched.admit.plan`` and ``sched.prefill.dispatch``, then
+        ``sched.decode.prepare``/``.dispatch``/``.sync``/``.emit``."""
+        self._iter_seq += 1
+        tl = get_timeline()
+        with tl.span("sched.iterate", "sched", self._track,
+                     seq=self._iter_seq):
+            with tl.span("sched.expire", "sched", self._track):
+                progressed = self._expire(stats)
+            progressed |= self._admit(stats)
+            progressed |= self._decode(stats)
+            # The device's high-water mark, while the run still holds its
+            # memory (one memory_stats() call per device).
+            get_memory_ledger().note_peaks()
+        return progressed
+
+    def _expire(self, stats: ServingStats) -> bool:
         stats.loop_iterations += 1
         depth = len(self.queue)
         stats.queue_depth_sum += depth
@@ -1904,6 +2012,4 @@ class ContinuousScheduler:
             if self.pool.get(slot).request.expired(now):
                 self._finish(slot, "deadline", stats)
                 progressed = True
-        progressed |= self._admit(stats)
-        progressed |= self._decode(stats)
         return progressed

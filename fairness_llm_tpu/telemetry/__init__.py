@@ -19,12 +19,16 @@ attribution, occupancy over time. This package is the shared substrate:
   schema validation, and the ``cli telemetry-report`` terminal renderer.
 - ``heartbeat`` — low-frequency liveness pulse for long sweeps, with
   missed-beat gap detection (``heartbeat_gap_s``).
-- ``timeline``  — device-step timeline: spans for every compiled-program
-  invocation + scheduler instants + request lanes, per-replica tracks,
+- ``timeline``  — device-step timeline: ``Timeline.span`` (ring entry +
+  profiler annotation) round every piece of the scheduler's iteration,
+  scheduler instants + request lanes, per-replica tracks,
   Chrome-trace/Perfetto export (``--trace-out``), and the ``step_gap_s``
   host-sync histogram.
 - ``compilestats`` — compile observability: ``compiles_total{program,
-  reason}``, first-call ``compile_seconds``, cache hit/miss counters.
+  reason}``, and from JAX's own monitoring events
+  ``compile_stage_seconds_total{program, stage}``,
+  ``compile_cache_persistent_total{program, outcome}``, ``compile_seconds``
+  (the stages' sum) and ``compile.<program>`` spans.
 - ``roofline``  — the bytes-per-step model as LIVE gauges
   (``decode_step_bytes`` / ``achieved_hbm_gbps`` /
   ``achieved_over_achievable`` per program/replica).
@@ -76,7 +80,7 @@ from fairness_llm_tpu.telemetry.timeline import (
     use_timeline,
     validate_chrome_trace,
 )
-from fairness_llm_tpu.telemetry.compilestats import note_lookup, record_compile
+from fairness_llm_tpu.telemetry.compilestats import record_compile
 from fairness_llm_tpu.telemetry.costmodel import (
     COMPONENT_TITLES,
     COMPONENTS,
@@ -247,7 +251,6 @@ __all__ = [
     "set_attribution",
     "validate_chrome_trace",
     "summarize_chrome_trace",
-    "note_lookup",
     "record_compile",
     "COMPONENTS",
     "COMPONENT_TITLES",

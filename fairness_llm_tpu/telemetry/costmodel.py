@@ -65,10 +65,12 @@ from __future__ import annotations
 import dataclasses
 import logging
 import re
+import time
 from typing import Dict, List, Optional, Tuple
 
 import jax
 
+from fairness_llm_tpu.telemetry.compilestats import book_stage, program_scope
 from fairness_llm_tpu.telemetry.registry import get_registry
 from fairness_llm_tpu.telemetry.timeline import attribution_on
 
@@ -530,10 +532,12 @@ class InstrumentedJit:
     """A ``jax.jit`` wrapper that computes and publishes the program's cost
     ledger on its first attribution-on invocation.
 
-    The extra ``jax.make_jaxpr`` trace runs at most once per compiled
-    program, on the same call that pays the XLA compile (tracing is a
-    sliver of that wall), BEFORE the jitted call — donated input buffers
-    are gone after it. A failed trace logs once and never fails the decode;
+    The ``jax.make_jaxpr`` trace runs at most once per compiled program,
+    on the same call that pays the XLA compile, BEFORE the jitted call —
+    donated input buffers are gone after it. JAX 0.9 keeps the jaxpr it
+    traced, so the jitted call does not trace again: what the ledger adds
+    is its walk, booked as the ``ledger_trace`` compile stage (0.18 s a
+    program at 7B, PERF.md). A failed trace logs once and never fails the decode;
     the jitted function is untouched either way.
 
     ``collectives``: optional ``[(scope, bytes, flops), ...]`` rows folded
@@ -554,8 +558,16 @@ class InstrumentedJit:
         self._collectives = list(collectives or ())
 
     def __call__(self, *args):
+        # Every compile stage JAX reports inside this call is booked to
+        # this program (telemetry/compilestats.py), whenever it happens.
+        with program_scope(self.program):
+            self._first_call_instrumentation(args)
+            return self._jit(*args)
+
+    def _first_call_instrumentation(self, args) -> None:
         if self.ledger is None and not self._ledger_failed \
                 and attribution_on():
+            t0 = time.monotonic()
             try:
                 ledger = jaxpr_ledger(
                     jax.make_jaxpr(self._pyfn)(*args), self.program
@@ -571,6 +583,10 @@ class InstrumentedJit:
                 self._ledger_failed = True
                 logger.warning("cost ledger for %s unavailable: %s: %s",
                                self.program, type(e).__name__, e)
+            # What this instrumentation adds to the first call, as a stage
+            # of its own beside JAX's (trace, lower, backend_compile): the
+            # block's wall less the tracing JAX reported inside it.
+            book_stage("ledger_trace", time.monotonic() - t0)
         if not self._memory_done and not self._memory_failed:
             # AOT memory_analysis capture (ISSUE 18): what XLA itself
             # budgeted for this program — temp/argument/output/peak — as
@@ -592,7 +608,6 @@ class InstrumentedJit:
                     logger.warning(
                         "AOT memory analysis for %s unavailable: %s: %s",
                         self.program, type(e).__name__, e)
-        return self._jit(*args)
 
 
 def instrument_jit(pyfn, program: str, collectives=None,

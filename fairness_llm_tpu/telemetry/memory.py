@@ -121,6 +121,15 @@ def tree_shard_bytes(tree) -> Dict[int, int]:
     return out
 
 
+def all_device_memory_stats() -> Dict[int, Dict]:
+    """``memory_stats()`` of every local device, by device id: one call
+    each; ``{}`` for a device whose backend doesn't implement it (CPU)."""
+    import jax
+
+    return {int(d.id): getattr(d, "memory_stats", lambda: None)() or {}
+            for d in jax.local_devices()}
+
+
 def device_memory_stats() -> Dict:
     """``memory_stats()`` of device 0, or ``{}`` where the backend doesn't
     implement it (CPU) — the same defensive shape the engine preflight has
@@ -237,21 +246,47 @@ class MemoryLedger:
         if self._on():
             self.refresh()
 
-    def _limits(self) -> Tuple[Optional[int], Optional[int], str]:
+    def _limits(self, stats: Optional[Dict] = None
+                ) -> Tuple[Optional[int], Optional[int], str]:
         """(limit, bytes_in_use, reconciliation_mode). Mode is
         ``measured`` only when the DEVICE reported a limit."""
-        stats = device_memory_stats()
+        if stats is None:
+            stats = device_memory_stats()
         limit = stats.get("bytes_limit")
         in_use = stats.get("bytes_in_use")
         if limit:
             return int(limit), (int(in_use) if in_use else None), "measured"
         return self._analytic_limit, None, "indicative"
 
+    def note_peaks(self) -> None:
+        """Each device's high-water mark and limit into
+        ``hbm_peak_bytes{device}`` / ``hbm_limit_bytes{device}``. The
+        scheduler calls this at the end of every iteration (one
+        ``memory_stats()`` call per device), so the peak is in the
+        registry while the run still holds its memory; nothing else of
+        ``reconcile`` runs there, whose drift alert counts per call."""
+        if self._on():
+            self._publish_peaks(all_device_memory_stats())
+
+    @staticmethod
+    def _publish_peaks(per_device: Dict[int, Dict]) -> None:
+        reg = get_registry()
+        for dev, st in per_device.items():
+            if st.get("peak_bytes_in_use") and st.get("bytes_limit"):
+                reg.gauge("hbm_peak_bytes", component="memory",
+                          device=str(dev)).set(int(st["peak_bytes_in_use"]))
+                reg.gauge("hbm_limit_bytes", component="memory",
+                          device=str(dev)).set(int(st["bytes_limit"]))
+
     def reconcile(self) -> Dict:
         """Compare the ledger against the device's own accounting and
         publish the limit/headroom/delta gauges. Returns the comparison
-        (what ``memory-report`` renders and tests assert on)."""
-        limit, in_use, mode = self._limits()
+        (what ``memory-report`` renders and tests assert on), and the
+        devices' own high-water marks (``note_peaks``)."""
+        per_device = all_device_memory_stats()
+        self._publish_peaks(per_device)
+        limit, in_use, mode = self._limits(
+            per_device[min(per_device)] if per_device else {})
         total = self.total_bytes()
         reg = get_registry()
         lbl = {"component": "memory", "reconciliation": mode}

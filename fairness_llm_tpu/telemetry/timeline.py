@@ -25,6 +25,17 @@ stall while a slot pool sat idle. This module records that timeline:
   (Kernel Looping, arxiv 2410.23668) exists to eliminate. The gap also
   rides on each decode span's args, so the trace shows *which* gap.
 
+``Timeline.span`` is the one span primitive for code that knows a span's
+ends as it runs: a context manager that pushes the span into the ring (with
+its enclosing open span as ``parent`` and the iteration's ``seq``) and
+enters a ``jax.profiler.TraceAnnotation`` of the same name, so that under a
+profiler session the span also lands in the xplane's ``/host:CPU`` plane,
+on the profiler's clock, beside the device's ``XLA Modules``. Names are
+dotted and prefixed (``sched.decode.sync``, ``compile.serve_step``), never
+a bare program label: the benchmark's trace reduction reads host events
+named by a program label as ITS call spans. ``record_span`` stays for spans
+whose ends are known only afterwards.
+
 Export is the Chrome trace-event JSON format (``to_chrome_trace`` /
 ``export``), openable in Perfetto (https://ui.perfetto.dev) or
 ``chrome://tracing`` — ``--trace-out trace.json`` on the CLI. Timestamps are
@@ -58,6 +69,64 @@ TRACE_FILENAME = "trace.json"
 _TOP_GAPS = 16
 
 
+class _NullSpan:
+    """What ``Timeline.span`` hands out while attribution is off."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def set(self, **args) -> None:
+        return None
+
+
+_NULL_SPAN = _NullSpan()
+_annotation = None  # jax.profiler.TraceAnnotation, imported at first use
+
+
+class _Span:
+    """One open span: ring entry at exit, profiler annotation throughout."""
+
+    __slots__ = ("tl", "name", "cat", "track", "args", "parent", "t0", "ann")
+
+    def __init__(self, tl: "Timeline", name: str, cat: str, track: str,
+                 args: Dict):
+        self.tl, self.name, self.cat, self.track = tl, name, cat, track
+        self.args = args
+
+    def set(self, **args) -> None:
+        """Args learned inside the span (rows, ids, steps)."""
+        self.args.update(args)
+
+    def __enter__(self) -> "_Span":
+        global _annotation
+        if _annotation is None:
+            from jax.profiler import TraceAnnotation as _annotation
+        open_ = self.tl._open
+        outer = open_[-1] if open_ else None
+        self.parent = outer.name if outer is not None else None
+        if outer is not None and "seq" in outer.args:
+            self.args.setdefault("seq", outer.args["seq"])
+        open_.append(self)
+        self.ann = _annotation(self.name)
+        self.ann.__enter__()
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.monotonic()
+        self.ann.__exit__(*exc)
+        self.tl._open.pop()
+        self.tl._push({"type": "span", "name": self.name, "cat": self.cat,
+                       "track": self.track, "t0": self.t0,
+                       "dur_s": t1 - self.t0,
+                       "args": {"parent": self.parent, **self.args}})
+
+
 class Timeline:
     """Bounded event recorder + Chrome-trace exporter. Single-threaded by
     design, like the scheduler loop that is its main writer."""
@@ -79,6 +148,7 @@ class Timeline:
         self._last_busy_end: Dict[str, float] = {}
         self.top_gaps: List[Tuple[float, float, str]] = []  # (gap_s, t, track)
         self._epoch: Optional[float] = None
+        self._open: List[_Span] = []  # the spans entered and not yet left
 
     # -- recording -----------------------------------------------------------
 
@@ -90,6 +160,22 @@ class Timeline:
         if self._epoch is None or ev["t0"] < self._epoch:
             self._epoch = ev["t0"]
         self._events.append(ev)
+
+    def span(self, name: str, cat: str, track: str, **args):
+        """``with tl.span("sched.decode.sync", "decode", track): ...`` — a
+        span whose ends are the block's. In the ring it carries ``parent``
+        (the enclosing open span's name) and the ``seq`` of the outermost
+        span that gave one (the scheduler's iteration number); under a
+        profiler session the same span is in the device trace's host
+        plane. Off, it is this one test."""
+        if not self.enabled:
+            return _NULL_SPAN
+        return _Span(self, name, cat, track, args)
+
+    def open_span(self) -> Optional[_Span]:
+        """The innermost span open now (compile accounting hangs its
+        spans under it), or None."""
+        return self._open[-1] if self._open else None
 
     def record_span(self, name: str, cat: str, track: str, t0: float,
                     dur_s: float, **args) -> None:
@@ -126,6 +212,7 @@ class Timeline:
     def decode_chunk(self, track: str, t0: float, dur_s: float, steps: int,
                      labels: Optional[Dict[str, str]] = None,
                      program: Optional[str] = None,
+                     busy_from: Optional[float] = None,
                      **args) -> Optional[float]:
         """A decode-chunk span, plus the step-gap accounting: the time from
         the previous chunk's end (same track) to this chunk's start is
@@ -133,8 +220,11 @@ class Timeline:
         the ``step_gap_s`` histogram and stamped onto the span. With
         ``program`` set, the gap ALSO accumulates into the per-program
         ``cost_host_gap_s_total`` gauge — the MEASURED host-gap term of the
-        cost-ledger gap decomposition (telemetry/costmodel.py). Returns the
-        gap (None for the track's first chunk, or when gated off) so the
+        cost-ledger gap decomposition (telemetry/costmodel.py).
+        ``busy_from`` (at most ``t0``) is the time of an earlier dispatch
+        that kept the device busy up to this chunk, a prefill queued ahead
+        of it on an asynchronous backend: the device-idle gap then ends
+        there, and the busy interval starts there. Returns the gap (None for the track's first chunk, or when gated off) so the
         caller can stamp it onto its flight-recorder ring entry."""
         if not self.enabled:
             return None
@@ -154,15 +244,17 @@ class Timeline:
                 # would double-attribute it as "host gap".
                 busy_end = max(last_end,
                                self._last_busy_end.get(track, last_end))
+                busy_t0 = t0 if busy_from is None else min(busy_from, t0)
                 get_registry().gauge(
                     "cost_host_gap_s_total", component="costmodel",
                     program=program,
-                ).add(max(t0 - busy_end, 0.0))
+                ).add(max(busy_t0 - busy_end, 0.0))
             self.top_gaps.append((gap, t0, track))
             self.top_gaps.sort(reverse=True)
             del self.top_gaps[_TOP_GAPS:]
         self._last_chunk_end[track] = t0 + dur_s
-        self.note_busy(track, t0, dur_s)
+        busy_t0 = t0 if busy_from is None else min(busy_from, t0)
+        self.note_busy(track, busy_t0, t0 + dur_s - busy_t0)
         if gap is not None:
             args = {**args, "gap_s": round(gap, 6)}
         if program is not None:
@@ -327,9 +419,11 @@ def validate_chrome_trace(trace) -> List[str]:
 
 
 def summarize_chrome_trace(trace: Dict, top_n: int = 10) -> str:
-    """Terminal summary of an exported trace: top programs by accumulated
-    wall (the ``summarize_trace`` of the host-side world) and the largest
-    step gaps — the ``telemetry-report --timeline`` section."""
+    """Terminal summary of an exported trace: top spans by accumulated
+    wall, by category and name (``sched.iterate`` and the pieces inside
+    it, ``decode_chunk[k]``, ``compile.<program>``; a parent's wall
+    includes its children's), and the largest step gaps — the
+    ``telemetry-report --timeline`` section."""
     by_prog: Dict[Tuple[str, str], List[float]] = {}
     gaps: List[Tuple[float, float]] = []  # (gap_ms, ts_ms)
     outcomes: Dict[str, int] = {}

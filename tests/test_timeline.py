@@ -26,7 +26,7 @@ from fairness_llm_tpu.telemetry import (
     use_timeline,
     validate_chrome_trace,
 )
-from fairness_llm_tpu.telemetry.compilestats import note_lookup, record_compile
+from fairness_llm_tpu.telemetry.compilestats import record_compile
 from fairness_llm_tpu.telemetry.roofline import (
     decode_step_bytes,
     observe_decode,
@@ -40,7 +40,8 @@ from fairness_llm_tpu.telemetry.slo import render_slo_report
 
 def test_timeline_spans_export_and_schema():
     tl = Timeline()
-    tl.record_span("prefill[8x64]", "prefill", "serving", 10.0, 0.5, rows=3)
+    tl.record_span("sched.prefill.dispatch", "prefill", "serving", 10.0, 0.5,
+                   rows=3)
     tl.record_instant("fence", "r1", t=10.2, reason="crash")
     tl.record_request("req-1", "serving", 9.8, 11.0, "completed", tokens=4)
     trace = tl.to_chrome_trace()
@@ -50,7 +51,7 @@ def test_timeline_spans_export_and_schema():
     # relative to the EARLIEST event (no negative timestamps).
     assert all(e.get("ts", 0) >= 0 for e in evs if e["ph"] != "M")
     x = [e for e in evs if e["ph"] == "X"]
-    assert len(x) == 1 and x[0]["name"] == "prefill[8x64]"
+    assert len(x) == 1 and x[0]["name"] == "sched.prefill.dispatch"
     assert x[0]["dur"] == pytest.approx(0.5e6)
     # Request lanes: one async b/e pair with the request id.
     b = [e for e in evs if e["ph"] == "b"]
@@ -111,14 +112,14 @@ def test_validate_chrome_trace_catches_corruption():
 
 def test_summarize_chrome_trace_groups_programs_and_gaps():
     tl = Timeline()
-    tl.record_span("prefill[8x64]", "prefill", "serving", 0.0, 0.5)
+    tl.record_span("sched.prefill.dispatch", "prefill", "serving", 0.0, 0.5)
     tl.decode_chunk("serving", 1.0, 0.2, steps=8)
     tl.decode_chunk("serving", 1.3, 0.2, steps=8)
     tl.record_request("r1", "serving", 0.0, 1.5, "completed")
     with use_registry():
         pass
     text = summarize_chrome_trace(tl.to_chrome_trace())
-    assert "prefill[8x64]" in text
+    assert "sched.prefill.dispatch" in text
     assert "decode_chunk[8]" in text
     assert "largest step gaps" in text
     assert "completed=1" in text
@@ -131,7 +132,6 @@ def test_attribution_switch_gates_everything():
             tl.record_span("x", "decode", "serving", 0.0, 1.0)
             tl.decode_chunk("serving", 0.0, 1.0, steps=4)
             tl.decode_chunk("serving", 2.0, 1.0, steps=4)
-            note_lookup("serve_step", hit=True)
             record_compile("serve_step", "shape", 1.0)
             observe_decode(get_model_config("tiny-test"),
                            {"batch": 2, "cache_slots": 8, "prefix_len": 0},
@@ -149,27 +149,19 @@ def test_attribution_switch_gates_everything():
 
 def test_compilestats_counters_and_span():
     with use_registry() as reg, use_timeline() as tl:
-        note_lookup("serve_step", hit=False)
-        note_lookup("serve_step", hit=True)
-        note_lookup("serve_step", hit=True)
-        record_compile("serve_step", "shape", 1.25, track="serving",
+        record_compile("serve_step", "shape", 1.25,
                        key=("serve_step", 8, False))
-        record_compile("serve_step", "decode_chunk", 0.5, track="serving")
-        assert reg.counter("compile_cache_misses_total", component="compile",
-                           program="serve_step").value == 1
-        assert reg.counter("compile_cache_hits_total", component="compile",
-                           program="serve_step").value == 2
+        record_compile("serve_step", "decode_chunk", 0.5)
         assert reg.counter("compiles_total", component="compile",
                            program="serve_step", reason="shape").value == 1
         assert reg.counter("compiles_total", component="compile",
                            program="serve_step",
                            reason="decode_chunk").value == 1
-        h = reg.histogram("compile_seconds", component="compile",
-                          program="serve_step")
-        assert h.count == 2 and h.max == 1.25
-        spans = [e for e in tl.events() if e["type"] == "span"]
-        assert [s["name"] for s in spans] == ["compile:serve_step"] * 2
-        assert all(s["cat"] == "compile" for s in spans)
+        # compile_seconds and the compile.<program> spans come from JAX's
+        # own stage reports (tests/test_sched_spans.py), not from here.
+        assert reg.peek("compile_seconds", component="compile",
+                        program="serve_step") is None
+        assert tl.events() == []
 
 
 # -- roofline -----------------------------------------------------------------
@@ -385,8 +377,10 @@ def test_scheduler_emits_spans_compiles_and_roofline(engine):
         spans = [e for e in tl.events() if e["type"] == "span"]
         cats = {s["cat"] for s in spans}
         assert {"prefill", "decode", "compile"} <= cats
-        # Single-engine path: every span on the one "serving" track.
-        assert {s["track"] for s in spans} == {"serving"}
+        # Single-engine path: every span on the one "serving" track (a
+        # compile outside every program's call has no scheduler to name).
+        assert {s["track"] for s in spans
+                if s["name"] != "compile.unscoped"} == {"serving"}
         reqspans = [e for e in tl.events() if e["type"] == "request"]
         assert {e["name"] for e in reqspans} == {"tl0", "tl1", "tl2"}
         assert all(e["args"]["outcome"] == "completed" for e in reqspans)
@@ -394,11 +388,10 @@ def test_scheduler_emits_spans_compiles_and_roofline(engine):
         # step program each compiled once; later chunks were cache hits.
         assert reg.counter("compiles_total", component="compile",
                            program="serve_step", reason="shape").value == 1
-        assert reg.counter("compile_cache_misses_total",
-                           component="compile",
-                           program="serve_step").value == 1
-        assert reg.counter("compile_cache_hits_total", component="compile",
-                           program="serve_step").value >= 1
+        # ... and JAX's own stage reports put its seconds under its label.
+        assert reg.read_value("compile_stage_seconds_total",
+                              component="compile", program="serve_step",
+                              stage="backend_compile") > 0
         # Live roofline gauges populated from real chunk walls.
         assert reg.read_value("achieved_hbm_gbps", component="roofline",
                               program="serve_step") > 0

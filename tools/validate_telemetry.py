@@ -757,7 +757,8 @@ def _check_prefix_cache(snap: dict) -> list:
 def _check_profile(path: str, snap: dict) -> list:
     """The --require-profile gate: compile events, roofline gauges, step
     gaps, and a schema-valid trace.json with the span kinds the ISSUE-7
-    acceptance names (prefill/decode/request on tracks)."""
+    acceptance names (prefill/decode/request on tracks) and the named
+    spans of the scheduler's iteration."""
     import json
 
     from fairness_llm_tpu.telemetry import TRACE_FILENAME, validate_chrome_trace
@@ -814,6 +815,16 @@ def _check_profile(path: str, snap: dict) -> list:
     for want in ("prefill", "decode"):
         if want not in cats:
             problems.append(f"trace.json has no cat={want!r} spans")
+    # The pieces of the scheduler's iteration, by name (docs/OBSERVABILITY.md
+    # "Spans inside the iteration"): a serving run that decoded anything
+    # has every one of them.
+    names = {ev.get("name") for ev in trace.get("traceEvents", [])
+             if ev.get("ph") == "X"}
+    if "decode" in cats:
+        for want in ("sched.iterate", "sched.prefill.dispatch",
+                     "sched.decode.dispatch", "sched.decode.sync"):
+            if want not in names:
+                problems.append(f"trace.json has no {want!r} span")
     if not any(ev.get("ph") == "b" for ev in trace.get("traceEvents", [])):
         problems.append("trace.json has no request spans (async b/e events)")
     return problems
