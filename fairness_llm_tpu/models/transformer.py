@@ -66,18 +66,25 @@ def _cache_write(buf: jnp.ndarray, upd: jnp.ndarray, index, write_offsets):
     ``write_offsets=None``: one uniform ``dynamic_update_slice`` at ``index``
     (the left-padded batch invariant — every row writes the same slots).
     ``write_offsets=[B]``: per-row slot offsets (speculative verify steps,
-    where rows advance by their own accepted counts) — a vmapped DUS, which
-    XLA lowers to a batched scatter over the small [S, ...] update window.
+    where rows advance by their own accepted counts) — one scatter of the
+    [S, ...] update windows. The start is clamped as ``dynamic_update_slice``
+    clamps it, so a row's slots are in bounds, distinct and ascending. The
+    scatter says so: without those promises the TPU compiler expands it into
+    a loop over the rows (seven device ops per row, layer and step).
     """
     zero = jnp.zeros((), jnp.int32)
     if write_offsets is None:
         return jax.lax.dynamic_update_slice(
             buf, upd, (zero, index) + (zero,) * (buf.ndim - 2)
         )
+    S = upd.shape[1]
+    steps = jnp.arange(S, dtype=jnp.int32)
 
     def one(b, u, off):
-        return jax.lax.dynamic_update_slice(
-            b, u, (off,) + (zero,) * (b.ndim - 1)
+        slots = jnp.clip(off, 0, b.shape[0] - S) + steps
+        return b.at[slots].set(
+            u, mode="promise_in_bounds", unique_indices=True,
+            indices_are_sorted=True,
         )
 
     return jax.vmap(one)(buf, upd, write_offsets)
@@ -584,35 +591,31 @@ class Attention(nn.Module):
                 delta = positions[:, :, None] - key_positions[:, None, :]
                 allowed = allowed & (delta < cfg.sliding_window)
 
-            # GQA: repeat kv heads up to num_heads.
+            # GQA: query head h reads kv head h // rep, so q splits into
+            # [Hkv, rep] and both contractions keep the kv-head axis: the
+            # cache is read once, never written out rep times.
             rep = cfg.num_heads // cfg.num_kv_heads
-            dense_keys, dense_values = keys, values
-            if rep > 1:
-                dense_keys = jnp.repeat(dense_keys, rep, axis=2)
-                dense_values = jnp.repeat(dense_values, rep, axis=2)
+            q5 = q.reshape(B, S, cfg.num_kv_heads, rep, cfg.head_dim)
 
             scale = cfg.head_dim ** -0.5
-            scores = jnp.einsum("bqhd,bkhd->bhqk", q, dense_keys).astype(jnp.float32) * scale
-            scores = jnp.where(allowed[:, None, :, :], scores, -1e30)
+            scores = jnp.einsum("bqgrd,bkgd->bgrqk", q5, keys).astype(jnp.float32) * scale
+            scores = jnp.where(allowed[:, None, None, :, :], scores, -1e30)
             if shared_kv is not None:
                 sk, sv = shared_kv  # [Pc, Hkv, D]
-                if rep > 1:
-                    sk = jnp.repeat(sk, rep, axis=1)
-                    sv = jnp.repeat(sv, rep, axis=1)
-                # [B,H,S,Pc] — note sk has no batch dim: read once, not B times
-                s_sh = jnp.einsum("bqhd,khd->bhqk", q, sk.astype(q.dtype)).astype(jnp.float32) * scale
+                # [B,Hkv,rep,S,Pc] — note sk has no batch dim: read once, not B times
+                s_sh = jnp.einsum("bqgrd,kgd->bgrqk", q5, sk.astype(q.dtype)).astype(jnp.float32) * scale
                 if cfg.sliding_window is not None:
                     sh_pos = jnp.arange(sk.shape[0])
                     sh_allowed = (positions[:, :, None] - sh_pos[None, None, :]) < cfg.sliding_window
-                    s_sh = jnp.where(sh_allowed[:, None, :, :], s_sh, -1e30)
+                    s_sh = jnp.where(sh_allowed[:, None, None, :, :], s_sh, -1e30)
                 joint = jnp.concatenate([s_sh, scores], axis=-1)
                 probs = jax.nn.softmax(joint, axis=-1).astype(dtype)
                 p_sh, p_own = probs[..., : sk.shape[0]], probs[..., sk.shape[0]:]
-                out = jnp.einsum("bhqk,khd->bqhd", p_sh, sv.astype(dtype))
-                out = out + jnp.einsum("bhqk,bkhd->bqhd", p_own, dense_values)
+                out = jnp.einsum("bgrqk,kgd->bqgrd", p_sh, sv.astype(dtype))
+                out = out + jnp.einsum("bgrqk,bkgd->bqgrd", p_own, values)
             else:
                 probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
-                out = jnp.einsum("bhqk,bkhd->bqhd", probs, dense_values)
+                out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, values)
 
         out = out.reshape(B, S, cfg.q_dim)
         if cfg.weight_quant == "int8":
