@@ -18,6 +18,8 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from perfbench import families
+
 from . import traffic
 from .correct import Served
 
@@ -34,6 +36,8 @@ class Window:
     queue_wait_s: List[float] = dataclasses.field(default_factory=list)
     tpot_s: List[float] = dataclasses.field(default_factory=list)
     ttft_by_due: List[tuple] = dataclasses.field(default_factory=list)
+    ttft_s: List[float] = dataclasses.field(default_factory=list)
+    turn_s: List[float] = dataclasses.field(default_factory=list)
     pass_s: List[float] = dataclasses.field(default_factory=list)
     counters: Dict[str, float] = dataclasses.field(default_factory=dict)
     traced: Optional[dict] = None  # counters + seconds of the traced stretch
@@ -55,8 +59,14 @@ _COUNTERS = ("prefill_tokens", "prefill_batches", "admitted", "decode_steps",
              "decoded_tokens", "occupancy_sum", "completed")
 
 
-def _delta(after: dict, before: dict) -> dict:
-    return {k: after.get(k, 0) - before.get(k, 0) for k in _COUNTERS}
+def _delta(sut, after: dict, before: dict) -> dict:
+    """The traced stretch's counters: the harness's own, and those the
+    configuration's family names for its ``model_flops`` (which the program's
+    stats must then hold)."""
+    out = {k: after.get(k, 0) - before.get(k, 0) for k in _COUNTERS}
+    for k in getattr(families.of(sut.cfg), "STRETCH_COUNTERS", ()):
+        out[k] = after[k] - before[k]
+    return out
 
 
 def _context_sums(specs, outs) -> dict:
@@ -89,7 +99,7 @@ def run_closed(sut, mix: dict, seed: int, seconds: float, tracer,
     def close_trace():
         t1 = time.monotonic()
         tracer.stop()
-        return dict(_delta(sut.totals(), tr_c0), **tr_sums,
+        return dict(_delta(sut, sut.totals(), tr_c0), **tr_sums,
                     seconds=t1 - tr_t0)
 
     while True:
@@ -169,9 +179,13 @@ def run_open(sut, mix: dict, seed: int, seconds: float, tracer,
     want_trace = getattr(tracer, "enabled", False)
     tracing, traced = False, None
     t0 = time.monotonic()
+    turn_at = 0.0
     window_left = len(in_window)
     while window_left > 0 or (want_trace and traced is None):
         now = time.monotonic() - t0
+        if lo <= now < hi:  # a turn that ends in the window; a freeze is one
+            w.turn_s.append(now - turn_at)
+        turn_at = now
         while nxt < len(specs) and specs[nxt].due_s <= now:
             backlog.append(specs[nxt])
             nxt += 1
@@ -207,7 +221,7 @@ def run_open(sut, mix: dict, seed: int, seconds: float, tracer,
             t1 = time.monotonic()
             tracer.stop()
             tracing = False
-            traced = dict(_delta(dataclasses.asdict(stats), tr_c0),
+            traced = dict(_delta(sut, dataclasses.asdict(stats), tr_c0),
                           seconds=t1 - tr_t0)
         for rid in list(outstanding):
             res = sched.take_result(rid)
@@ -250,6 +264,7 @@ def run_open(sut, mix: dict, seed: int, seconds: float, tracer,
         if row.get("queue_wait") is not None:
             w.queue_wait_s.append(row["queue_wait"])
         w.served.append(Served(row["spec"], row["tokens"], True))
+    w.ttft_s = ttft
     w.end_to_end["ttft_p95_ms"] = 1e3 * float(np.percentile(ttft, 95))
     w.end_to_end["tpot_ms"] = 1e3 * span / max(ntok, 1)
     w.counters = dataclasses.asdict(stats)
@@ -264,6 +279,7 @@ def run_open(sut, mix: dict, seed: int, seconds: float, tracer,
             decode_context_sum=traced["occupancy_sum"] * (mean_p + out / 2))
     w.traced = traced
     w.marks["submitted"] = nxt
+    w.marks["turn_max_s"] = max(w.turn_s, default=0.0)
     return w
 
 

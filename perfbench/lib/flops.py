@@ -1,6 +1,8 @@
 """Operations and bytes the algorithm needs, from shapes alone. Kept with the
 benchmark so that no later change to the program can move a roofline share by
-recounting its own work.
+recounting its own work. A whole model's FLOPs are its family's
+(``perfbench/families``: ``model_flops``); a kernel's shape-to-work rule is a
+file under ``perfbench/costs``.
 
 Conventions: a multiply-add is 2 FLOPs; int8 weights are read once per call at
 1 byte each; bf16 activations are read and written once at 2 bytes each;
@@ -42,37 +44,3 @@ def causal_attention_cost(b: int, h: int, h_kv: int, s: int, d: int,
 def min_time_s(flops: float, nbytes: float, peaks: dict) -> float:
     """The least time the chip could take: the larger of the two bounds."""
     return max(flops / peaks["flops_per_s"], nbytes / peaks["bytes_per_s"])
-
-
-def matmul_params(cfg: dict) -> int:
-    """Parameters that take part in matrix products for one token: every
-    projection of every layer plus the output head (the embedding is a
-    lookup, not a product)."""
-    d = cfg["hidden_size"]
-    hd = cfg["head_dim"]
-    q = cfg["num_attention_heads"] * hd
-    kv = cfg["num_key_value_heads"] * hd
-    per_layer = d * q + 2 * d * kv + q * d + 3 * d * cfg["intermediate_size"]
-    return cfg["num_hidden_layers"] * per_layer
-
-
-def head_params(cfg: dict) -> int:
-    return cfg["hidden_size"] * cfg["vocab_size"]
-
-
-def model_flops(cfg: dict, *, prefill_tokens: int, prefill_rows: int,
-                decode_tokens: int, prefill_context: float,
-                decode_context: float) -> float:
-    """Model FLOPs of a stretch of serving: 2 x matmul parameters x tokens,
-    the output head once per prefilled row (last position only) and once per
-    decoded token, and attention's 4*S*D per head and token, where S is the
-    mean number of keys a token attends to (half the prompt while it is
-    prefilled, the whole context while decoding). Real tokens only: padding
-    and idle slots are not work."""
-    body = 2 * matmul_params(cfg)
-    head = 2 * head_params(cfg)
-    attn = 4 * cfg["num_attention_heads"] * cfg["head_dim"] \
-        * cfg["num_hidden_layers"]
-    return (prefill_tokens * (body + attn * prefill_context)
-            + prefill_rows * head
-            + decode_tokens * (body + head + attn * decode_context))

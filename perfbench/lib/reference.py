@@ -1,23 +1,29 @@
-"""The plain reference: the published decoder block (pre-norm RMSNorm, rotary
-GQA attention, SwiGLU) as a full forward pass in float32 ``jax.numpy`` with
+"""The plain reference: a full forward pass in float32 ``jax.numpy`` with
 ``jax.default_matmul_precision("highest")``. No kernels, no cache, no
 batching tricks; it imports nothing of the program and makes its own weights
 from the seed, layer by layer, so only one layer's float32 copy is ever live.
+The block of each kind of layer is its family's (``perfbench/families``); the
+embedding, the loop over the layers, the final norm, the head and the control
+are here, the same for every family.
 
 ``act_format`` is the control: the same forward with the input of every
 linear layer rounded to a lower-precision format per token ("int8": symmetric
 per-row int8; "fp8": float8_e4m3), the step below the bfloat16 activations the
-configurations state.
+configurations state. A block reaches it through ``Ops``, which is the only
+way it is given to multiply.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from perfbench import families
 
 from . import weights as W
 
@@ -42,71 +48,35 @@ def _dense(x, leaf, act_format):
     return _lower(x, act_format) @ w
 
 
-def _rmsnorm(x, scale, eps):
+@dataclasses.dataclass(frozen=True)
+class Ops:
+    """How a family's block multiplies, with the control inside."""
+
+    act_format: Optional[str] = None
+
+    def lower(self, x):
+        """``x`` as the input of a matrix product."""
+        return _lower(x, self.act_format)
+
+    def dense(self, x, leaf):
+        """``x @ dequantised(leaf)`` for an ``int8`` leaf (kernel, scale)."""
+        return _dense(x, leaf, self.act_format)
+
+
+def rmsnorm(x, scale, eps):
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
 
 
-def _rope(x, theta):
-    """x: [S, H, D]; rotate_half convention of the published code."""
-    s, _, d = x.shape
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[:, None, :]
-    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[:, None, :]
-    x1, x2 = x[..., : d // 2], x[..., d // 2:]
-    return x * cos + jnp.concatenate([-x2, x1], -1) * sin
-
-
-def _attend_row(q, k, v, window):
-    """One sequence. q: [S, H, D]; k, v: [S, Hkv, D]. Causal, windowed."""
-    s, h, d = q.shape
-    rep = h // k.shape[1]
-    k = jnp.repeat(k, rep, axis=1)
-    v = jnp.repeat(v, rep, axis=1)
-    scores = jnp.einsum("qhd,khd->hqk", q, k) / np.sqrt(d)
-    i = jnp.arange(s)[:, None]
-    j = jnp.arange(s)[None, :]
-    ok = j <= i
-    if window is not None:
-        ok &= (i - j) < window
-    scores = jnp.where(ok[None], scores, -jnp.inf)
-    return jnp.einsum("hqk,khd->qhd", jax.nn.softmax(scores, axis=-1), v)
-
-
-@functools.partial(jax.jit, static_argnames=("spec",))
-def _block(x, layer, spec):
-    """x: [N, S, d] float32 -> [N, S, d]. ``spec`` is a hashable tuple of the
-    block's constants."""
-    (h, hkv, hd, eps, theta, window, act_format) = spec
-    n, s, _ = x.shape
-    y = _rmsnorm(x, layer["attn_norm"], eps)
-    q = _dense(y, layer["q_proj"], act_format)
-    k = _dense(y, layer["k_proj"], act_format)
-    v = _dense(y, layer["v_proj"], act_format)
-    if "q_bias" in layer:
-        q = q + layer["q_bias"].astype(jnp.float32)
-        k = k + layer["k_bias"].astype(jnp.float32)
-        v = v + layer["v_bias"].astype(jnp.float32)
-    q = q.reshape(n, s, h, hd)
-    k = k.reshape(n, s, hkv, hd)
-    v = v.reshape(n, s, hkv, hd)
-
-    def row(args):
-        qr, kr, vr = args
-        return _attend_row(_rope(qr, theta), _rope(kr, theta), vr, window)
-
-    a = jax.lax.map(row, (q, k, v)).reshape(n, s, h * hd)
-    x = x + _dense(a, layer["o_proj"], act_format)
-    y = _rmsnorm(x, layer["mlp_norm"], eps)
-    g = _dense(y, layer["gate_proj"], act_format)
-    u = _dense(y, layer["up_proj"], act_format)
-    return x + _dense(jax.nn.silu(g) * u, layer["down_proj"], act_format)
+@functools.partial(jax.jit, static_argnames=("fn", "spec", "act_format"))
+def _block(x, layer, fn, spec, act_format):
+    """One layer: its family's ``fn`` under the shared ``Ops``."""
+    return fn(x, layer, spec, Ops(act_format))
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "act_format"))
 def _head(x, final_norm, lm_head, eps, act_format):
-    return _dense(_rmsnorm(x, final_norm, eps), lm_head, act_format)
+    return _dense(rmsnorm(x, final_norm, eps), lm_head, act_format)
 
 
 def logits_at(cfg: dict, seed: int, tokens: np.ndarray, take: np.ndarray,
@@ -114,18 +84,16 @@ def logits_at(cfg: dict, seed: int, tokens: np.ndarray, take: np.ndarray,
     """Full forward over ``tokens`` [N, S] (right-padded; causal attention
     keeps padding from reaching real positions) and the logits at positions
     ``take`` [N, T] -> float32 [N, T, vocab]."""
-    if cfg.get("hidden_act", "silu") != "silu":
-        raise ValueError("the reference implements the SwiGLU block only")
+    family = families.of(cfg)
+    kinds = family.layer_kinds(cfg)
+    blocks = {kind: family.block(cfg, kind) for kind in dict.fromkeys(kinds)}
     words = W.seed_words(seed)
-    spec = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-            cfg["head_dim"], float(cfg["rms_norm_eps"]),
-            float(cfg["rope_theta"]), cfg["window"], act_format)
-    make_layer = jax.jit(lambda w, i: W.make_layer(cfg, W.base_key(w), i))
+    make_layer, make_globals = W.jitted_makers(cfg)
     with jax.default_matmul_precision("highest"):
-        glob = jax.jit(lambda w: W.make_globals(cfg, W.base_key(w)))(words)
+        glob = make_globals(words)
         x = glob["embedding"][jnp.asarray(tokens)].astype(jnp.float32)
-        for i in range(cfg["num_hidden_layers"]):
-            x = _block(x, make_layer(words, jnp.int32(i)), spec)
+        for i, kind in enumerate(kinds):
+            x = _block(x, make_layer(words, i), *blocks[kind], act_format)
         picked = jnp.take_along_axis(
             x, jnp.asarray(take)[:, :, None], axis=1)
         out = _head(picked, glob["final_norm"], glob["lm_head"],

@@ -1,8 +1,10 @@
 """The system under test, and nothing else of the program: the serving path
 built as ``pipeline.backends.backend_for`` builds it (``DecodeEngine`` ->
 ``ServingBackend`` -> ``ContinuousScheduler`` -> ``stepbuilder`` programs),
-from a configuration file's keys, with weights the benchmark makes from the
-seed and a tokenizer that hands the scheduler the token ids the traffic drew.
+from a configuration file's keys as its family reads them
+(``perfbench/families``: the program's ``ModelConfig`` and the names of its
+leaves), with weights the benchmark makes from the seed and a tokenizer that
+hands the scheduler the token ids the traffic drew.
 
 Spans: the benchmark wraps the scheduler's one call into its compiled
 programs (``_run_compiled``) from outside, names each span by the program's
@@ -11,10 +13,13 @@ own label, and counts calls; spans inside the program are a later PR's.
 
 from __future__ import annotations
 
+import dataclasses
 import time
 from typing import Dict, List, Sequence
 
 import numpy as np
+
+from perfbench import families
 
 from . import weights as W
 
@@ -40,59 +45,30 @@ class IdTokenizer:
         return ""
 
 
-def program_model_config(cfg: dict, name: str):
-    from fairness_llm_tpu.models.configs import ModelConfig
+def serving_config(mix: dict, pool: dict):
+    """The program's ``ServingConfig``: its defaults, the cell's pool and
+    prompt budget, then the cell's ``serving`` overrides by field name."""
+    from fairness_llm_tpu.config import ServingConfig
 
-    served = cfg.get("served", {})
-    return ModelConfig(
-        name=name, vocab_size=cfg["vocab_size"],
-        num_layers=cfg["num_hidden_layers"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"], d_model=cfg["hidden_size"],
-        d_ff=cfg["intermediate_size"], head_dim=cfg["head_dim"],
-        max_seq_len=cfg["max_position_embeddings"],
-        rope_theta=float(cfg["rope_theta"]),
-        norm_eps=float(cfg["rms_norm_eps"]), qkv_bias=cfg["qkv_bias"],
-        activation=cfg.get("hidden_act", "silu"),
-        tie_embeddings=bool(cfg.get("tie_word_embeddings", False)),
-        sliding_window=cfg["window"], eos_token_id=cfg["eos_token_id"],
-        pad_token_id=cfg["pad_token_id"],
-        dtype=served.get("activation_dtype", "bfloat16"),
-        weight_quant=served.get("weight_quant", "int8"),
+    config = ServingConfig(
+        enabled=True, num_slots=int(pool["num_slots"]),
+        max_prompt_len=int(pool["prompt_budget"]),
+        max_new_tokens=int(mix["output_tokens"]),
+        queue_capacity=int(pool.get("queue_capacity", 128)),
     )
-
-
-def program_tree(cfg: dict, glob: dict, layers: List[dict]) -> dict:
-    """The made leaves under the names the program's model looks them up by."""
-
-    def dense(leaf, bias=None):
-        out = {"kernel_q": leaf[0], "kernel_scale": leaf[1]}
-        if bias is not None:
-            out["bias"] = bias
-        return out
-
-    tree = {"embedding": glob["embedding"],
-            "final_norm": {"scale": glob["final_norm"]},
-            "lm_head": dense(glob["lm_head"])}
-    for i, lw in enumerate(layers):
-        tree[f"layer_{i}"] = {
-            "attn_norm": {"scale": lw["attn_norm"]},
-            "mlp_norm": {"scale": lw["mlp_norm"]},
-            "attn": {p: dense(lw[p], lw.get(p[0] + "_bias"))
-                     for p in ("q_proj", "k_proj", "v_proj")}
-            | {"o_proj": dense(lw["o_proj"])},
-            "mlp": {p: dense(lw[p])
-                    for p in ("gate_proj", "up_proj", "down_proj")},
-        }
-    return tree
+    overrides = pool.get("serving", {})
+    known = {f.name for f in dataclasses.fields(ServingConfig)}
+    unknown = sorted(set(overrides) - known)
+    if unknown:
+        raise ValueError(f"the cell's serving overrides name {unknown}, which "
+                         "ServingConfig does not have")
+    return dataclasses.replace(config, **overrides)
 
 
 class ServedModel:
     def __init__(self, cfg: dict, name: str, mix: dict, pool: dict,
                  seed: int):
-        import jax
-
-        from fairness_llm_tpu.config import ModelSettings, ServingConfig
+        from fairness_llm_tpu.config import ModelSettings
         from fairness_llm_tpu.runtime.engine import DecodeEngine
         from fairness_llm_tpu.serving import ServingBackend
 
@@ -103,13 +79,8 @@ class ServedModel:
         self.cfg, self.mix = cfg, mix
         self.marks: Dict[str, float] = {}
         t0 = time.perf_counter()
-        # One small program per kind of sub-tree, the layer index and the
-        # seed as data: a single jitted call over all layers takes 80 s to
-        # compile (32 unrolled layers), this takes 3 s and runs as fast.
-        self._make_layer = jax.jit(
-            lambda w, i: W.make_layer(cfg, W.base_key(w), i))
-        self._make_globals = jax.jit(
-            lambda w: W.make_globals(cfg, W.base_key(w)))
+        self.family = families.of(cfg)
+        self._make_layer, self._make_globals = W.jitted_makers(cfg)
         params = self._make(seed)
         self.marks["weights_s"] = time.perf_counter() - t0
         self.tokenizer = IdTokenizer(cfg["vocab_size"], cfg["pad_token_id"],
@@ -117,15 +88,11 @@ class ServedModel:
         self.settings = ModelSettings(
             temperature=0.0, max_tokens=int(mix["output_tokens"]))
         t0 = time.perf_counter()
-        self.engine = DecodeEngine(program_model_config(cfg, name),
+        self.engine = DecodeEngine(self.family.model_config(cfg, name),
                                    params=params, tokenizer=self.tokenizer,
                                    seed=seed & 0x7FFFFFFF)
-        self.backend = ServingBackend(self.engine, ServingConfig(
-            enabled=True, num_slots=int(pool["num_slots"]),
-            max_prompt_len=int(pool["prompt_budget"]),
-            max_new_tokens=int(mix["output_tokens"]),
-            queue_capacity=int(pool.get("queue_capacity", 128)),
-        ), name=name)
+        self.backend = ServingBackend(self.engine, serving_config(mix, pool),
+                                      name=name)
         self.scheduler = self.backend.scheduler_for(self.settings)
         self.marks["build_s"] = time.perf_counter() - t0
         self.calls: List[tuple] = []  # (program label, host t0, host t1)
@@ -155,13 +122,12 @@ class ServedModel:
     def _make(self, seed: int) -> dict:
         """The served tree, on the device, in the types it is served in."""
         import jax
-        import jax.numpy as jnp
 
         words = W.seed_words(seed)
-        tree = program_tree(
+        tree = self.family.program_tree(
             self.cfg, self._make_globals(words),
-            [self._make_layer(words, jnp.int32(i))
-             for i in range(self.cfg["num_hidden_layers"])])
+            [self._make_layer(words, i)
+             for i in range(len(self.family.layer_kinds(self.cfg)))])
         jax.block_until_ready(tree)
         return tree
 
@@ -196,8 +162,6 @@ class ServedModel:
     # -- closed batch: ServingBackend.generate as decode_sweep calls it -----
 
     def generate(self, specs, call_seed: int):
-        import dataclasses
-
         for s in specs:
             self.tokenizer.register(s.id, s.ids)
         settings = dataclasses.replace(self.settings,
@@ -217,8 +181,6 @@ class ServedModel:
     # -- open loop: ContinuousScheduler.submit / step / take_result ---------
 
     def request(self, spec):
-        import dataclasses
-
         from fairness_llm_tpu.serving.request import Request
 
         self.tokenizer.register(spec.id, spec.ids)

@@ -1,6 +1,8 @@
 """Weights from the seed. One rule per kind of leaf, one key per leaf, so the
 served tree (made on the device, in the types it is served in) and the reference's per-layer float32 copies are the same numbers made
-twice, not handed from one to the other.
+twice, not handed from one to the other. Which leaves a layer has, and in what
+order, is its family's (``perfbench/families``); the key rule and the
+library's leaf kinds are here.
 
 Kinds: ``int8`` kernels are uniform int8 with a per-output-channel float32
 scale (dequantised values have the configuration's ``initializer_range`` as
@@ -11,35 +13,14 @@ the initializer range; all float leaves are bfloat16, as served.
 
 from __future__ import annotations
 
-from typing import Dict, List, Tuple
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
 
+from perfbench import families
+
 _UNIFORM_INT8_STD = 73.9  # std of a uniform draw from -128..127
-
-
-def layer_leaves(cfg: dict) -> List[Tuple[str, str, tuple]]:
-    """(name, kind, shape) of one block's leaves, in key order."""
-    d, ff = cfg["hidden_size"], cfg["intermediate_size"]
-    q = cfg["num_attention_heads"] * cfg["head_dim"]
-    kv = cfg["num_key_value_heads"] * cfg["head_dim"]
-    leaves = [("attn_norm", "norm", (d,)),
-              ("q_proj", "int8", (d, q)), ("k_proj", "int8", (d, kv)),
-              ("v_proj", "int8", (d, kv)), ("o_proj", "int8", (q, d)),
-              ("mlp_norm", "norm", (d,)),
-              ("gate_proj", "int8", (d, ff)), ("up_proj", "int8", (d, ff)),
-              ("down_proj", "int8", (ff, d))]
-    if cfg["qkv_bias"]:
-        leaves += [("q_bias", "bias", (q,)), ("k_bias", "bias", (kv,)),
-                   ("v_bias", "bias", (kv,))]
-    return leaves
-
-
-def global_leaves(cfg: dict) -> List[Tuple[str, str, tuple]]:
-    d, v = cfg["hidden_size"], cfg["vocab_size"]
-    return [("embedding", "embed", (v, d)), ("final_norm", "norm", (d,)),
-            ("lm_head", "int8", (d, v))]
 
 
 def base_key(seed_words):
@@ -58,7 +39,10 @@ def seed_words(seed: int):
                        jnp.uint32)
 
 
-def make_leaf(key, kind: str, shape: tuple, sigma: float):
+def make_leaf(key, kind: str, shape: tuple, sigma: float, makers=None):
+    """``makers``: the family's own leaf kinds, looked in first."""
+    if makers and kind in makers:
+        return makers[kind](key, shape, sigma)
     if kind == "int8":
         kq, ks = jax.random.split(key)
         wq = jax.lax.bitcast_convert_type(
@@ -81,13 +65,32 @@ def _leaf_key(base, layer: int, index: int):
     return jax.random.fold_in(jax.random.fold_in(base, layer + 1), index)
 
 
-def make_layer(cfg: dict, base, layer: int) -> Dict[str, object]:
+def _make(cfg: dict, base, layer: int, leaves, makers) -> Dict[str, object]:
     sigma = float(cfg.get("initializer_range", 0.02))
-    return {name: make_leaf(_leaf_key(base, layer, i), kind, shape, sigma)
-            for i, (name, kind, shape) in enumerate(layer_leaves(cfg))}
+    return {name: make_leaf(_leaf_key(base, layer, i), kind, shape, sigma,
+                            makers)
+            for i, (name, kind, shape) in enumerate(leaves)}
 
 
-def make_globals(cfg: dict, base) -> Dict[str, object]:
-    sigma = float(cfg.get("initializer_range", 0.02))
-    return {name: make_leaf(_leaf_key(base, -1, i), kind, shape, sigma)
-            for i, (name, kind, shape) in enumerate(global_leaves(cfg))}
+def jitted_makers(cfg: dict):
+    """The jitted makers of a configuration's weights: one small program per
+    layer kind, the layer index and the seed's words as data (a single jitted
+    call over all layers takes 80 s to compile at 32 unrolled layers, these
+    take 3 s and run as fast), and one for the globals. Returns
+    ``layer(words, i)`` and ``globals_(words)``."""
+    family = families.of(cfg)
+    kinds = family.layer_kinds(cfg)
+    makers = getattr(family, "LEAF_MAKERS", None)
+
+    def maker(leaves):
+        return jax.jit(lambda w, i: _make(cfg, base_key(w), i, leaves, makers))
+
+    make = {kind: maker(family.layer_leaves(cfg, kind))
+            for kind in dict.fromkeys(kinds)}
+    globals_ = jax.jit(lambda w: _make(
+        cfg, base_key(w), -1, family.global_leaves(cfg), makers))
+
+    def layer(words, i: int):
+        return make[kinds[i]](words, jnp.int32(i))
+
+    return layer, globals_

@@ -1,8 +1,9 @@
 """The whole step's share of the chip's peak: model FLOPs of every prefill
-and decode call in the traced stretch (real rows and tokens only) over the
-traced seconds times the peak. Nothing excluded from the time."""
+and decode call in the traced stretch (real rows and tokens only, counted by
+the configuration's family) over the traced seconds times the peak. Nothing
+excluded from the time."""
 
-from perfbench.lib import flops
+from perfbench import families
 
 
 def read(ctx):
@@ -11,11 +12,7 @@ def read(ctx):
         return None
     if not (t["prompt_tokens"] or t["decoded"]):
         return None
-    work = flops.model_flops(
-        ctx.cfg, prefill_tokens=t["prompt_tokens"], prefill_rows=t["rows"],
-        decode_tokens=t["decoded"],
-        prefill_context=t["prefill_context_sum"] / max(t["prompt_tokens"], 1),
-        decode_context=t["decode_context_sum"] / max(t["decoded"], 1))
+    work = families.of(ctx.cfg).model_flops(ctx.cfg, **t)
     share = 100.0 * work / (t["seconds"] * ctx.peaks["flops_per_s"]
                             * ctx.chips)
     if share > 100.0:

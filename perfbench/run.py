@@ -12,8 +12,10 @@ end-to-end metrics, ``--trace 1`` its per-layer metrics.
 
 Everything a cell is lives in data: ``BENCHMARK.json`` names the cell, its
 configuration (``configs/<config>.json``) and traffic (``traffic/<mix>.json``);
-``workloads/<cell>.json`` holds what belongs to the pair (pool, rate, limits);
-``layer_metrics/<metric>.json`` names each per-layer metric's reader.
+``workloads/<cell>.json`` holds what belongs to the pair (pool, rate, limits,
+``serving`` overrides); ``layer_metrics/<metric>.json`` names each per-layer
+metric's reader. The kind of model is the configuration's ``family``, one file
+under ``families/``: leaves, the program's names, the reference's block, FLOPs.
 
 With no ``--rehearse`` it refuses anything but a TPU with the chips the cell
 asks for. ``--rehearse`` is the CPU rehearsal: a toy model, the same control
@@ -86,6 +88,8 @@ def prepare(workload: str, rehearse: bool):
     devices = jax.devices()
     chips = c["cell"]["chips"]
     c["pool"] = dict(c["own"]["pool"])
+    if "serving" in c["own"]:  # ServingConfig overrides, applied after the pool
+        c["pool"]["serving"] = dict(c["own"]["serving"])
     if rehearse:
         c["cfg"] = modelcfg.rehearsal_cut(c["cfg"])
         c["mix"].update(c["own"].get("rehearsal_overrides", {}))

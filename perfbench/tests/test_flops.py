@@ -2,6 +2,7 @@
 
 import pytest
 
+from perfbench.families import dense_gqa
 from perfbench.lib import flops, peaks
 
 
@@ -40,8 +41,7 @@ def test_model_flops_counts_real_tokens_only():
                num_key_value_heads=1, intermediate_size=8,
                num_hidden_layers=3, vocab_size=10)
     body = 3 * (4 * 4 + 2 * 4 * 2 + 4 * 4 + 3 * 4 * 8)
-    assert flops.matmul_params(cfg) == body
-    got = flops.model_flops(cfg, prefill_tokens=5, prefill_rows=1,
-                            decode_tokens=2, prefill_context=0,
-                            decode_context=0)
+    assert dense_gqa.matmul_params(cfg) == body
+    got = dense_gqa.model_flops(cfg, prompt_tokens=5, rows=1, decoded=2,
+                                prefill_context_sum=0, decode_context_sum=0)
     assert got == 5 * 2 * body + 2 * 40 + 2 * (2 * body + 2 * 40)
