@@ -451,6 +451,10 @@ def config_from_args(args: argparse.Namespace) -> Config:
                         mc = get_model_config(args.model)
                     except KeyError:
                         mc = None
+                    if mc is not None and mc.attention != "gqa":
+                        raise SystemExit(
+                            f"--tp shards GQA heads; {args.model} keeps a "
+                            f"{mc.attention} cache, which has no head axis")
                     if mc is not None and (mc.num_heads % args.tp != 0 or
                                            mc.num_kv_heads % args.tp != 0):
                         raise SystemExit(

@@ -10,12 +10,19 @@ Flags rather than subclasses select family behavior:
 - ``mlp``: "glu" (SwiGLU/GeGLU) or "mlp" (GPT-2's fc->gelu->proj)
 - ``embed_scale``: Gemma multiplies embeddings by sqrt(d_model)
 - ``sliding_window``: Mistral's local attention span
+
+A model whose layers are not all one kind (latent attention, routed experts
+after leading dense layers) is ``SparseLatentConfig``, a subclass: what each
+layer is and keeps is asked of the configuration (``layer_kinds``,
+``attention``, ``cache_values_per_token``, the parameter counts), and the
+dense answers stay on ``ModelConfig``, whose fields do not change.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+import math
+from typing import Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
@@ -84,15 +91,143 @@ class ModelConfig:
     def kv_dim(self) -> int:
         return self.num_kv_heads * self.head_dim
 
+    # -- what each layer is and keeps ----------------------------------------
+    # One kind of layer here (GQA attention, one dense MLP); a configuration
+    # with another pattern is a subclass that answers the same questions
+    # (``SparseLatentConfig``). Everything that reckons parameters or cache
+    # bytes asks these, never ``2 * n_kv * head_dim`` or ``d_ff`` directly.
+
+    @property
+    def attention(self) -> str:
+        """"gqa": k and v per KV head in the cache; "mla": one latent."""
+        return "gqa"
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """The MLP of each layer, in order: "dense" or "moe"."""
+        return ("dense",) * self.num_layers
+
+    @property
+    def cache_values_per_token(self) -> int:
+        """Values one layer's cache keeps for one token of one row."""
+        return 2 * self.kv_dim
+
+    @property
+    def attention_param_count(self) -> int:
+        return self.d_model * (2 * self.q_dim + 2 * self.kv_dim)
+
+    def mlp_param_count(self, kind: str) -> int:
+        """Matmul parameters of one layer's MLP as this chip holds it."""
+        if kind != "dense":
+            raise ValueError(f"{type(self).__name__} has no {kind!r} layer")
+        mlp_in = 2 if self.mlp == "glu" else 1
+        return self.d_model * self.d_ff * (mlp_in + 1)
+
     @property
     def approx_param_count(self) -> int:
-        """Parameter-count estimate from the architecture constants."""
+        """Parameter-count estimate from the architecture constants: what
+        this chip holds, layer by layer."""
         embed = self.vocab_size * self.d_model
-        attn = self.d_model * (2 * self.q_dim + 2 * self.kv_dim)
-        mlp_in = 2 if self.mlp == "glu" else 1
-        mlp = self.d_model * self.d_ff * (mlp_in + 1)
         head = 0 if self.tie_embeddings else embed
-        return embed + head + self.num_layers * (attn + mlp)
+        layers = sum(self.attention_param_count + self.mlp_param_count(kind)
+                     for kind in self.layer_kinds)
+        return embed + head + layers
+
+
+@dataclasses.dataclass(frozen=True)
+class SparseLatentConfig(ModelConfig):
+    """Latent attention (MLA) with a leading run of dense layers and then
+    routed experts, of which this chip holds one rank's share.
+
+    ``head_dim`` is the query/key head (``qk_nope_head_dim +
+    qk_rope_head_dim``); ``d_ff`` the dense layers' width. The router scores
+    all ``num_experts`` (sigmoid, float32), the ``experts_per_token`` largest
+    are kept, their weights normalised over all of them and scaled by
+    ``routed_scaling``; the layer computes the shared expert and those chosen
+    experts it holds, ``experts_held``: rank ``expert_rank`` of
+    ``expert_shards`` equal ranges (``parallel/sharding.held_experts``). What
+    the absent ranks would add is left out, not stood in for.
+    """
+
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN (the DeepSeek-V3 convention): factor 1 is plain RoPE.
+    rope_factor: float = 1.0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_original_max: int = 4096
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    first_dense_layers: int = 1
+    num_experts: int = 0
+    experts_per_token: int = 0
+    expert_d_ff: int = 0
+    num_shared_experts: int = 0
+    routed_scaling: float = 1.0
+    norm_topk_prob: bool = True
+    expert_shards: int = 1
+    expert_rank: int = 0
+
+    def __post_init__(self):
+        if self.head_dim != self.qk_nope_head_dim + self.qk_rope_head_dim:
+            raise ValueError("head_dim must be qk_nope_head_dim + "
+                             "qk_rope_head_dim")
+        if self.num_experts % max(self.expert_shards, 1):
+            raise ValueError(f"{self.num_experts} experts do not divide over "
+                             f"{self.expert_shards} ranks")
+        if not 0 <= self.expert_rank < self.expert_shards:
+            raise ValueError("expert_rank outside expert_shards")
+
+    @property
+    def attention(self) -> str:
+        return "mla"
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        return tuple("dense" if i < self.first_dense_layers else "moe"
+                     for i in range(self.num_layers))
+
+    @property
+    def experts_held(self) -> Tuple[int, int]:
+        """(first, count) of the routed experts this chip holds."""
+        from fairness_llm_tpu.parallel.sharding import held_experts
+
+        return held_experts(self.num_experts, self.expert_shards,
+                            self.expert_rank)
+
+    @property
+    def cache_values_per_token(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def attention_param_count(self) -> int:
+        d, h = self.d_model, self.num_heads
+        return (d * self.q_lora_rank + self.q_lora_rank * h * self.head_dim
+                + d * (self.kv_lora_rank + self.qk_rope_head_dim)
+                + self.kv_lora_rank * h
+                * (self.qk_nope_head_dim + self.v_head_dim)
+                + h * self.v_head_dim * d)
+
+    def mlp_param_count(self, kind: str) -> int:
+        if kind == "dense":
+            return 3 * self.d_model * self.d_ff
+        if kind == "moe":
+            one = 3 * self.d_model * self.expert_d_ff
+            return (one * (self.num_shared_experts + self.experts_held[1])
+                    + self.d_model * self.num_experts)
+        raise ValueError(f"{type(self).__name__} has no {kind!r} layer")
+
+    @property
+    def softmax_scale(self) -> float:
+        """``head_dim^-1/2`` times the square of YaRN's attention factor."""
+        scale = self.head_dim ** -0.5
+        if self.rope_factor > 1.0 and self.rope_mscale_all_dim:
+            m = 0.1 * self.rope_mscale_all_dim * math.log(self.rope_factor) + 1.0
+            scale *= m * m
+        return scale
 
 
 MODEL_CONFIGS = {
@@ -130,6 +265,18 @@ MODEL_CONFIGS = {
         pos_emb="learned", norm="layernorm", mlp="mlp", use_bias=True,
         activation="gelu_tanh", tie_embeddings=True, eos_token_id=0,
         pad_token_id=0, dtype="float32", use_flash_attention=False,
+    ),
+    # Tiny latent-attention + routed-experts config (tests): one dense layer,
+    # then two expert layers of 16 experts, top-4, all held.
+    "tiny-mla-moe": SparseLatentConfig(
+        name="tiny-mla-moe", vocab_size=512, num_layers=3, num_heads=4,
+        num_kv_heads=4, d_model=64, d_ff=128, head_dim=24, max_seq_len=256,
+        eos_token_id=1, dtype="float32", use_flash_attention=False,
+        q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, rope_factor=4.0,
+        rope_original_max=64, rope_mscale=1.0, rope_mscale_all_dim=1.0,
+        first_dense_layers=1, num_experts=16, experts_per_token=4,
+        expert_d_ff=32, num_shared_experts=1, routed_scaling=2.5,
     ),
     "gpt2-small": ModelConfig(
         name="gpt2-small", vocab_size=50257, num_layers=12, num_heads=12,

@@ -91,6 +91,17 @@ def _cache_write(buf: jnp.ndarray, upd: jnp.ndarray, index, write_offsets):
 
 
 @flax.struct.dataclass
+class LatentCache:
+    """What a latent-attention (MLA) layer keeps per token: the normed
+    compressed key/value latent and the one rotated key every head shares.
+    ``kv_lora_rank + qk_rope_head_dim`` values a token, whatever the number
+    of heads."""
+
+    c_kv: jnp.ndarray  # [B, max_len, kv_lora_rank]
+    k_rope: jnp.ndarray  # [B, max_len, qk_rope_head_dim]
+
+
+@flax.struct.dataclass
 class KVCache:
     """Decode state shared across layers.
 
@@ -98,23 +109,45 @@ class KVCache:
     ``key_valid`` marks real (non-pad) cached keys; ``key_positions`` holds RoPE
     positions of cached keys (needed for Mistral's sliding-window test);
     ``lengths`` counts real tokens per row (the next RoPE position).
+
+    ``layers`` holds, per layer, whatever that layer keeps per request: a
+    ``LayerCache`` (k and v per KV head) or a ``LatentCache``. Every leaf is
+    ``[B, max_len, ...]``, which is all the step programs rely on when they
+    scatter rows into the slot pool (``runtime/stepbuilder.py``).
+    ``counters`` is None unless the model counts something on the device
+    (routed experts: tokens, local pairs, fullest expert); the forward adds
+    to it and the serving step hands it to the host with the chunk's tokens.
     """
 
-    layers: Tuple[LayerCache, ...]
+    layers: Tuple[Any, ...]
     key_valid: jnp.ndarray  # [B, max_len] bool
     key_positions: jnp.ndarray  # [B, max_len] int32
     index: jnp.ndarray  # scalar int32
     lengths: jnp.ndarray  # [B] int32
+    counters: Optional[jnp.ndarray] = None  # [3] int32, see SparseMLP
 
     @property
     def max_len(self) -> int:
-        return self.layers[0].k.shape[1]
+        return self.key_valid.shape[1]
+
+
+MOE_COUNTERS = ("moe_tokens", "moe_local_pairs", "moe_fullest_expert_pairs")
 
 
 def init_cache(config: ModelConfig, batch_size: int, max_len: int, dtype=None) -> KVCache:
     dtype = dtype or _dtype_of(config)
     shape = (batch_size, max_len, config.num_kv_heads, config.head_dim)
-    if config.kv_cache_quant:
+    if config.attention == "mla":
+        if config.kv_cache_quant:
+            raise NotImplementedError("no int8 form of the latent cache")
+        layers = tuple(
+            LatentCache(
+                c_kv=jnp.zeros(shape[:2] + (config.kv_lora_rank,), dtype),
+                k_rope=jnp.zeros(shape[:2] + (config.qk_rope_head_dim,), dtype),
+            )
+            for _ in range(config.num_layers)
+        )
+    elif config.kv_cache_quant:
         layers = tuple(
             LayerCache(
                 k=jnp.zeros(shape, jnp.int8),
@@ -135,6 +168,8 @@ def init_cache(config: ModelConfig, batch_size: int, max_len: int, dtype=None) -
         key_positions=jnp.zeros((batch_size, max_len), jnp.int32),
         index=jnp.zeros((), jnp.int32),
         lengths=jnp.zeros((batch_size,), jnp.int32),
+        counters=(jnp.zeros((len(MOE_COUNTERS),), jnp.int32)
+                  if "moe" in config.layer_kinds else None),
     )
 
 
@@ -170,6 +205,50 @@ def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.astype(x.dtype)
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, beta_fast: float,
+                  beta_slow: float, original_max: int):
+    """YaRN's rotary frequencies ``[dim / 2]`` (the DeepSeek-V3 convention):
+    dimensions that turn more than ``beta_fast`` times over the original
+    context keep ``theta^(-2i/dim)``, those that turn fewer than
+    ``beta_slow`` times are divided by ``factor``, and a linear ramp over the
+    pair index joins the two. ``factor`` 1 is plain RoPE."""
+    import numpy as np
+
+    extra = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if factor <= 1.0:
+        return extra.astype(np.float32)
+
+    def correction_dim(rotations):
+        return (dim * np.log(original_max / (rotations * 2 * np.pi))
+                / (2 * np.log(theta)))
+
+    low = max(np.floor(correction_dim(beta_fast)), 0)
+    high = min(np.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0, 1)
+    return (extra / factor * ramp + extra * (1 - ramp)).astype(np.float32)
+
+
+def rope_interleaved(x: jnp.ndarray, positions: jnp.ndarray,
+                     inv_freq) -> jnp.ndarray:
+    """Rotary embedding whose pairs are NEIGHBOURS, (x[2i], x[2i+1]), as the
+    DeepSeek-V3 family stores its rotary dimensions. x: [B, S, ..., D],
+    positions: [B, S]. The result comes back with the rotated firsts in the
+    leading half and the seconds in the trailing half (the published code's
+    layout after its own de-interleave); q and k are permuted alike, so
+    their products are those of rotating in place."""
+    angles = positions.astype(jnp.float32)[..., None] * jnp.asarray(inv_freq)
+    angles = angles.reshape(angles.shape[:2] + (1,) * (x.ndim - 3)
+                            + angles.shape[-1:])
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., 0::2], x32[..., 1::2]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
 
@@ -638,16 +717,18 @@ class Attention(nn.Module):
 
 class MLP(nn.Module):
     config: ModelConfig
+    width: Optional[int] = None  # a shared expert's; None: config.d_ff
 
     @nn.compact
     def __call__(self, x):
         cfg = self.config
+        d_ff = self.width or cfg.d_ff
         dtype = _dtype_of(cfg)
         act = _activation(cfg.activation)
         use_bias = cfg.use_bias
         if cfg.weight_quant == "int8":
             up_d = lambda name: QuantDense(  # noqa: E731
-                cfg.d_ff, in_axis="embed", out_axis="ff",
+                d_ff, in_axis="embed", out_axis="ff",
                 use_bias=use_bias, dtype=dtype, name=name,
             )
             down_d = QuantDense(
@@ -658,7 +739,7 @@ class MLP(nn.Module):
             up_init = nn.with_logical_partitioning(nn.initializers.normal(0.02), ("embed", "ff"))
             down_init = nn.with_logical_partitioning(nn.initializers.normal(0.02), ("ff", "embed"))
             up_d = lambda name: nn.DenseGeneral(  # noqa: E731
-                cfg.d_ff, use_bias=use_bias, dtype=dtype, kernel_init=up_init, name=name,
+                d_ff, use_bias=use_bias, dtype=dtype, kernel_init=up_init, name=name,
             )
             down_d = nn.DenseGeneral(
                 cfg.d_model, use_bias=use_bias, dtype=dtype, kernel_init=down_init,
@@ -671,22 +752,267 @@ class MLP(nn.Module):
         return down_d(h)
 
 
-class Block(nn.Module):
+class _Kernel(nn.Module):
+    """The leaves of a linear map, handed back raw for a caller that does
+    its own multiplying: ``(kernel_q int8, kernel_scale f32)`` with the scale
+    over the last axis when ``quant``, else ``(kernel, None)``. The names are
+    ``QuantDense``'s and ``DenseGeneral``'s, so a tree is laid out alike
+    whichever module owns the product."""
+
+    shape: Tuple[int, ...]
+    axes: Tuple[Optional[str], ...]
+    quant: bool
+
+    @nn.compact
+    def __call__(self):
+        if self.quant:
+            return (
+                self.param("kernel_q", nn.with_logical_partitioning(
+                    _int8_kernel_init, self.axes), self.shape),
+                self.param("kernel_scale", nn.with_logical_partitioning(
+                    _scale_init, self.axes[:-2] + self.axes[-1:]),
+                    self.shape[:-2] + self.shape[-1:]),
+            )
+        return self.param("kernel", nn.with_logical_partitioning(
+            nn.initializers.normal(0.02), self.axes), self.shape), None
+
+
+def _linear(cfg: ModelConfig, features: int, in_axis, out_axis, name: str):
+    """A bias-free projection in the configuration's weight format."""
+    dtype = _dtype_of(cfg)
+    if cfg.weight_quant == "int8":
+        return QuantDense(features, in_axis=in_axis, out_axis=out_axis,
+                          dtype=dtype, name=name)
+    return nn.DenseGeneral(
+        features, use_bias=False, dtype=dtype, name=name,
+        kernel_init=nn.with_logical_partitioning(
+            nn.initializers.normal(0.02), (in_axis, out_axis)))
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention. Queries go through a low-rank pair
+    (``q_a_proj``, norm, ``q_b_proj``); keys and values through one shared
+    latent: ``[c_kv | k_r] = x W_kva``, ``c_kv`` normed, ``k_r`` rotated once
+    for all heads, and ``[k_nope | v]`` per head ``= c_kv W_kvb``. The cache
+    keeps only ``c_kv`` and ``k_r`` (``LatentCache``).
+
+    Two compositions of the same mathematics. *Expanded*, when the new
+    tokens are the whole key set (no cache, or a first prefill, which the
+    callers mark as for the flash path: left-padded, S > 1, uniform write):
+    k and v are made from the latent and attended to as usual. *Absorbed*,
+    whenever the cache is read: ``W_kvb``'s key half moves into the query
+    (``q' = q_nope W_k^T``, ``kv_lora_rank`` wide per head), scores and the
+    value sum run over the latent itself, and the value half is applied to
+    the result, so a step reads ``kv_lora_rank + qk_rope_head_dim`` values a
+    token and never expands the cache.
+    """
+
     config: ModelConfig
 
     @nn.compact
+    def __call__(self, x, positions, cache_layer, cache_index, key_valid,
+                 key_positions, left_padded=False, shared_kv=None,
+                 write_offsets=None):
+        cfg = self.config
+        if shared_kv is not None:
+            raise NotImplementedError(
+                "a shared prefix of latents (the static engine's prefix "
+                "cache) is not built for latent attention")
+        if cfg.attention_impl != "xla":
+            raise NotImplementedError("latent attention has the XLA path only")
+        dtype = _dtype_of(cfg)
+        B, S, _ = x.shape
+        H, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        dv, rank = cfg.v_head_dim, cfg.kv_lora_rank
+        inv_freq = yarn_inv_freq(dr, cfg.rope_theta, cfg.rope_factor,
+                                 cfg.rope_beta_fast, cfg.rope_beta_slow,
+                                 cfg.rope_original_max)
+        f32 = jnp.float32
+
+        c_q = RMSNorm(eps=cfg.norm_eps, name="q_a_norm")(
+            _linear(cfg, cfg.q_lora_rank, "embed", None, "q_a_proj")(x))
+        q = _linear(cfg, H * (dn + dr), None, "q_heads", "q_b_proj")(c_q)
+        q = q.reshape(B, S, H, dn + dr)
+        q_nope = q[..., :dn]
+        q_rope = rope_interleaved(q[..., dn:], positions, inv_freq)
+        kv_a = _linear(cfg, rank + dr, "embed", None, "kv_a_proj")(x)
+        c_kv = RMSNorm(eps=cfg.norm_eps, name="kv_a_norm")(kv_a[..., :rank])
+        k_r = rope_interleaved(kv_a[..., rank:], positions, inv_freq)
+        w_kvb, w_scale = _Kernel((rank, H * (dn + dv)), (None, "q_heads"),
+                                 cfg.weight_quant == "int8",
+                                 name="kv_b_proj")()
+
+        if cache_layer is not None:
+            lat = _cache_write(cache_layer.c_kv, c_kv.astype(dtype),
+                               cache_index, write_offsets)
+            rot = _cache_write(cache_layer.k_rope, k_r.astype(dtype),
+                               cache_index, write_offsets)
+            new_cache_layer = LatentCache(c_kv=lat, k_rope=rot)
+        else:
+            lat, rot, new_cache_layer = c_kv, k_r, None
+
+        expanded = cache_layer is None or (
+            left_padded and S > 1 and write_offsets is None)
+        if expanded:
+            with jax.named_scope("mla.expand"):
+                if w_scale is not None:
+                    from fairness_llm_tpu.ops.quant_matmul import quant_matmul
+
+                    kv = quant_matmul(
+                        c_kv.reshape(B * S, rank).astype(dtype), w_kvb,
+                        w_scale, out_dtype=dtype)
+                else:
+                    kv = jnp.dot(c_kv.reshape(B * S, rank).astype(dtype),
+                                 w_kvb.astype(dtype))
+                kv = kv.reshape(B, S, H, dn + dv)
+            allowed = (jnp.tril(jnp.ones((S, S), jnp.bool_))[None]
+                       & key_valid[:, None, :S])
+            with jax.named_scope("mla.attend"):
+                scores = (
+                    jnp.einsum("bqhd,bkhd->bhqk", q_nope, kv[..., :dn],
+                               preferred_element_type=f32)
+                    + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_r.astype(dtype),
+                                 preferred_element_type=f32)
+                ) * cfg.softmax_scale
+                scores = jnp.where(allowed[:, None], scores, -1e30)
+                probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
+                out = jnp.einsum("bhqk,bkhd->bqhd", probs, kv[..., dn:])
+        else:
+            K = lat.shape[1]
+            j_idx = jnp.arange(K)[None, :]
+            if write_offsets is None:
+                q_idx = cache_index + jnp.arange(S)[:, None]
+                causal = (j_idx <= q_idx)[None, :, :]
+            else:
+                q_idx = (write_offsets[:, None, None]
+                         + jnp.arange(S)[None, :, None])
+                causal = j_idx[None, :, :] <= q_idx
+            allowed = causal & key_valid[:, None, :]  # [B, S, K]
+            # The per-channel scale commutes with both absorbed products, so
+            # it goes onto the activations ([.., H, dn] and [.., H, dv]) and
+            # the int8 kernel is only cast: no dequantised copy of W_kvb is
+            # made in every step of the decode loop.
+            w = w_kvb.astype(dtype).reshape(rank, H, dn + dv)
+            scale = None if w_scale is None else \
+                w_scale.astype(f32).reshape(H, dn + dv)
+            with jax.named_scope("mla.absorb"):
+                q_in = q_nope if scale is None else \
+                    (q_nope * scale[:, :dn]).astype(dtype)
+                q_lat = jnp.einsum("bqhd,rhd->bqhr", q_in, w[..., :dn])
+            with jax.named_scope("mla.attend"):
+                scores = (
+                    jnp.einsum("bqhr,bkr->bhqk", q_lat, lat,
+                               preferred_element_type=f32)
+                    + jnp.einsum("bqhd,bkd->bhqk", q_rope, rot,
+                                 preferred_element_type=f32)
+                ) * cfg.softmax_scale
+                scores = jnp.where(allowed[:, None], scores, -1e30)
+                probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
+                o_lat = jnp.einsum("bhqk,bkr->bqhr", probs, lat)
+            with jax.named_scope("mla.absorb"):
+                out = jnp.einsum("bqhr,rhd->bqhd", o_lat, w[..., dn:],
+                                 preferred_element_type=f32)
+                if scale is not None:
+                    out = out * scale[:, dn:]
+                out = out.astype(dtype)
+
+        out = _linear(cfg, cfg.d_model, "q_heads", "embed", "o_proj")(
+            out.reshape(B, S, H * dv))
+        return out, new_cache_layer
+
+
+def route_tokens(x: jnp.ndarray, router: jnp.ndarray, cfg: ModelConfig):
+    """``(chosen [T, k] int32 over ALL experts, weights [T, k] float32)``:
+    sigmoid scores in float32 at the highest matmul precision, the k largest
+    (ties to the lower index), normalised over the chosen, times
+    ``routed_scaling``."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    top, chosen = jax.lax.top_k(scores, cfg.experts_per_token)
+    if cfg.norm_topk_prob:
+        top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+    return chosen.astype(jnp.int32), top * cfg.routed_scaling
+
+
+class SparseMLP(nn.Module):
+    """Routed experts with a shared expert, as one rank of the expert axis.
+
+    ``p = sigmoid(x W_g)`` over ALL experts, in float32 (with random or
+    trained weights a near-tie picks another expert in bfloat16); the
+    ``experts_per_token`` largest; ``w = routed_scaling * p_sel / sum(p_sel)``
+    over all the chosen, held here or not; ``y = Shared(x) + sum_i w_i
+    E_i(x)`` over the chosen experts this rank holds
+    (``config.experts_held``). What the other ranks' experts would add is
+    left out: on one chip there is no exchange, and nothing stands in for it.
+    Returns ``(y, int32 [3])``: valid tokens, local (token, expert) pairs,
+    and the fullest held expert's pairs (``MOE_COUNTERS``).
+    """
+
+    config: ModelConfig
+
+    @nn.compact
+    def __call__(self, x, token_valid):
+        from fairness_llm_tpu.ops.expert_dispatch import routed_experts
+
+        cfg = self.config
+        dtype = _dtype_of(cfg)
+        quant = cfg.weight_quant == "int8"
+        B, S, d = x.shape
+        first, held = cfg.experts_held
+        ff = cfg.expert_d_ff
+        router, _ = _Kernel((d, cfg.num_experts), ("embed", None), False,
+                            name="router")()
+        experts = [
+            _Kernel((held,) + shape, ("expert",) + axes, quant, name=name)()
+            for name, shape, axes in (
+                ("experts_gate", (d, ff), ("embed", "ff")),
+                ("experts_up", (d, ff), ("embed", "ff")),
+                ("experts_down", (ff, d), ("ff", "embed")))
+        ]
+        x2 = x.reshape(B * S, d)
+        with jax.named_scope("moe.route"):
+            chosen, top = route_tokens(x2, router, cfg)
+        # Kept only by an apply that asks for "intermediates" (a diagnostic:
+        # perfbench/tools/router_flips.py); otherwise not even traced.
+        self.sow("intermediates", "chosen", chosen.reshape(B, S, -1))
+        with jax.named_scope("moe.experts"):
+            routed, counters = routed_experts(
+                x2.astype(dtype), chosen, top,
+                token_valid.reshape(B * S), *experts, first=first,
+                num_experts=cfg.num_experts,
+                activation=_activation(cfg.activation))
+        shared = MLP(cfg, width=cfg.num_shared_experts * ff, name="shared")(x)
+        y = shared + routed.astype(dtype).reshape(B, S, d)
+        return y, counters
+
+
+class Block(nn.Module):
+    config: ModelConfig
+    kind: str = "dense"  # this layer's MLP: config.layer_kinds[i]
+
+    @nn.compact
     def __call__(self, x, positions, cache_layer, cache_index, key_valid, key_positions,
-                 left_padded=False, shared_kv=None, write_offsets=None):
-        attn_out, new_cache = Attention(self.config, name="attn")(
+                 left_padded=False, shared_kv=None, write_offsets=None,
+                 token_valid=None):
+        """Returns ``(x, the layer's new cache, its counters or None)``."""
+        attention = LatentAttention if self.config.attention == "mla" else Attention
+        attn_out, new_cache = attention(self.config, name="attn")(
             _norm(self.config, "attn_norm")(x),
             positions, cache_layer, cache_index, key_valid, key_positions,
             left_padded=left_padded, shared_kv=shared_kv,
             write_offsets=write_offsets,
         )
         x = x + attn_out
-        x = x + MLP(self.config, name="mlp")(_norm(self.config, "mlp_norm")(x))
+        counters = None
+        if self.kind == "moe":
+            y, counters = SparseMLP(self.config, name="mlp")(
+                _norm(self.config, "mlp_norm")(x), token_valid)
+            x = x + y
+        else:
+            x = x + MLP(self.config, name="mlp")(_norm(self.config, "mlp_norm")(x))
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
-        return x, new_cache
+        return x, new_cache, counters
 
 
 # ---------------------------------------------------------------------------
@@ -755,16 +1081,19 @@ class Transformer(nn.Module):
             key_positions = positions
 
         new_layers = []
-        for i in range(cfg.num_layers):
+        counters = cache.counters if cache is not None else None
+        for i, kind in enumerate(cfg.layer_kinds):
             layer_cache = cache.layers[i] if cache is not None else None
-            x, new_layer = Block(cfg, name=f"layer_{i}")(
+            x, new_layer, counted = Block(cfg, kind=kind, name=f"layer_{i}")(
                 x, positions,
                 layer_cache, cache.index if cache is not None else None,
                 key_valid, key_positions, left_padded=left_padded,
                 shared_kv=shared_layers[i] if shared_layers is not None else None,
-                write_offsets=write_offsets,
+                write_offsets=write_offsets, token_valid=token_valid,
             )
             new_layers.append(new_layer)
+            if counted is not None and counters is not None:
+                counters = counters + counted
 
         x = _norm(cfg, "final_norm")(x)
         if last_only:
@@ -808,6 +1137,7 @@ class Transformer(nn.Module):
                 key_positions=key_positions,
                 index=cache.index + S,
                 lengths=cache.lengths + jnp.sum(token_valid, axis=1, dtype=jnp.int32),
+                counters=counters,
             )
         return logits, new_cache
 

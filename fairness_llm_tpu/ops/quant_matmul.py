@@ -54,6 +54,8 @@ __all__ = [
     "quant_tileable",
     "quant_matmul",
     "quant_matmul_sharded",
+    "grouped_quant_matmul",
+    "grouped_tileable",
     "force_pallas",
     "lowering_for_tpu",
 ]
@@ -212,6 +214,131 @@ def quant_matmul(
                      "untileable" if on_tpu or interpret else "not_tpu")
     y = jnp.dot(x, wq.astype(x.dtype), preferred_element_type=jnp.float32)
     return (y * scale[None, :].astype(jnp.float32)).astype(out_dtype)
+
+
+# ---------------------------------------------------------------------------
+# Grouped product over stacked expert kernels
+# ---------------------------------------------------------------------------
+
+_GROUP_WEIGHT_BLOCK_BYTES = 8 << 20  # one [K, bn] int8 block, double-buffered
+_GROUP_VMEM_LIMIT = 96 << 20  # of a v5e core's 128 MiB
+
+
+def _group_blocks(k: int, n: int) -> Tuple[int, int]:
+    """(K chunk of the in-kernel loop, N block of the grid): the widest
+    lane-multiple divisor of ``n`` whose int8 ``[k, bn]`` block stays under
+    the budget, so an expert's kernel streams in as few grid steps as fit."""
+    bn = max((c for c in range(_LANE, n + 1, _LANE)
+              if n % c == 0 and k * c <= _GROUP_WEIGHT_BLOCK_BYTES),
+             default=0)
+    return _pick_block(k), bn
+
+
+def grouped_tileable(k: int, n: int) -> bool:
+    """Static gate of the grouped kernel, as :func:`quant_tileable`."""
+    return quant_tileable(k, n) and all(_group_blocks(k, n))
+
+
+def _grouped_kernel(te_ref, tb_ref, used_ref, x_ref, wq_ref, scale_ref, o_ref,
+                    *, kb: int, nk: int):
+    """One (n block, row tile) step. A row tile holds rows of ONE expert
+    (``te_ref``); tiles past ``used_ref[0]`` hold no row and are skipped:
+    their block indices repeat the last used tile's, so nothing is fetched
+    for them and the output block is written once."""
+    del te_ref, tb_ref
+
+    @pl.when(pl.program_id(1) < used_ref[0])
+    def _tile():
+        # K in static chunks: the int8 -> float cast of a [kb, bn] piece is
+        # the only float copy of the weights there ever is.
+        acc = jnp.zeros(o_ref.shape, jnp.float32)
+        for c in range(nk):
+            ks = slice(c * kb, (c + 1) * kb)
+            acc += jnp.dot(
+                x_ref[:, ks], wq_ref[ks, :].astype(x_ref.dtype),
+                preferred_element_type=jnp.float32)
+        o_ref[:] = (acc * scale_ref[:].astype(jnp.float32)).astype(o_ref.dtype)
+
+
+def _grouped_pallas(x, wq, scale, tile_expert, tile_block, tiles_used, bm,
+                    interpret: bool, out_dtype):
+    m, k = x.shape
+    e, _, n = wq.shape
+    kb, bn = _group_blocks(k, n)
+    # N blocks outermost: consecutive row tiles of one expert then reuse the
+    # weight block already in VMEM, and only x tiles (small) are re-read.
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(n // bn, m // bm),
+        in_specs=[
+            pl.BlockSpec((bm, k), lambda j, i, te, tb, used: (tb[i], 0)),
+            pl.BlockSpec((None, k, bn),
+                         lambda j, i, te, tb, used: (te[i], 0, j)),
+            pl.BlockSpec((None, 1, bn),
+                         lambda j, i, te, tb, used: (te[i], 0, j)),
+        ],
+        out_specs=pl.BlockSpec((bm, bn),
+                               lambda j, i, te, tb, used: (tb[i], j)),
+    )
+    return pl.pallas_call(
+        functools.partial(_grouped_kernel, kb=kb, nk=k // kb),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_GROUP_VMEM_LIMIT,
+        ),
+        interpret=interpret,
+        name="grouped_int8_matmul",
+    )(tile_expert, tile_block, tiles_used, x, wq, scale[:, None, :])
+
+
+def grouped_quant_matmul(
+    x: jnp.ndarray,
+    wq: jnp.ndarray,
+    scale: Optional[jnp.ndarray],
+    tile_expert: jnp.ndarray,
+    tile_block: jnp.ndarray,
+    tiles_used: jnp.ndarray,
+    *,
+    bm: int,
+    interpret: bool = False,
+    out_dtype=None,
+) -> jnp.ndarray:
+    """``y[rows of tile t] = x[rows of tile t] @ dequant(wq[tile_expert[t]])``.
+
+    ``x [M, K]`` holds the rows sorted by expert in whole tiles of ``bm``
+    rows (ragged groups padded to the tile, never cut: no row is dropped);
+    ``wq [E, K, N]`` int8 with ``scale [E, N]``, or a float ``wq`` with
+    ``scale`` None. ``tile_expert [M // bm]`` names each tile's expert,
+    ``tiles_used [1]`` how many tiles hold rows, and ``tile_block`` is
+    ``min(t, tiles_used - 1)``; ``tile_expert`` past the used tiles repeats
+    the last used one. Rows of unused tiles come back unwritten: the caller
+    reads only the rows it placed.
+
+    Pallas on TPU for int8 kernels (the dequantisation stays in VMEM, as in
+    :func:`quant_matmul`: XLA would hoist a bf16 copy of every expert out of
+    the decode loop); otherwise one batched XLA product over the tiles.
+    """
+    out_dtype = out_dtype or x.dtype
+    m, k = x.shape
+    _, _, n = wq.shape
+    on_tpu = lowering_for_tpu()
+    if scale is not None and (on_tpu or interpret):
+        if grouped_tileable(k, n):
+            note_kernel_path("grouped_int8_matmul", "pallas", wq.shape)
+            return _grouped_pallas(x, wq, scale, tile_expert, tile_block,
+                                   tiles_used, bm, interpret, out_dtype)
+        reason = "untileable"
+    else:
+        reason = "not_tpu" if scale is not None else "float_kernel"
+    note_kernel_path("grouped_int8_matmul", "xla", wq.shape, reason)
+    y = jnp.einsum("tbk,tkn->tbn", x.reshape(m // bm, bm, k),
+                   wq[tile_expert].astype(x.dtype),
+                   preferred_element_type=jnp.float32)
+    if scale is not None:
+        y = y * scale[tile_expert][:, None, :].astype(jnp.float32)
+    return y.reshape(m, n).astype(out_dtype)
 
 
 def quant_matmul_sharded(

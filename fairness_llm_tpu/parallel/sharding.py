@@ -11,6 +11,11 @@ onto the physical ``("dp", "tp", "sp")`` mesh:
   inserts the all-reduces the NCCL world would do by hand)
 - "seq"              -> "sp"   (sequence/context parallel for long prompts)
 - "embed"            -> replicated
+- "expert"           -> replicated: the routed experts' stacked kernels carry
+  the axis name, and which of them a chip holds is its rank on that axis
+  (``held_experts``). No mesh built here has the axis, so its size is 1 and
+  the rank is the configuration's own ``expert_rank``: the exchange between
+  expert ranks does not exist yet, and a mesh that asks for one is refused.
 
 An axis is only mapped when its size divides the mesh axis (GQA models with few
 KV heads fall back to replicated KV, which is also what production TP serving
@@ -95,6 +100,28 @@ def resolve_logical_axis(name: str, mesh: Mesh) -> Optional[str]:
     return axis if axis and mesh.shape.get(axis, 1) > 1 else None
 
 
+def held_experts(num_experts: int, shards: int, rank: int) -> Tuple[int, int]:
+    """(first, count) of the routed experts that rank ``rank`` of ``shards``
+    on the expert axis holds: equal contiguous ranges, ``[rank * n, rank * n
+    + n)`` with ``n = num_experts / shards``."""
+    if shards < 1 or num_experts % shards or not 0 <= rank < shards:
+        raise ValueError(f"{num_experts} experts over {shards} ranks, rank "
+                         f"{rank}: not an equal share")
+    count = num_experts // shards
+    return rank * count, count
+
+
+def require_gqa(model_config: ModelConfig, what: str) -> None:
+    """Raise for a layer kind ``what`` reckons GQA's sizes for and does not
+    know: a wrong answer in GQA's terms is worse than none."""
+    if model_config.attention != "gqa" or set(model_config.layer_kinds) != {
+            "dense"}:
+        raise NotImplementedError(
+            f"{what} knows dense GQA layers only; {model_config.name} has "
+            f"{model_config.attention} attention and "
+            f"{sorted(set(model_config.layer_kinds))} layers")
+
+
 def make_axis_rules(model_config: ModelConfig, mesh: Mesh) -> AxisRules:
     """Logical->mesh axis rules, dropping mappings that don't divide evenly.
 
@@ -107,6 +134,10 @@ def make_axis_rules(model_config: ModelConfig, mesh: Mesh) -> AxisRules:
     """
     tp = mesh.shape.get("tp", 1)
     sp = mesh.shape.get("sp", 1)
+    if tp > 1 or mesh.shape.get("ep", 1) > 1:
+        # Heads of a latent cache and experts across chips (with their
+        # exchange) are not built: refuse rather than shard as GQA would.
+        require_gqa(model_config, "tensor- or expert-parallel sharding")
 
     def fits(size: int) -> bool:
         return tp > 1 and size % tp == 0
@@ -119,6 +150,7 @@ def make_axis_rules(model_config: ModelConfig, mesh: Mesh) -> AxisRules:
         ("kv_heads", "tp" if fits(model_config.num_kv_heads) else None),
         ("ff", "tp" if fits(model_config.d_ff) else None),
         ("vocab", "tp" if fits(model_config.vocab_size) else None),
+        ("expert", None),
     ]
     return tuple(rules)
 
@@ -266,9 +298,14 @@ def per_device_kv_cache_bytes(model_config: ModelConfig, mesh: Mesh, batch: int,
     the f32 scales)."""
     if rules is None:
         rules = make_axis_rules(model_config, mesh)
+    dp = mesh.shape.get("dp", 1)
+    if model_config.attention != "gqa":
+        # The layer's own count: a latent cache has no head axis to shard.
+        item = 2 if model_config.dtype == "bfloat16" else 4
+        return (model_config.num_layers * -(-batch // dp) * max_len
+                * model_config.cache_values_per_token * item)
     kv_axis = dict(rules).get("kv_heads")
     kv_div = mesh.shape.get(kv_axis, 1) if kv_axis else 1
-    dp = mesh.shape.get("dp", 1)
     # ceil, matching the engine's batch padding to a dp multiple — floor would
     # undercount (batch 12 on dp=8 decodes 2 rows/device, not 1).
     rows_per_device = -(-batch // dp)

@@ -292,7 +292,8 @@ class DecodeEngine:
     @property
     def seq_bucket(self) -> int:
         """Sequence bucket multiple: 128 only when this model can actually take
-        the Pallas flash path (head_dim tiling + TPU); otherwise 64 to halve
+        the Pallas flash path (head_dim tiling + TPU) or attends over lane-
+        padded XLA scores on the TPU (latent attention); otherwise 64 to halve
         padding. Shared by decode prefill and scoring so both stay eligible."""
         from fairness_llm_tpu.ops.quant_matmul import lowering_for_tpu
 
@@ -301,7 +302,11 @@ class DecodeEngine:
             and self.config.head_dim % 64 == 0
             and lowering_for_tpu()
         )
-        return 128 if flash_eligible else 64
+        # Latent attention's prefill builds [.., S, S] scores in XLA: a key
+        # axis of 192 is padded to 256 lanes on the chip anyway, and one
+        # bucket for a sweep's 175-195 tokens means one prefill program.
+        lane_scores = self.config.attention == "mla" and lowering_for_tpu()
+        return 128 if flash_eligible or lane_scores else 64
 
     # -- compiled program ---------------------------------------------------
 
@@ -482,6 +487,11 @@ class DecodeEngine:
         # its resume-subset — fine for one-shot calls, not for sweeps.
         from fairness_llm_tpu.models.tokenizer import _left_pad
 
+        if self.config.attention != "gqa":
+            # A shared prefix is [Pc, Hkv, D] keys and values; a latent cache
+            # has no such form yet (stepbuilder.build_prefix refuses it), so
+            # every row prefills its whole prompt.
+            share_prefix, prefix_ids = False, None
         rows = [self.tokenizer.encode(p) for p in prompts]
         shared_ids: Optional[list] = None
         if share_prefix is not False and n >= 1 and prefix_ids is not None:

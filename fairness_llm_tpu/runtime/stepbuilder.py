@@ -77,6 +77,7 @@ import jax
 import jax.numpy as jnp
 
 from fairness_llm_tpu.models.transformer import LayerCache, init_cache
+from fairness_llm_tpu.parallel.sharding import require_gqa
 from fairness_llm_tpu.runtime.sampling import (
     SamplerSettings,
     greedy_accept_length,
@@ -471,6 +472,8 @@ def build_prefix(cfg, model, *, prefix_len: int):
     """Compiled forward over the shared prompt prefix [1, Pc] -> per-layer
     (k, v) arrays [Pc, Hkv, D] every batch row reads (but never copies)."""
 
+    require_gqa(cfg, "the shared-prefix forward")
+
     def run(params, tokens):
         positions = jnp.arange(prefix_len, dtype=jnp.int32)[None, :]
         cache = init_cache(cfg, 1, prefix_len)
@@ -562,9 +565,16 @@ def build_serve_step(cfg, model, sampler: SamplerSettings, pad_id: int,
         )
         c = loop(params, cache, prev_logits, row_seeds, emitted0, base,
                  caps, live0, None)
+        cache, counters = c[1], c[6]
+        if cache.counters is not None:
+            # What the model counted on the device since the last chunk
+            # (prefills included) rides to the host behind the loop's own
+            # two counters, and starts again from zero.
+            counters = jnp.concatenate([counters, cache.counters])
+            cache = cache.replace(counters=jnp.zeros_like(cache.counters))
         if guard:
-            return c[1], c[2], c[5], c[4], c[6], c[7]
-        return c[1], c[2], c[5], c[4], c[6]
+            return cache, c[2], c[5], c[4], counters, c[7]
+        return cache, c[2], c[5], c[4], counters
 
     run.__name__ = program_label("serve_step", fuse)
     return run
@@ -599,19 +609,16 @@ def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
         def scat(big, rows):
             return big.at[slots, :P].set(rows, mode="drop")
 
-        new_layers = []
-        for bl, sl in zip(cache.layers, small.layers):
-            kw = dict(k=scat(bl.k, sl.k), v=scat(bl.v, sl.v))
-            if bl.k_scale is not None:
-                kw.update(
-                    k_scale=scat(bl.k_scale, sl.k_scale),
-                    v_scale=scat(bl.v_scale, sl.v_scale),
-                )
-            new_layers.append(LayerCache(**kw))
+        # Whatever leaves each layer keeps per request (k and v, their
+        # scales, or a latent and its rotated key): each is [rows, slots,
+        # ...], and each row lands in its slot.
+        new_layers = jax.tree.map(scat, cache.layers, small.layers)
         key_valid = scat(cache.key_valid, small.key_valid)
         key_valid = key_valid.at[slots, P:].set(False, mode="drop")
         new_cache = cache.replace(
-            layers=tuple(new_layers),
+            layers=new_layers,
+            counters=(None if cache.counters is None
+                      else cache.counters + small.counters),
             key_valid=key_valid,
             key_positions=scat(cache.key_positions, small.key_positions),
             lengths=cache.lengths.at[slots].set(
@@ -663,6 +670,7 @@ def build_paged_prefill(model, *, nb: int, S: int, guard: bool,
     """
     from fairness_llm_tpu.serving.paged import gather_view, scatter_view
 
+    require_gqa(model.config, "the paged prefill")
     masked_finite = _masked_finite() if guard else None
 
     def run(params, arena, prev_logits, tokens, valid, positions,

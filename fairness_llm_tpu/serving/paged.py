@@ -84,6 +84,9 @@ def init_arena(
     config: ModelConfig, num_blocks: int, block_size: int, num_slots: int,
     dtype=None,
 ) -> BlockArena:
+    from fairness_llm_tpu.parallel.sharding import require_gqa
+
+    require_gqa(config, "the paged block arena")
     dtype = dtype or (
         jnp.bfloat16 if config.dtype == "bfloat16" else jnp.float32
     )

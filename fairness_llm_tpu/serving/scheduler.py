@@ -93,7 +93,7 @@ from fairness_llm_tpu.config import (
     ServingConfig,
 )
 from fairness_llm_tpu.models.tokenizer import _left_pad
-from fairness_llm_tpu.models.transformer import init_cache
+from fairness_llm_tpu.models.transformer import MOE_COUNTERS, init_cache
 from fairness_llm_tpu.parallel import sharding as shd
 from fairness_llm_tpu.resilience.breaker import BreakerBoard
 from fairness_llm_tpu.resilience.drain import (
@@ -1892,6 +1892,11 @@ class ContinuousScheduler:
         steps = int(counters[0])
         stats.decode_steps += steps
         stats.occupancy_sum += int(counters[1])
+        # What the model itself counted on the device since the last chunk
+        # (routed experts: tokens, local pairs, the fullest expert's pairs,
+        # summed over layers, steps and the prefills in between).
+        for name, value in zip(MOE_COUNTERS, counters[2:]):
+            setattr(stats, name, getattr(stats, name) + int(value))
         # Performance attribution (telemetry/): the chunk's span on this
         # scheduler's timeline track (the gap to the previous chunk feeds
         # the step_gap_s histogram — the per-step host sync ROADMAP item 3

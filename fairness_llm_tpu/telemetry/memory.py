@@ -514,17 +514,22 @@ _ALL_REDUCE = re.compile(r"\ball-reduce(-start)?\(")
 
 
 def count_hlo_ops(hlo_text: str) -> Dict[str, int]:
-    """What a compiled module holds of the ops a run has to prove: the two
+    """What a compiled module holds of the ops a run has to prove: the
     Pallas kernels, found by the ``name=`` their ``pallas_call`` carries into
     the Mosaic custom call's op_name (ops/quant_matmul.py,
     ops/flash_attention.py), and the all-reduces GSPMD inserts after
     partitioning (invisible to the jaxpr walk)."""
-    counts = {"int8_matmul": 0, "flash_attention": 0, "all-reduce": 0}
+    counts = {"int8_matmul": 0, "grouped_int8_matmul": 0,
+              "flash_attention": 0, "all-reduce": 0}
     for line in hlo_text.splitlines():
         if "tpu_custom_call" in line:
-            for kernel in ("int8_matmul", "flash_attention"):
+            # The grouped product's name ends in the plain one's: look for
+            # it first, and count a call once.
+            for kernel in ("grouped_int8_matmul", "int8_matmul",
+                           "flash_attention"):
                 if f"{kernel}/pallas_call" in line:
                     counts[kernel] += 1
+                    break
         elif "all-reduce" in line and _ALL_REDUCE.search(line):
             counts["all-reduce"] += 1
     return counts

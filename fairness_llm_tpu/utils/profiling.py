@@ -302,6 +302,11 @@ class ServingStats:
       = occupancy_sum / decode_steps)
     - ``queue_depth_sum`` / ``queue_depth_max`` / ``loop_iterations``:
       admission-queue pressure over the scheduler loop
+    - ``moe_tokens`` / ``moe_local_pairs`` / ``moe_fullest_expert_pairs``:
+      routed-expert layers only, counted on the device and summed over
+      layers, decode steps and prefills: real tokens routed, the (token,
+      expert) pairs whose expert this chip holds, and the pairs of the
+      fullest held expert (x held experts / local pairs = load max over mean)
     """
 
     num_slots: int = 0
@@ -321,6 +326,9 @@ class ServingStats:
     queue_depth_sum: int = 0
     queue_depth_max: int = 0
     loop_iterations: int = 0
+    moe_tokens: int = 0
+    moe_local_pairs: int = 0
+    moe_fullest_expert_pairs: int = 0
 
     @property
     def tokens_per_step(self) -> float:

@@ -29,11 +29,13 @@ import os
 import sys
 import time
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
 
 
 def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
-              tp: int, layers: int | None, nb: int) -> list:
+              tp: int, layers: int | None, nb: int,
+              bench_config: str | None = None) -> list:
     import flax.linen as nn
     import jax
     import jax.numpy as jnp
@@ -58,7 +60,17 @@ def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
     )
     from fairness_llm_tpu.telemetry.memory import count_hlo_ops
 
-    cfg = dataclasses.replace(get_model_config(model_name), weight_quant="int8")
+    if bench_config:
+        # A benchmark configuration, as its family hands it to the program.
+        from perfbench import families
+        from perfbench.lib import modelcfg
+
+        bench = modelcfg.load_config(os.path.join(
+            ROOT, "perfbench", "configs", bench_config + ".json"))
+        cfg = families.of(bench).model_config(bench, bench_config)
+    else:
+        cfg = dataclasses.replace(get_model_config(model_name),
+                                  weight_quant="int8")
     if layers is not None:
         cfg = dataclasses.replace(cfg, num_layers=layers)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
@@ -123,6 +135,8 @@ def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
             "live_gb": round(live / 1e9, 2),
             "hbm_limit_gb": V5E_HBM_GB,
             "fits": bool(live / 1e9 < V5E_HBM_GB),
+            "wants_flash": bool(cfg.use_flash_attention),
+            "wants_grouped": "moe" in cfg.layer_kinds,
         }
         print(json.dumps(rec), flush=True)
         out.append(rec)
@@ -132,6 +146,9 @@ def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
 if __name__ == "__main__":
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--model", default="mistral-7b")
+    ap.add_argument("--bench-config", default=None,
+                    help="a configuration of perfbench/configs by name, "
+                         "built as the benchmark builds it (overrides --model)")
     ap.add_argument("--slots", type=int, default=8)
     ap.add_argument("--prompt-budget", type=int, default=1152)
     ap.add_argument("--max-new", type=int, default=128)
@@ -146,9 +163,11 @@ if __name__ == "__main__":
 
     place_compile_cache()
     recs = preflight(a.model, a.slots, a.prompt_budget, a.max_new, a.tp,
-                     a.layers, a.nb)
+                     a.layers, a.nb, a.bench_config)
     bad = [r["program"] for r in recs
            if not r["fits"] or not r["int8_matmul"]
-           or (r["program"] == "serve_prefill" and not r["flash_attention"])
+           or (r["program"] == "serve_prefill" and r["wants_flash"]
+               and not r["flash_attention"])
+           or (r["wants_grouped"] and not r["grouped_int8_matmul"])
            or (a.tp > 1 and not r["all-reduce"])]
     sys.exit(f"pre-flight failed for {bad}" if bad else 0)
