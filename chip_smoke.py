@@ -179,11 +179,12 @@ def _check_kernels(reg, cfg, tp: int, on_tpu: bool, static: bool) -> List[str]:
     suffix = f"@tp{tp}" if tp > 1 else ""
     # The static sweep shares its prompt prefix: the prefix program takes the
     # flash kernel, the decode program attends over prefix + own cache in XLA.
+    # The served prefill extends the slot pool a window at a time and attends
+    # over the cache in XLA too.
     wanted = ([("prefix", "int8_matmul"), ("prefix", "flash_attention"),
                ("decode", "int8_matmul")]
               if static else
               [(f"serve_prefill{suffix}", "int8_matmul"),
-               (f"serve_prefill{suffix}", "flash_attention"),
                (f"serve_step{suffix}", "int8_matmul")])
     if tp > 1:
         wanted.append((f"serve_step{suffix}", "all-reduce"))

@@ -583,54 +583,122 @@ def build_serve_step(cfg, model, sampler: SamplerSettings, pad_id: int,
 # -- serving prefill programs --------------------------------------------------
 
 
+#: New tokens a ``serve_prefill`` call computes per row. Prompt buckets are
+#: multiples of the sequence bucket (64 or 128), so 64 divides every ``P``; it
+#: is the smallest such width that covers the study's divergent tails (17-37
+#: ids) in one window. 128 measured on the chip: ``PERF.md`` section 6, PR 29.
+EXTEND_WINDOW = 64
+
+
 def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
                         num_slots: int):
-    """[nb, P] prompt prefill + row scatter into the shared cache.
+    """Extend ``nb`` rows of the slot pool by one window of ``EXTEND_WINDOW``
+    tokens: prefill appends to what a row's ``[:P]`` extent already holds.
 
-    Numerically the engine's batch entry: left-padded tokens, positions
-    from the valid cumsum, ``last_only`` logits. The fresh [nb, P] cache's
-    post-write rows (k/v/key_valid/key_positions/lengths) scatter into the
-    big cache at ``slots``; slots >= num_slots (batch-bucket pad rows)
-    drop. Rows' tail slots [P, cache_len) are re-invalidated here, so a
-    recycled slot never exposes its previous tenant's keys.
+    A row's prompt is left-padded to ``P`` (``pad`` slots of padding, real
+    token ``t`` at slot ``pad + t`` and position ``t``). A call computes the
+    slots ``[w, w + S)`` of every row it names; the host walks a prompt's
+    windows in order, so what lies before ``w`` is there already:
+
+    1. each row first takes ``copied`` positions from ``donor``, a resident
+       row whose prompt starts with the same ids: slots ``[donor_pad,
+       donor_pad + copied)`` of the donor land in ``[pad, pad + copied)``.
+       Keys are rotated at their logical positions, which do not depend on
+       the slot or the padding, so they are this row's keys too. Whatever
+       leaves a layer keeps per request (k and v, their scales, or a latent
+       and its rotated key) and ``key_positions`` move alike; ``copied = 0``
+       moves nothing;
+    2. validity is constructed, never read: real slots before the window
+       are visible, everything else in the extent is dark, so a recycled
+       slot cannot expose its previous tenant's keys;
+    3. the window goes forward at the cache's ``index = w`` (every row of
+       the call writes the same slots, so the uniform write serves): query
+       ``i`` sees slot ``j`` iff ``j <= w + i`` and the slot is valid, the
+       small causal window against an already-valid cache that
+       ``build_paged_prefill`` runs per row through ``write_offsets``
+       (latent layers read the latent cache through the absorbed form);
+    4. the extent scatters back at ``slots`` (slots >= num_slots, the
+       batch-bucket pad rows and the rows that sit this window out, drop),
+       the tail ``[P, cache_len)`` is invalidated, and the window's last
+       position lands in the carried logits: the row's next-token logits
+       once its last window (``w + S == P``) has run.
     """
     masked_finite = _masked_finite() if guard else None
+    S = EXTEND_WINDOW
+    if P % S:
+        raise ValueError(f"prompt bucket {P} is not a multiple of the "
+                         f"extend window {S}")
 
-    def run(params, cache, prev_logits, tokens, valid, slots):
-        positions = jnp.maximum(
-            jnp.cumsum(valid.astype(jnp.int32), axis=1) - 1, 0
+    def run(params, cache, prev_logits, tokens, valid, slots, pad, donor,
+            donor_pad, copied, w):
+        L = cache.max_len
+        own = jnp.minimum(slots, num_slots - 1)
+        j = jnp.arange(P, dtype=jnp.int32)[None, :]
+        lead = pad[:, None]
+        from_donor = (j >= lead) & (j < lead + copied[:, None])
+        # The donor's row rolled so that its slot donor_pad meets pad. The
+        # copied range never wraps: it lies inside the donor's own extent.
+        shift = jnp.mod(donor_pad - pad, L)
+
+        def take(big):
+            # ``lax.gather`` spelled out: NumPy-style indexing and ``vmap``
+            # cost several times as much to trace, per leaf, in every
+            # prefill bucket's set-up.
+            tail = big.shape[2:]
+            offsets = tuple(range(1, big.ndim))
+
+            def rows_of(operand, starts, length, index_map):
+                return jax.lax.gather(
+                    operand, starts, jax.lax.GatherDimensionNumbers(
+                        offset_dims=offsets, collapsed_slice_dims=(0,),
+                        start_index_map=index_map),
+                    (1, length) + tail, mode="promise_in_bounds")
+
+            theirs = rows_of(big, donor[:, None], L, (0,))
+            theirs = jnp.concatenate([theirs, theirs[:, :P]], axis=1)
+            theirs = rows_of(
+                theirs, jnp.stack([jnp.arange(nb, dtype=jnp.int32), shift],
+                                  axis=1), P, (0, 1))
+            mask = from_donor.reshape(from_donor.shape
+                                      + (1,) * (big.ndim - 2))
+            return jnp.where(mask, theirs,
+                             rows_of(big, own[:, None], P, (0,)))
+
+        view = cache.replace(
+            layers=jax.tree.map(take, cache.layers),
+            key_positions=take(cache.key_positions),
+            key_valid=(j >= lead) & (j < w),
+            lengths=jnp.maximum(w - pad, 0),
+            index=w,
+            counters=(None if cache.counters is None
+                      else jnp.zeros_like(cache.counters)),
         )
-        small = init_cache(cfg, nb, P)
-        logits, small = model.apply(
-            {"params": params}, tokens, positions, valid, small,
-            left_padded=True, last_only=True,
+        positions = jnp.maximum(
+            w + jnp.arange(S, dtype=jnp.int32)[None, :] - lead, 0)
+        logits, view = model.apply(
+            {"params": params}, tokens, positions, valid, view,
+            last_only=True,
         )
 
         def scat(big, rows):
             return big.at[slots, :P].set(rows, mode="drop")
 
-        # Whatever leaves each layer keeps per request (k and v, their
-        # scales, or a latent and its rotated key): each is [rows, slots,
-        # ...], and each row lands in its slot.
-        new_layers = jax.tree.map(scat, cache.layers, small.layers)
-        key_valid = scat(cache.key_valid, small.key_valid)
+        key_valid = scat(cache.key_valid, view.key_valid)
         key_valid = key_valid.at[slots, P:].set(False, mode="drop")
         new_cache = cache.replace(
-            layers=new_layers,
+            layers=jax.tree.map(scat, cache.layers, view.layers),
             counters=(None if cache.counters is None
-                      else cache.counters + small.counters),
+                      else cache.counters + view.counters),
             key_valid=key_valid,
-            key_positions=scat(cache.key_positions, small.key_positions),
-            lengths=cache.lengths.at[slots].set(
-                small.lengths, mode="drop"
-            ),
+            key_positions=scat(cache.key_positions, view.key_positions),
+            lengths=cache.lengths.at[slots].set(view.lengths, mode="drop"),
         )
         new_logits = prev_logits.at[slots].set(
             logits[:, -1, :], mode="drop"
         )
         if guard:
-            # Real admissions only (batch-bucket pad rows scatter-drop
-            # and may hold anything): one reduced flag for the batch.
+            # Real rows of this window only (the others scatter-drop and
+            # may hold anything): one reduced flag for the batch.
             return new_cache, new_logits, masked_finite(
                 logits[:, -1, :], slots < num_slots
             )

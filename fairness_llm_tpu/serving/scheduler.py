@@ -92,7 +92,6 @@ from fairness_llm_tpu.config import (
     ResilienceConfig,
     ServingConfig,
 )
-from fairness_llm_tpu.models.tokenizer import _left_pad
 from fairness_llm_tpu.models.transformer import MOE_COUNTERS, init_cache
 from fairness_llm_tpu.parallel import sharding as shd
 from fairness_llm_tpu.resilience.breaker import BreakerBoard
@@ -104,6 +103,7 @@ from fairness_llm_tpu.resilience.drain import (
 from fairness_llm_tpu.resilience.watchdog import StepWatchdog
 from fairness_llm_tpu.runtime.sampling import SamplerSettings
 from fairness_llm_tpu.runtime.stepbuilder import (
+    EXTEND_WINDOW,
     build_paged_prefill,
     build_serve_prefill,
     build_serve_step,
@@ -123,7 +123,7 @@ from fairness_llm_tpu.serving.request import (
     Result,
 )
 from fairness_llm_tpu.serving.paged import PagedKV, init_arena
-from fairness_llm_tpu.serving.slots import SlotPool, SlotState
+from fairness_llm_tpu.serving.slots import LiveRowPrefixes, SlotPool, SlotState
 from fairness_llm_tpu.telemetry import (
     Heartbeat,
     RequestTracer,
@@ -564,9 +564,10 @@ class ContinuousScheduler:
                                    tokens=tokens, scope=scope)
 
     def _prefill_fn(self, nb: int, P: int, guard: bool):
-        """[nb, P] prompt prefill + row scatter into the shared cache — the
-        builder's ``serve_prefill`` composition (see
-        ``stepbuilder.build_serve_prefill`` for the program semantics)."""
+        """One window's extend of [nb] rows' ``[:P]`` extents of the shared
+        cache: the builder's ``serve_prefill`` composition (see
+        ``stepbuilder.build_serve_prefill`` for the program semantics). The
+        collectives row counts the window's tokens."""
         key = compile_key("serve_prefill", nb=nb, P=P, guard=guard,
                           tp=self.tp)
         program = program_label("serve_prefill", tp=self.tp)
@@ -581,8 +582,9 @@ class ContinuousScheduler:
         # OTHER live slots' cache rows intact, and a donated input buffer
         # doesn't survive a raised call. instrument_jit = jax.jit + the cost
         # ledger (telemetry/costmodel.py) on every compiled program.
-        fn = instrument_jit(run, program,
-                            collectives=self._collectives(nb, P, "call"))
+        fn = instrument_jit(
+            run, program,
+            collectives=self._collectives(nb, EXTEND_WINDOW, "call"))
         self._compiled[key] = fn
         return fn
 
@@ -1255,21 +1257,23 @@ class ContinuousScheduler:
         with tl.span("sched.admit.plan", "sched", self._track,
                      queue_depth=len(self.queue),
                      live_rows=self.pool.occupancy) as plan_span:
-            plan = self._plan_admission(n, stats)
-            if plan is None:
+            popped = self._plan_admission(n, stats)
+            if popped is None:
                 return False
-            admitted, injected_hang = plan
+            admitted, injected_hang = popped
             if self.paged:
                 groups = self._plan_paged(admitted)
                 plan_span.set(rows=sum(len(g) for g in groups.values()))
             else:
-                call = self._plan_prefill(admitted)
-                plan_span.set(rows=len(admitted), nb=call["nb"], P=call["P"])
+                plan = self._plan_prefill(admitted)
+                plan_span.set(rows=len(admitted), nb=plan["nb"], P=plan["P"],
+                              donors=plan["donors"],
+                              windows=len(plan["calls"]))
         if self.paged:
             for S in sorted(groups):
                 self._paged_prefill_group(groups[S], S, stats, injected_hang)
             return bool(groups)
-        return self._dispatch_prefill(call, stats, injected_hang)
+        return self._dispatch_prefill(plan, stats, injected_hang)
 
     def _plan_admission(self, n: int, stats: ServingStats):
         """Pop ``n`` requests and settle each one's fate before any device
@@ -1352,98 +1356,162 @@ class ContinuousScheduler:
         return admitted, injected_hang
 
     def _plan_prefill(self, admitted) -> dict:
-        """Slots, bucket and padded host arrays for one contiguous prefill
-        call, on the device before the call is made."""
+        """What one contiguous admission batch has left to compute, as the
+        arguments of its ``serve_prefill`` calls (one per window of
+        ``EXTEND_WINDOW`` slots, in order), on the device before the first
+        call is made.
+
+        A row whose prompt starts like a live row's copies what it can and
+        extends over its last windows only: with ``m`` ids shared and ``k``
+        the fewest windows for which ``n - k S <= m``, ``n - k S`` positions
+        come from the donor. A row with no such donor (or fewer than ``S``
+        ids) runs every window that holds one of its tokens. Donors are rows
+        resident before this call: never a slot it writes.
+        """
         tok = self.engine.tokenizer
-        # ONE prefill per admission batch, at the max prompt bucket of the
-        # batch. Shorter rows pad up to it — numerically free (pad slots are
-        # masked, contributing exact zeros to every reduction; parity tests
-        # pin this) and much cheaper than a compiled call per bucket when
+        S = EXTEND_WINDOW
+        # ONE bucket per admission batch, the largest of its rows'. Shorter
+        # rows pad up to it: numerically free (pad slots are masked,
+        # contributing exact zeros to every reduction; parity tests pin
+        # this) and much cheaper than a compiled shape per bucket when
         # backfills trickle in one or two rows at a time. A row's ``base``
         # is therefore the bucket it was PREFILLED at, which its decode
         # write offsets continue from.
         P = max(item[2] for item in admitted)
-        rows = [ids for _, ids, _ in admitted]
-        reqs = [r for r, _, _ in admitted]
-        slots = []
-        for req, ids, _ in admitted:
+        n_rows = len(admitted)
+        nb = _bucket_pow2(n_rows, max(self.serving.prefill_group, n_rows))
+        live = LiveRowPrefixes(self.pool)
+        reqs, slots = [], []
+        tokens = np.full((nb, P), tok.pad_id, np.int32)
+        lead = np.full((nb,), P, np.int32)  # left padding; P: no real token
+        donor = np.zeros((nb,), np.int32)
+        donor_pad = np.zeros((nb,), np.int32)
+        copied = np.zeros((nb,), np.int32)
+        # The window a row starts in; batch-bucket pad rows start in none.
+        first = np.full((nb,), P // S, np.int32)
+        for r, (req, ids, _) in enumerate(admitted):
+            ids = np.asarray(ids[-P:], np.int32)
+            n = len(ids)
+            tokens[r, P - n:] = ids
+            lead[r] = P - n
+            first[r] = min((P - n) // S, P // S - 1)
+            src, m = live.longest(ids)
+            k = max(1, -(-(n - m) // S))
+            if src is not None and n - k * S > 0:
+                st = self.pool.get(src)
+                donor[r], donor_pad[r] = src, st.base - st.real_len
+                copied[r] = n - k * S
+                first[r] = P // S - k
+            reqs.append(req)
+        # Slots are claimed after every row has looked for its donor, so
+        # that no row of this batch can be another's.
+        for r, req in enumerate(reqs):
             slot = self.pool.alloc(SlotState(
-                request=req, base=P, real_len=min(len(ids), P),
+                request=req, base=P, real_len=int(P - lead[r]),
+                ids=tokens[r, lead[r]:],
             ))
             assert slot is not None  # admission is free-count bounded
             slots.append(slot)
             self.tracer.record(req.id, "admitted")
-        nb = _bucket_pow2(len(admitted), max(self.serving.prefill_group,
-                                             len(admitted)))
-        pad_id = tok.pad_id
-        tb = _left_pad(rows, pad_id, max_len=P)
-        tokens = np.full((nb, P), pad_id, np.int32)
-        valid = np.zeros((nb, P), bool)
-        tokens[: len(admitted)] = tb.tokens
-        valid[: len(admitted)] = tb.valid
-        # Batch-bucket pad rows: one valid token so softmax has mass
-        # (engine idiom); their slot id is out of range -> scatter-drop.
-        valid[len(admitted):, -1] = True
         slot_ids = np.full((nb,), self.num_slots, np.int32)
-        slot_ids[: len(admitted)] = slots
+        slot_ids[:n_rows] = slots
+        valid = np.arange(P)[None, :] >= lead[:, None]
+        calls = []
+        for i in range(int(first.min()), P // S):
+            w = i * S
+            rides = first <= i
+            # A row that sits this window out (or pads the batch bucket)
+            # has no valid token and an out-of-range slot id: it attends to
+            # nothing (a uniform softmax over masked keys, finite), routes
+            # to no expert, and its extent scatter-drops.
+            calls.append(dict(w=w, rows=int(rides.sum()), args=tuple(
+                jnp.asarray(x) for x in (
+                    np.where(rides[:, None], tokens[:, w:w + S], tok.pad_id),
+                    valid[:, w:w + S] & rides[:, None],
+                    np.where(rides, slot_ids, self.num_slots),
+                    np.where(rides, lead, P),
+                    donor, donor_pad,
+                    np.where(first == i, copied, 0),
+                    np.asarray(w, np.int32),  # 0-d: a transfer, no program
+                ))))
         # First use of this (batch, prompt) bucket compiles; that wall is
         # exempt from hang classification (injected stalls still classify).
         guard = self._guard()
         pf_key = compile_key("serve_prefill", nb=nb, P=P, guard=guard,
                              tp=self.tp)
-        first_compile = pf_key not in self._compiled
         return dict(
             nb=nb, P=P, reqs=reqs, slots=slots, guard=guard, key=pf_key,
-            first_compile=first_compile, fn=self._prefill_fn(nb, P, guard),
+            first_compile=pf_key not in self._compiled,
+            fn=self._prefill_fn(nb, P, guard),
             program=program_label("serve_prefill", tp=self.tp),
-            prompt_tokens=int(tb.lengths.sum()),
-            args=(jnp.asarray(tokens), jnp.asarray(valid),
-                  jnp.asarray(slot_ids)),
+            prompt_tokens=int((P - lead).sum()),
+            reused_tokens=int(copied.sum()),
+            donors=int((copied > 0).sum()), calls=calls,
         )
 
-    def _dispatch_prefill(self, call: dict, stats: ServingStats,
+    def _dispatch_prefill(self, plan: dict, stats: ServingStats,
                           injected_hang: float) -> bool:
-        """The one compiled call of a contiguous admission batch, as the
-        ``sched.prefill.dispatch`` span that names the requests it admits.
-        On an asynchronous backend the call returns once the work is
-        queued: the span, ``prefill_wall_s`` and ``note_invocation`` are the
-        DISPATCH; the device is known to be done only at the next
-        ``sched.decode.sync``."""
-        nb, P, reqs, slots = call["nb"], call["P"], call["reqs"], call["slots"]
-        fn, guard, first_compile = call["fn"], call["guard"], \
-            call["first_compile"]
+        """The compiled calls of a contiguous admission batch, window by
+        window, each as a ``sched.prefill.dispatch`` span that names the
+        requests it admits. On an asynchronous backend a call returns once
+        the work is queued: the span, ``prefill_wall_s`` and
+        ``note_invocation`` are the DISPATCH; the device is known to be
+        done with a batch's last window only at the next
+        ``sched.decode.sync`` (the windows before it are waited for, so
+        that no two of a batch are in flight). A call that fails
+        releases the whole batch: a row some earlier window wrote stays
+        out of every decode step and is no donor, and its slot is reset
+        or prefilled anew before anything reads it."""
+        nb, P, reqs, slots = plan["nb"], plan["P"], plan["reqs"], plan["slots"]
+        fn, guard, first_compile = plan["fn"], plan["guard"], \
+            plan["first_compile"]
         pf_t0 = time.monotonic()
         for req in reqs:
             self.tracer.record(req.id, "prefill_start", t=pf_t0)
-        if self.watchdog is not None:
-            self.watchdog.arm("prefill")
         try:
-            with get_timeline().span(
-                    "sched.prefill.dispatch", "prefill", self._track,
-                    program=call["program"], nb=nb, P=P, rows=len(reqs),
-                    request_ids=[r.id for r in reqs]):
-                self._note_dispatch(pf_t0)
-                out = self._run_compiled(
-                    fn, self.engine.params, self._cache, self._prev_logits,
-                    *call["args"],
-                )
-                if guard:
-                    new_cache, new_logits, finite = out
-                    # Checked BEFORE the state swap: prefill isn't donated,
-                    # so a poisoned batch leaves the previous cache/logits
-                    # untouched (the containment branch releases the new
-                    # slots).
-                    check_finite(finite, "serving", "prefill")
-                else:
-                    new_cache, new_logits = out
-            self._cache, self._prev_logits = new_cache, new_logits
-            if self.watchdog is not None:
-                # Post-hoc hang classification (see resilience/watchdog.py):
-                # an over-budget prefill raises HangFault INTO the
-                # containment branch below — the cache rows it wrote are
-                # released with their slots, so nothing stale survives.
-                self.watchdog.observe("prefill", extra_s=injected_hang,
-                                      classify=not first_compile)
+            for call in plan["calls"]:
+                t0 = time.monotonic()
+                if self.watchdog is not None:
+                    self.watchdog.arm("prefill")
+                with get_timeline().span(
+                        "sched.prefill.dispatch", "prefill", self._track,
+                        program=plan["program"], nb=nb, P=P, w=call["w"],
+                        rows=call["rows"],
+                        request_ids=[r.id for r in reqs]):
+                    self._note_dispatch(t0)
+                    if call is not plan["calls"][0]:
+                        # This window reads what the one before wrote.
+                        # Dispatched behind it unfinished, it would hold a
+                        # third copy of the undonated pool (that call's
+                        # input and output, and its own output); waiting
+                        # costs one host round trip.
+                        jax.block_until_ready(self._prev_logits)
+                    out = self._run_compiled(
+                        fn, self.engine.params, self._cache,
+                        self._prev_logits, *call["args"],
+                    )
+                    if guard:
+                        new_cache, new_logits, finite = out
+                        # Checked BEFORE the state swap: prefill isn't
+                        # donated, so a poisoned call leaves the previous
+                        # cache/logits untouched (the containment branch
+                        # releases the new slots).
+                        check_finite(finite, "serving", "prefill")
+                    else:
+                        new_cache, new_logits = out
+                self._cache, self._prev_logits = new_cache, new_logits
+                if self.watchdog is not None:
+                    # Post-hoc hang classification (see
+                    # resilience/watchdog.py): an over-budget prefill raises
+                    # HangFault INTO the containment branch below — the
+                    # cache rows it wrote are released with their slots, so
+                    # nothing stale survives.
+                    self.watchdog.observe("prefill", extra_s=injected_hang,
+                                          classify=not first_compile)
+                self._note_prefill(plan["program"], time.monotonic() - t0,
+                                   fn, first_compile, plan["key"])
+                stats.prefill_batches += 1
+                first_compile, injected_hang = False, 0.0
         except Exception as e:  # noqa: BLE001 — containment is the point
             kind = ("hang" if isinstance(e, HangFault)
                     else "numerics" if isinstance(e, NumericsFault)
@@ -1465,10 +1533,8 @@ class ContinuousScheduler:
             return True
         if self.breakers is not None:
             self.breakers.record_success("prefill")
-        self._note_prefill(call["program"], time.monotonic() - pf_t0, fn,
-                           first_compile, call["key"])
-        stats.prefill_batches += 1
-        stats.prefill_tokens += call["prompt_tokens"]
+        stats.prefill_tokens += plan["prompt_tokens"]
+        stats.prefill_tokens_reused += plan["reused_tokens"]
         stats.admitted += len(reqs)
         return True
 

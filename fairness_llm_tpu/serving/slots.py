@@ -32,7 +32,9 @@ from __future__ import annotations
 
 import dataclasses
 import heapq
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
+
+import numpy as np
 
 from fairness_llm_tpu.serving.paged import PagedKV
 from fairness_llm_tpu.serving.request import Request
@@ -45,6 +47,40 @@ class SlotState:
     real_len: int  # real prompt tokens (position origin for decode)
     emitted: int = 0  # generated tokens so far
     tokens: List[int] = dataclasses.field(default_factory=list)
+    # The prompt's ids as prefilled (private-row layout): real token t sits
+    # at slot ``base - real_len + t``, which is what lets a later row with
+    # the same leading ids copy them (``LiveRowPrefixes``). None: never a donor.
+    ids: Optional[np.ndarray] = None
+
+
+class LiveRowPrefixes:
+    """The live rows' prompt ids side by side, so that a new row finds the
+    resident row sharing its longest prefix in one comparison. Built once
+    per admission batch, before the batch claims its slots: a row it writes
+    is never in it."""
+
+    def __init__(self, pool: "SlotPool"):
+        self.slots = [slot for slot in pool.live_slots()
+                      if pool.get(slot).ids is not None]
+        rows = [pool.get(slot).ids for slot in self.slots]
+        width = max(map(len, rows), default=0)
+        self.ids = np.full((len(rows), width), -1, np.int64)  # -1: no token
+        for i, ids in enumerate(rows):
+            self.ids[i, :len(ids)] = ids
+
+    def longest(self, ids: np.ndarray) -> Tuple[Optional[int], int]:
+        """(slot, shared ids) of the live row that shares most leading ids
+        with ``ids``, the lowest slot among equals; (None, 0) when no live
+        row shares the first."""
+        if not self.slots:
+            return None, 0
+        n = min(len(ids), self.ids.shape[1])
+        differ = self.ids[:, :n] != np.asarray(ids[:n])[None, :]
+        shared = np.where(differ.any(axis=1), differ.argmax(axis=1), n)
+        best = int(shared.argmax())
+        if shared[best] == 0:
+            return None, 0
+        return self.slots[best], int(shared[best])
 
 
 class SlotPool:

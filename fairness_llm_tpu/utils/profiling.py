@@ -293,8 +293,11 @@ class ServingStats:
       ``shed_total{class,reason}``
     - ``rejected``: submissions refused at the queue (capacity/rate)
     - ``requeued``: fault-hit slots sent back for one retry
-    - ``prefill_batches`` / ``prefill_tokens``: compiled prefill forwards and
-      the REAL prompt tokens they processed
+    - ``prefill_batches`` / ``prefill_tokens``: compiled prefill calls and
+      the REAL prompt tokens admitted through them, whether their positions
+      were computed or copied
+    - ``prefill_tokens_reused``: of those, the positions a row copied from a
+      resident row with the same leading ids instead of computing them
     - ``decode_steps`` / ``decoded_tokens``: compiled decode-step forwards
       and real tokens emitted; tokens/step measures how full the slot pool
       ran (max = ``num_slots``)
@@ -320,6 +323,7 @@ class ServingStats:
     requeued: int = 0
     prefill_batches: int = 0
     prefill_tokens: int = 0
+    prefill_tokens_reused: int = 0
     decode_steps: int = 0
     decoded_tokens: int = 0
     occupancy_sum: int = 0
@@ -381,7 +385,8 @@ class ServingStats:
         for name in (
             "admitted", "completed", "failed", "expired", "preempted",
             "shed", "rejected", "requeued", "prefill_batches",
-            "prefill_tokens", "decode_steps", "decoded_tokens",
+            "prefill_tokens", "prefill_tokens_reused", "decode_steps",
+            "decoded_tokens",
             "loop_iterations",
         ):
             reg.counter(f"serving_{name}_total", component=component,

@@ -55,6 +55,7 @@ def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
     from fairness_llm_tpu.parallel import sharding as shd
     from fairness_llm_tpu.runtime.sampling import SamplerSettings
     from fairness_llm_tpu.runtime.stepbuilder import (
+        EXTEND_WINDOW,
         build_serve_prefill,
         build_serve_step,
     )
@@ -107,8 +108,9 @@ def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
     row = lambda dt: host((slots,), dt)  # noqa: E731
     programs = [
         ("serve_prefill", jax.jit(prefill),
-         (params, cache, logits, host((nb, prompt_budget), jnp.int32),
-          host((nb, prompt_budget), jnp.bool_), host((nb,), jnp.int32))),
+         (params, cache, logits, host((nb, EXTEND_WINDOW), jnp.int32),
+          host((nb, EXTEND_WINDOW), jnp.bool_))
+         + (host((nb,), jnp.int32),) * 5 + (host((), jnp.int32),)),
         ("serve_step", jax.jit(step, donate_argnums=(1, 2)),
          (params, cache, logits, row(jnp.uint32), row(jnp.int32),
           row(jnp.int32), row(jnp.int32), row(jnp.bool_), row(jnp.bool_))),
@@ -135,7 +137,6 @@ def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
             "live_gb": round(live / 1e9, 2),
             "hbm_limit_gb": V5E_HBM_GB,
             "fits": bool(live / 1e9 < V5E_HBM_GB),
-            "wants_flash": bool(cfg.use_flash_attention),
             "wants_grouped": "moe" in cfg.layer_kinds,
         }
         print(json.dumps(rec), flush=True)
@@ -166,8 +167,6 @@ if __name__ == "__main__":
                      a.layers, a.nb, a.bench_config)
     bad = [r["program"] for r in recs
            if not r["fits"] or not r["int8_matmul"]
-           or (r["program"] == "serve_prefill" and r["wants_flash"]
-               and not r["flash_attention"])
            or (r["wants_grouped"] and not r["grouped_int8_matmul"])
            or (a.tp > 1 and not r["all-reduce"])]
     sys.exit(f"pre-flight failed for {bad}" if bad else 0)
