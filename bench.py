@@ -47,7 +47,7 @@ _ALL_ENTRIES = (
     "speculative", "continuous", "resilience", "integrity", "profiling",
     "fused_decode", "serve_tp", "incidents", "memory", "rollout", "fleet",
     "overload",
-    "fairness", "prefix_cache", "capacity", "large_sweep", "phase2_listwise",
+    "fairness", "capacity", "large_sweep", "phase2_listwise",
     "flash_proof", "int8_70b", "shard70b", "live8b",
 )
 
@@ -155,14 +155,6 @@ def baseline_entries(result: dict) -> dict:
         wall("speculative.speedup", s.get("speedup"))
         exact("speculative.acceptance_rate", s.get("acceptance_rate"))
         exact("speculative.verify_steps", s.get("verify_steps"))
-    p = d.get("prefix_cache")
-    if p:
-        exact("prefix_cache.hit_ratio", p.get("on", {}).get("hit_ratio"))
-        exact("prefix_cache.prefill_tokens_on",
-              p.get("on", {}).get("prefill_tokens"))
-        exact("prefix_cache.prefill_token_reduction",
-              p.get("prefill_token_reduction"))
-        wall("prefix_cache.speedup_ratio", p.get("speedup_ratio"))
     ov = d.get("overload_overhead")
     if ov:
         wall("overload.overhead_ratio", ov.get("overhead_ratio"),
@@ -201,8 +193,6 @@ def baseline_entries(result: dict) -> dict:
         # mesh silently degenerated to replication.
         wall("serve_tp.tokens_per_sec_contig_k4",
              stp.get("contig_k4", {}).get("tokens_per_sec"))
-        wall("serve_tp.tokens_per_sec_paged_k4",
-             stp.get("paged_k4", {}).get("tokens_per_sec"))
         exact("serve_tp.token_checksum", stp.get("token_checksum"))
         exact("serve_tp.useful_tokens", stp.get("useful_tokens"))
     cap = d.get("capacity")
@@ -810,7 +800,7 @@ def _published_fraction(reg, program: str) -> float | None:
 
 def measure_serve_tp() -> dict | None:
     """Real-mesh tensor-parallel serving (the stepbuilder's mesh axis):
-    tp=2 continuous serving — contiguous AND paged, fuse 1 AND 4 — with
+    tp=2 continuous serving — fuse 1 AND 4 — with
     the collectives EXECUTED, not modeled, on a real 2-device mesh
     (``--xla_force_host_platform_device_count``; the worker pins itself
     to the CPU platform and its record says so). The worker
@@ -1039,8 +1029,7 @@ def measure_memory_overhead(engine, prompts, settings_cls) -> dict | None:
                     ), "AOT memory capture published nothing"
                     out[tag].update({
                         "ledger_bytes": int(mem.total_bytes()),
-                        "kv_bytes": int(mem.pool_bytes("kv_contiguous")
-                                        + mem.pool_bytes("kv_paged")),
+                        "kv_bytes": int(mem.pool_bytes("kv_contiguous")),
                         "reconciliation_alerts": int(alerts),
                     })
                 else:
@@ -1719,83 +1708,6 @@ def phase2_7b_committed() -> dict | None:
         return None
 
 
-def measure_prefix_cache(engine, prompts, settings_cls) -> dict | None:
-    """Paged KV + radix prefix reuse A/B on the phase-1-shaped sweep
-    (ISSUE 10 / ROADMAP item 1).
-
-    The counterfactual prompts are byte-identical except for the trailing
-    demographics block, so with ``--paged-kv`` admission should match most
-    of every prompt and prefill only the short suffix. Same engine/params,
-    same slots, greedy for parity; best-of-3 per mode in one process
-    (docs/PERFORMANCE.md methodology — the CPU harness has ±30-60%
-    single-run jitter). Reported: profiles/sec off vs on, prefill tokens
-    off vs on (the measured reduction), and the radix hit rate — with
-    token parity asserted and the ROADMAP >80% hit-rate target asserted
-    on the warm-cache timed runs.
-    """
-    import dataclasses
-
-    from fairness_llm_tpu.config import ServingConfig, default_config
-    from fairness_llm_tpu.serving import ContinuousScheduler, Request
-
-    num_slots = max(default_config().decode_batch_size, 1)
-    budget = 32  # modest decode: keep prefill visible in the wall
-
-    def greedy(m):
-        return _greedy(settings_cls, m)
-
-    scfg = ServingConfig(
-        enabled=True, num_slots=num_slots, max_prompt_len=512,
-        max_new_tokens=budget, decode_chunk=8,
-    )
-    pcfg = dataclasses.replace(scfg, paged_kv=True, kv_block_size=32)
-
-    def run(sched, tag, rep):
-        reqs = [
-            Request(prompt=p, id=f"px_{tag}_{rep}_{i:04d}",
-                    settings=greedy(budget))
-            for i, p in enumerate(prompts)
-        ]
-        t0 = time.perf_counter()
-        results = sched.serve(reqs)
-        wall = time.perf_counter() - t0
-        assert all(r.ok for r in results)
-        toks = [tuple(int(t) for t in r.tokens) for r in results]
-        return wall, toks, sched.last_stats
-
-    out = {"profiles": len(prompts), "num_slots": num_slots,
-           "kv_block_size": 32}
-    tokens = {}
-    for tag, cfg in (("off", scfg), ("on", pcfg)):
-        sched = ContinuousScheduler(engine, cfg, settings=greedy(budget))
-        run(sched, tag, 0)  # warmup: compiles AND (on) populates the radix
-        wall, toks, stats = min((run(sched, tag, rep) for rep in (1, 2, 3)),
-                                key=lambda r: r[0])
-        tokens[tag] = toks
-        out[tag] = {
-            "wall_s": round(wall, 3),
-            "profiles_per_sec": round(len(prompts) / wall, 2),
-            "prefill_tokens": stats.prefill_tokens,
-        }
-        if tag == "on":
-            paged = sched.pool.paged
-            out[tag]["hit_ratio"] = round(paged.hit_ratio, 4)
-            # The ROADMAP item-1 target, on the workload just decoded.
-            assert paged.hit_ratio > 0.8, (
-                f"warm-cache hit ratio {paged.hit_ratio:.3f} <= 0.8"
-            )
-    # Prefix reuse must never change the tokens — the parity contract.
-    assert tokens["on"] == tokens["off"], "paged KV changed output"
-    out["prefill_token_reduction"] = round(
-        1.0 - out["on"]["prefill_tokens"] / max(out["off"]["prefill_tokens"],
-                                                1), 4
-    )
-    out["speedup_ratio"] = round(
-        out["off"]["wall_s"] / out["on"]["wall_s"], 3
-    )
-    return out
-
-
 def measure_capacity(engine, prompts, settings_cls) -> dict | None:
     """Capacity planning: the SAME seeded trace replayed against fixed
     fleets of 1 -> 3 replicas (ISSUE 11).
@@ -2346,18 +2258,6 @@ def _run(baseline_out: "str | None" = None) -> None:
         print(f"fairness overhead A/B skipped: {type(e).__name__}: {e}",
               file=sys.stderr)
 
-    # Paged-KV prefix-cache A/B (ISSUE 10): the phase-1-shaped sweep with
-    # private-row slots vs the paged radix-indexed arena — profiles/sec,
-    # measured prefill-token reduction, and the hit rate, parity asserted.
-    prefix_cache = None
-    try:
-        if _enabled("prefix_cache"):
-            prefix_cache = measure_prefix_cache(engine, prompts,
-                                                ModelSettings)
-    except Exception as e:  # noqa: BLE001 — auxiliary measurement only
-        print(f"prefix cache A/B skipped: {type(e).__name__}: {e}",
-              file=sys.stderr)
-
     # Capacity planning (ISSUE 11): one seeded synthetic trace replayed
     # against 1/2/3-replica fleets — profiles/sec/chip vs interactive SLO
     # attainment vs shed rate, token parity across sizes asserted.
@@ -2723,7 +2623,6 @@ def _run(baseline_out: "str | None" = None) -> None:
             "fleet": fleet,
             "overload_overhead": overload,
             "fairness_overhead": fairness,
-            "prefix_cache": prefix_cache,
             "capacity": capacity,
             "large_sweep": large_sweep,
             "large_sweep_int8kv": large_sweep_int8,

@@ -146,32 +146,15 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--tp", type=int, default=None, metavar="N",
                    help="with --continuous: tensor-parallel serving over an "
                         "N-device tp mesh — every step program (prefill, "
-                        "decode, fused, paged) lowers as ONE SPMD "
+                        "decode, fused) lowers as ONE SPMD "
                         "computation: params sharded by the parallel/ "
-                        "rules, the slot KV cache / block arena sharded on "
+                        "rules, the slot KV cache sharded on "
                         "kv heads, collectives inserted by XLA. N must "
                         "divide the model's attention heads and the device "
                         "count (checked at parse time). --tp 1 is "
                         "byte-identical to no flag. Mutually exclusive "
                         "with --mesh; CPU harness: "
                         "XLA_FLAGS=--xla_force_host_platform_device_count=N")
-    p.add_argument("--paged-kv", action="store_true",
-                   help="with --continuous: paged KV cache with radix-tree "
-                        "prefix reuse (serving/paged.py) — slots hold block "
-                        "tables into one shared arena, admission matches "
-                        "the longest cached prompt prefix (refcounted, "
-                        "copy-on-write at the divergence point) and "
-                        "prefills only the unmatched suffix. The "
-                        "counterfactual sweep's near-duplicate prompts "
-                        "become lookups; greedy output is token-for-token "
-                        "identical to the non-paged path")
-    p.add_argument("--kv-block-size", type=int, default=None, metavar="B",
-                   help="with --paged-kv: tokens per KV block — the "
-                        "prefix-sharing granularity (default 16)")
-    p.add_argument("--kv-blocks", type=int, default=None, metavar="N",
-                   help="with --paged-kv: total arena blocks (default 2x "
-                        "the all-slots-private worst case, so a full pool "
-                        "still leaves an equal prefix-cache reserve)")
     p.add_argument("--overload", action="store_true",
                    help="with --continuous: arm overload control "
                         "(serving/overload.py) — QoS classes (interactive/"
@@ -417,17 +400,13 @@ def config_from_args(args: argparse.Namespace) -> Config:
                 raise SystemExit("--ngram-max must be >= 1")
             spec_kwargs["ngram_max"] = args.ngram_max
         updates["speculation"] = SpeculationConfig(**spec_kwargs)
-    if args.continuous or args.slots is not None or args.paged_kv \
-            or args.kv_block_size is not None or args.kv_blocks is not None \
+    if args.continuous or args.slots is not None \
             or args.fuse_steps is not None or args.tp is not None:
         from fairness_llm_tpu.config import ServingConfig
 
-        if not args.paged_kv and (args.kv_block_size is not None
-                                  or args.kv_blocks is not None):
-            raise SystemExit("--kv-block-size/--kv-blocks require --paged-kv")
         if not args.continuous:
             raise SystemExit(
-                "--slots/--paged-kv/--fuse-steps/--tp require --continuous")
+                "--slots/--fuse-steps/--tp require --continuous")
         serve_kwargs = {"enabled": True}
         if args.tp is not None:
             # Same parse-time discipline as the --fuse-steps gates: every
@@ -486,16 +465,6 @@ def config_from_args(args: argparse.Namespace) -> Config:
                     "fused tree speculation is deferred to the "
                     "tree-speculation PR")
             serve_kwargs["fuse_steps"] = args.fuse_steps
-        if args.paged_kv:
-            serve_kwargs["paged_kv"] = True
-            if args.kv_block_size is not None:
-                if args.kv_block_size < 1:
-                    raise SystemExit("--kv-block-size must be >= 1")
-                serve_kwargs["kv_block_size"] = args.kv_block_size
-            if args.kv_blocks is not None:
-                if args.kv_blocks < 1:
-                    raise SystemExit("--kv-blocks must be >= 1")
-                serve_kwargs["kv_blocks"] = args.kv_blocks
         updates["serving"] = ServingConfig(**serve_kwargs)
     overload_flags = (args.shed_burn_threshold, args.shed_healthy_window,
                       args.batch_token_cap)
@@ -746,7 +715,7 @@ def perf_report(argv) -> int:
 def memory_report(argv) -> int:
     """``cli memory-report <dir|snapshot.json>`` — render the HBM memory
     ledger a run recorded (telemetry/memory.py): per-pool residency
-    (params / contiguous KV / paged arena / prefix cache / carried
+    (params / contiguous KV / prefix cache / carried
     logits), the reconciliation verdict against the device's own
     ``memory_stats`` (measured on TPU, indicative on CPU), headroom
     against the limit, and the per-program AOT memory table XLA budgeted

@@ -91,24 +91,10 @@ class ServingConfig:
     # None = no quota. Exists for parity with the reference's API-era
     # limiter and for multi-tenant deployments.
     admission_per_minute: Optional[int] = None
-    # Paged KV cache with radix-tree prefix reuse (serving/paged.py, CLI
-    # --paged-kv): slots hold per-block tables into one shared block arena
-    # instead of private cache rows, admission matches the longest cached
-    # prompt prefix (refcounted, copy-on-write at the divergence point) and
-    # prefills only the unmatched suffix. Greedy decode stays token-for-
-    # token identical to the non-paged path (pinned in
-    # tests/test_paged_kv.py); what changes is prefill WORK — the
-    # counterfactual sweep's near-duplicate prompts become lookups. Off by
-    # default: the non-paged path is byte-identical to before.
-    paged_kv: bool = False
-    kv_block_size: int = 16  # tokens per KV block (the sharing granularity)
-    # Total arena blocks; None = 2x the all-slots-private worst case, so a
-    # full pool still leaves an equal reserve working as prefix cache.
-    kv_blocks: Optional[int] = None
     # Tensor-parallel serving (--tp, runtime/stepbuilder.py's mesh axis):
     # every compiled serving program lowers as ONE SPMD computation over a
     # tp-way mesh — params placed by parallel/sharding.py rules, the KV
-    # cache/arena sharded on KV heads, XLA GSPMD inserting the all-reduces.
+    # cache sharded on KV heads, XLA GSPMD inserting the all-reduces.
     # The scheduler cross-checks this against the engine's actual mesh (a
     # tp=2 ServingConfig on a meshless engine fails loudly at construction
     # instead of silently serving single-device). tp=1 is byte-identical

@@ -212,18 +212,15 @@ def kv_heads_sharded(model_config: ModelConfig, mesh: Mesh) -> bool:
 
 
 def kv_tree_shardings(model_config: ModelConfig, mesh: Mesh, tree: Any) -> Any:
-    """NamedSharding pytree for a serving KV container — the contiguous
-    ``KVCache`` or the paged ``BlockArena``.
+    """NamedSharding pytree for the serving ``KVCache``.
 
-    Both lay their k/v leaves (and the int8 path's scales) out with the KV
-    head axis at position 2: ``[rows, slots, n_kv, head_dim]`` cache rows,
-    ``[blocks, block_size, n_kv, head_dim]`` arena blocks. Those leaves
-    shard on ``tp`` at the head axis when it divides (so each shard holds
-    its own heads' KV and the paged gather/scatter table ops — which index
-    axis 0 — stay local per shard); every bookkeeping leaf (key_valid,
-    positions, lengths, index) and a non-dividing head axis replicate.
-    Row/block axes never shard here: the slot scatter is not dp-aware,
-    which is exactly why the scheduler accepts tp-only meshes.
+    Its k/v leaves (and the int8 path's scales) carry the KV head axis at
+    position 2: ``[rows, slots, n_kv, head_dim]``. Those leaves shard on
+    ``tp`` at the head axis when it divides (so each shard holds its own
+    heads' KV); every bookkeeping leaf (key_valid, positions, lengths,
+    index) and a non-dividing head axis replicate. The row axis never
+    shards here: the slot scatter is not dp-aware, which is exactly why
+    the scheduler accepts tp-only meshes.
     """
     shard_heads = kv_heads_sharded(model_config, mesh)
     n_kv = model_config.num_kv_heads

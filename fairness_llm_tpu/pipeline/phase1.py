@@ -260,7 +260,7 @@ def run_phase1(
     prompts = [recommendation_prompt(p) for p in profiles]
     keys = [p.id for p in profiles]
     # Prefix-reuse layout check (pipeline/prompts.py): counterfactual pairs
-    # must diverge LATE (demographics last) or the paged KV cache has
+    # must diverge LATE (demographics last) or serving's admission has
     # nothing to share. Measured every run, warned when violated, recorded
     # in metadata below; tools/prefix_stats.py inspects it pre-run.
     prompt_by_key = dict(zip(keys, prompts))
@@ -419,7 +419,7 @@ def run_phase1(
             # --fairness-obs was off)
             "fairness": fairness_block,
             # counterfactual-pair shared-prefix fractions (byte LCP / max
-            # len) — the layout property the paged KV cache's hit rate
+            # len) — the layout property serving's prefix reuse
             # rides on; see pipeline/prompts.py check_late_divergence
             "prompt_divergence": divergence,
         },

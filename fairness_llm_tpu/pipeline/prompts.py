@@ -84,8 +84,8 @@ def lcp_len(a: str, b: str) -> int:
 def divergence_stats(
     pair_prompts: Sequence[Tuple[str, str]]
 ) -> Dict[str, float]:
-    """How LATE counterfactual pairs diverge — the property the paged KV
-    cache's hit rate rides on (the shared-everything-but-demographics
+    """How LATE counterfactual pairs diverge — the property the serving
+    path's prefix reuse rides on (the shared-everything-but-demographics
     layout ``recommendation_prompt`` documents).
 
     For each (prompt_a, prompt_b) pair: ``lcp / max(len)`` — the fraction
@@ -106,11 +106,11 @@ def divergence_stats(
     }
 
 
-# The layout contract the paged KV cache depends on: a counterfactual pair
-# must share at least this fraction of its bytes as a prefix. The stock
-# template puts the demographics block last and clears ~0.9; a custom
-# template that leads with demographics would tank the prefix-cache hit
-# rate — warn loudly instead of silently serving at full prefill cost.
+# The layout contract prefix reuse depends on: a counterfactual pair must
+# share at least this fraction of its bytes as a prefix. The stock template
+# puts the demographics block last and clears ~0.9; a custom template that
+# leads with demographics would leave admission nothing to copy from a live
+# row — warn loudly instead of silently serving at full prefill cost.
 LATE_DIVERGENCE_MIN_FRAC = 0.5
 
 
@@ -119,15 +119,15 @@ def check_late_divergence(
 ) -> Dict[str, float]:
     """Measure pair divergence and WARN when the swap lands early. The
     stats land in the phase's result metadata either way, so the expected
-    prefix-cache hit rate is inspectable before (tools/prefix_stats.py)
-    and after a run."""
+    reuse share is inspectable before (tools/prefix_stats.py) and after a
+    run."""
     stats = divergence_stats(pair_prompts)
     if stats["pairs"] and stats["min_frac"] < LATE_DIVERGENCE_MIN_FRAC:
         logger.warning(
             "%s: counterfactual pairs diverge EARLY (min shared-prefix "
             "fraction %.2f < %.2f) — the demographic swap should land as "
-            "late as possible in the prompt or prefix-KV reuse "
-            "(--paged-kv) has little to share",
+            "late as possible in the prompt or prefix reuse at admission "
+            "has little to share",
             phase, stats["min_frac"], LATE_DIVERGENCE_MIN_FRAC,
         )
     return stats

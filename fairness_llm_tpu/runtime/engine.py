@@ -343,7 +343,7 @@ class DecodeEngine:
         if fn is not None:
             return fn
         # The plain program is the builder's batch entry + the SHARED greedy
-        # while_loop skeleton (the same loop serve_step/paged_step run over
+        # while_loop skeleton (the same loop serve_step runs over
         # the slot pool) with a uniform cap. instrument_jit = jax.jit + the
         # cost ledger (telemetry/costmodel.py): the first attribution-on
         # call walks the program's jaxpr into cost_ledger_bytes/flops

@@ -1,24 +1,15 @@
 """One step-program builder for every compiled decode variant.
 
-Before this module, the stack carried six hand-threaded compiled decode
-programs — the static engine's plain ``decode`` and speculative
-``spec_decode``, the serving scheduler's ``serve_prefill``/``serve_step``,
-and the paged-KV ``paged_prefill``/``paged_step`` — each re-implementing
-the same while-loop skeleton, guard layering, and compile-key bookkeeping
-by hand. Every cross-cutting feature (the numerics guard changing return
-arity, mutable ``decode_chunk`` compile keys, paged gather/scatter,
-per-row write offsets) had to be woven through each variant separately,
-and every planned decode mode (fused dispatch, real-mesh sharding, tree
-verify, sampling) would have multiplied the count again.
+The static engine's plain ``decode`` and speculative ``spec_decode`` and
+the serving scheduler's ``serve_prefill``/``serve_step`` share one
+while-loop skeleton, guard layering, and compile-key scheme, so a
+cross-cutting feature (the numerics guard changing return arity, mutable
+``decode_chunk`` compile keys, per-row write offsets) is woven through
+once. The programs are compositions over four orthogonal axes:
 
-This module collapses them into compositions over four orthogonal axes:
-
-- **KV source** — contiguous (private cache rows; released-slot reset mask
-  rides the program entry) or paged (block tables gathered into a
-  contiguous view at entry, private blocks scattered back at exit);
 - **token selection** — greedy/sampled single-token steps
   (:func:`make_greedy_loop`, the ONE while-loop skeleton the plain engine
-  decode, ``serve_step``, and ``paged_step`` all run) or the speculative
+  decode and ``serve_step`` both run) or the speculative
   draft-and-verify window (:func:`build_spec_decode`);
 - **guard layer** — ``guard=True`` folds the on-device finite check
   (``integrity/numerics.masked_finite``) into the carry as one AND-reduced
@@ -26,16 +17,15 @@ This module collapses them into compositions over four orthogonal axes:
 - **fuse factor** — ``fuse=k`` runs ``k`` decode chunks' worth of steps
   inside ONE compiled dispatch (the Kernel-Looping move: per-step host
   sync amortizes 1/k) with per-row live masks, caps, and write offsets
-  advancing in-program, so continuous batching, paged block tables, and
-  the guard compose unchanged. Fused programs publish under their own
+  advancing in-program, so continuous batching and the guard compose
+  unchanged. Fused programs publish under their own
   telemetry label (:func:`program_label`) so the cost ledger, roofline
   gauges, and host-gap accounting attribute them separately;
 - **mesh/sharding** — the axis PR 14 reserved: under a tp mesh every
   composition lowers as ONE SPMD computation (params placed by
   ``parallel/sharding.py`` rules, activations constrained along the model
   axis by the transformer's ``with_logical_constraint`` annotations, the
-  contiguous KV cache and paged BlockArena sharded on KV heads so the
-  gather/scatter table ops stay local per shard). The programs themselves
+  KV cache sharded on KV heads). The programs themselves
   are mesh-agnostic — the scheduler runs them inside ``with mesh,
   nn.logical_axis_rules(...)`` and places the carried device state
   (``parallel.sharding.kv_tree_shardings``); what changes here is the key
@@ -55,7 +45,7 @@ original.
 
 Every builder names its closure before ``jax.jit`` sees it (the program's
 label plus its bucket: ``serve_step``, ``serve_prefill_8x256``,
-``paged_step_fused``, ``decode_4x64``), so the executable reads
+``serve_step_fused``, ``decode_4x64``), so the executable reads
 ``jit_serve_step(...)`` on the device's ``XLA Modules`` line of a profiler
 trace and in JAX's compile logs, and not ``jit_run`` for all of them.
 
@@ -76,7 +66,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from fairness_llm_tpu.models.transformer import LayerCache, init_cache
+from fairness_llm_tpu.models.transformer import init_cache
 from fairness_llm_tpu.parallel.sharding import require_gqa
 from fairness_llm_tpu.runtime.sampling import (
     SamplerSettings,
@@ -85,9 +75,8 @@ from fairness_llm_tpu.runtime.sampling import (
 )
 from fairness_llm_tpu.runtime.speculative import ngram_draft
 
-#: The decode-loop step programs a scheduler can dispatch, by (paged, fused).
-STEP_PROGRAMS = ("serve_step", "paged_step",
-                 "serve_step_fused", "paged_step_fused")
+#: The decode-loop step programs a scheduler can dispatch (plain, fused).
+STEP_PROGRAMS = ("serve_step", "serve_step_fused")
 
 
 def program_label(base: str, fuse: int = 1, tp: int = 1) -> str:
@@ -106,7 +95,7 @@ def program_label(base: str, fuse: int = 1, tp: int = 1) -> str:
 
 def base_program(label: str) -> str:
     """Strip the mesh suffix off a :func:`program_label` name:
-    ``paged_step_fused@tp2`` -> ``paged_step_fused``. The inverse the
+    ``serve_step_fused@tp2`` -> ``serve_step_fused``. The inverse the
     telemetry gates (``validate_telemetry``'s fused-program checks) use so
     a sharded fused program is still recognized as fused."""
     return label.split("@", 1)[0]
@@ -126,9 +115,8 @@ def compile_key(program: str, *, batch: Optional[int] = None,
 
     Axes are per-program-shape (batch/prompt buckets, decode caps), plus
     the cross-cutting ones every variant shares: the guard flag (return
-    arity), the mutable ``decode_chunk``, paged-ness (via the base name),
-    and the fuse factor. See the module docstring for the pinned layout
-    invariants.
+    arity), the mutable ``decode_chunk``, and the fuse factor. See the
+    module docstring for the pinned layout invariants.
 
     The mesh axis: ``tp > 1`` APPENDS a ``("tp", k)`` element — a sharded
     program lowers to a different SPMD computation (GSPMD-inserted
@@ -148,10 +136,10 @@ def compile_key(program: str, *, batch: Optional[int] = None,
         # pair, which diagnostics (and the compile-key test) rely on.
         key = ("spec_decode", batch, prompt_len, max_new, prefix_len,
                guard, ngram_max, draft_len)
-    elif program in ("serve_prefill", "paged_prefill"):
-        key = (program, nb, P, guard)
-    elif program in ("serve_step", "paged_step"):
-        key = (program, chunk, guard, fuse)
+    elif program == "serve_prefill":
+        key = ("serve_prefill", nb, P, guard)
+    elif program == "serve_step":
+        key = ("serve_step", chunk, guard, fuse)
     else:
         raise ValueError(f"unknown step program {program!r}")
     if tp > 1:
@@ -196,8 +184,7 @@ def make_greedy_loop(model, sample, pad_id: int, eos_id: int, *, batch: int,
                      steps: int, guard: bool, prefix_len: int = 0,
                      per_row_offsets: bool = False):
     """The shared greedy/sampled decode ``while_loop`` — the skeleton the
-    plain engine decode, ``serve_step``, ``paged_step``, and their fused
-    variants all run.
+    plain engine decode, ``serve_step`` and its fused variant all run.
 
     Per iteration: sample from the carried logits with the row's own
     ``fold_in(emitted)`` key stream (identical to the engine's
@@ -505,20 +492,14 @@ def build_prefix(cfg, model, *, prefix_len: int):
 
 def build_serve_step(cfg, model, sampler: SamplerSettings, pad_id: int,
                      eos_id: int, *, num_slots: int, chunk: int,
-                     guard: bool, paged: bool, fuse: int = 1):
+                     guard: bool, fuse: int = 1):
     """The serving decode program: the shared greedy loop over the slot
-    pool, composed with a KV-source adapter.
+    pool.
 
-    Contiguous (``paged=False``): released-slot invalidation rides on the
-    program entry's reset mask (rows in ``reset`` lose their key_valid/
-    lengths before any attention can touch them — one program instead of a
-    separate invalidate dispatch per iteration). Paged (``paged=True``):
-    block tables gather into the per-row contiguous view ONCE at entry,
-    the exact same loop runs, and the private blocks scatter back once at
-    exit — shared prefix entries' write-table slots drop, so two rows
-    sharing a prefix stream one copy of its KV bytes per gather. No reset
-    mask rides the paged program: a released BLOCK re-enters tables only
-    through a prefill that cleared its ``key_valid`` first.
+    Released-slot invalidation rides on the program entry's reset mask
+    (rows in ``reset`` lose their key_valid/lengths before any attention
+    can touch them — one program instead of a separate invalidate dispatch
+    per iteration).
 
     ``fuse=k`` multiplies the dispatch's step budget to ``chunk * k`` —
     per-row caps, EOS stops, live masks, and write offsets all advance
@@ -534,24 +515,6 @@ def build_serve_step(cfg, model, sampler: SamplerSettings, pad_id: int,
     T = chunk * max(1, fuse)
     loop = make_greedy_loop(model, sample, pad_id, eos_id, batch=B,
                             steps=T, guard=guard, per_row_offsets=True)
-
-    if paged:
-        from fairness_llm_tpu.serving.paged import gather_view, scatter_view
-
-        def run(params, arena, prev_logits, tables, wtables, row_seeds,
-                emitted0, base, caps, live0):
-            cache = gather_view(arena, tables, arena.lengths)
-            c = loop(params, cache, prev_logits, row_seeds, emitted0, base,
-                     caps, live0, None)
-            cache = c[1]
-            arena = scatter_view(arena, cache, wtables)
-            arena = arena.replace(lengths=cache.lengths)
-            if guard:
-                return arena, c[2], c[5], c[4], c[6], c[7]
-            return arena, c[2], c[5], c[4], c[6]
-
-        run.__name__ = program_label("paged_step", fuse)
-        return run
 
     def run(params, cache, prev_logits, row_seeds, emitted0, base, caps,
             live0, reset):
@@ -614,8 +577,7 @@ def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
     3. the window goes forward at the cache's ``index = w`` (every row of
        the call writes the same slots, so the uniform write serves): query
        ``i`` sees slot ``j`` iff ``j <= w + i`` and the slot is valid, the
-       small causal window against an already-valid cache that
-       ``build_paged_prefill`` runs per row through ``write_offsets``
+       small causal window against an already-valid cache
        (latent layers read the latent cache through the absorbed form);
     4. the extent scatters back at ``slots`` (slots >= num_slots, the
        batch-bucket pad rows and the rows that sit this window out, drop),
@@ -705,82 +667,4 @@ def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
         return new_cache, new_logits
 
     run.__name__ = f"serve_prefill_{nb}x{P}"
-    return run
-
-
-def build_paged_prefill(model, *, nb: int, S: int, guard: bool,
-                        num_slots: int):
-    """[nb, S] SUFFIX prefill through block tables (--paged-kv).
-
-    Each row's cached prefix (``matched`` tokens: full shared blocks + the
-    copy-on-write lead of one partially-shared block) is already in the
-    arena; this program:
-
-    1. copies the CoW source block into the row's private divergence block
-       (the shared source is never mutated),
-    2. clears ``key_valid`` for EVERY private block in the batch's write
-       tables — the block-granularity invalidation discipline: a recycled
-       block is unreadable before its new tenant's writes,
-    3. gathers each row's table into a contiguous view whose validity is
-       constructed as ``position < matched`` (prefix visible, everything
-       else dark),
-    4. forwards the right-padded suffix with per-row ``write_offsets =
-       matched`` — the speculative-verify causal window: suffix query i
-       sees cached slot j iff j <= matched + i, which is exactly "the
-       whole prefix plus my own earlier suffix",
-    5. scatters the view back through the write tables (shared entries
-       drop) and lands each row's LAST-REAL-TOKEN logits in the carried
-       sampler state.
-
-    Numerically this is the engine's forward over the same token content
-    at the same positions — parity with the non-paged path is pinned in
-    tests/test_paged_kv.py.
-    """
-    from fairness_llm_tpu.serving.paged import gather_view, scatter_view
-
-    require_gqa(model.config, "the paged prefill")
-    masked_finite = _masked_finite() if guard else None
-
-    def run(params, arena, prev_logits, tokens, valid, positions,
-            tables, wtables, cow_src, cow_dst, matched, slots, last_idx):
-        def cp(big):
-            # Out-of-range cow_dst drops (no-CoW rows); out-of-range
-            # cow_src clamps on the gather, harmless under the drop.
-            return big.at[cow_dst].set(big[cow_src], mode="drop")
-
-        new_layers = []
-        for lc in arena.layers:
-            kw = dict(k=cp(lc.k), v=cp(lc.v))
-            if lc.k_scale is not None:
-                kw.update(k_scale=cp(lc.k_scale), v_scale=cp(lc.v_scale))
-            new_layers.append(LayerCache(**kw))
-        arena = arena.replace(
-            layers=tuple(new_layers),
-            key_positions=cp(arena.key_positions),
-            key_valid=arena.key_valid.at[wtables].set(False, mode="drop"),
-        )
-        view = gather_view(arena, tables, matched)
-        L = view.key_valid.shape[1]
-        view = view.replace(
-            key_valid=jnp.arange(L)[None, :] < matched[:, None]
-        )
-        logits, view = model.apply(
-            {"params": params}, tokens, positions, valid, view,
-            write_offsets=matched,
-        )
-        last = jnp.take_along_axis(
-            logits, last_idx[:, None, None], axis=1
-        )[:, 0, :]
-        arena = scatter_view(arena, view, wtables)
-        arena = arena.replace(
-            lengths=arena.lengths.at[slots].set(view.lengths, mode="drop")
-        )
-        new_logits = prev_logits.at[slots].set(last, mode="drop")
-        if guard:
-            return arena, new_logits, masked_finite(
-                last, slots < num_slots
-            )
-        return arena, new_logits
-
-    run.__name__ = f"paged_prefill_{nb}x{S}"
     return run

@@ -3,9 +3,7 @@
 Layers (bottom-up): ``request`` (Request/Result wire format, QoS classes)
 -> ``queue`` (bounded admission + rate limiting; per-class sub-queues in
 QoS mode) -> ``overload`` (shed controller + deadline-feasibility
-admission) -> ``paged`` (block arena + radix-tree prefix index, the
-``--paged-kv`` shared-prefix layout) -> ``slots`` (KV slot pool allocator,
-block-table owner in paged mode)
+admission) -> ``slots`` (KV slot pool allocator, the live rows' prefixes)
 -> ``scheduler`` (the prefill/decode step loop) -> ``router``/``fleet``
 (health-aware routing over N replica schedulers, per-replica fault domains
 with fence/migrate/rejoin) -> ``autoscaler`` (SLO-coupled elastic
@@ -32,12 +30,6 @@ from fairness_llm_tpu.serving.overload import (
     DeadlineEstimator,
     ShedController,
 )
-from fairness_llm_tpu.serving.paged import (
-    BlockArena,
-    PagedKV,
-    RadixIndex,
-    init_arena,
-)
 from fairness_llm_tpu.serving.queue import AdmissionQueue, ClassedAdmissionQueue
 from fairness_llm_tpu.serving.rollout import (
     RolloutController,
@@ -51,7 +43,6 @@ from fairness_llm_tpu.serving.slots import SlotPool, SlotState
 __all__ = [
     "AdmissionQueue",
     "Autoscaler",
-    "BlockArena",
     "ReplayClock",
     "ReplayDriver",
     "ReplayReport",
@@ -63,9 +54,6 @@ __all__ = [
     "ClassedAdmissionQueue",
     "ContinuousScheduler",
     "DeadlineEstimator",
-    "PagedKV",
-    "RadixIndex",
-    "init_arena",
     "QOS_CLASSES",
     "ShedController",
     "HealthRouter",

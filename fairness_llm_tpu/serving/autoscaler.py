@@ -171,7 +171,7 @@ class Autoscaler:
         # Opt-in (up_headroom_frac > 0): a measured-HBM headroom collapse
         # is a capacity signal like a deep queue — scaling up spreads the
         # KV pools across more replicas' devices. Soft by design: the
-        # ledger forewarns, the arena allocator stays the hard gate.
+        # ledger forewarns, it never blocks an admission.
         if cfg.up_headroom_frac > 0 and \
                 sig["headroom_frac"] <= cfg.up_headroom_frac:
             return f"hbm_headroom {sig['headroom_frac']:.2f}"

@@ -7,12 +7,12 @@ Differences from ``EngineBackend`` that callers should know:
 
 - each row decodes independently in its own KV slot; the sweep-wide shared
   prefix (``prefix_ids``) is accepted and IGNORED — the engine's per-batch
-  prefix mechanism doesn't fit per-request admission. With
-  ``ServingConfig.paged_kv`` the sharing comes back strictly more general:
-  the radix-indexed block arena (serving/paged.py) matches each request's
-  longest cached prefix at admission, batch boundaries irrelevant. Greedy
-  output is token-for-token identical either way (the parity contract is
-  vs ``DecodeEngine.generate`` alone, which is how the tests pin it).
+  prefix mechanism doesn't fit per-request admission. The scheduler finds
+  sharing itself: at admission a row copies the longest prefix some live
+  row already holds (``slots.LiveRowPrefixes``) and prefills the rest.
+  Greedy output is token-for-token identical either way (the parity
+  contract is vs ``DecodeEngine.generate`` alone, which is how the tests
+  pin it).
 - per-request failures come back as ``None`` texts (the
   ``with_failure_containment`` sentinel convention) instead of failing the
   chunk, because the scheduler already contains faults per-request.
@@ -67,7 +67,7 @@ logger = logging.getLogger(__name__)
 class ServingBackend:
     # decode_sweep's shared_prefix_ids checks this before computing the
     # sweep-wide token LCP — serving ignores prefix_ids, so don't pay for
-    # it (paged_kv's radix index discovers sharing from token ids itself).
+    # it (admission discovers sharing from the live rows' ids itself).
     use_shared_prefix = False
 
     def __init__(self, engine, serving: Optional[ServingConfig] = None,

@@ -1051,7 +1051,7 @@ class ReplicaSet:
         )
         migrated = self._evacuate(rep, "retired", count_failover=False)
         # Retirement is the permanent exit: the scheduler's device trees
-        # (arena/cache/logits) leave with it, so its memory-ledger entries
+        # (cache/logits) leave with it, so its memory-ledger entries
         # go too — a fence keeps the replica AND its memory, so the fence
         # path deliberately does not release (ISSUE 18).
         rep.sched.release_memory()

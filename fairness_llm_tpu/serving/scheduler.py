@@ -58,10 +58,9 @@ containable ``NumericsFault`` (requeue-once, breaker-visible, counted in
 logits host-side so the guard is drillable on the CPU harness.
 
 Tensor-parallel meshes ARE supported (``--tp N``): the scheduler accepts an
-engine built over a tp-only mesh, places the persistent KV cache / paged
-BlockArena on the mesh sharded along the kv-head axis
-(``parallel.sharding.kv_tree_shardings`` — gather/scatter table ops stay
-local to each shard) and the carried logits along vocab, and runs every
+engine built over a tp-only mesh, places the persistent KV cache on the
+mesh sharded along the kv-head axis (``parallel.sharding.kv_tree_shardings``)
+and the carried logits along vocab, and runs every
 compiled program under ``with mesh, nn.logical_axis_rules(...)`` so the
 whole step lowers as one SPMD computation with XLA-inserted collectives.
 Compile keys gain a ``("tp", k)`` element and telemetry programs a
@@ -104,7 +103,6 @@ from fairness_llm_tpu.resilience.watchdog import StepWatchdog
 from fairness_llm_tpu.runtime.sampling import SamplerSettings
 from fairness_llm_tpu.runtime.stepbuilder import (
     EXTEND_WINDOW,
-    build_paged_prefill,
     build_serve_prefill,
     build_serve_step,
     compile_key,
@@ -122,7 +120,6 @@ from fairness_llm_tpu.serving.request import (
     Request,
     Result,
 )
-from fairness_llm_tpu.serving.paged import PagedKV, init_arena
 from fairness_llm_tpu.serving.slots import LiveRowPrefixes, SlotPool, SlotState
 from fairness_llm_tpu.telemetry import (
     Heartbeat,
@@ -260,27 +257,8 @@ class ContinuousScheduler:
         # budget just leaves a few always-invalid slots per row).
         self.max_prompt_bucket = _bucket_len(self.prompt_budget, engine.seq_bucket)
         self.num_slots = self.serving.num_slots
-        # Paged KV (serving/paged.py, --paged-kv): slots map into a shared
-        # block arena through per-slot tables, and admission reuses cached
-        # prompt prefixes via the radix index. The per-slot logical extent
-        # must cover the real prompt budget + the decode cap, PLUS one
-        # suffix bucket of headroom: suffix prefill writes a bucketed
-        # [S]-token window at the row's matched offset, and same-bucket
-        # grouping bounds that window's end by prompt_budget + seq_bucket.
-        self.paged = bool(self.serving.paged_kv)
-        if self.paged:
-            bs = self.serving.kv_block_size
-            span = self.prompt_budget + max(cap, engine.seq_bucket)
-            blocks_per_slot = -(-span // bs)
-            self.cache_len = blocks_per_slot * bs  # the gathered view length
-            self.pool = SlotPool(self.num_slots, paged=PagedKV(
-                self.num_slots, blocks_per_slot, bs,
-                num_blocks=self.serving.kv_blocks,
-                labels={"replica": replica} if replica else None,
-            ))
-        else:
-            self.cache_len = self.max_prompt_bucket + cap
-            self.pool = SlotPool(self.num_slots)
+        self.cache_len = self.max_prompt_bucket + cap
+        self.pool = SlotPool(self.num_slots)
         # Overload control (serving/overload.py): with it armed, the queue
         # becomes the per-class variant and the shed controller +
         # deadline-feasibility estimator gate admission at this front door.
@@ -314,25 +292,16 @@ class ContinuousScheduler:
         # Sheds recorded outside a drain (public submit() refusals between
         # drains) — folded into the next drain's stats like rejections.
         self._shed_untaken = 0
-        # Persistent device state: the shared KV cache (private rows, or the
-        # paged block arena) + each slot's carried next-token logits (f32 —
-        # what the sampler consumes).
-        if self.paged:
-            self._cache = None
-            self._arena = init_arena(
-                cfg, self.pool.paged.num_blocks,
-                self.serving.kv_block_size, self.num_slots,
-            )
-        else:
-            self._cache = init_cache(cfg, self.num_slots, self.cache_len)
-            self._arena = None
+        # Persistent device state: the shared KV cache (one private row a
+        # slot) + each slot's carried next-token logits (f32 — what the
+        # sampler consumes).
+        self._cache = init_cache(cfg, self.num_slots, self.cache_len)
         self._prev_logits = jnp.zeros(
             (self.num_slots, cfg.vocab_size), jnp.float32
         )
         self._place_device_state()
         self._mem_handle = f"sched{ContinuousScheduler._mem_seq}"
         ContinuousScheduler._mem_seq += 1
-        self._block_pressure = False
         self._account_device_state()
         self._compiled: Dict[tuple, object] = {}
         # Overflow beyond queue capacity (deque: _feed pops from the head)
@@ -409,25 +378,18 @@ class ContinuousScheduler:
     # -- mesh placement -----------------------------------------------------
 
     def _place_device_state(self) -> None:
-        """Pin the persistent carried state to the mesh: KV (contiguous
-        cache or paged arena) sharded along the kv-head axis when tp
-        divides it (``kv_tree_shardings`` — per-row gather/scatter table
-        ops then stay local to each shard), carried logits along vocab.
+        """Pin the persistent carried state to the mesh: the KV cache
+        sharded along the kv-head axis when tp divides it
+        (``kv_tree_shardings``), carried logits along vocab.
         Committed placement, so the jit'd programs consume the shards
         in-place instead of re-replicating per call. No-op off-mesh."""
         if self.mesh is None:
             return
         cfg = self.engine.config
-        if self._cache is not None:
-            self._cache = jax.tree.map(
-                jax.device_put, self._cache,
-                shd.kv_tree_shardings(cfg, self.mesh, self._cache),
-            )
-        if self._arena is not None:
-            self._arena = jax.tree.map(
-                jax.device_put, self._arena,
-                shd.kv_tree_shardings(cfg, self.mesh, self._arena),
-            )
+        self._cache = jax.tree.map(
+            jax.device_put, self._cache,
+            shd.kv_tree_shardings(cfg, self.mesh, self._cache),
+        )
         self._prev_logits = jax.device_put(
             self._prev_logits, shd.logits_sharding(cfg, self.mesh))
 
@@ -435,31 +397,19 @@ class ContinuousScheduler:
 
     def _account_device_state(self) -> None:
         """Register this scheduler's persistent device trees with the
-        memory ledger — the paged arena under ``kv_paged``, the contiguous
-        slot cache under ``kv_contiguous``, the carried logits under
-        ``logits_carry`` — so ``hbm_bytes{pool}`` tracks the live trees.
+        memory ledger — the slot cache under ``kv_contiguous``, the
+        carried logits under ``logits_carry`` — so ``hbm_bytes{pool}``
+        tracks the live trees.
         Runs at init AND after the containment rebuild (re-registering the
         same handle replaces the entry: the rebuild made new arrays of the
         same shape, and the gauges must say so rather than go stale)."""
         from fairness_llm_tpu.telemetry.memory import (  # lazy: no cycle
             get_memory_ledger,
-            tree_device_bytes,
         )
 
         mem = get_memory_ledger()
-        if self._arena is not None:
-            mem.register("kv_paged", f"{self._mem_handle}:arena",
-                         self._arena, replica=self.replica)
-            # Per-block device bytes, from the REAL arena (quantization,
-            # validity/position planes included) — what the headroom
-            # forecaster prices an admission's block growth with.
-            self._block_bytes = (tree_device_bytes(self._arena)
-                                 // max(1, self.pool.paged.num_blocks))
-        else:
-            self._block_bytes = 0
-        if self._cache is not None:
-            mem.register("kv_contiguous", f"{self._mem_handle}:cache",
-                         self._cache, replica=self.replica)
+        mem.register("kv_contiguous", f"{self._mem_handle}:cache",
+                     self._cache, replica=self.replica)
         mem.register("logits_carry", f"{self._mem_handle}:logits",
                      self._prev_logits, replica=self.replica)
 
@@ -472,43 +422,6 @@ class ContinuousScheduler:
         )
 
         get_memory_ledger().release_matching(f"{self._mem_handle}:")
-
-    def _note_block_pressure(self, exhausted: bool, deferred) -> None:
-        """Memory-pressure bookkeeping for the block-exhaustion deferral:
-        flip the recoverable ``memory_pressure_active`` gauge, and on
-        exhaustion price the deferred admission's worst-case private-block
-        growth against the measured headroom and fire the deduplicated
-        ``memory_pressure`` incident naming the deferring requests. Soft
-        path only — the arena allocator stays the hard gate; this is the
-        measured basis the deferral always lacked."""
-        from fairness_llm_tpu.telemetry.memory import (  # lazy: no cycle
-            get_memory_ledger,
-        )
-
-        mem = get_memory_ledger()
-        scope = self.replica or "serving"
-        if not exhausted:
-            if self._block_pressure:
-                self._block_pressure = False
-                mem.note_pressure(scope, False)
-            return
-        self._block_pressure = True
-        mem.note_pressure(scope, True)
-        # Worst case: the head-of-line row shares nothing and claims a
-        # full slot's private blocks.
-        fc = mem.forecast(self.pool.paged.blocks_per_slot
-                          * self._block_bytes)
-        maybe_trigger(
-            "memory_pressure",
-            cause=f"paged arena exhausted; {len(deferred)} admission(s) "
-                  "deferred to decode-side block frees",
-            scope=scope, replica=self.replica,
-            request_ids=[r.id for r in deferred],
-            deferred=len(deferred),
-            cost_bytes=fc["cost_bytes"],
-            headroom_bytes=fc["headroom_bytes"],
-            basis=fc["basis"],
-        )
 
     def _run_compiled(self, fn, *args):
         """Invoke a compiled program under the mesh context: inside
@@ -537,14 +450,13 @@ class ContinuousScheduler:
         return bool(getattr(self.engine, "numerics_guards", False))
 
     def _step_key(self, guard: bool) -> tuple:
-        """This scheduler's CURRENT decode-program key: paged-ness via the
-        base name, the mutable ``decode_chunk`` (the degradation ladder can
-        change it mid-run — a halved chunk compiles its own program and
-        restoring reuses the original), the numerics-guard flag (return
-        arity), and the fuse factor — the one scheme every compiled
-        variant shares (``stepbuilder.compile_key``)."""
-        return compile_key("paged_step" if self.paged else "serve_step",
-                           chunk=self.decode_chunk, guard=guard,
+        """This scheduler's CURRENT decode-program key: the mutable
+        ``decode_chunk`` (the degradation ladder can change it mid-run — a
+        halved chunk compiles its own program and restoring reuses the
+        original), the numerics-guard flag (return arity), and the fuse
+        factor — the one scheme every compiled variant shares
+        (``stepbuilder.compile_key``)."""
+        return compile_key("serve_step", chunk=self.decode_chunk, guard=guard,
                            fuse=self.fuse_steps, tp=self.tp)
 
     def _step_program(self) -> str:
@@ -553,8 +465,7 @@ class ContinuousScheduler:
         ``<base>_fused`` (``validate_telemetry`` holds them to that), and
         mesh-sharded programs under a ``@tp<k>`` suffix so single-device
         and sharded measurements never mix in one series."""
-        return program_label("paged_step" if self.paged else "serve_step",
-                             self.fuse_steps, tp=self.tp)
+        return program_label("serve_step", self.fuse_steps, tp=self.tp)
 
     def _collectives(self, rows: int, tokens: int, scope: str):
         """Analytic collectives rows for the cost ledger when the jaxpr
@@ -590,9 +501,8 @@ class ContinuousScheduler:
 
     def _step_fn(self):
         """The decode program: ``decode_chunk x fuse_steps`` steps in one
-        while_loop — the builder's shared greedy loop composed with this
-        scheduler's KV source (contiguous reset-mask entry, or paged
-        gather/scatter). See ``stepbuilder.build_serve_step``."""
+        while_loop — the builder's shared greedy loop entered through the
+        slot pool's reset mask. See ``stepbuilder.build_serve_step``."""
         guard = self._guard()
         key = self._step_key(guard)
         program = self._step_program()
@@ -603,37 +513,12 @@ class ContinuousScheduler:
             self.engine.config, self.engine.model, self.sampler,
             self.engine.tokenizer.pad_id, self.engine.tokenizer.eos_id,
             num_slots=self.num_slots, chunk=self.decode_chunk, guard=guard,
-            paged=self.paged, fuse=self.fuse_steps,
+            fuse=self.fuse_steps,
         )
         fn = instrument_jit(
             run, program, donate_argnums=self._donate(),
             collectives=self._collectives(self.num_slots, 1, "step"),
         )
-        self._compiled[key] = fn
-        return fn
-
-    def _paged_prefill_fn(self, nb: int, S: int, guard: bool):
-        """[nb, S] SUFFIX prefill through block tables (--paged-kv) — the
-        builder's ``paged_prefill`` composition: CoW copy, private-block
-        invalidation, gather to a contiguous view, suffix forward with
-        ``write_offsets = matched``, scatter back. See
-        ``stepbuilder.build_paged_prefill`` for the program semantics;
-        parity with the non-paged path is pinned in tests/test_paged_kv.py.
-        """
-        key = compile_key("paged_prefill", nb=nb, P=S, guard=guard,
-                          tp=self.tp)
-        program = program_label("paged_prefill", tp=self.tp)
-        fn = self._compiled.get(key)
-        if fn is not None:
-            return fn
-        run = build_paged_prefill(
-            self.engine.model, nb=nb, S=S, guard=guard,
-            num_slots=self.num_slots,
-        )
-        # Not donated, like the plain prefill: a raised call must leave the
-        # other live slots' arena blocks intact.
-        fn = instrument_jit(run, program,
-                            collectives=self._collectives(nb, S, "call"))
         self._compiled[key] = fn
         return fn
 
@@ -1261,18 +1146,10 @@ class ContinuousScheduler:
             if popped is None:
                 return False
             admitted, injected_hang = popped
-            if self.paged:
-                groups = self._plan_paged(admitted)
-                plan_span.set(rows=sum(len(g) for g in groups.values()))
-            else:
-                plan = self._plan_prefill(admitted)
-                plan_span.set(rows=len(admitted), nb=plan["nb"], P=plan["P"],
-                              donors=plan["donors"],
-                              windows=len(plan["calls"]))
-        if self.paged:
-            for S in sorted(groups):
-                self._paged_prefill_group(groups[S], S, stats, injected_hang)
-            return bool(groups)
+            plan = self._plan_prefill(admitted)
+            plan_span.set(rows=len(admitted), nb=plan["nb"], P=plan["P"],
+                          donors=plan["donors"],
+                          windows=len(plan["calls"]))
         return self._dispatch_prefill(plan, stats, injected_hang)
 
     def _plan_admission(self, n: int, stats: ServingStats):
@@ -1559,190 +1436,6 @@ class ContinuousScheduler:
         note_invocation(program, wall, ledger=getattr(fn, "ledger", None),
                         compiling=first_compile)
 
-    def _plan_paged(self, admitted) -> Dict[int, list]:
-        """Paged admission (--paged-kv): radix-match each popped row, claim
-        blocks (private tail + refs on the shared prefix chain), and group
-        the rows that go ahead by the bucket of their UNMATCHED suffix —
-        only that is prefilled, one compiled shape per group, and every
-        row's bucketed write window provably fits its slot extent.
-
-        Two deferral rules put rows back at the queue head (order
-        preserved) instead of admitting them this iteration:
-
-        - intra-batch sharing: a row whose prompt shares a full block with
-          a row planned THIS iteration waits one iteration, so it matches
-          the committed blocks instead of re-prefilling them — that is how
-          a counterfactual pair arriving together still shares its prefix;
-        - block exhaustion: when the arena (after LRU eviction of
-          unreferenced cache) cannot cover a row's private tail, the row
-          and everything behind it wait for decode to free blocks — the
-          same backpressure shape as a full slot pool.
-        """
-        paged = self.pool.paged
-        bs = paged.block_size
-        planned = []  # (req, ids, slot, plan, real_s)
-        deferred: List[Request] = []
-        pending_chunks: set = set()
-        exhausted = False
-        for req, ids, _ in admitted:
-            if exhausted:
-                deferred.append(req)
-                continue
-            chunks = {tuple(ids[k * bs:(k + 1) * bs])
-                      for k in range(len(ids) // bs)}
-            if chunks & pending_chunks:
-                deferred.append(req)
-                continue
-            slot = self.pool.alloc(SlotState(
-                request=req, base=len(ids), real_len=len(ids),
-            ))
-            assert slot is not None  # admission is free-count bounded
-            plan = paged.admit(slot, ids)
-            if plan is None:
-                self.pool.release(slot)
-                deferred.append(req)
-                exhausted = True
-                continue
-            pending_chunks |= chunks
-            planned.append((req, ids, slot, plan, len(ids) - plan.matched))
-            self.tracer.record(req.id, "admitted")
-        for req in reversed(deferred):
-            self.queue.requeue(req)
-        self._note_block_pressure(exhausted, deferred)
-        groups: Dict[int, list] = {}
-        for row in planned:
-            S = self._bucket_len(row[4], self.engine.seq_bucket)
-            assert row[3].matched + S <= self.cache_len, (
-                "suffix write window overflows the slot extent "
-                f"(matched {row[3].matched} + bucket {S} > {self.cache_len})"
-            )
-            groups.setdefault(S, []).append(row)
-        return groups
-
-    def _paged_prefill_group(self, rows, S: int, stats: ServingStats,
-                             injected_hang: float) -> None:
-        """One compiled suffix-prefill call for rows sharing suffix bucket
-        ``S``; mirrors the non-paged batch prefill's spans, telemetry,
-        watchdog, breaker, and containment discipline. A fault releases
-        exactly this group's slots (blocks freed before commit, so nothing
-        leaks into the radix index) and requeues each rider once."""
-        paged = self.pool.paged
-        tl = get_timeline()
-        with tl.span("sched.admit.plan", "sched", self._track, stage="pad",
-                     rows=len(rows), P=S) as plan_span:
-            nb, matched_tokens, args = self._pad_paged_group(rows, S)
-            guard = self._guard()
-            pf_key = compile_key("paged_prefill", nb=nb, P=S, guard=guard,
-                                 tp=self.tp)
-            pf_program = program_label("paged_prefill", tp=self.tp)
-            first_compile = pf_key not in self._compiled
-            fn = self._paged_prefill_fn(nb, S, guard)
-            plan_span.set(nb=nb)
-        pf_t0 = time.monotonic()
-        for req, *_ in rows:
-            self.tracer.record(req.id, "prefill_start", t=pf_t0)
-        if self.watchdog is not None:
-            self.watchdog.arm("prefill")
-        try:
-            # The span carries the matched-prefix total, so the timeline
-            # shows prefill work disappear under prefix reuse.
-            with tl.span("sched.prefill.dispatch", "prefill", self._track,
-                         program=pf_program, nb=nb, P=S, rows=len(rows),
-                         matched_prefix_tokens=matched_tokens,
-                         request_ids=[r[0].id for r in rows]):
-                self._note_dispatch(pf_t0)
-                out = self._run_compiled(
-                    fn, self.engine.params, self._arena, self._prev_logits,
-                    *args,
-                )
-                if guard:
-                    new_arena, new_logits, finite = out
-                    check_finite(finite, "serving", "prefill")
-                else:
-                    new_arena, new_logits = out
-            self._arena, self._prev_logits = new_arena, new_logits
-            if self.watchdog is not None:
-                self.watchdog.observe("prefill", extra_s=injected_hang,
-                                      classify=not first_compile)
-        except Exception as e:  # noqa: BLE001 — containment is the point
-            kind = ("hang" if isinstance(e, HangFault)
-                    else "numerics" if isinstance(e, NumericsFault)
-                    else "device")
-            logger.warning("paged prefill group (%d, %d) failed: %s",
-                           nb, S, e)
-            get_registry().counter(
-                "faults_total", component="serving",
-                kind=kind, stage="prefill", **self.labels,
-            ).inc()
-            self._note_fault("prefill", kind, [r[0].id for r in rows], e)
-            if self.breakers is not None:
-                self.breakers.record_failure("prefill")
-            for req, ids, slot, plan, real_s in rows:
-                self.pool.release(slot)
-                self._requeue_or_fail(req, f"prefill failed: {e}", stats,
-                                      cause=kind)
-            return
-        if self.breakers is not None:
-            self.breakers.record_success("prefill")
-        reg = get_registry()
-        for req, ids, slot, plan, real_s in rows:
-            # Commit AFTER the device call: the freshly-written full prompt
-            # blocks become matchable, and later rows (deferred above) find
-            # them in the index.
-            paged.commit(slot, ids)
-            reg.histogram(
-                "matched_prefix_len", component="paged_kv", **self.labels
-            ).observe(plan.matched)
-        self._note_prefill(pf_program, time.monotonic() - pf_t0, fn,
-                           first_compile, pf_key)
-        stats.prefill_batches += 1
-        # Suffix tokens only: the hit/miss counters hold the reuse story,
-        # and this total IS the measured prefill-token reduction.
-        stats.prefill_tokens += sum(r[4] for r in rows)
-        stats.admitted += len(rows)
-
-    def _pad_paged_group(self, rows, S: int):
-        """The padded host arrays of one suffix-prefill group, on the
-        device: ``(nb, matched prefix tokens, call arguments)``."""
-        paged = self.pool.paged
-        tok = self.engine.tokenizer
-        cfg = self.engine.config
-        N = paged.num_blocks
-        nbl = paged.blocks_per_slot
-        nb = _bucket_pow2(len(rows), max(self.serving.prefill_group,
-                                         len(rows)))
-        tokens = np.full((nb, S), tok.pad_id, np.int32)
-        valid = np.zeros((nb, S), bool)
-        positions = np.zeros((nb, S), np.int32)
-        tables = np.zeros((nb, nbl), np.int32)
-        wtables = np.full((nb, nbl), N, np.int32)
-        cow_src = np.full((nb,), N, np.int32)
-        cow_dst = np.full((nb,), N, np.int32)
-        matched = np.zeros((nb,), np.int32)
-        slot_ids = np.full((nb,), self.num_slots, np.int32)
-        last_idx = np.zeros((nb,), np.int32)
-        for i, (req, ids, slot, plan, real_s) in enumerate(rows):
-            tokens[i, :real_s] = ids[plan.matched:]
-            valid[i, :real_s] = True
-            # Absolute positions (prefix at 0.. is what makes it shareable);
-            # the pad tail clamps inside the model's position tables.
-            positions[i] = np.minimum(plan.matched + np.arange(S),
-                                      cfg.max_seq_len - 1)
-            tables[i] = plan.table
-            wtables[i] = plan.write_table
-            cow_src[i], cow_dst[i] = plan.cow_src, plan.cow_dst
-            matched[i] = plan.matched
-            slot_ids[i] = slot
-            last_idx[i] = real_s - 1
-        # Batch-bucket pad rows: one valid token so softmax has mass (engine
-        # idiom); their write tables are all-drop and their slot id is out
-        # of range, so nothing they compute lands anywhere.
-        valid[len(rows):, 0] = True
-        return nb, int(matched.sum()), tuple(
-            jnp.asarray(x) for x in (tokens, valid, positions, tables,
-                                     wtables, cow_src, cow_dst, matched,
-                                     slot_ids, last_idx))
-
     def _prepare_decode(self, stats: ServingStats) -> Optional[dict]:
         """Everything a decode chunk needs before its call: scripted
         faults, the live rows' masks, seeds and caps as host arrays, the
@@ -1784,9 +1477,7 @@ class ContinuousScheduler:
         # Released-slot invalidation rides on the step program's reset mask
         # (no separate dispatch). Slots released and REUSED before this
         # point never enter the mask — SlotPool.alloc cancels their pending
-        # invalidation because prefill re-initialized the row. (Paged mode
-        # has no reset mask at all: a released BLOCK re-enters a table only
-        # through a prefill that cleared its key_valid in-program.)
+        # invalidation because prefill re-initialized the row.
         reset = np.zeros((self.num_slots,), bool)
         reset[self.pool.take_invalidations()] = True
 
@@ -1808,24 +1499,9 @@ class ContinuousScheduler:
         step_key = self._step_key(guard)
         prog = self._step_program()
         first_compile = step_key not in self._compiled
-        if self.paged:
-            paged = self.pool.paged
-            tables = np.zeros((B, paged.blocks_per_slot), np.int32)
-            wtables = np.full((B, paged.blocks_per_slot),
-                              paged.num_blocks, np.int32)
-            for slot in live_ids:
-                tables[slot] = paged.table_for(slot)
-                wtables[slot] = paged.write_table_for(slot)
         fn = self._step_fn()
-        if self.paged:
-            args = (self._arena, self._prev_logits,
-                    jnp.asarray(tables), jnp.asarray(wtables))
-        else:
-            args = (self._cache, self._prev_logits)
-        args += tuple(jnp.asarray(x) for x in
-                      (seeds, emitted, base, caps, live))
-        if not self.paged:
-            args += (jnp.asarray(reset),)
+        args = (self._cache, self._prev_logits) + tuple(
+            jnp.asarray(x) for x in (seeds, emitted, base, caps, live, reset))
         return dict(live_ids=live_ids, injected_hang=injected_hang, fn=fn,
                     args=args, prog=prog, step_key=step_key, guard=guard,
                     first_compile=first_compile)
@@ -1854,15 +1530,11 @@ class ContinuousScheduler:
                 self._note_dispatch(dc_t0)
                 out = self._run_compiled(fn, self.engine.params, *args)
             if guard:
-                (new_kv, self._prev_logits, toks, emitted_after,
+                (self._cache, self._prev_logits, toks, emitted_after,
                  counters, finite) = out
             else:
-                new_kv, self._prev_logits, toks, emitted_after, \
+                self._cache, self._prev_logits, toks, emitted_after, \
                     counters = out
-            if self.paged:
-                self._arena = new_kv
-            else:
-                self._cache = new_kv
             # Where the host learns that the chunk, and every prefill
             # dispatched ahead of it, is done.
             with tl.span("sched.decode.sync", "decode", self._track):
@@ -1914,20 +1586,9 @@ class ContinuousScheduler:
             # Every live slot was just released, so nothing in the cache is
             # still needed — rebuild device state from scratch (with TPU
             # buffer donation, a raised call may have consumed the inputs).
-            # Paged: the arena rebuild zeroes every cached prefix too, so
-            # the radix index and block accounting must forget them —
-            # matching a tree node whose block was zeroed would silently
-            # serve a blank prefix.
-            if self.paged:
-                self._arena = init_arena(
-                    self.engine.config, self.pool.paged.num_blocks,
-                    self.serving.kv_block_size, self.num_slots,
-                )
-                self.pool.paged.reset()
-            else:
-                self._cache = init_cache(
-                    self.engine.config, self.num_slots, self.cache_len
-                )
+            self._cache = init_cache(
+                self.engine.config, self.num_slots, self.cache_len
+            )
             self._prev_logits = jnp.zeros(
                 (self.num_slots, self.engine.config.vocab_size), jnp.float32
             )
@@ -1995,16 +1656,10 @@ class ContinuousScheduler:
                         else "shape"),
                 seconds=dc_wall, key=step_key, labels=self.labels,
             )
-        roof_stats = {"batch": self.num_slots, "cache_slots": self.cache_len,
-                      "prefix_len": 0}
-        if self.paged:
-            # Paged-KV traffic model (telemetry/roofline.py): the per-chunk
-            # gather/scatter copies between the block arena and the
-            # contiguous view move real bytes the contiguous-layout model
-            # omits — amortized over the steps this chunk actually ran.
-            roof_stats.update(paged_kv=True, chunk_steps=steps)
         observe_decode(
-            self.engine.config, roof_stats,
+            self.engine.config,
+            {"batch": self.num_slots, "cache_slots": self.cache_len,
+             "prefix_len": 0},
             steps, dc_wall, program=prog, labels=self.labels,
         )
         # Gap attribution (telemetry/costmodel.py): the chunk's measured

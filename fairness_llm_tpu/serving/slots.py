@@ -1,19 +1,14 @@
 """Fixed-size KV slot pool: the host-side allocator behind continuous batching.
 
 The device holds ONE persistent cache (allocated once — see ``scheduler.py``):
-either ``num_slots`` private rows shaped [num_slots, cache_len] per layer, or
-— with ``--paged-kv`` — a shared block arena the pool's :class:`PagedKV`
-manager maps slots into through per-slot block tables (``serving/paged.py``).
-This module tracks which slots are live, what request occupies each, and the
+``num_slots`` private rows shaped [num_slots, cache_len] per layer. This
+module tracks which slots are live, what request occupies each, and the
 per-slot layout the decode step needs:
 
 - ``base``: the first decode write offset — the prompt bucket the row was
-  PREFILLED at in the private-row layout (its admission batch's max bucket),
-  or the REAL prompt length in the paged layout (paged rows are not
-  left-padded; the prefix must sit at absolute positions to be shareable).
-  Decode step t writes its KV at slot ``base + emitted`` (the engine's
-  per-row ``write_offsets`` machinery from the speculative-decoding PR,
-  promoted to the serving path)
+  PREFILLED at (its admission batch's max bucket). Decode step t writes its
+  KV at slot ``base + emitted`` (the engine's per-row ``write_offsets``
+  machinery from the speculative-decoding PR, promoted to the serving path)
 - ``real_len``: real (non-pad) prompt tokens — RoPE/learned positions
   continue from here, exactly as a batch-1 ``DecodeEngine.generate`` would
 - ``emitted``: generated tokens so far (incl. a stopping EOS)
@@ -22,10 +17,7 @@ Free slots form an explicit free list (lowest id first, deterministic);
 ``release`` returns the slot and marks it for device-side invalidation —
 the scheduler zeroes the row's ``key_valid``/``lengths`` before the next
 decode step, so a recycled slot can never attend to its previous tenant's
-keys even transiently. In paged mode the discipline moves from rows to
-blocks: ``release`` routes through ``PagedKV.release`` (deref the radix
-chain, free the private blocks) and a recycled block is only ever reachable
-through a table whose prefill program cleared its ``key_valid`` first.
+keys even transiently.
 """
 
 from __future__ import annotations
@@ -36,7 +28,6 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from fairness_llm_tpu.serving.paged import PagedKV
 from fairness_llm_tpu.serving.request import Request
 
 
@@ -47,9 +38,9 @@ class SlotState:
     real_len: int  # real prompt tokens (position origin for decode)
     emitted: int = 0  # generated tokens so far
     tokens: List[int] = dataclasses.field(default_factory=list)
-    # The prompt's ids as prefilled (private-row layout): real token t sits
-    # at slot ``base - real_len + t``, which is what lets a later row with
-    # the same leading ids copy them (``LiveRowPrefixes``). None: never a donor.
+    # The prompt's ids as prefilled: real token t sits at slot
+    # ``base - real_len + t``, which is what lets a later row with the same
+    # leading ids copy them (``LiveRowPrefixes``). None: never a donor.
     ids: Optional[np.ndarray] = None
 
 
@@ -84,15 +75,10 @@ class LiveRowPrefixes:
 
 
 class SlotPool:
-    def __init__(self, num_slots: int, paged: Optional[PagedKV] = None):
+    def __init__(self, num_slots: int):
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
         self.num_slots = num_slots
-        # Paged-KV block manager (serving/paged.py): when present, the pool
-        # owns the block tables — release frees/derefs blocks instead of
-        # queueing a row invalidation, and the scheduler plans admissions
-        # through ``paged.admit``/``commit``.
-        self.paged = paged
         self._free: List[int] = list(range(num_slots))
         heapq.heapify(self._free)
         self._live: Dict[int, SlotState] = {}
@@ -142,21 +128,12 @@ class SlotPool:
         return slot
 
     def release(self, slot: int) -> SlotState:
-        """Free ``slot`` and queue it for device-side invalidation (private-
-        row mode) or release its blocks (paged mode). Raises KeyError for a
-        slot that isn't live (double-release is a bug, not a no-op — silent
-        tolerance would mask allocator corruption)."""
+        """Free ``slot`` and queue it for device-side invalidation. Raises
+        KeyError for a slot that isn't live (double-release is a bug, not a
+        no-op — silent tolerance would mask allocator corruption)."""
         state = self._live.pop(slot)
         heapq.heappush(self._free, slot)
-        if self.paged is not None:
-            # Block-granularity discipline: deref the shared radix chain
-            # (the nodes stay cached for future matches) and free the
-            # private tail. No row reset rides the next step — a freed
-            # block re-enters a table only through a prefill that clears
-            # its key_valid in-program first.
-            self.paged.release(slot)
-        else:
-            self._pending_invalidation[slot] = None
+        self._pending_invalidation[slot] = None
         return state
 
     def take_invalidations(self) -> List[int]:

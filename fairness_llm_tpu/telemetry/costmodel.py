@@ -5,7 +5,7 @@ ROADMAP item 3's headline — decode stuck at 0.4-0.5 of measured achievable
 HBM bandwidth — was a single scalar (``achieved_over_achievable``) with no
 live attribution: before choosing between fused multi-token steps (Kernel
 Looping, arxiv 2410.23668) and tree speculation, the question is *per
-compiled program*, how much of the gap is host sync, dispatch, paged
+compiled program*, how much of the gap is host sync, dispatch,
 gather/scatter traffic, or genuinely memory-bound in-step work. That
 accounting existed only as an offline TPU-only xplane tool
 (``tools/account_decode_step.py``). This module makes it live, on any
@@ -29,14 +29,14 @@ backend, with no profiler capture:
   ``dot_general``, one-per-output-element otherwise) per component.
   Equations inside a ``while_loop`` body land in the ``per_step`` table
   (the decode loop runs them once per token); everything else is
-  ``per_call`` (prefill, gather/scatter of the paged view, setup).
-- **Compile-time hook** — ``instrument_jit`` wraps the six decode-program
+  ``per_call`` (prefill, a donor row's gather and the extent's scatter,
+  setup).
+- **Compile-time hook** — ``instrument_jit`` wraps the decode-program
   builders where ``telemetry/compilestats.py`` already intercepts compiles
   (engine ``decode``/``spec_decode``/``prefix``, serving
-  ``serve_prefill``/``serve_step``, paged ``paged_prefill``/
-  ``paged_step``): the first attribution-on invocation traces the python
-  function once more (``jax.make_jaxpr`` — a sliver next to the XLA
-  compile happening on the same call) and publishes
+  ``serve_prefill``/``serve_step``): the first attribution-on invocation
+  traces the python function once more (``jax.make_jaxpr`` — a sliver next
+  to the XLA compile happening on the same call) and publishes
   ``cost_ledger_bytes{program, component, scope}`` /
   ``cost_ledger_flops{...}`` gauges.
 - **Gap attribution** — per invocation, ``note_invocation`` accumulates
@@ -116,7 +116,7 @@ COMPONENT_TITLES = {
     "matmuls": "matmuls (params)",
     "norms_elementwise": "norms/elementwise",
     "sampling": "sampling/argmax/rng",
-    "gather_scatter": "paged gather-scatter",
+    "gather_scatter": "gather-scatter",
     "control": "loop/control",
 }
 

@@ -105,7 +105,6 @@ INCIDENT_CLASSES = (
     "slo_burn",
     "integrity_fault",
     "heartbeat_gap",
-    "memory_pressure",   # paged-arena exhaustion deferred admissions
     "rollout",           # a rollout rolled back (the gate that fired)
 )
 

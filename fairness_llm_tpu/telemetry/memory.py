@@ -4,10 +4,9 @@ memory analysis + headroom-coupled capacity signals (ISSUE 18).
 The attribution stack can say where every second and every byte of
 *bandwidth* of a decode step went (timeline, roofline, cost ledger,
 incidents) — but not where a single byte of HBM *resides*. Params, the
-contiguous slot KV cache, the paged block arena, the engine's prefix-KV
-LRU, and the carried logits all hold device memory, and until this module
-the only memory signal in the tree was a one-shot log warning at engine
-init. KV-cache memory is the dominant serving-capacity constraint for LLM
+slot KV cache, the engine's prefix-KV LRU, and the carried logits all
+hold device memory, and until this module the only memory signal in the
+tree was a one-shot log warning at engine init. KV-cache memory is the dominant serving-capacity constraint for LLM
 inference, and the 70B/disaggregated roadmap items need a *measured*
 headroom signal, not a guess.
 
@@ -42,14 +41,11 @@ Three layers, one module:
   it arms with the exporters (``telemetry.configure``) or explicitly
   (``set_aot_memory_capture``), not in bare library use.
 
-The control plane reads the ledger through :meth:`MemoryLedger.forecast`:
-the scheduler prices a paged admission's worst-case block growth against
-the measured headroom (the block-exhaustion deferral's measured basis and
-the ``memory_pressure`` incident trigger), the autoscaler treats a
-headroom collapse as a hot signal, and the overload ladder's rung-2 batch
-cap engages early when headroom is tight. All of it is SOFT: the ledger
-never blocks an admission itself — the arena allocator stays the hard
-gate, the ledger explains and forewarns.
+The control plane reads the ledger through
+:meth:`MemoryLedger.headroom_frac`: the autoscaler treats a headroom
+collapse as a hot signal, and the overload ladder's rung-2 batch cap
+engages early when headroom is tight. All of it is SOFT: the ledger never
+blocks an admission itself — it explains and forewarns.
 
 Gating follows the house rule: ``set_attribution(False)`` silences the
 whole ledger (register/release become no-ops, nothing publishes), and the
@@ -74,8 +70,7 @@ logger = logging.getLogger(__name__)
 # typo'd pool at a call site should fail tests, not open a new label.
 POOLS = (
     "params",         # engine parameter tree (per engine instance)
-    "kv_contiguous",  # non-paged slot KV cache (scheduler._cache)
-    "kv_paged",       # paged block arena (scheduler._arena)
+    "kv_contiguous",  # slot KV cache (scheduler._cache)
     "prefix_cache",   # engine prefix-KV LRU entries
     "logits_carry",   # per-slot carried next-token logits
     "other",          # anything a caller accounts that fits no pool above
@@ -147,7 +142,7 @@ class MemoryLedger:
 
     Entries are keyed ``(pool, name[, replica])`` where ``name`` is the
     caller's stable handle for one allocation site ("engine0", "sched2:
-    arena", a prefix hash) — re-registering the same handle REPLACES the
+    cache", a prefix hash) — re-registering the same handle REPLACES the
     entry (rebuild semantics), releasing removes it. Gauges always reflect
     the sum over live entries; a (pool, replica) combination that drains
     to zero publishes 0 rather than going stale.
@@ -165,7 +160,6 @@ class MemoryLedger:
         # (tests, drills, capacity planning on CPU). A REAL device limit
         # always wins.
         self._analytic_limit: Optional[int] = None
-        self._pressure: Dict[str, bool] = {}
 
     # -- gating ---------------------------------------------------------------
 
@@ -321,7 +315,7 @@ class MemoryLedger:
                 )
         return out
 
-    # -- the headroom forecaster ----------------------------------------------
+    # -- headroom -----------------------------------------------------------
 
     def headroom_bytes(self) -> Optional[int]:
         limit, in_use, _ = self._limits()
@@ -339,47 +333,6 @@ class MemoryLedger:
             return None
         return max(0.0, (limit - max(self.total_bytes(), in_use or 0))
                    / limit)
-
-    def forecast(self, cost_bytes: int) -> Dict:
-        """Price an admission against the current headroom: would
-        ``cost_bytes`` more device memory (a slot's KV rows, a paged
-        admission's worst-case private-block growth) still fit? ``fits``
-        is None when no limit is known — the caller's hard allocator
-        stays the decider either way; this is the measured basis the
-        deferral/incident path reports."""
-        limit, in_use, mode = self._limits()
-        cost = max(int(cost_bytes), 0)
-        if limit is None:
-            return {"basis": None, "cost_bytes": cost,
-                    "headroom_bytes": None, "fits": None,
-                    "headroom_after_frac": None}
-        headroom = limit - max(self.total_bytes(), in_use or 0)
-        return {
-            "basis": mode,
-            "cost_bytes": cost,
-            "headroom_bytes": int(headroom),
-            "fits": cost <= headroom,
-            "headroom_after_frac": max(0.0, (headroom - cost) / limit),
-        }
-
-    # -- pressure -------------------------------------------------------------
-
-    def note_pressure(self, scope: str, on: bool) -> None:
-        """Flip the per-scope pressure gauge (1 while a scheduler is
-        deferring admissions for memory, back to 0 once admission
-        succeeds) — the recoverable signal the chaos drill asserts on."""
-        if not self._on():
-            return
-        prev = self._pressure.get(scope, False)
-        self._pressure[scope] = bool(on)
-        lbl = {"component": "memory"}
-        if scope:
-            lbl["replica"] = scope
-        get_registry().gauge("memory_pressure_active", **lbl).set(
-            1.0 if on else 0.0)
-        if on and not prev:
-            self._record_ring("pressure", "kv_paged", scope or "serving",
-                              self.pool_bytes("kv_paged"), scope or None)
 
     # -- publication ----------------------------------------------------------
 
@@ -693,13 +646,4 @@ def render_memory_report(snap: Dict) -> str:
                 f"{_fmt_bytes(k.get('temp')):>10} "
                 f"{_fmt_bytes(k.get('peak')):>10}"
             )
-    pressure = [g for g in gauges
-                if g.get("name") == "memory_pressure_active"
-                and g.get("value", 0)]
-    if pressure:
-        scopes = ", ".join(g.get("labels", {}).get("replica", "serving")
-                           for g in pressure)
-        lines.append("")
-        lines.append(f"MEMORY PRESSURE ACTIVE: {scopes} (admissions "
-                     "deferring on block exhaustion)")
     return "\n".join(lines)

@@ -106,18 +106,6 @@ def decode_step_bytes(config, stats) -> int:
     prefix-cache win). ``stats`` carries ``batch`` / ``cache_slots`` /
     ``prefix_len`` (the ``GenerateOutput.stats`` shape).
 
-    Paged KV (``--paged-kv``, serving/paged.py): the ``paged_step``
-    program (``stepbuilder.build_serve_step(paged=True)``) runs the same
-    per-step while_loop over a CONTIGUOUS view it gathers from
-    the block arena once per chunk and scatters back once per chunk —
-    traffic the contiguous-layout model omits, understating achieved
-    bandwidth. With ``stats["paged_kv"]`` true, the per-chunk copies are
-    amortized over ``stats["chunk_steps"]`` (the steps the chunk actually
-    ran): gather reads the arena blocks and writes the view (2x the pool
-    KV), scatter reads the view and writes the private blocks back
-    (modeled as 2x — shared prefix entries drop, but the read side always
-    covers the full view).
-
     Param width: the COMPUTE dtype, not the storage dtype — the round-3
     device trace shows XLA hoists the f32->bf16 cast of a bf16-config
     model's f32-stored tree out of the decode loop, so each step streams
@@ -148,16 +136,7 @@ def decode_step_bytes(config, stats) -> int:
     prefix = stats["prefix_len"] * (
         per_token * model_item * config.num_layers
     )
-    paged = 0
-    if stats.get("paged_kv"):
-        # Per-chunk: gather (arena read + view write = 2x pool KV) then
-        # scatter (view read + private-block write = 2x). 4x total,
-        # amortized per step. The copies move STORAGE-width bytes (the
-        # arena holds the quantized values + scales when kv_cache_quant),
-        # which per_slot already accounts for.
-        chunk_steps = max(int(stats.get("chunk_steps", 1)), 1)
-        paged = 4 * kv // chunk_steps
-    return params + kv + prefix + paged
+    return params + kv + prefix
 
 
 def observe_decode(config, stats: Dict, steps: int, wall_s: float,

@@ -349,8 +349,7 @@ def test_serve_step_streams_the_kv_pool_once_on_v5e_topology():
     cache = placed(cache, shd.kv_tree_shardings(cfg, mesh, cache))
     step = build_serve_step(
         cfg, Transformer(cfg), SamplerSettings(temperature=0.0, top_k=0, top_p=1.0),
-        cfg.pad_token_id, cfg.eos_token_id, num_slots=slots, chunk=8, guard=True,
-        paged=False)
+        cfg.pad_token_id, cfg.eos_token_id, num_slots=slots, chunk=8, guard=True)
     row = lambda dt: host((slots,), dt)  # noqa: E731
     with force_pallas(), mesh, nn.logical_axis_rules(rules):
         compiled = jax.jit(step, donate_argnums=(1, 2)).lower(

@@ -11,9 +11,9 @@ Five contracts:
   attention-shaped dot, one cache DUS, one while loop) produce exactly the
   bytes/FLOPs first principles predict, split per-call vs per-step;
 - EVERY compiled decode variant (plain/spec engine decode, serving
-  prefill/step, paged prefill/step) publishes a nonzero ledger after a
-  continuous + paged + speculative smoke, and the gap decomposition's
-  components sum to the measured wall exactly;
+  prefill/step) publishes a nonzero ledger after a continuous +
+  speculative smoke, and the gap decomposition's components sum to the
+  measured wall exactly;
 - the perf sentinel accepts a clean same-fingerprint re-run, rejects an
   injected 3x slowdown and token-parity drift, and REFUSES a baseline
   recorded under a different harness fingerprint;
@@ -24,7 +24,6 @@ Five contracts:
 """
 
 import copy
-import dataclasses
 import json
 import sys
 
@@ -46,7 +45,6 @@ from fairness_llm_tpu.telemetry import (
     use_timeline,
 )
 from fairness_llm_tpu.telemetry.costmodel import COMPONENTS, classify
-from fairness_llm_tpu.telemetry.roofline import decode_step_bytes
 
 
 def _tool(name):
@@ -210,30 +208,6 @@ def test_jaxpr_ledger_scan_multiplies_by_length():
     assert led.per_call["norms_elementwise"].flops == 5 * 8
 
 
-# -- paged roofline satellite --------------------------------------------------
-
-
-def test_decode_step_bytes_paged_oracle():
-    cfg = get_model_config("tiny-test")
-    model_item = 2 if cfg.dtype == "bfloat16" else 4
-    per_slot = cfg.num_kv_heads * cfg.head_dim * model_item * 2 * cfg.num_layers
-    contiguous = {"batch": 4, "cache_slots": 96, "prefix_len": 0}
-    base = decode_step_bytes(cfg, contiguous)
-    kv = 4 * 96 * per_slot
-    assert base == cfg.approx_param_count * model_item + kv
-    # Paged: the chunk's one gather (arena read + view write) and one
-    # scatter (view read + block write) move 4x the pool KV, amortized over
-    # the steps the chunk ran.
-    paged8 = decode_step_bytes(cfg, {**contiguous, "paged_kv": True,
-                                     "chunk_steps": 8})
-    assert paged8 == base + 4 * kv // 8
-    # Fewer steps per chunk -> worse amortization -> MORE bytes per step.
-    paged1 = decode_step_bytes(cfg, {**contiguous, "paged_kv": True,
-                                     "chunk_steps": 1})
-    assert paged1 == base + 4 * kv
-    assert paged1 > paged8 > base
-
-
 # -- collectives component (ISSUE 16 satellite) --------------------------------
 #
 # Three layers, each pinned: the xplane regex (measured captures), the jaxpr
@@ -355,7 +329,7 @@ def test_instrument_jit_never_double_counts_explicit_collectives():
     assert run.ledger.per_call["collectives"].bytes == 2 * 4 * 4
 
 
-# -- six decode variants publish ledgers + decomposition sums ------------------
+# -- every decode variant publishes a ledger + decomposition sums ------------------
 
 
 @pytest.fixture(scope="module")
@@ -372,7 +346,6 @@ def _smoke_all_variants(engine):
 
     scfg = ServingConfig(enabled=True, num_slots=2, max_prompt_len=192,
                          max_new_tokens=16, decode_chunk=4)
-    pcfg = dataclasses.replace(scfg, paged_kv=True, kv_block_size=16)
     engine.generate(["one two three", "four five six"], _greedy(6))
     engine.generate(["a b c d e f g h i j k l"], _greedy(6),
                     speculation=SpeculationConfig(enabled=True))
@@ -380,17 +353,12 @@ def _smoke_all_variants(engine):
     r = s1.serve([Request(prompt="a b c", id="c1", settings=_greedy(8)),
                   Request(prompt="d e f", id="c2", settings=_greedy(8))])
     assert all(x.ok for x in r)
-    s2 = ContinuousScheduler(engine, pcfg, settings=_greedy(8))
-    r = s2.serve([Request(prompt="a b c", id="p1", settings=_greedy(8)),
-                  Request(prompt="a b c d", id="p2", settings=_greedy(8))])
-    assert all(x.ok for x in r)
 
 
-SIX_VARIANTS = ("decode", "spec_decode", "serve_prefill", "serve_step",
-                "paged_prefill", "paged_step")
+VARIANTS = ("decode", "spec_decode", "serve_prefill", "serve_step")
 
 
-def test_all_six_decode_variants_publish_ledgers(engine):
+def test_all_decode_variants_publish_ledgers(engine):
     with use_registry() as reg, use_timeline():
         _smoke_all_variants(engine)
         snap = snapshot(reg)
@@ -400,17 +368,17 @@ def test_all_six_decode_variants_publish_ledgers(engine):
         if g["name"] == "cost_ledger_bytes":
             p = g["labels"]["program"]
             by_prog[p] = by_prog.get(p, 0.0) + g["value"]
-    for prog in SIX_VARIANTS:
+    for prog in VARIANTS:
         assert by_prog.get(prog, 0.0) > 0, f"no ledger for {prog}"
     # The loop programs split per-step work out of the per-call remainder.
     step_scopes = {g["labels"]["program"] for g in snap["gauges"]
                    if g["name"] == "cost_ledger_bytes"
                    and g["labels"].get("scope") == "step"}
-    assert {"decode", "spec_decode", "serve_step", "paged_step"} <= step_scopes
+    assert {"decode", "spec_decode", "serve_step"} <= step_scopes
     # Gap decomposition: every program's components sum EXACTLY to the
     # measured wall (+ measured host gap) — the acceptance tolerance check.
     decomp = gap_decomposition(snap)
-    for prog in SIX_VARIANTS:
+    for prog in VARIANTS:
         d = decomp[prog]
         assert d["wall_s"] > 0
         assert d["sum_check_s"] == pytest.approx(d["total_s"], rel=1e-9)
@@ -422,10 +390,9 @@ def test_all_six_decode_variants_publish_ledgers(engine):
     # Serving step programs ran >= 2 chunks, so their host gap is a
     # MEASURED nonzero quantity, not an estimate.
     assert decomp["serve_step"]["host_gap_s"] > 0
-    assert decomp["paged_step"]["host_gap_s"] > 0
     # The report renders and names a contributor per program.
     report = render_cost_report(snap)
-    for prog in SIX_VARIANTS:
+    for prog in VARIANTS:
         assert f"[{prog}]" in report
     assert "top gap contributor:" in report
     assert "sum check: OK" in report
@@ -657,10 +624,9 @@ def test_bench_baseline_shape():
                 "continuous": {"tokens_per_sec": 50.0, "useful_tokens": 999},
                 "speedup_tokens_per_sec": 1.37,
             },
-            "prefix_cache": {
-                "on": {"hit_ratio": 0.965, "prefill_tokens": 45},
-                "prefill_token_reduction": 0.998,
-                "speedup_ratio": 1.14,
+            "speculative": {
+                "speedup": 1.14, "acceptance_rate": 0.62,
+                "verify_steps": 45,
             },
         },
     }
@@ -670,8 +636,8 @@ def test_bench_baseline_shape():
     assert entries["headline.token_checksum"]["kind"] == "exact"
     assert entries["continuous.useful_tokens"] == {"kind": "exact",
                                                    "value": 999}
-    assert entries["prefix_cache.hit_ratio"]["kind"] == "exact"
-    assert entries["prefix_cache.speedup_ratio"]["kind"] == "wall"
+    assert entries["speculative.acceptance_rate"]["kind"] == "exact"
+    assert entries["speculative.speedup"]["kind"] == "wall"
     fp = bench.harness_fingerprint("tiny-test")
     assert set(fp) == {"jax", "platform", "device_kind", "cpu",
                        "cpu_count", "model"}

@@ -12,10 +12,9 @@ logits of size 1); the tolerance is 1e-5. Tokens are compared between two
 different programs, so a disagreement counts only where the whole-prompt
 logits do not call it a near-tie (ROADMAP D9)."""
 
-import dataclasses
+import importlib.util
 import json
 import os
-import sys
 import zlib
 
 import jax
@@ -23,46 +22,45 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
+from toys import (
+    ROOT,
+    IdPrompts,
+    alone,
+    assert_same_tokens,
+    toy_engine,
+    whole_prompt_logits as _whole_prompt_logits,
+)
 
-from fairness_llm_tpu.config import ModelSettings, ServingConfig  # noqa: E402
-from fairness_llm_tpu.runtime.engine import DecodeEngine  # noqa: E402
+from fairness_llm_tpu.config import (  # noqa: E402
+    FleetConfig,
+    ModelSettings,
+    ServingConfig,
+)
+from fairness_llm_tpu.models.tokenizer import ByteTokenizer  # noqa: E402
 from fairness_llm_tpu.runtime.stepbuilder import EXTEND_WINDOW  # noqa: E402
-from fairness_llm_tpu.serving import ContinuousScheduler, Request  # noqa: E402
+from fairness_llm_tpu.serving import (  # noqa: E402
+    ContinuousScheduler,
+    ReplicaSet,
+    Request,
+)
 from fairness_llm_tpu.serving.slots import (  # noqa: E402
     LiveRowPrefixes,
     SlotPool,
     SlotState,
 )
+from fairness_llm_tpu.telemetry import use_registry  # noqa: E402
+from fairness_llm_tpu.utils.failures import ScriptedFaultInjector  # noqa: E402
 from fairness_llm_tpu.utils.profiling import ServingStats  # noqa: E402
-from perfbench import families  # noqa: E402
-from perfbench.lib import modelcfg  # noqa: E402
-from perfbench.lib import weights as W  # noqa: E402
-from perfbench.lib.sut import IdTokenizer  # noqa: E402
 
 S = EXTEND_WINDOW
 TOL = 1e-5
-NEAR_TIE = 1e-4
 SEED = 29
 NEW = 6
 GREEDY = ModelSettings(temperature=0.0, max_tokens=NEW)
 
 
 def _toy(config: str):
-    """(engine, rng) for one family's toy, float32, ids as prompts."""
-    cut = modelcfg.rehearsal_cut(modelcfg.load_config(
-        os.path.join(ROOT, "perfbench", "configs", config + ".json")))
-    cut["eos_token_id"] = 1  # never drawn; a row may still end on it
-    family = families.of(cut)
-    make_layer, make_globals = W.jitted_makers(cut)
-    words = W.seed_words(SEED)
-    tree = family.program_tree(cut, make_globals(words), [
-        make_layer(words, i) for i in range(cut["num_hidden_layers"])])
-    mc = dataclasses.replace(family.model_config(cut, "toy"), dtype="float32")
-    tok = IdTokenizer(cut["vocab_size"], cut["pad_token_id"],
-                      cut["eos_token_id"])
-    return DecodeEngine(mc, params=tree, tokenizer=tok, seed=0), cut
+    return toy_engine(config, SEED)
 
 
 @pytest.fixture(scope="module", params=["mistral-7b-int8", "ax-k1-int8"],
@@ -79,48 +77,16 @@ def _scheduler(engine, **kw):
     return ContinuousScheduler(engine, serving, settings=GREEDY)
 
 
-class _Prompts:
-    """Prompts as ids the tokenizer hands back by name."""
-
-    def __init__(self, engine, cut, seed=SEED):
-        self.tok, self.vocab = engine.tokenizer, cut["vocab_size"]
-        self.rng = np.random.default_rng(seed)
-        self.count = 0
-
-    def draw(self, n):
-        return self.rng.integers(3, self.vocab, size=n).astype(np.int32)
-
-    def request(self, ids):
-        name = f"r{self.count}"
-        self.count += 1
-        self.tok.register(name, np.asarray(ids, np.int32))
-        return Request(prompt=name, id=name, settings=GREEDY)
-
-
-def _whole_prompt_logits(engine, ids):
-    """Next-token logits of ONE forward over the whole prompt, no cache."""
-    ids = jnp.asarray(ids, jnp.int32)[None]
-    pos = jnp.arange(ids.shape[1], dtype=jnp.int32)[None]
-    logits, _ = jax.jit(
-        lambda p, t, q: engine.model.apply({"params": p}, t, q)
-    )(engine.params, ids, pos)
-    return np.asarray(logits[0, -1])
+def _Prompts(engine, cut, seed=SEED, tag="r"):
+    return IdPrompts(engine, cut, seed, GREEDY, tag)
 
 
 def _assert_same_greedy(engine, name, served_tokens, ids):
     """The engine alone decodes the same tokens; where the two programs
     part, the whole-sequence logits must call the step a near-tie."""
-    alone = np.asarray(engine.generate([name], GREEDY).tokens[0])
-    served = np.asarray(served_tokens)
-    assert len(served) == NEW
-    for t in range(NEW):
-        if served[t] != alone[t]:
-            ref = _whole_prompt_logits(
-                engine, np.concatenate([ids, served[:t]]))
-            assert abs(ref[served[t]] - ref[alone[t]]) < NEAR_TIE, (
-                f"{name} token {t}: served {served[t]}, engine {alone[t]}, "
-                "and the reference's logits are not a tie")
-            break
+    assert len(served_tokens) == NEW
+    assert_same_tokens(engine, name, ids, served_tokens,
+                       alone(engine, name, GREEDY, NEW))
 
 
 def _slot_of(sched, name):
@@ -362,10 +328,319 @@ def test_live_row_prefixes_take_the_longest_share_and_the_lowest_slot():
     assert match.longest(np.asarray([10, 11, 99, 13])) == (0, 2)
     pool.release(1)
     assert LiveRowPrefixes(pool).longest(np.arange(10, 35)) == (2, 25)
-    # a row without ids (the paged layout keeps none) is never a donor
-    bare = SlotPool(1)
-    bare.alloc(SlotState(request=Request(prompt="q"), base=64, real_len=5))
-    assert LiveRowPrefixes(bare).longest(np.arange(5)) == (None, 0)
+
+
+def _pool_of(*rows):
+    pool = SlotPool(len(rows) + 1)
+    for i, ids in enumerate(rows):
+        pool.alloc(SlotState(request=Request(prompt=f"p{i}"), base=192,
+                             real_len=150 if ids is None else len(ids),
+                             ids=ids))
+    return pool
+
+
+@pytest.mark.parametrize("case", [
+    "the_copy_stops_a_window_short_of_the_whole_prompt",
+    "never_a_slot_the_call_writes",
+    "never_a_row_without_ids",
+    "a_released_slot_drops_out",
+])
+def test_live_row_prefixes_invariants(case):
+    ids = np.arange(10, 160)
+    if case == "the_copy_stops_a_window_short_of_the_whole_prompt":
+        # an identical resident prompt is matched whole; what the plan copies
+        # of it leaves the last window to run, so that its logits exist
+        assert LiveRowPrefixes(_pool_of(ids)).longest(ids) == (0, len(ids))
+        assert _tool("prefix_stats").expected_reuse([ids, ids]) == \
+            [0, len(ids) - S]
+    elif case == "never_a_slot_the_call_writes":
+        pool = _pool_of(ids[:80])
+        live = LiveRowPrefixes(pool)  # built before the batch claims slots
+        assert pool.alloc(SlotState(request=Request(prompt="new"), base=192,
+                                    real_len=len(ids), ids=ids)) == 1
+        assert live.longest(ids) == (0, 80)
+    elif case == "never_a_row_without_ids":
+        assert LiveRowPrefixes(_pool_of(None)).longest(ids) == (None, 0)
+        assert LiveRowPrefixes(_pool_of(None, ids[:30])).longest(ids) == \
+            (1, 30)
+    else:
+        pool = _pool_of(ids, ids[:40])
+        pool.release(0)
+        assert LiveRowPrefixes(pool).slots == [1]
+        assert LiveRowPrefixes(pool).longest(ids) == (1, 40)
+
+
+# -- what prefix sharing is asked of, for both kinds of cache -------------------
+
+
+@pytest.fixture(scope="module")
+def shared(toy):
+    """One 4-slot scheduler per kind of cache, its programs compiled once
+    for every scenario below. Each scenario leaves the pool empty."""
+    engine, cut = toy
+    return engine, cut, _scheduler(engine)
+
+
+def _family(prompts, n, stem=100, tails=(40, 60)):
+    """``n`` prompts of one bucket that share their first ``stem`` ids."""
+    head = prompts.draw(stem)
+    return [np.concatenate([head, prompts.draw(int(t))])
+            for t in np.linspace(tails[0], tails[1], n)]
+
+
+def _drained(sched, stats, n):
+    sched._run_loop(stats)
+    assert stats.completed == n and stats.failed == 0
+    assert sched.pool.occupancy == 0
+
+
+def test_shared_prefix_under_churn_and_a_requeue_is_serving_alone(shared):
+    """Eight near-duplicates through four slots, one of them faulted while it
+    decodes beside the rows that copied from the same donor."""
+    engine, cut, sched = shared
+    prompts = _Prompts(engine, cut, seed=101, tag="churn")
+    fam = _family(prompts, 8)
+    reqs = [prompts.request(x) for x in fam]
+    sched.fault_injector = ScriptedFaultInjector({(reqs[5].id, "decode"): 1})
+    try:
+        results = sched.serve(reqs)
+    finally:
+        sched.fault_injector = None
+    stats = sched.last_stats
+    assert all(r.ok for r in results) and sched.pool.occupancy == 0
+    assert stats.requeued == 1 and results[5].retries == 1
+    assert stats.prefill_tokens_reused > 0
+    for req, res, ids in zip(reqs, results, fam):
+        _assert_same_greedy(engine, req.id, res.tokens, ids)
+
+
+def test_a_request_decodes_the_same_among_other_neighbours_and_slots(shared):
+    """First in slot 0 with nothing to copy, then last of a serve in slot 3
+    behind a live row that holds its first hundred ids."""
+    engine, cut, sched = shared
+    prompts = _Prompts(engine, cut, seed=102, tag="nb")
+    target = prompts.draw(150)
+    kin = [np.concatenate([target[:100], prompts.draw(45)]) for _ in (0, 1)]
+    strangers = [prompts.draw(n) for n in (140, 155, 135)]
+    first = sched.serve([prompts.request(x) for x in [target] + strangers])
+    assert sched.last_stats.prefill_tokens_reused == 0
+    again = prompts.request(target)
+    second = sched.serve([prompts.request(x) for x in kin + strangers[:1]]
+                         + [again])
+    # the kin share a group and so nothing; the target copies from one
+    assert sched.last_stats.prefill_tokens_reused == 150 - S
+    assert first[0].ok and second[-1].ok
+    assert_same_tokens(engine, "target", target, second[-1].tokens,
+                       first[0].tokens)
+    _assert_same_greedy(engine, again.id, second[-1].tokens, target)
+
+
+def test_a_donor_decodes_its_solo_tokens_while_a_sharer_is_admitted(shared):
+    engine, cut, sched = shared
+    prompts = _Prompts(engine, cut, seed=103, tag="mid")
+    donor = prompts.draw(150)
+    sharer = np.concatenate([donor[:100], prompts.draw(40)])
+    stats = ServingStats(num_slots=sched.num_slots)
+    d = prompts.request(donor)
+    assert sched.submit(d)
+    sched.step(stats)  # admitted, and one chunk decoded
+    assert 0 < sched.pool.get(_slot_of(sched, d.id)).emitted < NEW
+    s = prompts.request(sharer)
+    assert sched.submit(s)
+    sched._admit(stats)
+    assert stats.prefill_tokens_reused == 140 - S
+    _drained(sched, stats, 2)
+    for req, ids in ((d, donor), (s, sharer)):
+        _assert_same_greedy(engine, req.id, sched.take_result(req.id).tokens,
+                            ids)
+
+
+def test_a_released_donor_changes_nothing_and_is_no_donor_again(shared):
+    """The donor ends after two tokens and leaves the pool while its copier
+    decodes on; the next sharer lands in the freed slot and copies from the
+    copier (70 ids shared), not from what the slot held (140)."""
+    engine, cut, sched = shared
+    prompts = _Prompts(engine, cut, seed=104, tag="rel")
+    donor = prompts.draw(150)
+    copier = np.concatenate([donor[:70], prompts.draw(75)])
+    late = np.concatenate([donor[:140], prompts.draw(10)])
+    stats = ServingStats(num_slots=sched.num_slots)
+    d = prompts.request(donor, ModelSettings(temperature=0.0, max_tokens=2))
+    assert sched.submit(d)
+    sched._admit(stats)
+    freed = _slot_of(sched, d.id)
+    c = prompts.request(copier)
+    assert sched.submit(c)
+    sched.step(stats)  # the copier copies, and a chunk ends the donor
+    assert stats.prefill_tokens_reused == 145 - 2 * S
+    assert sched.pool.live_slots() == [_slot_of(sched, c.id)]
+    assert freed not in LiveRowPrefixes(sched.pool).slots
+    e = prompts.request(late)
+    assert sched.submit(e)
+    sched._admit(stats)
+    assert _slot_of(sched, e.id) == freed
+    assert stats.prefill_tokens_reused == (145 - 2 * S) + (150 - 2 * S)
+    _drained(sched, stats, 3)
+    assert_same_tokens(engine, d.id, donor, sched.take_result(d.id).tokens,
+                       alone(engine, d.id, GREEDY, 2))
+    for req, ids in ((c, copier), (e, late)):
+        _assert_same_greedy(engine, req.id, sched.take_result(req.id).tokens,
+                            ids)
+
+
+def _tool(name):
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(ROOT, "tools", name + ".py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def test_reuse_on_the_studys_pairs_is_what_prefix_stats_predicts(shared):
+    """Two of phase 1's counterfactual pairs (their last 150-192 byte ids,
+    from one offset, so that they part where the pair parts), admitted one
+    by one into a pool that keeps every earlier row: the share
+    ``tools/prefix_stats.py`` reckons before a run is the share the drain
+    counts."""
+    from fairness_llm_tpu.config import default_config
+    from fairness_llm_tpu.data.movielens import load_movielens
+    from fairness_llm_tpu.data.profiles import (
+        create_base_preferences,
+        create_profile_grid,
+        profile_pairs,
+    )
+    from fairness_llm_tpu.pipeline.prompts import recommendation_prompt
+
+    engine, cut, sched = shared
+    config = default_config()
+    data = load_movielens(config.data_dir, seed=config.random_seed)
+    profiles = create_profile_grid(
+        create_base_preferences(data, seed=config.random_seed), config, 1)
+    text = {p.id: recommendation_prompt(p) for p in profiles}
+    picked = []
+    for a, b in profile_pairs(profiles):
+        if a not in picked and b not in picked:
+            picked += [a, b]
+        if len(picked) == sched.num_slots:
+            break
+    byte_ids = [ByteTokenizer(512).encode(text[i]) for i in picked]
+    start = min(map(len, byte_ids)) - 150
+    rows = [3 + np.asarray(ids[start:], np.int32) % (cut["vocab_size"] - 3)
+            for ids in byte_ids]
+    assert all(150 <= len(r) <= 192 for r in rows)
+    expected = _tool("prefix_stats").expected_reuse(rows)
+    assert expected[0] == 0 and sum(expected) > 0
+
+    prompts = _Prompts(engine, cut, seed=105, tag="pair")
+    stats = ServingStats(num_slots=sched.num_slots)
+    for row in rows:
+        assert sched.submit(prompts.request(row))
+        sched._admit(stats)
+    assert stats.prefill_tokens == sum(map(len, rows))
+    assert stats.prefill_tokens_reused == sum(expected)
+    _drained(sched, stats, len(rows))
+
+
+def _float_rows(fn, *caches):
+    """``fn`` over the leaves that hold a row's keys, values or latents."""
+    return jax.tree.map(
+        lambda x, *rest: fn(x, *rest)
+        if jnp.issubdtype(x.dtype, jnp.floating) and x.ndim >= 3 else x,
+        *caches)
+
+
+def test_a_poisoned_donor_trips_the_guard_for_its_readers_only(shared):
+    """NaN in a live row's cache: a stranger admitted beside it is untouched,
+    the two rows that copy from it fail the finite check of their own call
+    and are requeued, and no row that was live is released."""
+    engine, cut, sched = shared
+    prompts = _Prompts(engine, cut, seed=106, tag="nan")
+    donor, stranger = prompts.draw(150), prompts.draw(140)
+    readers = [np.concatenate([donor[:100], prompts.draw(n)])
+               for n in (40, 45)]
+    stats = ServingStats(num_slots=sched.num_slots)
+    engine.numerics_guards = True
+    try:
+        with use_registry() as reg:
+            d = prompts.request(donor)
+            assert sched.submit(d)
+            sched._admit(stats)
+            slot = _slot_of(sched, d.id)
+            clean = sched._cache
+            sched._cache = _float_rows(
+                lambda x: x.at[slot].set(jnp.nan), clean)
+            u = prompts.request(stranger)
+            assert sched.submit(u)
+            sched._admit_once(stats)
+            assert stats.requeued == 0
+            live = sched.pool.live_slots()
+            assert len(live) == 2
+            rs = [prompts.request(x) for x in readers]
+            for r in rs:
+                assert sched.submit(r)
+            sched._admit_once(stats)
+            assert stats.requeued == 2 and sched.pool.live_slots() == live
+            assert reg.peek("faults_total", component="serving",
+                            kind="numerics", stage="prefill").value == 1
+            # the row heals; the requeued readers find it whole
+            sched._cache = _float_rows(
+                lambda now, was: now.at[slot].set(was[slot]),
+                sched._cache, clean)
+    finally:
+        engine.numerics_guards = False
+    _drained(sched, stats, 4)
+    for req, ids, retries in ((d, donor, 0), (u, stranger, 0),
+                              (rs[0], readers[0], 1), (rs[1], readers[1], 1)):
+        res = sched.take_result(req.id)
+        assert res.ok and res.retries == retries
+        _assert_same_greedy(engine, req.id, res.tokens, ids)
+
+
+def test_a_migrated_request_decodes_the_same_with_or_without_a_donor(toy):
+    """A request decoding on r1 when r1 is retired starts anew on r0, beside
+    a live row that holds its first hundred ids or beside a stranger."""
+    engine, cut = toy
+    prompts = _Prompts(engine, cut, seed=107, tag="mig")
+    target = prompts.draw(150)
+    beside = {"donor": np.concatenate([target[:100], prompts.draw(45)]),
+              "stranger": prompts.draw(145)}
+    serving = ServingConfig(
+        enabled=True, num_slots=2, queue_capacity=8, max_prompt_len=192,
+        max_new_tokens=NEW, decode_chunk=4, prefill_group=2)
+    tokens = {}
+    for case, ids in beside.items():
+        with use_registry():
+            fleet = ReplicaSet(engine, serving, settings=GREEDY,
+                               fleet=FleetConfig(replicas=2))
+            a, t = prompts.request(ids), prompts.request(target)
+            assert fleet.submit(a) and fleet.submit(t)
+            fleet.tick()  # one to each replica, and a chunk decoded on both
+            r0, r1 = fleet.replicas
+            assert a.id in r0.assigned and t.id in r1.assigned
+            assert fleet.retire_replica(r1) == 1
+            stats = fleet.drain()
+            assert stats.prefill_tokens_reused == \
+                (150 - S if case == "donor" else 0)
+            res = fleet.take_result(t.id)
+            assert res.ok and fleet.take_result(a.id).ok
+            _assert_same_greedy(engine, t.id, res.tokens, target)
+            tokens[case] = res.tokens
+    assert_same_tokens(engine, "migrated", target, tokens["donor"],
+                       tokens["stranger"])
+
+
+def test_a_second_serve_takes_no_row_of_the_first_as_donor(shared):
+    engine, cut, sched = shared
+    prompts = _Prompts(engine, cut, seed=108, tag="twice")
+    ids = prompts.draw(150)
+    first = sched.serve([prompts.request(ids)])[0]
+    assert LiveRowPrefixes(sched.pool).slots == []
+    again = prompts.request(ids)
+    second = sched.serve([again])[0]
+    assert (sched.last_stats.prefill_batches,
+            sched.last_stats.prefill_tokens_reused) == (3, 0)
+    assert_same_tokens(engine, "twice", ids, second.tokens, first.tokens)
+    _assert_same_greedy(engine, again.id, second.tokens, ids)
 
 
 # -- the benchmark's new layer metrics -----------------------------------------------
@@ -397,3 +672,39 @@ def test_the_reuse_share_files_load_and_read_the_counters(metric, cells):
     assert read(Ctx(stats.as_dict()), **args) == pytest.approx(55.0)
     # the parent's stats have no such counter: the line leaves the metric out
     assert read(Ctx({"prefill_tokens": 8000}), **args) is None
+
+
+# -- the prompt layout the reuse rides on ---------------------------------------------
+
+
+def test_recommendation_prompt_pairs_diverge_late():
+    """The layout contract the hit rate rides on: counterfactual pairs
+    share most of their bytes as a prefix (demographics last)."""
+    from fairness_llm_tpu.data.profiles import Profile
+    from fairness_llm_tpu.pipeline.prompts import (
+        divergence_stats,
+        recommendation_prompt,
+    )
+
+    movies = [f"Movie {i}" for i in range(10)]
+    pairs = []
+    for g1, g2 in (("male", "female"), ("female", "non-binary")):
+        a = Profile(id="a", gender=g1, age="25-34", occupation="pro",
+                    watched_movies=movies, favorite_genres=["drama"])
+        b = Profile(id="b", gender=g2, age="25-34", occupation="pro",
+                    watched_movies=movies, favorite_genres=["drama"])
+        pairs.append((recommendation_prompt(a), recommendation_prompt(b)))
+    stats = divergence_stats(pairs)
+    assert stats["pairs"] == 2
+    assert stats["min_frac"] > 0.7, stats
+
+
+def test_divergence_stats_math():
+    from fairness_llm_tpu.pipeline.prompts import divergence_stats, lcp_len
+
+    assert lcp_len("abcd", "abXd") == 2
+    assert lcp_len("abc", "abc") == 3
+    s = divergence_stats([("aaaa", "aaXX"), ("bb", "bb")])
+    assert s["min_frac"] == pytest.approx(0.5)
+    assert s["max_frac"] == pytest.approx(1.0)
+    assert divergence_stats([])["pairs"] == 0

@@ -224,7 +224,7 @@ def _serve_step_jaxpr(cfg):
     run = build_serve_step(
         cfg, Transformer(cfg), SamplerSettings(temperature=0.0, top_k=0, top_p=1.0),
         cfg.pad_token_id, cfg.eos_token_id, num_slots=SLOTS, chunk=2,
-        guard=False, paged=False)
+        guard=False)
     params = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
     cache = jax.eval_shape(lambda: init_cache(cfg, SLOTS, CACHE_LEN))
     row = lambda dt: jax.ShapeDtypeStruct((SLOTS,), dt)  # noqa: E731

@@ -1,13 +1,11 @@
 """HBM memory ledger tests (ISSUE 18, telemetry/memory.py).
 
 The accounting contract: every pool gauge equals the hand-computed nbytes
-of the live device tree it claims to describe (contiguous cache, paged
-arena, carried logits, params, prefix-KV LRU entries — and the per-shard
-split on a tp mesh), registration/release/rebuild conserve the total, the
-headroom forecaster's arithmetic is exact against an injected analytic
-limit, arena exhaustion produces exactly one deduplicated
-``memory_pressure`` bundle naming the deferring requests, attribution-off
-records nothing, and the ``--require-memory`` validator gate accepts a
+of the live device tree it claims to describe (the slot cache of k and v
+or of latents, carried logits, params, prefix-KV LRU entries — and the
+per-shard split on a tp mesh), registration/release/rebuild conserve the
+total, the headroom arithmetic is exact against an injected analytic
+limit, attribution-off records nothing, and the ``--require-memory`` validator gate accepts a
 real run and rejects a stripped one.
 """
 
@@ -28,12 +26,9 @@ from fairness_llm_tpu.telemetry import (
     set_aot_memory_capture,
     set_attribution,
     snapshot,
-    use_flight_recorder,
-    use_incident_manager,
     use_registry,
     use_timeline,
 )
-import fairness_llm_tpu.telemetry as T
 from fairness_llm_tpu.telemetry.memory import (
     MemoryLedger,
     use_memory_ledger,
@@ -115,26 +110,34 @@ def test_contiguous_cache_oracle(engine):
                               pool="kv_contiguous") == expect_kv
 
 
-def test_paged_arena_oracle(engine):
-    """hbm_bytes{pool=kv_paged} equals the hand-computed arena bytes:
-    per layer k/v [N, bs, n_kv, head_dim] f32 plus the validity (bool) and
-    position (int32) planes plus per-slot lengths."""
+def test_latent_cache_oracle():
+    """hbm_bytes{pool=kv_contiguous} of a pool of latents equals the
+    hand-computed bytes of its leaves: layers x [num_slots, cache_len] x
+    one latent and its rotated key a token, f32, and the same bookkeeping
+    planes as the dense pool."""
+    from toys import KINDS, toy_engine
+
+    from fairness_llm_tpu.models.transformer import MOE_COUNTERS
+
+    engine, _ = toy_engine(KINDS["latent"], seed=18)
     cfg = engine.config
-    with use_registry(), use_memory_ledger() as mem:
+    with use_registry() as reg, use_memory_ledger() as mem:
         sched = ContinuousScheduler(engine, ServingConfig(
             enabled=True, num_slots=2, max_prompt_len=64, max_new_tokens=16,
-            paged_kv=True, kv_block_size=16,
         ), settings=greedy(8))
-        N = sched.pool.paged.num_blocks
-        bs = 16
-        expect = (cfg.num_layers * 2 * N * bs * cfg.num_kv_heads
-                  * cfg.head_dim * 4     # k/v planes, f32
-                  + N * bs * 1           # key_valid, bool
-                  + N * bs * 4           # key_positions, int32
-                  + 2 * 4)               # lengths, int32 per slot
-        assert mem.pool_bytes("kv_paged") == expect
-        # The forecaster's per-block price derives from the same tree.
-        assert sched._block_bytes == expect // N
+        L = sched.cache_len
+        per_token = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+        assert per_token == cfg.cache_values_per_token
+        expect = (cfg.num_layers * 2 * L * per_token * 4  # latents, f32
+                  + 2 * L * 1    # key_valid, bool
+                  + 2 * L * 4    # key_positions, int32
+                  + 4            # index, scalar int32
+                  + 2 * 4        # lengths, int32 per slot
+                  + len(MOE_COUNTERS) * 4)  # the experts' counters, int32
+        assert sum(x.nbytes for x in jax.tree.leaves(sched._cache)) == expect
+        assert mem.pool_bytes("kv_contiguous") == expect
+        assert reg.read_value("hbm_bytes", component="memory",
+                              pool="kv_contiguous") == expect
 
 
 def test_params_pool_and_rebuild():
@@ -193,25 +196,18 @@ def test_prefix_kv_lru_instrumented(engine):
                               component="engine") >= 1
 
 
-# -- reconciliation / forecast -------------------------------------------------
+# -- reconciliation / headroom -------------------------------------------------
 
 
-def test_headroom_forecast_math():
+def test_headroom_math():
     with use_registry() as reg, use_memory_ledger() as mem:
-        # No limit known: forecast abstains, it never guesses.
-        fc = mem.forecast(1000)
-        assert fc["basis"] is None and fc["fits"] is None
+        # No limit known: the ledger abstains, it never guesses.
+        assert mem.headroom_bytes() is None
         assert mem.headroom_frac() is None
         mem.register("other", "t:a", jnp.zeros((256,), jnp.float32))  # 1 KiB
         mem.set_analytic_limit(10_240)
-        fc = mem.forecast(2_048)
-        assert fc["basis"] == "indicative"
-        assert fc["headroom_bytes"] == 10_240 - 1_024
-        assert fc["fits"] is True
-        assert fc["headroom_after_frac"] == pytest.approx(
-            (10_240 - 1_024 - 2_048) / 10_240)
+        assert mem.headroom_bytes() == 10_240 - 1_024
         assert mem.headroom_frac() == pytest.approx(9_216 / 10_240)
-        assert mem.forecast(9_217)["fits"] is False
         assert reg.read_value("hbm_bytes_limit", component="memory",
                               reconciliation="indicative") == 10_240
         assert reg.read_value("hbm_headroom_bytes", component="memory",
@@ -229,59 +225,12 @@ def test_attribution_off_records_nothing():
             assert mem.register("other", "t:a",
                                 jnp.zeros((64,), jnp.float32)) == 0
             assert mem.total_bytes() == 0
-            mem.note_pressure("serving", True)
             assert not any(
                 getattr(m, "name", "").startswith(("hbm_", "memory_"))
                 for m in reg.instruments()
             )
     finally:
         set_attribution(prev)
-
-
-# -- memory pressure -----------------------------------------------------------
-
-
-def test_arena_exhaustion_fires_one_bundle(engine, tmp_path):
-    """A scarce paged arena defers admissions (the pre-existing hard
-    gate), and the ledger turns that into exactly ONE deduplicated
-    memory_pressure bundle naming the deferring requests — with the
-    recoverable memory_pressure_active gauge back at 0 once the drain
-    completes."""
-    probe = ContinuousScheduler(engine, ServingConfig(
-        enabled=True, num_slots=2, max_prompt_len=192, max_new_tokens=32,
-        decode_chunk=4, paged_kv=True, kv_block_size=16,
-    ), settings=greedy(8))
-    scarce = probe.pool.paged.blocks_per_slot + 2
-    del probe
-    cfg = ServingConfig(
-        enabled=True, num_slots=2, max_prompt_len=192, max_new_tokens=32,
-        decode_chunk=4, paged_kv=True, kv_block_size=16, kv_blocks=scarce,
-    )
-    stem = ("recommend five movies for a user who enjoyed Alien, Heat, "
-            "Fargo, Tron and likes thrillers; profile ")
-    fam = [stem + t for t in ("male 18-24", "female 18-24", "male 25-34",
-                              "female 25-34")]
-    with use_registry() as reg, use_timeline(), use_memory_ledger() as mem, \
-            use_flight_recorder() as rec, use_incident_manager() as mgr:
-        mgr.arm(str(tmp_path / "incidents"))
-        sched = ContinuousScheduler(engine, cfg, settings=greedy(8))
-        mem.set_analytic_limit(mem.total_bytes() + (16 << 20))
-        res = sched.serve([Request(prompt=p, id=f"mem{i}",
-                                   settings=greedy(8))
-                           for i, p in enumerate(fam)])
-        assert all(r.ok for r in res)
-        bundles = T.list_bundles(str(tmp_path / "incidents"))
-        mem_bundles = [b for b in bundles if b["class"] == "memory_pressure"]
-        assert len(mem_bundles) == 1
-        named = (mem_bundles[0].get("context") or {}).get("request_ids")
-        assert named and all(str(r).startswith("mem") for r in named)
-        assert mem_bundles[0]["context"]["headroom_bytes"] is not None
-        # Recoverable: pressure cleared once admission succeeded again.
-        assert reg.read_value("memory_pressure_active", default=-1.0,
-                              component="memory", replica="serving") == 0.0
-        # The flight recorder's memory ring saw the pressure transition.
-        assert any(e.get("event") == "pressure"
-                   for e in rec.rings["memory"])
 
 
 # -- validator gate / CLI ------------------------------------------------------

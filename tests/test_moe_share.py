@@ -227,7 +227,6 @@ def test_reckonings_read_the_layers_own_sizes():
 
     from fairness_llm_tpu.parallel import sharding as shd
     from fairness_llm_tpu.runtime.stepbuilder import build_prefix
-    from fairness_llm_tpu.serving.paged import init_arena
     from fairness_llm_tpu.telemetry.roofline import decode_step_bytes
 
     full = modelcfg.load_config(
@@ -268,7 +267,6 @@ def test_reckonings_read_the_layers_own_sizes():
 
     for refuse in (lambda: shd.make_axis_rules(cfg, tp2),
                    lambda: build_prefix(cfg, None, prefix_len=8),
-                   lambda: init_arena(cfg, 4, 16, 2),
                    lambda: hf_name_map(cfg)):
         with pytest.raises(NotImplementedError):
             refuse()

@@ -10,6 +10,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from fairness_llm_tpu.config import ModelSettings, ServingConfig
@@ -58,13 +59,22 @@ def _requests(n, tag="sp"):
                     settings=_greedy(6)) for i in range(n)]
 
 
-@pytest.fixture(scope="module", params=[False, True], ids=["slots", "paged"])
+@pytest.fixture(scope="module", params=["dense", "latent"])
 def served(request, engine):
     """One drain of three requests through two slots, its timeline and
-    registry: contiguous pool and paged pool."""
+    registry: a pool of k and v per KV head, and a pool of latents."""
+    from toys import KINDS, toy_engine
+
+    requests = _requests(3)
+    if request.param == "latent":
+        engine, cut = toy_engine(KINDS["latent"], seed=5)
+        rng = np.random.default_rng(5)
+        for r in requests:  # the toy's prompts are ids, kept by name
+            engine.tokenizer.register(
+                r.prompt, rng.integers(3, cut["vocab_size"], size=12))
     with use_registry() as reg, use_timeline() as tl:
-        sched = _scheduler(engine, paged_kv=request.param)
-        results = sched.serve(_requests(3))
+        sched = _scheduler(engine)
+        results = sched.serve(requests)
         assert all(r.ok for r in results)
         return tl.events(), reg
 
@@ -105,8 +115,8 @@ def test_dispatch_span_names_the_requests_it_admits(served):
     assert not _spans(events, "prefill[")  # replaced, not doubled
     admitted_by = {}
     for d in dispatches:
-        assert d["cat"] == "prefill" and d["args"]["program"] in (
-            "serve_prefill", "paged_prefill")
+        assert d["cat"] == "prefill" \
+            and d["args"]["program"] == "serve_prefill"
         for rid in d["args"]["request_ids"]:
             admitted_by[rid] = d
     instants = [e for e in events if e["type"] == "instant"
@@ -247,24 +257,19 @@ def test_second_build_loads_from_the_persistent_cache(persistent_cache):
 
 def test_ledger_trace_is_a_stage_of_its_own(served):
     _, reg = served
-    for program in ("serve_step", "paged_step"):
-        stages = _stage_seconds(reg, program)
-        if stages:
-            assert stages["ledger_trace"] > 0
-            assert {"trace", "lower", "backend_compile"} <= set(stages)
-            return
-    pytest.fail("no step program booked any stage")
+    stages = _stage_seconds(reg, "serve_step")
+    assert stages, "the step program booked no stage"
+    assert stages["ledger_trace"] > 0
+    assert {"trace", "lower", "backend_compile"} <= set(stages)
 
 
 @pytest.mark.parametrize("builder, kwargs, name", [
-    ("build_serve_step", dict(num_slots=2, chunk=2, guard=False,
-                              paged=False), "serve_step"),
-    ("build_serve_step", dict(num_slots=2, chunk=2, guard=False,
-                              paged=True, fuse=2), "paged_step_fused"),
+    ("build_serve_step", dict(num_slots=2, chunk=2, guard=False),
+     "serve_step"),
+    ("build_serve_step", dict(num_slots=2, chunk=2, guard=False, fuse=2),
+     "serve_step_fused"),
     ("build_serve_prefill", dict(nb=8, P=256, guard=False, num_slots=2),
      "serve_prefill_8x256"),
-    ("build_paged_prefill", dict(nb=2, S=64, guard=False, num_slots=2),
-     "paged_prefill_2x64"),
 ])
 def test_step_programs_are_named_before_jit(engine, builder, kwargs, name):
     from fairness_llm_tpu.runtime import stepbuilder
@@ -273,8 +278,6 @@ def test_step_programs_are_named_before_jit(engine, builder, kwargs, name):
     args = [engine.config, engine.model]
     if builder == "build_serve_step":
         args += [SamplerSettings(temperature=0.0, top_k=0, top_p=1.0), 0, 1]
-    if builder == "build_paged_prefill":
-        args = [engine.model]
     run = getattr(stepbuilder, builder)(*args, **kwargs)
     assert run.__name__ == name
 

@@ -121,6 +121,24 @@ def test_slot_pool_invalidation_cancelled_on_reuse():
     assert pool.pending_invalidation == []
 
 
+def test_pending_invalidation_is_o1_and_ordered():
+    """The satellite: dict-backed pending set keeps deterministic (release-
+    order) flush while alloc's cancellation is O(1)."""
+    pool = SlotPool(4)
+    for i in range(4):
+        pool.alloc(SlotState(request=Request(prompt=f"p{i}"), base=1,
+                             real_len=1))
+    pool.release(2)
+    pool.release(0)
+    pool.release(3)
+    assert pool.pending_invalidation == [2, 0, 3]  # release order, not id
+    assert pool.alloc(SlotState(request=Request(prompt="r"), base=1,
+                                real_len=1)) == 0
+    assert pool.pending_invalidation == [2, 3]
+    assert pool.take_invalidations() == [2, 3]
+    assert pool.pending_invalidation == []
+
+
 def test_slot_pool_churn_invariants():
     rng = np.random.default_rng(0)
     pool = SlotPool(4)
@@ -188,9 +206,11 @@ MIXED_PROMPTS = [
     "the quick brown fox",
     "hi",
     "abc abc abc abc abc abc",
-    # ~181 tokens: lands in a bigger prompt bucket than the others while
-    # staying inside the 192-token serving budget (see SCFG note above)
-    "a long prompt that shifts padding " * 5 + "and lands in a big bucket",
+    # 162 tokens with the BOS: lands in a bigger prompt bucket than the
+    # others while staying inside the 192-token serving budget (see SCFG
+    # note above; a fifth repeat is 196 and is truncated, which the
+    # engine alone is not)
+    "a long prompt that shifts padding " * 4 + "and lands in a big bucket",
     "zz",
     "recommend ten films please",
     "one two three one two three",

@@ -9,7 +9,6 @@ rounding (2e-6 on logits of size 1), the tolerance 1e-4. Dropping the
 ``q_rope . k_r`` term or the routed scaling of 2.5 from the reference moves
 the same number by twenty tolerances and more, and the tests say so."""
 
-import dataclasses
 import os
 import sys
 
@@ -21,15 +20,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from fairness_llm_tpu.config import ModelSettings, ServingConfig  # noqa: E402
-from fairness_llm_tpu.runtime.engine import DecodeEngine  # noqa: E402
 from fairness_llm_tpu.serving import ContinuousScheduler, Request  # noqa: E402
 from fairness_llm_tpu.utils.profiling import ServingStats  # noqa: E402
-from perfbench import families  # noqa: E402
 from perfbench.families import latent_moe  # noqa: E402
-from perfbench.lib import correct, modelcfg, reference  # noqa: E402
-from perfbench.lib import weights as W  # noqa: E402
-from perfbench.lib.sut import IdTokenizer  # noqa: E402
+from perfbench.lib import correct, reference  # noqa: E402
 from perfbench.lib.traffic import RequestSpec  # noqa: E402
+from toys import toy_engine  # noqa: E402
 
 TOL = 1e-4
 SEED = 77
@@ -41,18 +37,8 @@ NEW = [10, 6, 10, 8, 10, 7]
 def served():
     """The toy through a 2-slot scheduler (most rows ride recycled slots):
     (cut cfg, specs, their served tokens, the drain's stats)."""
-    cut = modelcfg.rehearsal_cut(modelcfg.load_config(
-        os.path.join(ROOT, "perfbench", "configs", "ax-k1-int8.json")))
-    cut["eos_token_id"] = 1  # never drawn; a row may still end on it
-    family = families.of(cut)
-    make_layer, make_globals = W.jitted_makers(cut)
-    words = W.seed_words(SEED)
-    tree = family.program_tree(cut, make_globals(words), [
-        make_layer(words, i) for i in range(cut["num_hidden_layers"])])
-    mc = dataclasses.replace(family.model_config(cut, "toy"), dtype="float32")
-    tok = IdTokenizer(cut["vocab_size"], cut["pad_token_id"],
-                      cut["eos_token_id"])
-    engine = DecodeEngine(mc, params=tree, tokenizer=tok, seed=0)
+    engine, cut = toy_engine("ax-k1-int8", SEED)
+    tok = engine.tokenizer
     rng = np.random.default_rng(SEED)
     specs = [RequestSpec(id=f"r{i}", ids=rng.integers(
         3, cut["vocab_size"], size=n).astype(np.int32), max_new=m, row_seed=i)
@@ -133,11 +119,3 @@ def test_the_expert_counters_ride_with_the_chunks(served):
     assert 0 < stats.moe_local_pairs <= 4 * tokens
     assert 0 < stats.moe_fullest_expert_pairs <= stats.moe_local_pairs
     assert stats.as_dict()["moe_local_pairs"] == stats.moe_local_pairs
-
-
-def test_paged_serving_refuses_a_latent_cache(served):
-    sched = served[4]
-    serving = dataclasses.replace(sched.serving, paged_kv=True)
-    with pytest.raises(NotImplementedError, match="paged"):
-        ContinuousScheduler(sched.engine, serving, settings=ModelSettings(
-            temperature=0.0, max_tokens=10))

@@ -5,8 +5,8 @@ composition the builder emits — across the five axes it exposes — decodes
 token-for-token what ``DecodeEngine.generate`` decodes for the same prompt
 alone:
 
-    {contiguous, paged} x {greedy, spec-verify} x {guards on, off}
-                        x {fuse 1, 2, 4} x {tp 1, 2, 8}    (where legal)
+    {dense, latent cache} x {greedy, spec-verify} x {guards on, off}
+                          x {fuse 1, 2, 4} x {tp 1, 2, 8}    (where legal)
 
 The tp axis runs on REAL devices (conftest forces 8 virtual CPU devices):
 a tp mesh shards params, the slot KV cache (kv-head axis), and the carried
@@ -17,7 +17,7 @@ element's tp=1 byte-identity and the ``@tp<k>`` telemetry label scheme.
 
 Illegal cells are structural, not skipped-for-time: spec-verify is an
 engine-path selection (the serving scheduler is greedy/sampled per-row),
-paged KV is a serving-path KV source, and fuse composes only with the
+latent heads over ``tp`` are refused, and fuse composes only with the
 serving dispatch (the engine's whole generation is already one dispatch).
 
 On top of the grid: recycled-slot, requeue-after-fault, and fleet-migration
@@ -54,7 +54,6 @@ from fairness_llm_tpu.serving.fleet import ReplicaSet
 from fairness_llm_tpu.serving.request import Request
 from fairness_llm_tpu.serving.scheduler import ContinuousScheduler
 from fairness_llm_tpu.telemetry import use_registry
-from fairness_llm_tpu.telemetry.roofline import decode_step_bytes
 from fairness_llm_tpu.telemetry.timeline import set_attribution, use_timeline
 from fairness_llm_tpu.utils.failures import ScriptedFaultInjector
 
@@ -63,7 +62,7 @@ def greedy(m: int) -> ModelSettings:
     return ModelSettings(temperature=0.0, max_tokens=m)
 
 
-# A near-duplicate family (shared prefix for the paged radix index) plus
+# A near-duplicate family (a shared prefix for admission to copy) plus
 # genuinely mixed-length odd prompts, enough of them that a 2-slot pool
 # recycles every slot several times per serve.
 PROMPTS = [
@@ -78,11 +77,11 @@ PROMPTS = [
 M = 8  # tokens per request — enough to cross several chunk boundaries
 
 
-def _scfg(fuse=1, paged=False, slots=2, chunk=2):
+def _scfg(fuse=1, slots=2, chunk=2):
     return ServingConfig(
         enabled=True, num_slots=slots, queue_capacity=64,
         max_prompt_len=192, max_new_tokens=32, decode_chunk=chunk,
-        fuse_steps=fuse, paged_kv=paged, kv_block_size=16,
+        fuse_steps=fuse,
     )
 
 
@@ -129,21 +128,21 @@ def test_compile_key_scheme_layout():
     assert k[0] == "spec_decode" and k[-2:] == (3, 8) and k[5] is False
     assert compile_key("serve_step", chunk=8, guard=False) == \
         ("serve_step", 8, False, 1)
-    assert compile_key("paged_step", chunk=4, guard=True, fuse=4) == \
-        ("paged_step", 4, True, 4)
+    assert compile_key("serve_step", chunk=4, guard=True, fuse=4) == \
+        ("serve_step", 4, True, 4)
     assert compile_key("serve_prefill", nb=4, P=64, guard=False) == \
         ("serve_prefill", 4, 64, False)
     assert compile_key("prefix", prefix_len=128) == ("prefix", 128)
-    with pytest.raises(ValueError):
-        compile_key("warp_drive")
+    for gone in ("warp_drive", "paged_step", "paged_prefill"):
+        with pytest.raises(ValueError):
+            compile_key(gone)
 
 
 def test_program_label_fused_naming():
     assert program_label("serve_step", 1) == "serve_step"
     assert program_label("serve_step", 4) == "serve_step_fused"
-    assert program_label("paged_step", 2) == "paged_step_fused"
-    assert set(STEP_PROGRAMS) == {
-        "serve_step", "paged_step", "serve_step_fused", "paged_step_fused"}
+    assert program_label("serve_step", 2) == "serve_step_fused"
+    assert set(STEP_PROGRAMS) == {"serve_step", "serve_step_fused"}
 
 
 def test_compile_key_mesh_element():
@@ -159,8 +158,10 @@ def test_compile_key_mesh_element():
         ("serve_prefill", 4, 64, False)
     assert compile_key("serve_step", chunk=8, guard=False, tp=2) == \
         ("serve_step", 8, False, 1, ("tp", 2))
-    assert compile_key("paged_prefill", nb=4, P=64, guard=True, tp=8) == \
-        ("paged_prefill", 4, 64, True, ("tp", 8))
+    assert compile_key("serve_prefill", nb=4, P=64, guard=True, tp=8) == \
+        ("serve_prefill", 4, 64, True, ("tp", 8))
+    assert compile_key("serve_step", chunk=8, guard=True, fuse=4, tp=8) == \
+        ("serve_step", 8, True, 4, ("tp", 8))
     # Disjoint across the whole (chunk, guard, fuse, tp) product.
     keys = {compile_key("serve_step", chunk=c, guard=g, fuse=f, tp=t)
             for c in (4, 8) for g in (False, True)
@@ -179,9 +180,9 @@ def test_program_label_mesh_suffix():
     strips the suffix for structural (``*_fused``) checks."""
     assert program_label("serve_step", 1, tp=1) == "serve_step"
     assert program_label("serve_step", 1, tp=2) == "serve_step@tp2"
-    assert program_label("paged_step", 4, tp=8) == "paged_step_fused@tp8"
+    assert program_label("serve_step", 4, tp=8) == "serve_step_fused@tp8"
     assert program_label("serve_prefill", tp=2) == "serve_prefill@tp2"
-    assert base_program("paged_step_fused@tp8") == "paged_step_fused"
+    assert base_program("serve_step_fused@tp8") == "serve_step_fused"
     assert base_program("serve_step") == "serve_step"
     assert base_program("serve_step@tp2").endswith("_fused") is False
     assert base_program("serve_step_fused@tp2").endswith("_fused")
@@ -199,27 +200,55 @@ def test_step_keys_disjoint_across_fuse_and_chunk(engine):
 # -- the parity grid -----------------------------------------------------------
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["contiguous", "paged"])
+@pytest.fixture(scope="module")
+def latent():
+    """The latent-cache toy (``tests/toys.py``) with PROMPTS' lengths as
+    ids, and what the engine alone decodes for each: float32 at the
+    highest matmul precision, so that two programs part at near-ties only."""
+    import jax
+    from toys import KINDS, IdPrompts, alone, toy_engine
+
+    with jax.default_matmul_precision("highest"):
+        engine, cut = toy_engine(KINDS["latent"], seed=14)
+        prompts = IdPrompts(engine, cut, 14, greedy(M), tag="lat")
+        stem = prompts.draw(40)
+        ids = [np.concatenate([stem, prompts.draw(n)]) for n in (15, 14, 16)]
+        ids += [prompts.draw(n) for n in (19, 31, 8)]
+        reqs = [prompts.request(x) for x in ids]
+        solo = {r.id: alone(engine, r.id, greedy(M), M) for r in reqs}
+        yield engine, reqs, dict(zip((r.id for r in reqs), ids)), solo
+
+
+@pytest.mark.parametrize("kind", ["dense", "latent"])
 @pytest.mark.parametrize("guard", [False, True], ids=["plain", "guarded"])
 @pytest.mark.parametrize("fuse", [1, 2, 4])
-def test_serving_grid_parity(engine, baseline, paged, guard, fuse):
-    """{contiguous, paged} x {guards on, off} x {fuse 1, 2, 4}, greedy
-    selection: 6 mixed requests over 2 slots (every slot recycles), each
-    token-identical to the engine alone. The fused cells are the tentpole's
-    acceptance surface: per-row caps/EOS stops advance in-program, so
-    folding k chunks into one dispatch must not move a single token."""
-    engine.numerics_guards = guard
-    try:
-        sched = ContinuousScheduler(
-            engine, _scfg(fuse=fuse, paged=paged), settings=greedy(M),
-        )
+def test_serving_grid_parity(engine, baseline, latent, kind, guard, fuse):
+    """{k and v per head, one latent a token} x {guards on, off} x {fuse 1,
+    2, 4}, greedy selection: 6 mixed requests over 2 slots (every slot
+    recycles), each token-identical to the engine alone. The fused cells:
+    per-row caps/EOS stops advance in-program, so folding k chunks into one
+    dispatch must not move a single token."""
+    from toys import assert_same_tokens
+
+    if kind == "latent":
+        engine, reqs, ids, solo = latent
+    else:
         reqs = [Request(id=f"g{i}", prompt=p, settings=greedy(M))
                 for i, p in enumerate(PROMPTS)]
+    engine.numerics_guards = guard
+    try:
+        sched = ContinuousScheduler(engine, _scfg(fuse=fuse),
+                                    settings=greedy(M))
         results = sched.serve(reqs)
-        _assert_parity(engine, baseline, reqs, results)
+        if kind == "latent":
+            for req, res in zip(reqs, results):
+                assert res.ok, (req.id, res.finish_reason, res.error)
+                assert_same_tokens(engine, req.id, ids[req.id], res.tokens,
+                                   solo[req.id][:len(res.tokens)])
+        else:
+            _assert_parity(engine, baseline, reqs, results)
         # The dispatched program compiled under the unified key.
-        base = "paged_step" if paged else "serve_step"
-        assert compile_key(base, chunk=2, guard=guard, fuse=fuse) \
+        assert compile_key("serve_step", chunk=2, guard=guard, fuse=fuse) \
             in sched._compiled
     finally:
         engine.numerics_guards = False
@@ -346,35 +375,6 @@ def test_degradation_rung2_resets_fuse(engine):
     engine.restore_speculation()
 
 
-# -- roofline: the fused byte oracle ------------------------------------------
-
-
-def test_fused_vs_unfused_paged_byte_oracle(engine):
-    """Hand-computed sibling of PR 12's paged oracle: the paged gather/
-    scatter tax amortizes over the steps the dispatch ACTUALLY ran, so a
-    fused dispatch (k x the steps) pays 1/k the per-step paged overhead
-    while the contiguous terms (params + pool KV) are unchanged."""
-    cfg = engine.config
-    item = 2 if cfg.dtype == "bfloat16" else 4
-    params = cfg.approx_param_count * item
-    per_slot = cfg.num_kv_heads * cfg.head_dim * item * 2 * cfg.num_layers
-    kv = 2 * 64 * per_slot  # batch=2 slots, 64 cache slots each
-    base = {"batch": 2, "cache_slots": 64, "prefix_len": 0}
-    plain = decode_step_bytes(cfg, base)
-    assert plain == params + kv
-
-    unfused = decode_step_bytes(
-        cfg, {**base, "paged_kv": True, "chunk_steps": 8})
-    fused = decode_step_bytes(
-        cfg, {**base, "paged_kv": True, "chunk_steps": 32})
-    assert unfused == params + kv + 4 * kv // 8
-    assert fused == params + kv + 4 * kv // 32
-    assert fused < unfused
-    # Contiguous fused steps stream the same bytes per step as unfused:
-    # the fusion win is host-gap amortization, not a byte-model change.
-    assert decode_step_bytes(cfg, base) == plain
-
-
 # -- fused telemetry attribution ----------------------------------------------
 
 
@@ -445,33 +445,31 @@ def tp2_engine():
                         mesh=make_mesh(MeshConfig(tp=2)))
 
 
-def _tp_scfg(tp, fuse=1, paged=False):
+def _tp_scfg(tp, fuse=1):
     return ServingConfig(
         enabled=True, num_slots=2, queue_capacity=64,
         max_prompt_len=192, max_new_tokens=32, decode_chunk=2,
-        fuse_steps=fuse, paged_kv=paged, kv_block_size=16, tp=tp,
+        fuse_steps=fuse, tp=tp,
     )
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["contiguous", "paged"])
 @pytest.mark.parametrize("fuse", [1, 4])
-def test_tp2_serving_grid_parity(engine, baseline, tp2_engine, paged, fuse):
-    """{contiguous, paged} x {fuse 1, 4} at tp=2: 6 mixed requests over 2
-    slots (every slot recycles) decode token-for-token what the
+def test_tp2_serving_grid_parity(engine, baseline, tp2_engine, fuse):
+    """{fuse 1, 4} at tp=2 (dense cache; latent heads over tp are refused):
+    6 mixed requests over 2 slots (every slot recycles) decode token-for-token what the
     SINGLE-DEVICE engine decodes — sharding the cache on kv heads and the
     matmuls on the model axis must move zero tokens. The compiled key
     carries the ("tp", 2) element and the program the @tp2 label."""
     sched = ContinuousScheduler(
-        tp2_engine, _tp_scfg(2, fuse=fuse, paged=paged), settings=greedy(M),
+        tp2_engine, _tp_scfg(2, fuse=fuse), settings=greedy(M),
     )
     reqs = [Request(id=f"g{i}", prompt=p, settings=greedy(M))
             for i, p in enumerate(PROMPTS)]
     results = sched.serve(reqs)
     _assert_parity(engine, baseline, reqs, results)
-    base = "paged_step" if paged else "serve_step"
-    assert compile_key(base, chunk=2, guard=False, fuse=fuse, tp=2) \
+    assert compile_key("serve_step", chunk=2, guard=False, fuse=fuse, tp=2) \
         in sched._compiled
-    assert sched._step_program() == program_label(base, fuse, tp=2)
+    assert sched._step_program() == program_label("serve_step", fuse, tp=2)
 
 
 def test_tp8_heads_replicate_parity(engine, baseline):

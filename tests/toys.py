@@ -1,0 +1,95 @@
+"""The benchmark families' toys as serving engines, for the tests that ask
+one question of both kinds of cache the program has: ``dense_gqa`` (k and v
+per KV head) from Mistral's file and ``latent_moe`` (one latent a token)
+from A.X-K1's. Float32 on int8 weights made from a seed, prompts as token
+ids the tokenizer hands back by name."""
+
+import dataclasses
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from fairness_llm_tpu.config import ModelSettings  # noqa: E402
+from fairness_llm_tpu.runtime.engine import DecodeEngine  # noqa: E402
+from fairness_llm_tpu.serving import Request  # noqa: E402
+from perfbench import families  # noqa: E402
+from perfbench.lib import modelcfg  # noqa: E402
+from perfbench.lib import weights as W  # noqa: E402
+from perfbench.lib.sut import IdTokenizer  # noqa: E402
+
+#: fixture ids -> the configuration whose rehearsal cut is the toy
+KINDS = {"dense": "mistral-7b-int8", "latent": "ax-k1-int8"}
+NEAR_TIE = 1e-4
+
+
+def toy_engine(config: str, seed: int):
+    """(engine, cut) for one family's toy, float32, ids as prompts."""
+    cut = modelcfg.rehearsal_cut(modelcfg.load_config(
+        os.path.join(ROOT, "perfbench", "configs", config + ".json")))
+    cut["eos_token_id"] = 1  # never drawn; a row may still end on it
+    family = families.of(cut)
+    make_layer, make_globals = W.jitted_makers(cut)
+    words = W.seed_words(seed)
+    tree = family.program_tree(cut, make_globals(words), [
+        make_layer(words, i) for i in range(cut["num_hidden_layers"])])
+    mc = dataclasses.replace(family.model_config(cut, "toy"), dtype="float32")
+    tok = IdTokenizer(cut["vocab_size"], cut["pad_token_id"],
+                      cut["eos_token_id"])
+    return DecodeEngine(mc, params=tree, tokenizer=tok, seed=0), cut
+
+
+class IdPrompts:
+    """Prompts as ids the tokenizer hands back by name."""
+
+    def __init__(self, engine, cut, seed, settings: ModelSettings,
+                 tag: str = "r"):
+        self.tok, self.vocab = engine.tokenizer, cut["vocab_size"]
+        self.rng = np.random.default_rng(seed)
+        self.settings, self.tag, self.count = settings, tag, 0
+
+    def draw(self, n):
+        return self.rng.integers(3, self.vocab, size=n).astype(np.int32)
+
+    def request(self, ids, settings: ModelSettings = None):
+        name = f"{self.tag}{self.count}"
+        self.count += 1
+        self.tok.register(name, np.asarray(ids, np.int32))
+        return Request(prompt=name, id=name,
+                       settings=settings or self.settings)
+
+
+def whole_prompt_logits(engine, ids):
+    """Next-token logits of ONE forward over the whole prompt, no cache."""
+    ids = jnp.asarray(ids, jnp.int32)[None]
+    pos = jnp.arange(ids.shape[1], dtype=jnp.int32)[None]
+    logits, _ = jax.jit(
+        lambda p, t, q: engine.model.apply({"params": p}, t, q)
+    )(engine.params, ids, pos)
+    return np.asarray(logits[0, -1])
+
+
+def assert_same_tokens(engine, what, ids, served, other):
+    """Two programs decoded the same tokens from ``ids``; where they part,
+    the whole-sequence logits must call the step a near-tie."""
+    served, other = np.asarray(served), np.asarray(other)
+    assert len(served) == len(other), (what, served, other)
+    for t in range(len(served)):
+        if served[t] != other[t]:
+            ref = whole_prompt_logits(
+                engine, np.concatenate([ids, served[:t]]))
+            assert abs(ref[served[t]] - ref[other[t]]) < NEAR_TIE, (
+                f"{what} token {t}: {served[t]} against {other[t]}, and "
+                "the reference's logits are not a tie")
+            break
+
+
+def alone(engine, name, settings: ModelSettings, n: int):
+    """The first ``n`` tokens ``DecodeEngine`` alone decodes for the
+    registered prompt ``name``."""
+    return np.asarray(engine.generate([name], settings).tokens[0])[:n]

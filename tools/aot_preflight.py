@@ -104,7 +104,7 @@ def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
     step = build_serve_step(
         cfg, model, SamplerSettings(temperature=0.7, top_k=0, top_p=1.0),
         tok.pad_id, tok.eos_id, num_slots=slots, chunk=8, guard=True,
-        paged=False, fuse=1)
+        fuse=1)
     row = lambda dt: host((slots,), dt)  # noqa: E731
     programs = [
         ("serve_prefill", jax.jit(prefill),

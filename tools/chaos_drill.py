@@ -44,22 +44,14 @@ What it does, in one process, deterministically:
    with their members' serving events attributed (the requeues the
    injected faults caused); the rendered fairness report is written
    beside the snapshot (``fairness_report.txt``) for failure evidence;
-9. drills the PAGED KV CACHE (ISSUE 10): a counterfactual-shaped prompt
-   family through a ``--paged-kv`` scheduler with a scarce block arena —
-   a mid-sweep decode fault requeues a request whose prefix blocks are
-   SHARED with a live twin; asserting zero lost, every survivor
-   token-identical to the engine baseline (a stale or wrongly-freed
-   block would corrupt a survivor's tokens), the requeue re-admitted
-   through the radix index (nonzero hit tokens), and block accounting
-   whole at drain;
-10. drills the FUSED DISPATCH (ISSUE 14): the same workload through a
+9. drills the FUSED DISPATCH (ISSUE 14): the same workload through a
    ``--fuse-steps 4`` scheduler with an injected NaN landing INSIDE a
    fused window (four chunks in one compiled call) — the guard flag rides
    the fused carry, the whole dispatch is discarded at its boundary as
    one ``NumericsFault``, the poisoned rider requeues once, and every
    survivor decodes token-identical (fusion widens the blast radius per
    fault, never the outcome);
-11. validates the ISSUE-4/5/6 acceptance: every request terminal (zero
+10. validates the ISSUE-4/5/6 acceptance: every request terminal (zero
    lost), survivors token-for-token equal to the baseline (zero corrupt
    records — the NaN chunk was retried, not delivered), the breaker cycle
    + hang + numerics fault + manifest failure + canary mismatch + fleet
@@ -624,71 +616,7 @@ def main() -> int:
                         for d in mon.divergent],
             ) + "\n")
 
-    # 9. Paged KV prefix reuse under faults (ISSUE 10): the defining
-    # workload shape — near-duplicate prompts sharing a long prefix —
-    # through a paged scheduler with a scarce arena (~1 slot's worth + 2
-    # blocks, so block recycling is constant), with a mid-sweep decode
-    # fault hitting a request whose prefix blocks are SHARED with its
-    # live twin. The fault releases the victim's slot (derefs the shared
-    # chain) while the twin keeps decoding through the same blocks; the
-    # requeue re-admits through the radix index. Parity against the
-    # static engine is the no-stale-block-reads proof.
-    import dataclasses as _dc
-
-    paged_cfg = _dc.replace(SERVING, paged_kv=True, kv_block_size=16)
-    probe_sched = ContinuousScheduler(engine, paged_cfg, settings=GREEDY)
-    scarce_blocks = probe_sched.pool.paged.blocks_per_slot + 2
-    del probe_sched  # existed only to read blocks_per_slot; free its arena
-    paged_cfg = _dc.replace(paged_cfg, kv_blocks=scarce_blocks)
-    stem = ("recommend five movies for a user who enjoyed Alien, Heat, "
-            "Fargo, Tron and likes thrillers; profile ")
-    fam = [stem + t for t in ("male 18-24", "female 18-24", "male 25-34",
-                              "female 25-34", "male 35-44", "female 35-44")]
-    paged_baseline = {
-        f"paged{i}": np.asarray(engine.generate([p], GREEDY).tokens[0])
-        for i, p in enumerate(fam)
-    }
-    # paged1's prefix is shared with paged0 (served just before it) — the
-    # fault lands while those blocks are cached/refcounted.
-    paged_inj = ScriptedFaultInjector(faults={("paged1", "decode"): 1})
-    paged_sched = ContinuousScheduler(engine, paged_cfg, settings=GREEDY,
-                                      fault_injector=paged_inj)
-    paged_res = {r.id: r for r in paged_sched.serve(
-        [Request(prompt=p, id=f"paged{i}", settings=GREEDY)
-         for i, p in enumerate(fam)]
-    )}
-    check(len(paged_res) == len(fam)
-          and all(r.ok for r in paged_res.values()),
-          "paged chaos: zero lost under mid-sweep fault + scarce arena")
-    paged_parity = all(
-        np.array_equal(np.asarray(r.tokens),
-                       paged_baseline[rid][:len(r.tokens)])
-        and np.all(paged_baseline[rid][len(r.tokens):]
-                   == engine.tokenizer.pad_id)
-        for rid, r in paged_res.items()
-    )
-    check(paged_parity,
-          "paged chaos: survivors token-identical (no stale-block reads)")
-    check(paged_res["paged1"].retries == 1,
-          "paged chaos: shared-prefix victim requeued exactly once")
-    pkv = paged_sched.pool.paged
-    check(pkv._hit_tokens > 0 and pkv.hit_ratio > 0.5,
-          f"paged chaos: radix cache hit through the churn "
-          f"(ratio {pkv.hit_ratio:.2f})")
-    tree_blocks = 0
-    stack = [pkv.index.root]
-    while stack:
-        node = stack.pop()
-        for child in node.children.values():
-            tree_blocks += 1
-            stack.append(child)
-    check(pkv.free_blocks + tree_blocks == pkv.num_blocks
-          and not pkv._private,
-          "paged chaos: block accounting whole at drain "
-          f"(free {pkv.free_blocks} + cached {tree_blocks} "
-          f"== {pkv.num_blocks})")
-
-    # 10. FUSED multi-step dispatch under faults (ISSUE 14,
+    # 9. FUSED multi-step dispatch under faults (ISSUE 14,
     # runtime/stepbuilder.py): the same containment contract with the
     # dispatch boundary MOVED — --fuse-steps 4 folds four decode chunks
     # into one compiled call, and an injected NaN lands INSIDE that fused
@@ -697,6 +625,8 @@ def main() -> int:
     # requeues once, and survivors decode token-identical: fusion may
     # widen the blast radius per fault (k chunks of work), never the
     # outcome.
+    import dataclasses as _dc
+
     reg = T.get_registry()
     nf_before = reg.read_value("numerics_faults_total",
                                component="serving", stage="decode")
@@ -741,46 +671,6 @@ def main() -> int:
           in fused_sched._compiled,
           "fused chaos: the dispatch compiled under the fused key "
           "(chunk, guard, fuse)")
-
-    # 11. Memory-pressure drill (ISSUE 18, telemetry/memory.py): the same
-    # scarce arena as section 9 under its OWN incident scope
-    # (replica="memdrill" — section 9's exhaustion already owns the
-    # default "serving" dedup key) with an injected analytic HBM budget
-    # (CPU reports no memory_stats; reconciliation stays "indicative").
-    # Admission demand beyond the arena must defer exactly as before —
-    # the allocator stays the hard gate — while the ledger (a) fires
-    # EXACTLY ONE deduplicated memory_pressure bundle naming the
-    # deferring requests and (b) drops the recoverable
-    # memory_pressure_active gauge back to 0 once decode frees blocks
-    # and admission succeeds.
-    from fairness_llm_tpu.telemetry.memory import get_memory_ledger
-
-    mem = get_memory_ledger()
-    mem_sched = ContinuousScheduler(engine, paged_cfg, settings=GREEDY,
-                                    replica="memdrill")
-    mem.set_analytic_limit(mem.total_bytes() + (64 << 20))
-    mem_res = {r.id: r for r in mem_sched.serve(
-        [Request(prompt=p, id=f"mem{i}", settings=GREEDY)
-         for i, p in enumerate(fam)]
-    )}
-    check(len(mem_res) == len(fam) and all(r.ok for r in mem_res.values()),
-          "memory drill: zero lost under scarce-arena pressure")
-    mem_bundles = bundles("memory_pressure", scope="memdrill")
-    check(len(mem_bundles) == 1,
-          "memory drill: exactly one deduplicated memory_pressure bundle "
-          f"({len(mem_bundles)} found)")
-    named = ((mem_bundles[0].get("context") or {}).get("request_ids")
-             if mem_bundles else None) or []
-    check(bool(named) and all(str(r).startswith("mem") for r in named),
-          f"memory drill: bundle names the deferring requests ({named})")
-    check(reg.read_value("memory_pressure_active", default=-1.0,
-                         component="memory", replica="memdrill") == 0.0,
-          "memory drill: memory_pressure_active recovered to 0 at drain")
-    check(reg.read_value("hbm_headroom_bytes", component="memory",
-                         reconciliation="indicative") > 0,
-          "memory drill: headroom gauge published against the analytic "
-          "budget (indicative)")
-    mem.set_analytic_limit(None)
 
     snap = T.snapshot(T.get_registry())
     # Unlabeled entries only: the fleet section's per-replica boards write

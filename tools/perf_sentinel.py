@@ -29,7 +29,7 @@ noise-aware by construction:
 Usage:
     python tools/perf_sentinel.py --baseline bench_baseline.json --fresh fresh.json
     python tools/perf_sentinel.py --baseline bench_baseline.json \
-        --run --reps 2 --entries continuous,prefix_cache [--wall-warn-only]
+        --run --reps 2 --entries continuous [--wall-warn-only]
     python tools/perf_sentinel.py --self-check bench_baseline.json
 
 ``--self-check`` proves the gates bite on THIS harness without needing a
@@ -54,7 +54,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 DEFAULT_WALL_BAND = 2.0
 # Entries cheap enough for a CI runner (the headline sweep always rides).
-CHEAP_ENTRIES = "continuous,prefix_cache"
+CHEAP_ENTRIES = "continuous"
 
 EXIT_OK = 0
 EXIT_REGRESSION = 1

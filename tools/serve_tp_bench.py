@@ -17,8 +17,8 @@ process: the forced host device count only takes effect when set BEFORE
 jax initializes, which is why the env mutation sits above the imports.
 
 Contract (asserted, not just reported):
-  * token-for-token parity: tp=N serving — contiguous AND paged, fuse 1
-    AND 4 — decodes exactly the single-device engine's greedy stream;
+  * token-for-token parity: tp=N serving — fuse 1 AND 4 — decodes
+    exactly the single-device engine's greedy stream;
   * collectives executed: the compiled tp step program's HLO contains
     all-reduce (plus the cost ledger's nonzero ``collectives`` row under
     the ``@tp<N>`` program label).
@@ -98,56 +98,54 @@ def run(tp: int, model_name: str, reps: int) -> dict:
 
     mesh = make_mesh(MeshConfig(tp=tp))
     collective_rows = {}
-    for paged in (False, True):
-        for fuse in (1, 4):
-            tag = f"{'paged' if paged else 'contig'}_k{fuse}"
-            engine = DecodeEngine(cfg, seed=0, mesh=mesh)
-            with use_registry() as reg:
-                sched = ContinuousScheduler(
-                    engine,
-                    ServingConfig(
-                        enabled=True, num_slots=NUM_SLOTS, decode_chunk=4,
-                        fuse_steps=fuse, max_new_tokens=M, paged_kv=paged,
-                        tp=tp,
-                    ),
-                    settings=GREEDY,
-                )
+    for fuse in (1, 4):
+        tag = f"contig_k{fuse}"
+        engine = DecodeEngine(cfg, seed=0, mesh=mesh)
+        with use_registry() as reg:
+            sched = ContinuousScheduler(
+                engine,
+                ServingConfig(
+                    enabled=True, num_slots=NUM_SLOTS, decode_chunk=4,
+                    fuse_steps=fuse, max_new_tokens=M, tp=tp,
+                ),
+                settings=GREEDY,
+            )
 
-                def serve(rep):
-                    reqs = [Request(prompt=p, id=f"{tag}_{rep}_{i}",
-                                    settings=GREEDY)
-                            for i, p in enumerate(PROMPTS)]
-                    t0 = time.perf_counter()
-                    results = sched.serve(reqs)
-                    wall = time.perf_counter() - t0
-                    toks = [tuple(int(t) for t in r.tokens)
-                            for r in results]
-                    assert all(r.ok for r in results), (tag, results)
-                    return wall, toks
+            def serve(rep):
+                reqs = [Request(prompt=p, id=f"{tag}_{rep}_{i}",
+                                settings=GREEDY)
+                        for i, p in enumerate(PROMPTS)]
+                t0 = time.perf_counter()
+                results = sched.serve(reqs)
+                wall = time.perf_counter() - t0
+                toks = [tuple(int(t) for t in r.tokens)
+                        for r in results]
+                assert all(r.ok for r in results), (tag, results)
+                return wall, toks
 
-                serve("warm")  # compile outside the timed reps
-                best = None
-                for rep in range(reps):
-                    wall, toks = serve(rep)
-                    assert toks == ref_tokens, (
-                        f"{tag}: tp={tp} token stream diverged from the "
-                        f"single-device engine")
-                    if best is None or wall < best:
-                        best = wall
-                # Collectives executed, not omitted: the ledger published
-                # a nonzero collectives row under this tp program label.
-                coll = sum(
-                    inst.value for inst in reg.instruments()
-                    if inst.name == "cost_ledger_bytes"
-                    and inst.labels.get("component") == "collectives"
-                    and f"@tp{tp}" in inst.labels.get("program", "")
-                )
-                assert coll > 0, f"{tag}: no collectives attributed"
-                collective_rows[tag] = coll
-            out[tag] = {
-                "wall_s": round(best, 3),
-                "tokens_per_sec": round(out["useful_tokens"] / best, 1),
-            }
+            serve("warm")  # compile outside the timed reps
+            best = None
+            for rep in range(reps):
+                wall, toks = serve(rep)
+                assert toks == ref_tokens, (
+                    f"{tag}: tp={tp} token stream diverged from the "
+                    f"single-device engine")
+                if best is None or wall < best:
+                    best = wall
+            # Collectives executed, not omitted: the ledger published
+            # a nonzero collectives row under this tp program label.
+            coll = sum(
+                inst.value for inst in reg.instruments()
+                if inst.name == "cost_ledger_bytes"
+                and inst.labels.get("component") == "collectives"
+                and f"@tp{tp}" in inst.labels.get("program", "")
+            )
+            assert coll > 0, f"{tag}: no collectives attributed"
+            collective_rows[tag] = coll
+        out[tag] = {
+            "wall_s": round(best, 3),
+            "tokens_per_sec": round(out["useful_tokens"] / best, 1),
+        }
 
     # HLO witness: the sharded contiguous step program really contains
     # all-reduce ops (GSPMD inserted them post-partitioning, so the jaxpr
@@ -166,7 +164,7 @@ def run(tp: int, model_name: str, reps: int) -> dict:
     step = build_serve_step(
         engine.config, engine.model, SamplerSettings(),
         engine.tokenizer.pad_id, engine.tokenizer.eos_id,
-        num_slots=NUM_SLOTS, chunk=4, guard=False, paged=False, fuse=1,
+        num_slots=NUM_SLOTS, chunk=4, guard=False, fuse=1,
     )
     import jax.numpy as jnp
 

@@ -34,15 +34,6 @@ containing prefill + decode spans and request lanes.
 drill produces (ISSUE 8): nonzero ``shed_total``, an
 ``overload_transitions_total`` escalation AND a return to level 0, and
 every ``overload_level`` gauge ending at 0.
-``--require-prefix-cache`` requires the paged-KV prefix-reuse signals a
-``--paged-kv --continuous`` study produces (ISSUE 10): nonzero
-``prefix_cache_hit_tokens_total``, a ``prefix_cache_hit_ratio`` gauge above
-0.5 (the counterfactual sweep's near-duplicate prompts MUST mostly hit),
-populated block-arena occupancy gauges, a nonzero ``matched_prefix_len``
-histogram, and — when the serving canary ran — zero
-``canary_mismatch_total`` (the canary decodes through the live paged
-scheduler against a static-engine reference, so it IS the token-parity
-witness for the paged path).
 ``--require-autoscale`` requires the elastic-fleet signals the replay
 smoke drill produces (ISSUE 11): at least one
 ``autoscale_events_total{direction="up"}`` AND one ``direction="down"``
@@ -108,7 +99,6 @@ def check(path: str, require_serving: bool = False,
           require_profile: bool = False,
           require_overload: bool = False,
           require_fairness: bool = False,
-          require_prefix_cache: bool = False,
           require_autoscale: bool = False,
           require_costmodel: bool = False,
           require_incidents: bool = False,
@@ -133,8 +123,6 @@ def check(path: str, require_serving: bool = False,
         problems.extend(_check_fairness(snap))
     if require_autoscale:
         problems.extend(_check_autoscale(snap))
-    if require_prefix_cache:
-        problems.extend(_check_prefix_cache(snap))
     if require_overload:
         counters = snap.get("counters", [])
 
@@ -492,8 +480,8 @@ def _check_memory(snap: dict) -> list:
     total respects it."""
     problems = []
     gauges = snap.get("gauges", [])
-    # Pool residency: at least the params pool plus one KV pool must be
-    # nonzero — a serving run that allocated neither accounted nothing.
+    # Pool residency: the params pool and the KV pool must be nonzero — a
+    # serving run that allocated neither accounted nothing.
     pool_bytes = {}
     for g in gauges:
         lb = g.get("labels", {})
@@ -505,11 +493,9 @@ def _check_memory(snap: dict) -> list:
     if pool_bytes.get("params", 0.0) <= 0:
         problems.append("hbm_bytes{pool=params} is zero or absent (no "
                         "engine ever registered its param tree)")
-    if (pool_bytes.get("kv_contiguous", 0.0) <= 0
-            and pool_bytes.get("kv_paged", 0.0) <= 0):
-        problems.append("neither hbm_bytes{pool=kv_contiguous} nor "
-                        "{pool=kv_paged} is nonzero (no scheduler ever "
-                        "registered its KV state)")
+    if pool_bytes.get("kv_contiguous", 0.0) <= 0:
+        problems.append("hbm_bytes{pool=kv_contiguous} is zero or absent "
+                        "(no scheduler ever registered its KV state)")
     # Ledger total vs limit: where a limit exists (device-reported or
     # injected analytic), the accounted total must fit under it.
     total = sum(float(g.get("value", 0.0)) for g in gauges
@@ -705,55 +691,6 @@ def _check_fairness(snap: dict) -> list:
     return problems
 
 
-def _check_prefix_cache(snap: dict) -> list:
-    """The --require-prefix-cache gate (ISSUE 10): the paged KV cache hit,
-    the hit RATIO cleared 0.5 on the counterfactual study, the block arena
-    reported its occupancy, and the canary (when armed) saw zero
-    mismatches — parity-clean prefix reuse, not just nonzero counters."""
-    problems = []
-    counters = snap.get("counters", [])
-    gauges = snap.get("gauges", [])
-
-    def total(name):
-        return sum(c["value"] for c in counters if c.get("name") == name)
-
-    hit = total("prefix_cache_hit_tokens_total")
-    miss = total("prefix_cache_miss_tokens_total")
-    if not hit:
-        problems.append(
-            "prefix_cache_hit_tokens_total is zero (the radix index never "
-            "matched a prefix — was --paged-kv on?)"
-        )
-    elif hit + miss and hit / (hit + miss) <= 0.5:
-        problems.append(
-            f"prefix-cache hit ratio {hit / (hit + miss):.3f} <= 0.5 over "
-            f"{hit + miss} prompt tokens (the counterfactual sweep's "
-            "near-duplicate prompts should mostly hit)"
-        )
-    ratios = [g for g in gauges if g.get("name") == "prefix_cache_hit_ratio"]
-    if not ratios:
-        problems.append("no prefix_cache_hit_ratio gauge (paged KV never "
-                        "published its live ratio)")
-    occ = [g for g in gauges if g.get("name") == "kv_block_occupancy"]
-    if not occ:
-        problems.append("no kv_block_occupancy gauge (block arena "
-                        "accounting never published)")
-    matched = [h for h in snap.get("histograms", [])
-               if h.get("name") == "matched_prefix_len"]
-    if not any(h.get("count") for h in matched):
-        problems.append("matched_prefix_len histogram empty (no paged "
-                        "prefill recorded its match)")
-    runs = total("canary_runs_total")
-    mismatches = total("canary_mismatch_total")
-    if runs and mismatches:
-        problems.append(
-            f"canary_mismatch_total = {mismatches:g} with --paged-kv (the "
-            "paged scheduler decoded DIFFERENT tokens than the static "
-            "reference — prefix reuse broke parity)"
-        )
-    return problems
-
-
 def _check_profile(path: str, snap: dict) -> list:
     """The --require-profile gate: compile events, roofline gauges, step
     gaps, and a schema-valid trace.json with the span kinds the ISSUE-7
@@ -840,7 +777,6 @@ def main() -> int:
     ap.add_argument("--require-profile", action="store_true")
     ap.add_argument("--require-overload", action="store_true")
     ap.add_argument("--require-fairness", action="store_true")
-    ap.add_argument("--require-prefix-cache", action="store_true")
     ap.add_argument("--require-autoscale", action="store_true")
     ap.add_argument("--require-costmodel", action="store_true")
     ap.add_argument("--require-incidents", action="store_true")
@@ -855,7 +791,6 @@ def main() -> int:
                  require_profile=a.require_profile,
                  require_overload=a.require_overload,
                  require_fairness=a.require_fairness,
-                 require_prefix_cache=a.require_prefix_cache,
                  require_autoscale=a.require_autoscale,
                  require_costmodel=a.require_costmodel,
                  require_incidents=a.require_incidents,
