@@ -6,7 +6,7 @@ the call takes an XLA expression instead. For the int8 matmul that XLA
 expression is the one XLA hoists a bf16 copy of the weight tree for, so a
 silent miss on the chip is a capacity bug, not a detail. Every gate decision
 that depends on the device or the shape is counted here at trace time as
-``kernel_path_total{kernel, path, reason, shape}`` and logged once per
+``kernel_path_total{kernel, path, reason, shape, blocks}`` and logged once per
 distinct label set, so a run (``chip_smoke.py``) can fail on a miss instead
 of measuring the wrong program.
 """
@@ -26,19 +26,23 @@ COUNTER = "kernel_path_total"
 
 
 def note_kernel_path(kernel: str, path: str, shape: Tuple[int, ...],
-                     reason: str = "") -> None:
+                     reason: str = "", blocks: str = "") -> None:
     """Count one gate decision. ``path`` is ``"pallas"`` or ``"xla"``;
     ``reason`` says why a gate refused (``"not_tpu"``, ``"untileable"``,
-    ``"mesh_axes"``)."""
+    ``"mesh_axes"``); ``blocks`` what a kernel's block rule chose for the
+    call (``int8_matmul``: ``rows:bm x bk x bn``), so a run says which arm of
+    the rule each shape took."""
     c = get_registry().counter(
         COUNTER, component="ops", kernel=kernel, path=path, reason=reason,
-        shape="x".join(str(d) for d in shape),
+        shape="x".join(str(d) for d in shape), blocks=blocks,
     )
     if c.value == 0 and path == "xla":
         level = logging.WARNING if jax.default_backend() == "tpu" \
             else logging.DEBUG
         logger.log(level, "%s %s takes the XLA path (%s)", kernel,
                    c.labels["shape"], reason)
+    elif c.value == 0 and blocks:
+        logger.info("%s %s takes blocks %s", kernel, c.labels["shape"], blocks)
     c.inc()
 
 

@@ -5,9 +5,22 @@ computation out of decode loops. For int8-stored weights that "obvious"
 dequant-at-use expression materializes a full bf16 copy of the parameter
 tree in HBM (an earlier compile of llama3-70b tp=8: the 8.84 GB/chip of int8
 arguments would fit a v5e, but not the 35.2 GB of hoisted bf16 temps).
-Inside a Pallas kernel the int8->bf16 conversion happens per [bk, bn] tile
-in VMEM, so HBM only ever holds the int8 tree: weight-only-quantized
-serving streams half the bytes AND fits models that bf16 cannot.
+Inside a Pallas kernel the int8->bf16 conversion happens on a chunk of the
+weight block already in VMEM, so HBM only ever holds the int8 tree:
+weight-only-quantized serving streams half the bytes AND fits models that
+bf16 cannot.
+
+Blocks: at the 48-192 rows of a decode step the product only streams its
+weight, so what a call costs above the bytes is its grid: a fixed third of a
+microsecond a step, the first block's fetch and the last block's product
+unoverlapped. :func:`matmul_blocks` sizes the weight block by bytes from the
+call's ``(M, K, N)`` alone (about a megabyte to eight, growing with the root
+of the weight's bytes), as whole contiguous rows ``[bk, N]`` where K has the
+divisor and the rows are few, else K-deep ``[K, bn]``; rows go in one tile up
+to 512, so x is fetched once, and N blocks are outermost, so many rows reuse
+the weight block in VMEM. ``tools/int8_block_sweep.py`` times the rule against
+fixed candidates per shape on the chip (v5e: 48-row calls 51-56% of their
+roofline with the 512 x 512 blocks this replaced, 85-91% with the rule).
 
 Scheme: symmetric per-output-channel quantization. ``w ≈ wq * scale[None, :]``
 with ``wq`` int8 and ``scale`` float32. Because the scale is constant along
@@ -15,8 +28,8 @@ the contraction axis it commutes with the matmul:
 
     x @ (wq * scale[None, :]) == (x @ wq) * scale[None, :]
 
-so the kernel runs the MXU matmul on (bf16 x, int8->bf16 wq) tiles with a
-float32 accumulator and applies the scale once, on the final K step. The
+so the kernel runs the MXU matmul on (bf16 x, int8->bf16 wq) chunks with a
+float32 accumulator and applies the scale once, after the last K chunk. The
 int8->bf16 cast is exact (|q| <= 127 << 2^8), making the kernel numerically
 equivalent to a bf16 matmul against the dequantized weights.
 
@@ -38,7 +51,8 @@ from __future__ import annotations
 
 import contextvars
 import functools
-from typing import Optional, Tuple
+import math
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -120,24 +134,53 @@ def dequantize_weight(wq: jnp.ndarray, scale: jnp.ndarray, dtype=jnp.bfloat16) -
 # ---------------------------------------------------------------------------
 
 
-def _matmul_kernel(x_ref, wq_ref, scale_ref, o_ref, acc_ref, *, nk: int):
-    """One (m, n) output tile; grid dim 2 walks K accumulating into VMEM f32."""
+_VMEM_LIMIT = 96 << 20  # of a v5e core's 128 MiB; what both kernels ask for
+_WEIGHT_BLOCK_CAP = 8 << 20  # most bytes of one int8 block, double-buffered
+_ROW_TILE = 512  # most rows of one tile: x is then fetched once a call
+_K_CHUNK = 512  # rows of the weight block cast to float at a time
+# Two readings of the v5e behind the block's size (PERF.md §6, PR 31): the
+# bytes its HBM streams during one grid step's fixed cost (0.35 us at 819
+# GB/s), and the rows at which a weight byte's FLOPs take as long as its
+# fetch (197 TFLOP/s over 2 x 819 GB/s).
+_STEP_BYTES = 288 << 10
+_RIDGE_ROWS = 120
 
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # int8 -> x.dtype happens HERE, on a [bk, bn] tile already in VMEM — the
-    # whole point of the kernel: no dequantized copy of the weight ever
-    # exists in HBM, and XLA cannot hoist what it cannot see.
-    acc_ref[:] += jnp.dot(
-        x_ref[:], wq_ref[:].astype(x_ref.dtype),
-        preferred_element_type=jnp.float32,
-    )
+def _matmul_kernel(x_ref, wq_ref, scale_ref, o_ref, *acc, nk: int):
+    """One (n block, row tile, K block) step: the K block walked in static
+    chunks of ``_K_CHUNK``, and, where K is cut across the grid (``nk`` > 1),
+    the partial sums kept in a float32 VMEM scratch until the last K block."""
+    bk = wq_ref.shape[0]
+    part = None
+    for lo in range(0, bk, _K_CHUNK):
+        ks = slice(lo, min(lo + _K_CHUNK, bk))
+        # int8 -> x.dtype happens HERE, on a chunk already in VMEM:
+        # the whole point of the kernel: no dequantized copy of the weight
+        # ever exists in HBM, and XLA cannot hoist what it cannot see.
+        d = jnp.dot(x_ref[:, ks], wq_ref[ks, :].astype(x_ref.dtype),
+                    preferred_element_type=jnp.float32)
+        part = d if part is None else part + d
 
-    @pl.when(pl.program_id(2) == nk - 1)
-    def _done():
-        o_ref[:] = (acc_ref[:] * scale_ref[:].astype(jnp.float32)).astype(o_ref.dtype)
+    def _done(total):
+        o_ref[:] = (total * scale_ref[:].astype(jnp.float32)).astype(o_ref.dtype)
+
+    if nk == 1:
+        _done(part)
+        return
+    (acc_ref,) = acc
+    kk = pl.program_id(2)
+
+    @pl.when(kk == 0)
+    def _first():
+        acc_ref[:] = part
+
+    @pl.when(kk > 0)
+    def _add():
+        acc_ref[:] += part
+
+    @pl.when(kk == nk - 1)
+    def _last():
+        _done(acc_ref[:])
 
 
 def _pick_block(dim: int, candidates=(512, 256, 128)) -> int:
@@ -158,30 +201,119 @@ def quant_tileable(k: int, n: int) -> bool:
     return k > 0 and n > 0 and k % _LANE == 0 and n % _LANE == 0
 
 
-def _quant_matmul_pallas(x, wq, scale, interpret: bool, out_dtype):
+class MatmulBlocks(NamedTuple):
+    """What :func:`matmul_blocks` chose for one call."""
+
+    bm: int  # row tile (rows padded to a multiple of it)
+    bk: int  # K block of the grid, walked inside the kernel in chunks
+    bn: int  # N block of the grid, outermost
+
+    def vmem_bytes(self, x_itemsize: int, out_itemsize: int) -> int:
+        """What one grid step holds: double-buffered weight, x, scale and
+        output blocks, the accumulator, one chunk's float copy and one
+        product."""
+        kc = min(_K_CHUNK, self.bk)
+        return (2 * self.bk * self.bn + 2 * self.bm * self.bk * x_itemsize
+                + 2 * 8 * self.bn * 4 + 2 * self.bm * self.bn * out_itemsize
+                + 2 * self.bm * self.bn * 4 + kc * self.bn * x_itemsize)
+
+    def __str__(self) -> str:
+        return f"{self.bm}x{self.bk}x{self.bn}"
+
+
+def _widest_divisor(dim: int, limit: int) -> int:
+    """The largest lane-multiple divisor of ``dim`` that is at most
+    ``limit``; one lane tile where none is."""
+    return max((c for c in range(_LANE, min(dim, limit) + 1, _LANE)
+                if dim % c == 0), default=_LANE)
+
+
+def matmul_blocks(m: int, k: int, n: int, x_itemsize: int = 2,
+                  out_itemsize: int = 2, *,
+                  weight_block_bytes: Optional[int] = None,
+                  vmem_limit: int = _VMEM_LIMIT) -> MatmulBlocks:
+    """Row tile, K block and N block of ``x[m, k] @ wq[k, n]``, from the
+    call's shapes alone (``k`` and ``n`` lane multiples).
+
+    The weight block is sized by bytes. A call pays for its first block's
+    fetch and its last block's product unoverlapped, and a fixed cost for
+    every grid step, so the block that balances the two grows with the root
+    of the weight's bytes and shrinks as rows add FLOPs to a byte
+    (``weight_block_bytes``, when given, stands in for that target). Under
+    it, by what the v5e showed (``tools/int8_block_sweep.py``):
+
+    - *whole rows*, ``[bk, n]``: one contiguous burst a block, the partial
+      sums in a float32 scratch across the K steps. Taken when the call
+      only streams its weight (rows under the ridge), a divisor of ``k``
+      lands the block within half the target, and ``bk`` is at least twice
+      the rows, so that the scratch's read-modify-write stays a few bytes a
+      weight byte;
+    - else *K-deep*, ``[k, bn]`` with ``bn`` the widest lane-multiple
+      divisor of ``n`` under the target: each output block written once.
+      Only a ``k`` whose one-lane-tile column passes the cap is cut.
+
+    Rows go in one tile up to ``_ROW_TILE``, and x is fetched once; beyond
+    it in the fewest equal tiles (padded by under 8 rows a tile), innermost
+    under a K-deep block at least 512 wide, so one N block's weights are
+    fetched once and stay in VMEM while the x tiles stream past. What a step
+    holds stays under ``vmem_limit``: ``bn`` steps down its divisors, then
+    ``bm`` halves.
+    """
+    tiles = -(-m // _ROW_TILE)
+    bm = -(-m // (8 * tiles)) * 8
+    cap = weight_block_bytes or _WEIGHT_BLOCK_CAP
+    target = weight_block_bytes or min(cap, math.isqrt(
+        k * n * _STEP_BYTES * _RIDGE_ROWS // (_RIDGE_ROWS + max(m, _RIDGE_ROWS))))
+    bk = _widest_divisor(k, target // n)
+    if m <= _RIDGE_ROWS and target // 2 <= bk * n <= target and bk >= 2 * m:
+        blocks = MatmulBlocks(bm, bk, n)
+    else:
+        bk = k if k * _LANE <= cap else _widest_divisor(k, cap // _LANE)
+        wide = target // bk if tiles == 1 else max(target // bk, 512)
+        blocks = MatmulBlocks(bm, bk, _widest_divisor(n, wide))
+    while blocks.vmem_bytes(x_itemsize, out_itemsize) > vmem_limit:
+        if blocks.bn > _LANE:
+            blocks = blocks._replace(bn=_widest_divisor(n, blocks.bn - _LANE))
+        elif blocks.bm > 8:
+            blocks = blocks._replace(bm=-(-blocks.bm // 16) * 8)
+        elif blocks.bk > _LANE:
+            blocks = blocks._replace(bk=_widest_divisor(k, blocks.bk - _LANE))
+        else:
+            break  # one tile of each: nothing smaller to ask for
+    return blocks
+
+
+# Jitted so that a step program's 200-odd calls trace and lower each distinct
+# (shapes, blocks) once: the kernel body's unrolled chunks cost tens of
+# milliseconds a call to trace, at three stages a program, which was 13 s of
+# the routed-experts cell's set-up (PERF.md §6, PR 31). XLA inlines the calls,
+# so the compiled program holds the same ``int8_matmul`` custom calls.
+@functools.partial(jax.jit, static_argnames=("interpret", "out_dtype", "blocks"))
+def _quant_matmul_pallas(x, wq, scale, interpret: bool, out_dtype,
+                         blocks: MatmulBlocks):
     m, k = x.shape
     _, n = wq.shape
-    bm = m if m % 8 == 0 else -(-m // 8) * 8
-    if bm != m:
-        x = jnp.pad(x, ((0, bm - m), (0, 0)))
-    bm_t = min(bm, 256)
-    while bm % bm_t:
-        bm_t //= 2
-    bk, bn = _pick_block(k), _pick_block(n)
+    bm, bk, bn = blocks
+    mp = -(-m // bm) * bm
+    if mp != m:
+        x = jnp.pad(x, ((0, mp - m), (0, 0)))
     nk = k // bk
+    # N blocks outermost, K innermost: with K whole (nk == 1) the weight
+    # block's index holds while the row tiles go by, so it is fetched once.
     out = pl.pallas_call(
         functools.partial(_matmul_kernel, nk=nk),
-        grid=(bm // bm_t, n // bn, nk),
+        grid=(n // bn, mp // bm, nk),
         in_specs=[
-            pl.BlockSpec((bm_t, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
+            pl.BlockSpec((bm, bk), lambda j, i, kk: (i, kk)),
+            pl.BlockSpec((bk, bn), lambda j, i, kk: (kk, j)),
+            pl.BlockSpec((1, bn), lambda j, i, kk: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bm_t, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((bm, n), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm_t, bn), jnp.float32)],
+        out_specs=pl.BlockSpec((bm, bn), lambda j, i, kk: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((mp, n), out_dtype),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)] if nk > 1 else [],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=interpret,
         name="int8_matmul",
@@ -206,8 +338,11 @@ def quant_matmul(
     out_dtype = out_dtype or x.dtype
     on_tpu = lowering_for_tpu()
     if (on_tpu or interpret) and quant_tileable(*wq.shape):
-        note_kernel_path("int8_matmul", "pallas", wq.shape)
-        return _quant_matmul_pallas(x, wq, scale, interpret, out_dtype)
+        blocks = matmul_blocks(x.shape[0], *wq.shape, x.dtype.itemsize,
+                               jnp.dtype(out_dtype).itemsize)
+        note_kernel_path("int8_matmul", "pallas", wq.shape,
+                         blocks=f"{x.shape[0]}:{blocks}")
+        return _quant_matmul_pallas(x, wq, scale, interpret, out_dtype, blocks)
     # On the TPU this is the expression XLA hoists a bf16 copy of the weight
     # for (module docstring) — never taken silently.
     note_kernel_path("int8_matmul", "xla", wq.shape,
@@ -220,16 +355,13 @@ def quant_matmul(
 # Grouped product over stacked expert kernels
 # ---------------------------------------------------------------------------
 
-_GROUP_WEIGHT_BLOCK_BYTES = 8 << 20  # one [K, bn] int8 block, double-buffered
-_GROUP_VMEM_LIMIT = 96 << 20  # of a v5e core's 128 MiB
-
 
 def _group_blocks(k: int, n: int) -> Tuple[int, int]:
     """(K chunk of the in-kernel loop, N block of the grid): the widest
     lane-multiple divisor of ``n`` whose int8 ``[k, bn]`` block stays under
     the budget, so an expert's kernel streams in as few grid steps as fit."""
     bn = max((c for c in range(_LANE, n + 1, _LANE)
-              if n % c == 0 and k * c <= _GROUP_WEIGHT_BLOCK_BYTES),
+              if n % c == 0 and k * c <= _WEIGHT_BLOCK_CAP),
              default=0)
     return _pick_block(k), bn
 
@@ -286,7 +418,7 @@ def _grouped_pallas(x, wq, scale, tile_expert, tile_block, tiles_used, bm,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=_GROUP_VMEM_LIMIT,
+            vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=interpret,
         name="grouped_int8_matmul",

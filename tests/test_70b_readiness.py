@@ -363,3 +363,34 @@ def test_serve_step_streams_the_kv_pool_once_on_v5e_topology():
     assert f"[{slots},{cache_len},{hkv * rep},{d}]" not in text
     assert f"[{slots},{cache_len},{hkv},{rep},{d}]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 32 * 1024**2
+
+
+@pytest.mark.parametrize("m,k,n,out", [
+    (48, 4096, 14336, "bfloat16"),   # whole rows [128, N]
+    (96, 3584, 18944, "bfloat16"),   # K-deep [K, 512]
+    (48, 3584, 152064, "float32"),   # Qwen2's head: K-deep at the cap
+    (512, 18944, 3584, "bfloat16"),  # a 64-token window of 8 rows
+    (192, 18432, 7168, "bfloat16"),  # A.X-K1 layer 0: K-deep, one lane tile
+])
+def test_int8_matmul_blocks_compile_on_v5e_topology(m, k, n, out):
+    """The block rule's choices at the benchmark cells' real widths go
+    through the REAL v5e compiler (Mosaic refuses what interpret mode
+    accepts: a misaligned slice, more VMEM than a kernel may take)."""
+    try:
+        from jax.experimental import topologies
+
+        td = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this env
+        pytest.skip(f"TPU topology unavailable: {type(e).__name__}")
+    from fairness_llm_tpu.ops.quant_matmul import force_pallas, quant_matmul
+
+    one = jax.sharding.SingleDeviceSharding(td.devices[0])
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in (
+        ((m, k), jnp.bfloat16), ((k, n), jnp.int8), ((n,), jnp.float32))]
+    with force_pallas():
+        compiled = jax.jit(
+            lambda x, w, s: quant_matmul(x, w, s, out_dtype=jnp.dtype(out))
+        ).lower(*args).compile()
+    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+    # no float copy of the weight outside the kernel
+    assert compiled.memory_analysis().temp_size_in_bytes < k * n

@@ -19,8 +19,10 @@ import pytest
 from fairness_llm_tpu.config import MeshConfig, ModelSettings
 from fairness_llm_tpu.models.configs import get_model_config
 from fairness_llm_tpu.models.transformer import Transformer, init_params, init_params_lowmem
+from fairness_llm_tpu.ops import quant_matmul as qm
 from fairness_llm_tpu.ops.quant_matmul import (
     dequantize_weight,
+    matmul_blocks,
     quant_matmul,
     quant_tileable,
     quantize_weight,
@@ -107,6 +109,160 @@ def test_tileability_gate():
         np.asarray(_ref_matmul(x, wq, scale)),
         rtol=2e-5, atol=2e-5,
     )
+
+
+# ---------------------------------------------------------------------------
+# The block rule: total and legal over every shape the configurations produce
+# ---------------------------------------------------------------------------
+
+# (d_model, q_dim, kv_dim, d_ff, vocab) of the dense configurations the cells
+# and the readiness tests run; A.X-K1's projections are listed one by one.
+_DENSE = {
+    "mistral-7b": (4096, 4096, 1024, 14336, 32000),
+    "qwen2-7b": (3584, 3584, 512, 18944, 152064),
+    "llama3-70b": (8192, 8192, 1024, 28672, 128256),
+}
+_AX_K1 = [(7168, 1536), (1536, 12288), (8192, 7168), (512, 16384),
+          (7168, 2048), (2048, 7168), (7168, 18432), (18432, 7168),
+          (7168, 20480)]
+# decode pools (48, 96, 192), the replay's part-filled chunks, one 64-token
+# window of 8 rows, a single row, a row count that needs padding
+_ROWS = (1, 45, 48, 64, 96, 128, 192, 512)
+
+
+def _weight_shapes():
+    """name -> (K, N) of every int8 product, whole and as the tp = 4 / tp = 8
+    shards ``quant_matmul_sharded`` hands the kernel (column-parallel cuts N,
+    row-parallel cuts K); what misses the tile gate takes the XLA path and
+    is left out, as at the call."""
+    out = {}
+    for name, (d, q, kv, ff, vocab) in _DENSE.items():
+        for tp in (1, 4, 8):
+            col = [(d, q // tp), (d, kv // tp), (d, ff // tp), (d, vocab // tp)]
+            row = [(q // tp, d), (ff // tp, d)]
+            for k, n in col + row:
+                if quant_tileable(k, n):
+                    out[f"{name}-tp{tp}-{k}x{n}"] = (k, n)
+    for k, n in _AX_K1:
+        out[f"ax-k1-{k}x{n}"] = (k, n)
+    return out
+
+
+_SHAPES = _weight_shapes()
+
+
+@pytest.mark.parametrize("name", sorted(_SHAPES))
+def test_block_rule_total_and_legal(name):
+    k, n = _SHAPES[name]
+    for m in _ROWS:
+        for x_item, out_item in ((2, 2), (2, 4), (4, 4)):
+            b = matmul_blocks(m, k, n, x_item, out_item)
+            tiles = -(-m // b.bm)
+            # divides or pads as the kernel expects; lane and sublane multiples
+            assert k % b.bk == 0 and b.bk % 128 == 0, (m, b)
+            assert n % b.bn == 0 and b.bn % 128 == 0, (m, b)
+            assert b.bm % 8 == 0 and tiles * b.bm - m < 8 * tiles, (m, b)
+            # what a step holds fits the limit the call sets
+            assert b.vmem_bytes(x_item, out_item) <= qm._VMEM_LIMIT, (m, b)
+            # more than one row tile: K whole, so the weight block's index
+            # holds across the row tiles and the weight is streamed once
+            assert tiles == 1 or b.bk == k, (m, b)
+            # never a smaller weight block than the 512-divisor blocks were
+            assert b.bk * b.bn >= qm._pick_block(k) * qm._pick_block(n), (m, b)
+            # few steps, few unrolled chunks: set-up must not grow
+            assert (n // b.bn) * (k // b.bk) * tiles <= 512, (m, b)
+            assert -(-b.bk // qm._K_CHUNK) <= 64, (m, b)
+
+
+def test_block_rule_sizes_the_decode_blocks_by_bytes():
+    """The cells' decode calls: one row tile, about a megabyte a block and
+    more, whole rows where K has the divisor and K-deep where it has not."""
+    for m, k, n in [(48, 4096, 14336), (48, 14336, 4096), (48, 3584, 18944),
+                    (96, 18944, 3584), (48, 3584, 152064), (192, 7168, 2048)]:
+        b = matmul_blocks(m, k, n)
+        assert b.bm == m and b.bk * b.bn >= 768 << 10, (m, k, n, b)
+    assert matmul_blocks(48, 4096, 14336) == (48, 128, 14336)  # whole rows
+    assert matmul_blocks(96, 3584, 18944) == (96, 3584, 512)  # 128 < 2 x 96
+    assert matmul_blocks(48, 4736, 3584) == (48, 4736, 256)  # no K divisor
+    assert matmul_blocks(512, 4096, 14336) == (512, 4096, 256)  # FLOP-bound
+    assert matmul_blocks(1030, 4096, 14336) == (344, 4096, 512)  # 3 row tiles
+
+
+def test_block_rule_shrinks_under_a_small_budget():
+    """Each arm of the fit loop: N block first, then the row tile."""
+    wide = matmul_blocks(512, 896, 4736 * 2, weight_block_bytes=16 << 20)
+    assert wide == (512, 896, 4736 * 2)
+    cut_n = matmul_blocks(512, 896, 4736 * 2, weight_block_bytes=16 << 20,
+                          vmem_limit=48 << 20)
+    assert cut_n.bm == 512 and cut_n.bn == 4736
+    cut_m = matmul_blocks(512, 896, 4736, 4, 4, weight_block_bytes=128 << 10,
+                          vmem_limit=3 << 20)
+    assert cut_m.bn == 128 and cut_m.bk == 896 and cut_m.bm < 512
+    assert cut_m.vmem_bytes(4, 4) <= 3 << 20
+    cut_k = matmul_blocks(48, 896, 4736, weight_block_bytes=64 << 10)
+    assert cut_k == (48, 128, 128)
+
+
+# Toy shapes with the cells' factor structure (Qwen2: 3584 = 7 x 512,
+# 18944 = 37 x 512): K = 7 x 128 walks one full chunk and a ragged one,
+# N = 37 x 128 has no divisor between one lane tile and the whole row.
+_TOY_K, _TOY_N = 7 * 128, 37 * 128
+_ARMS = {
+    # K whole, one N block a lane tile wide, one row tile (the decode arm)
+    "kdeep": dict(weight_block_bytes=128 << 10),
+    # the whole weight one block
+    "whole": dict(weight_block_bytes=8 << 20),
+    # K cut across the grid: the float32 scratch carries the partial sums
+    "kgrid": dict(weight_block_bytes=64 << 10),
+    # several row tiles under one resident weight block
+    "rowtiles": dict(weight_block_bytes=128 << 10, vmem_limit=1 << 20),
+}
+
+
+@pytest.mark.parametrize("arm", sorted(_ARMS))
+@pytest.mark.parametrize("m", [48, 96, 192, 512])
+@pytest.mark.parametrize("dtypes", ["f32-f32", "bf16-bf16", "bf16-f32"])
+def test_kernel_oracle_each_arm_of_the_rule(arm, m, dtypes):
+    xd, od = (dict(f32=jnp.float32, bf16=jnp.bfloat16)[t]
+              for t in dtypes.split("-"))
+    kx, kw = jax.random.split(jax.random.key(m))
+    x = jax.random.normal(kx, (m, _TOY_K), xd)
+    w = jax.random.normal(kw, (_TOY_K, _TOY_N), jnp.float32) * 0.02
+    wq, scale = quantize_weight(w)
+    blocks = matmul_blocks(m, _TOY_K, _TOY_N, x.dtype.itemsize,
+                           jnp.dtype(od).itemsize, **_ARMS[arm])
+    if arm == "kdeep":
+        assert blocks.bk == _TOY_K and blocks.bn == 128 and blocks.bm == m
+    elif arm == "whole":
+        assert blocks == (m, _TOY_K, _TOY_N)
+    elif arm == "kgrid":
+        assert blocks.bk == 128 and _TOY_K // blocks.bk == 7
+    elif m >= 192:  # the small limit cuts the rows into tiles
+        assert blocks.bk == _TOY_K and blocks.bm < m
+    got = qm._quant_matmul_pallas(x, wq, scale, True, od, blocks)
+    assert got.shape == (m, _TOY_N) and got.dtype == od
+    want = _ref_matmul(x.astype(jnp.float32), wq, scale)
+    tol = 2e-5 if xd == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
+                               rtol=tol, atol=tol)
+
+
+def test_kernel_path_counter_names_the_blocks(caplog):
+    """A run says which blocks each shape took: the counter's ``blocks``
+    label and, once per label set, a log line."""
+    import logging
+
+    from fairness_llm_tpu.telemetry.registry import get_registry, use_registry
+
+    x = jnp.ones((48, 256), jnp.float32)
+    with use_registry(), caplog.at_level(logging.INFO):
+        quant_matmul(x, jnp.ones((256, 384), jnp.int8), jnp.ones((384,)),
+                     interpret=True)
+        labels = [dict(c.labels) for c in get_registry().instruments()
+                  if c.name == "kernel_path_total"]
+    assert [(w["path"], w["shape"], w["blocks"]) for w in labels] == [
+        ("pallas", "256x384", "48:48x256x384")]
+    assert "int8_matmul 256x384 takes blocks 48:48x256x384" in caplog.text
 
 
 # ---------------------------------------------------------------------------
