@@ -432,8 +432,10 @@ def config_from_args(args: argparse.Namespace) -> Config:
                         mc = None
                     if mc is not None and mc.attention != "gqa":
                         raise SystemExit(
-                            f"--tp shards GQA heads; {args.model} keeps a "
-                            f"{mc.attention} cache, which has no head axis")
+                            f"--tp shards GQA heads; {args.model} has "
+                            f"{sorted(set(mc.mixer_kinds))} mixers, whose "
+                            "caches (a latent, a per-row state) have no "
+                            "head axis sharded yet")
                     if mc is not None and (mc.num_heads % args.tp != 0 or
                                            mc.num_kv_heads % args.tp != 0):
                         raise SystemExit(

@@ -5,17 +5,21 @@ Architecture hyperparameters for the open-weight families named in
 plus tiny test configs. All sizes are public-knowledge architecture constants.
 
 Flags rather than subclasses select family behavior:
-- ``pos_emb``: "rope" (llama/mistral/gemma) or "learned" (gpt2)
+- ``pos_emb``: "rope" (llama/mistral/gemma), "learned" (gpt2) or "none"
 - ``norm``: "rmsnorm" or "layernorm"
 - ``mlp``: "glu" (SwiGLU/GeGLU) or "mlp" (GPT-2's fc->gelu->proj)
 - ``embed_scale``: Gemma multiplies embeddings by sqrt(d_model)
 - ``sliding_window``: Mistral's local attention span
 
-A model whose layers are not all one kind (latent attention, routed experts
-after leading dense layers) is ``SparseLatentConfig``, a subclass: what each
-layer is and keeps is asked of the configuration (``layer_kinds``,
-``attention``, ``cache_values_per_token``, the parameter counts), and the
-dense answers stay on ``ModelConfig``, whose fields do not change.
+A model whose layers are not all one kind is a subclass: latent attention
+with routed experts after leading dense layers is ``SparseLatentConfig``;
+delta-rule linear-attention layers that keep a fixed state per row beside
+gated softmax layers, every one with routed experts, is
+``LinearHybridConfig``. What each layer is and keeps is asked of the
+configuration (``layer_kinds`` for the MLP, ``mixer_kinds`` for the mixer,
+``layer_cache_values`` and ``row_state_bytes`` for what the cache holds, the
+parameter counts), and the dense answers stay on ``ModelConfig``, whose
+fields do not change.
 """
 
 from __future__ import annotations
@@ -38,7 +42,7 @@ class ModelConfig:
     max_seq_len: int = 2048
     rope_theta: float = 10000.0
     norm_eps: float = 1e-5
-    pos_emb: str = "rope"  # "rope" | "learned"
+    pos_emb: str = "rope"  # "rope" | "learned" | "none" (no positions at all)
     norm: str = "rmsnorm"  # "rmsnorm" | "layernorm"
     mlp: str = "glu"  # "glu" | "mlp"
     use_bias: bool = False  # biases on attention + MLP projections (gpt2 family)
@@ -98,9 +102,19 @@ class ModelConfig:
     # bytes asks these, never ``2 * n_kv * head_dim`` or ``d_ff`` directly.
 
     @property
+    def mixer_kinds(self) -> Tuple[str, ...]:
+        """The mixer of each layer, in order. "gqa": softmax attention, k and
+        v per KV head and position in the cache; "mla": one latent per
+        position; "kda": the delta rule, one fixed state per ROW."""
+        return ("gqa",) * self.num_layers
+
+    @property
     def attention(self) -> str:
-        """"gqa": k and v per KV head in the cache; "mla": one latent."""
-        return "gqa"
+        """The one kind of mixer every layer has, or "hybrid". Whoever
+        reckons in GQA's terms asks for "gqa" and so refuses a model of
+        which only some layers are."""
+        kinds = set(self.mixer_kinds)
+        return kinds.pop() if len(kinds) == 1 else "hybrid"
 
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
@@ -108,13 +122,45 @@ class ModelConfig:
         return ("dense",) * self.num_layers
 
     @property
+    def heads_held(self) -> Tuple[int, int, int]:
+        """(query, key/value, linear-attention) heads this chip holds of
+        each layer: all of them, unless the configuration is a share."""
+        return self.num_heads, self.num_kv_heads, 0
+
+    @property
+    def attention_gate(self) -> bool:
+        """A sigmoid gate on a softmax layer's output, elementwise over
+        heads x head_dim, before ``o_proj``."""
+        return False
+
+    @property
     def cache_values_per_token(self) -> int:
-        """Values one layer's cache keeps for one token of one row."""
+        """Values one position-keeping layer's cache keeps for one token of
+        one row."""
         return 2 * self.kv_dim
+
+    @property
+    def layer_cache_values(self) -> Tuple[int, ...]:
+        """Per layer, the values its cache keeps for one POSITION of one
+        row; 0 for a layer that keeps a per-row state instead."""
+        return (self.cache_values_per_token,) * self.num_layers
+
+    @property
+    def row_state_bytes(self) -> int:
+        """Bytes of per-row state (whatever the positions) that all the
+        layers together keep for one row: 0 unless a layer is recurrent.
+        The step programs, the scheduler's plan, speculation and the
+        sharding rules ask this before they assume a position axis."""
+        return 0
 
     @property
     def attention_param_count(self) -> int:
         return self.d_model * (2 * self.q_dim + 2 * self.kv_dim)
+
+    def mixer_param_count(self, kind: str) -> int:
+        """Matmul parameters of one layer's mixer as this chip holds it; one
+        figure unless the mixers differ per layer."""
+        return self.attention_param_count
 
     def mlp_param_count(self, kind: str) -> int:
         """Matmul parameters of one layer's MLP as this chip holds it."""
@@ -129,24 +175,65 @@ class ModelConfig:
         this chip holds, layer by layer."""
         embed = self.vocab_size * self.d_model
         head = 0 if self.tie_embeddings else embed
-        layers = sum(self.attention_param_count + self.mlp_param_count(kind)
-                     for kind in self.layer_kinds)
+        layers = sum(self.mixer_param_count(mixer) + self.mlp_param_count(kind)
+                     for mixer, kind in zip(self.mixer_kinds,
+                                            self.layer_kinds))
         return embed + head + layers
 
 
 @dataclasses.dataclass(frozen=True)
-class SparseLatentConfig(ModelConfig):
+class RoutedExpertsConfig(ModelConfig):
+    """Routed experts with a shared expert, of which this chip holds one
+    rank's share: what ``SparseMLP`` asks of a configuration.
+
+    The router scores all ``num_experts`` (sigmoid, float32), the
+    ``experts_per_token`` largest are kept, their weights normalised over all
+    of them and scaled by ``routed_scaling``; the layer computes the shared
+    expert and those chosen experts it holds, ``experts_held``: rank
+    ``expert_rank`` of ``expert_shards`` equal ranges
+    (``parallel/sharding.held_experts``). What the absent ranks would add is
+    left out, not stood in for.
+    """
+
+    num_experts: int = 0
+    experts_per_token: int = 0
+    expert_d_ff: int = 0
+    num_shared_experts: int = 0
+    routed_scaling: float = 1.0
+    norm_topk_prob: bool = True
+    expert_shards: int = 1
+    expert_rank: int = 0
+
+    def __post_init__(self):
+        if self.num_experts % max(self.expert_shards, 1):
+            raise ValueError(f"{self.num_experts} experts do not divide over "
+                             f"{self.expert_shards} ranks")
+        if not 0 <= self.expert_rank < self.expert_shards:
+            raise ValueError("expert_rank outside expert_shards")
+
+    @property
+    def experts_held(self) -> Tuple[int, int]:
+        """(first, count) of the routed experts this chip holds."""
+        from fairness_llm_tpu.parallel.sharding import held_experts
+
+        return held_experts(self.num_experts, self.expert_shards,
+                          self.expert_rank)
+
+    def mlp_param_count(self, kind: str) -> int:
+        if kind == "moe":
+            one = 3 * self.d_model * self.expert_d_ff
+            return (one * (self.num_shared_experts + self.experts_held[1])
+                    + self.d_model * self.num_experts)
+        return super().mlp_param_count(kind)
+
+
+@dataclasses.dataclass(frozen=True)
+class SparseLatentConfig(RoutedExpertsConfig):
     """Latent attention (MLA) with a leading run of dense layers and then
-    routed experts, of which this chip holds one rank's share.
+    routed experts (``RoutedExpertsConfig``).
 
     ``head_dim`` is the query/key head (``qk_nope_head_dim +
-    qk_rope_head_dim``); ``d_ff`` the dense layers' width. The router scores
-    all ``num_experts`` (sigmoid, float32), the ``experts_per_token`` largest
-    are kept, their weights normalised over all of them and scaled by
-    ``routed_scaling``; the layer computes the shared expert and those chosen
-    experts it holds, ``experts_held``: rank ``expert_rank`` of
-    ``expert_shards`` equal ranges (``parallel/sharding.held_experts``). What
-    the absent ranks would add is left out, not stood in for.
+    qk_rope_head_dim``); ``d_ff`` the dense layers' width.
     """
 
     q_lora_rank: int = 0
@@ -162,41 +249,21 @@ class SparseLatentConfig(ModelConfig):
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
     first_dense_layers: int = 1
-    num_experts: int = 0
-    experts_per_token: int = 0
-    expert_d_ff: int = 0
-    num_shared_experts: int = 0
-    routed_scaling: float = 1.0
-    norm_topk_prob: bool = True
-    expert_shards: int = 1
-    expert_rank: int = 0
 
     def __post_init__(self):
+        super().__post_init__()
         if self.head_dim != self.qk_nope_head_dim + self.qk_rope_head_dim:
             raise ValueError("head_dim must be qk_nope_head_dim + "
                              "qk_rope_head_dim")
-        if self.num_experts % max(self.expert_shards, 1):
-            raise ValueError(f"{self.num_experts} experts do not divide over "
-                             f"{self.expert_shards} ranks")
-        if not 0 <= self.expert_rank < self.expert_shards:
-            raise ValueError("expert_rank outside expert_shards")
 
     @property
-    def attention(self) -> str:
-        return "mla"
+    def mixer_kinds(self) -> Tuple[str, ...]:
+        return ("mla",) * self.num_layers
 
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
         return tuple("dense" if i < self.first_dense_layers else "moe"
                      for i in range(self.num_layers))
-
-    @property
-    def experts_held(self) -> Tuple[int, int]:
-        """(first, count) of the routed experts this chip holds."""
-        from fairness_llm_tpu.parallel.sharding import held_experts
-
-        return held_experts(self.num_experts, self.expert_shards,
-                            self.expert_rank)
 
     @property
     def cache_values_per_token(self) -> int:
@@ -211,15 +278,6 @@ class SparseLatentConfig(ModelConfig):
                 * (self.qk_nope_head_dim + self.v_head_dim)
                 + h * self.v_head_dim * d)
 
-    def mlp_param_count(self, kind: str) -> int:
-        if kind == "dense":
-            return 3 * self.d_model * self.d_ff
-        if kind == "moe":
-            one = 3 * self.d_model * self.expert_d_ff
-            return (one * (self.num_shared_experts + self.experts_held[1])
-                    + self.d_model * self.num_experts)
-        raise ValueError(f"{type(self).__name__} has no {kind!r} layer")
-
     @property
     def softmax_scale(self) -> float:
         """``head_dim^-1/2`` times the square of YaRN's attention factor."""
@@ -228,6 +286,89 @@ class SparseLatentConfig(ModelConfig):
             m = 0.1 * self.rope_mscale_all_dim * math.log(self.rope_factor) + 1.0
             scale *= m * m
         return scale
+
+
+@dataclasses.dataclass(frozen=True)
+class LinearHybridConfig(RoutedExpertsConfig):
+    """Delta-rule linear-attention layers ("kda": Kimi Delta Attention,
+    arXiv 2510.26692) beside gated softmax GQA layers without rotary
+    positions, every layer with routed experts (``RoutedExpertsConfig``),
+    held as one rank's share of heads and experts.
+
+    Layer ``i`` is softmax GQA where ``i`` is in ``softmax_layers`` and a
+    linear layer otherwise. ``num_heads``, ``num_kv_heads`` and
+    ``linear_heads`` are the PUBLISHED counts; this chip holds rank
+    ``head_rank`` of ``head_shards`` equal ranges of each (``heads_held``),
+    as tensor parallelism would cut them. A linear layer keeps, per row and
+    held head, a ``linear_head_dim x linear_head_dim`` float32 state and the
+    last ``conv_width - 1`` inputs of its short convolution; a softmax layer
+    keeps k and v per held KV head and position. ``gate_rank`` is the width
+    of the two low-rank gate projections (decay and output).
+    """
+
+    softmax_layers: Tuple[int, ...] = ()
+    linear_heads: int = 0
+    linear_head_dim: int = 128
+    conv_width: int = 4
+    gate_rank: int = 128
+    head_shards: int = 1
+    head_rank: int = 0
+
+    def __post_init__(self):
+        super().__post_init__()
+        for n in (self.num_heads, self.num_kv_heads, self.linear_heads):
+            if n % max(self.head_shards, 1):
+                raise ValueError(f"{n} heads do not divide over "
+                                 f"{self.head_shards} ranks")
+        if not 0 <= self.head_rank < self.head_shards:
+            raise ValueError("head_rank outside head_shards")
+
+    @property
+    def mixer_kinds(self) -> Tuple[str, ...]:
+        return tuple("gqa" if i in self.softmax_layers else "kda"
+                     for i in range(self.num_layers))
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        return ("moe",) * self.num_layers
+
+    @property
+    def heads_held(self) -> Tuple[int, int, int]:
+        from fairness_llm_tpu.parallel.sharding import held_heads
+
+        return tuple(held_heads(n, self.head_shards, self.head_rank)[1]
+                     for n in (self.num_heads, self.num_kv_heads,
+                               self.linear_heads))
+
+    @property
+    def attention_gate(self) -> bool:
+        return True
+
+    @property
+    def cache_values_per_token(self) -> int:
+        return 2 * self.heads_held[1] * self.head_dim
+
+    @property
+    def layer_cache_values(self) -> Tuple[int, ...]:
+        return tuple(self.cache_values_per_token if kind == "gqa" else 0
+                     for kind in self.mixer_kinds)
+
+    @property
+    def row_state_bytes(self) -> int:
+        item = 2 if self.dtype == "bfloat16" else 4
+        h, dk = self.heads_held[2], self.linear_head_dim
+        one = h * dk * dk * 4 + (self.conv_width - 1) * 3 * h * dk * item
+        return one * self.mixer_kinds.count("kda")
+
+    def mixer_param_count(self, kind: str) -> int:
+        """Matmul, gate and tap parameters of one layer's mixer as held."""
+        d = self.d_model
+        hq, hkv, hl = self.heads_held
+        if kind == "gqa":
+            return d * self.head_dim * (3 * hq + 2 * hkv)  # q, gate, o; k, v
+        c = hl * self.linear_head_dim
+        return (4 * d * c + 2 * (d + c) * self.gate_rank + d * hl
+                + 3 * c * self.conv_width + c + hl + self.linear_head_dim)
 
 
 MODEL_CONFIGS = {
@@ -277,6 +418,16 @@ MODEL_CONFIGS = {
         rope_original_max=64, rope_mscale=1.0, rope_mscale_all_dim=1.0,
         first_dense_layers=1, num_experts=16, experts_per_token=4,
         expert_d_ff=32, num_shared_experts=1, routed_scaling=2.5,
+    ),
+    # Tiny linear-attention hybrid (tests): period 4 (layer 0 gated softmax
+    # GQA, layers 1-3 delta rule), every layer 16 experts top-4, all held.
+    "tiny-kda-moe": LinearHybridConfig(
+        name="tiny-kda-moe", vocab_size=512, num_layers=4, num_heads=4,
+        num_kv_heads=2, d_model=64, d_ff=128, head_dim=16, max_seq_len=256,
+        eos_token_id=1, dtype="float32", use_flash_attention=False,
+        pos_emb="none", softmax_layers=(0,), linear_heads=4,
+        linear_head_dim=16, gate_rank=8, num_experts=16,
+        experts_per_token=4, expert_d_ff=32, num_shared_experts=1,
     ),
     "gpt2-small": ModelConfig(
         name="gpt2-small", vocab_size=50257, num_layers=12, num_heads=12,

@@ -102,6 +102,17 @@ class LatentCache:
 
 
 @flax.struct.dataclass
+class RowState:
+    """What a delta-rule linear-attention layer keeps per ROW, whatever the
+    row's length: the recurrence's state and the short convolution's last
+    ``conv_width - 1`` inputs (the q, k and v projections side by side,
+    before the convolution). No leaf has a position axis."""
+
+    state: jnp.ndarray  # [B, heads, dk, dv] float32
+    conv: jnp.ndarray  # [B, conv_width - 1, 3 * heads * dk], the model dtype
+
+
+@flax.struct.dataclass
 class KVCache:
     """Decode state shared across layers.
 
@@ -110,10 +121,19 @@ class KVCache:
     positions of cached keys (needed for Mistral's sliding-window test);
     ``lengths`` counts real tokens per row (the next RoPE position).
 
-    ``layers`` holds, per layer, whatever that layer keeps per request: a
-    ``LayerCache`` (k and v per KV head) or a ``LatentCache``. Every leaf is
-    ``[B, max_len, ...]``, which is all the step programs rely on when they
-    scatter rows into the slot pool (``runtime/stepbuilder.py``).
+    ``layers`` holds, per layer, whatever that layer keeps per request, and
+    the layer's class says which of two kinds its leaves are. A
+    ``LayerCache`` (k and v per KV head) or a ``LatentCache`` keeps something
+    per position: every leaf is ``[B, max_len, ...]``. A ``RowState`` keeps a
+    fixed state per row: every leaf is ``[B, ...]`` with no position axis.
+    That is all the step programs rely on (``runtime/stepbuilder.py``): a
+    per-position leaf is gathered over its ``[:P]`` extent, may take a
+    donor's positions, and is scattered back by slot and extent; a per-row
+    leaf is gathered and scattered by slot alone, starts from zero in the
+    prefill call that names its row (one call, as wide as the bucket), and
+    can be copied from no other row, whose state stands at that row's own
+    last token. The forward leaves a row's per-row leaves untouched wherever
+    ``token_valid`` is False (padding, idle slots, batch-bucket pad rows).
     ``counters`` is None unless the model counts something on the device
     (routed experts: tokens, local pairs, fullest expert); the forward adds
     to it and the serving step hands it to the host with the chunk's tokens.
@@ -136,8 +156,22 @@ MOE_COUNTERS = ("moe_tokens", "moe_local_pairs", "moe_fullest_expert_pairs")
 
 def init_cache(config: ModelConfig, batch_size: int, max_len: int, dtype=None) -> KVCache:
     dtype = dtype or _dtype_of(config)
-    shape = (batch_size, max_len, config.num_kv_heads, config.head_dim)
-    if config.attention == "mla":
+    _, kv_heads, linear_heads = config.heads_held
+    shape = (batch_size, max_len, kv_heads, config.head_dim)
+    if config.row_state_bytes:
+        if config.kv_cache_quant:
+            raise NotImplementedError("no int8 form of a per-row state")
+        dk, channels = config.linear_head_dim, 3 * linear_heads * \
+            config.linear_head_dim
+        layers = tuple(
+            LayerCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+            if kind == "gqa" else RowState(
+                state=jnp.zeros((batch_size, linear_heads, dk, dk),
+                                jnp.float32),
+                conv=jnp.zeros((batch_size, config.conv_width - 1, channels),
+                               dtype))
+            for kind in config.mixer_kinds)
+    elif config.attention == "mla":
         if config.kv_cache_quant:
             raise NotImplementedError("no int8 form of the latent cache")
         layers = tuple(
@@ -552,9 +586,12 @@ class Attention(nn.Module):
                 name=name,
             )
         B, S, _ = x.shape
-        q = dense(cfg.q_dim, "q_heads", "q_proj")(x).reshape(B, S, cfg.num_heads, cfg.head_dim)
-        k = dense(cfg.kv_dim, "kv_heads", "k_proj")(x).reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
-        v = dense(cfg.kv_dim, "kv_heads", "v_proj")(x).reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
+        # What this chip holds of the heads: all of them, or its share.
+        H, Hkv, _ = cfg.heads_held
+        q_dim, kv_dim = H * cfg.head_dim, Hkv * cfg.head_dim
+        q = dense(q_dim, "q_heads", "q_proj")(x).reshape(B, S, H, cfg.head_dim)
+        k = dense(kv_dim, "kv_heads", "k_proj")(x).reshape(B, S, Hkv, cfg.head_dim)
+        v = dense(kv_dim, "kv_heads", "v_proj")(x).reshape(B, S, Hkv, cfg.head_dim)
 
         if cfg.pos_emb == "rope":
             q = rope(q, positions, cfg.rope_theta)
@@ -639,12 +676,12 @@ class Attention(nn.Module):
                     k_scale=new_cache_layer.k_scale,
                     v_scale=new_cache_layer.v_scale,
                     q_offsets=write_offsets,
-                ).reshape(B, S, cfg.num_heads, cfg.head_dim)
+                ).reshape(B, S, H, cfg.head_dim)
             else:
                 out = decode_attention(
                     kq, keys.astype(dtype), values.astype(dtype), key_valid,
                     shared_kv=sh, q_offsets=write_offsets,
-                ).reshape(B, S, cfg.num_heads, cfg.head_dim)
+                ).reshape(B, S, H, cfg.head_dim)
         else:
             if cache_layer is not None:
                 K = keys.shape[1]
@@ -673,8 +710,8 @@ class Attention(nn.Module):
             # GQA: query head h reads kv head h // rep, so q splits into
             # [Hkv, rep] and both contractions keep the kv-head axis: the
             # cache is read once, never written out rep times.
-            rep = cfg.num_heads // cfg.num_kv_heads
-            q5 = q.reshape(B, S, cfg.num_kv_heads, rep, cfg.head_dim)
+            rep = H // Hkv
+            q5 = q.reshape(B, S, Hkv, rep, cfg.head_dim)
 
             scale = cfg.head_dim ** -0.5
             scores = jnp.einsum("bqgrd,bkgd->bgrqk", q5, keys).astype(jnp.float32) * scale
@@ -696,7 +733,14 @@ class Attention(nn.Module):
                 probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
                 out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, values)
 
-        out = out.reshape(B, S, cfg.q_dim)
+        out = out.reshape(B, S, q_dim)
+        if cfg.attention_gate:
+            # y = W_o (attn * sigmoid(W_gate x)), elementwise over heads x
+            # head_dim; the sigmoid in float32.
+            with jax.named_scope("attn.gate"):
+                gate = jax.nn.sigmoid(
+                    dense(q_dim, "q_heads", "gate_proj")(x).astype(jnp.float32))
+                out = (out.astype(jnp.float32) * gate).astype(dtype)
         if cfg.weight_quant == "int8":
             out = QuantDense(
                 cfg.d_model, in_axis="q_heads", out_axis="embed",
@@ -921,6 +965,138 @@ class LatentAttention(nn.Module):
         return out, new_cache_layer
 
 
+def _conv_init(key, shape):
+    """Short-convolution taps of order one, so that a random-weight layer's
+    q, k and v are not lost under the norms' epsilons."""
+    return jax.random.normal(key, shape) * 0.5
+
+
+class DeltaRuleAttention(nn.Module):
+    """A delta-rule linear-attention layer (Kimi Delta Attention, arXiv
+    2510.26692) over the heads this chip holds. Per token ``t``, with ``x``
+    the normed input:
+
+    - ``q~, k~, v~ = W x``; a causal depthwise convolution of ``conv_width``
+      taps over time on each channel (tap ``conv_width - 1`` on the current
+      token, zeros before a row's first real token), then SiLU;
+    - ``q = l2norm(q^) / sqrt(dk)``, ``k = l2norm(k^)``, ``v = v^``, per head;
+    - ``a = exp(-exp(A_log[h]) softplus(W_fb (W_fa x) + dt_bias))`` in
+      (0, 1)^dk, ``b = 2 sigmoid(w_beta . x)`` in (0, 2);
+    - ``S = (I - b k k^T) Diag(a) S + b k v^T``, ``o = S^T q``
+      (``ops/delta_rule.py``), ``S`` zero before the row's first token;
+    - ``y = W_o concat_h(RMSNorm(o) * sigmoid(W_gb (W_ga x)))``.
+
+    Float32: the state, ``a``, ``b``, the l2 norms, the convolution's sum
+    and the update. What the layer keeps between calls is a ``RowState``: the
+    state and the last ``conv_width - 1`` rows of ``[q~ | k~ | v~]``. A token
+    whose ``token_valid`` is False (padding, an idle slot) moves neither. One
+    promise is asked of the caller: within a call a row's valid tokens are
+    contiguous (left- or right-padded), which every caller here keeps.
+
+    One token with a cache is the decode step (``kda_step``, a Pallas kernel
+    over the whole pool on the TPU); anything longer runs the recurrence as a
+    ``lax.scan`` over its positions.
+    """
+
+    config: ModelConfig
+
+    @nn.compact
+    def __call__(self, x, cache_layer: Optional[RowState], token_valid,
+                 shared_kv=None, write_offsets=None):
+        from fairness_llm_tpu.ops.delta_rule import kda_step, kda_update
+
+        cfg = self.config
+        B, S, d = x.shape
+        if shared_kv is not None:
+            raise NotImplementedError(
+                "a shared prefix cannot stand in for a per-row state: the "
+                "state after the prefix would have to be restored per row "
+                "(snapshots are not built)")
+        if write_offsets is not None and S > 1:
+            raise NotImplementedError(
+                "a multi-token verify step cannot roll a per-row state back "
+                "to the last accepted token: no speculation over "
+                "linear-attention layers")
+        dtype, f32 = _dtype_of(cfg), jnp.float32
+        H, dk, W = cfg.heads_held[2], cfg.linear_head_dim, cfg.conv_width
+        C = H * dk
+
+        pre = jnp.concatenate(
+            [_linear(cfg, C, "embed", None, name)(x).astype(dtype)
+             for name in ("q_proj", "k_proj", "v_proj")], axis=-1)
+        taps = jnp.concatenate(
+            [self.param(name, _conv_init, (W, C))
+             for name in ("conv_q", "conv_k", "conv_v")], axis=-1)
+
+        with jax.named_scope("kda.conv"):
+            history = (jnp.zeros((B, W - 1, 3 * C), dtype)
+                       if cache_layer is None else cache_layer.conv)
+            ext = jnp.concatenate([history, pre], axis=1)  # [B, W-1+S, 3C]
+            # The kept inputs must sit right before the row's first valid
+            # token, whatever padding leads the call: entry j of the line
+            # the convolution reads is the history while j - lead is under
+            # W - 1, and the call's own token j - (W - 1) after that.
+            lead = jnp.where(jnp.any(token_valid, axis=1),
+                             jnp.argmax(token_valid, axis=1), S)[:, None]
+            j = jnp.arange(S + W - 1, dtype=jnp.int32)[None, :]
+            src = jnp.where(j >= lead + W - 1, j, jnp.clip(j - lead, 0, None))
+            line = jnp.take_along_axis(ext, src[:, :, None], axis=1)
+            mixed = sum(line[:, i:i + S].astype(f32) * taps[i].astype(f32)
+                        for i in range(W))
+            mixed = jax.nn.silu(mixed).reshape(B, S, 3, H, dk)
+            new_conv = line[:, S:]  # unchanged for a row with no valid token
+            q, k, v = mixed[:, :, 0], mixed[:, :, 1], mixed[:, :, 2]
+            q = q * jax.lax.rsqrt(
+                jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6) * dk ** -0.5
+            k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
+
+        with jax.named_scope("kda.gates"):
+            rank = cfg.gate_rank
+            a_log = self.param("A_log", nn.initializers.zeros, (H,))
+            dt_bias = self.param("dt_bias", nn.initializers.zeros, (C,))
+            w_beta, _ = _Kernel((d, H), ("embed", None), False,
+                                name="beta_proj")()
+            f = _linear(cfg, C, None, None, "f_b_proj")(
+                _linear(cfg, rank, "embed", None, "f_a_proj")(x))
+            decay = jax.nn.softplus(f.astype(f32) + dt_bias.astype(f32))
+            a = jnp.exp(-jnp.exp(a_log.astype(f32))[:, None]
+                        * decay.reshape(B, S, H, dk))
+            b = 2.0 * jax.nn.sigmoid(jnp.einsum(
+                "bsd,dh->bsh", x.astype(dtype), w_beta.astype(dtype),
+                preferred_element_type=f32))
+            g = jax.nn.sigmoid(_linear(cfg, C, None, None, "g_b_proj")(
+                _linear(cfg, rank, "embed", None, "g_a_proj")(x)).astype(f32))
+            # A token that is not valid decays nothing and writes nothing.
+            tv = token_valid[:, :, None]
+            a = jnp.where(tv[..., None], a, 1.0)
+            b = jnp.where(tv, b, 0.0)
+
+        with jax.named_scope("kda.recurrence"):
+            state = (jnp.zeros((B, H, dk, dk), f32) if cache_layer is None
+                     else cache_layer.state)
+            if S == 1 and cache_layer is not None:
+                state, o = kda_step(state, q[:, 0], k[:, 0], v[:, 0],
+                                    a[:, 0], b[:, 0])
+                o = o[:, None]
+            else:
+                def token(st, qkvab):
+                    new, out = kda_update(st, *qkvab[:5])
+                    keep = qkvab[5][:, None, None, None]
+                    return jnp.where(keep, new, st), out
+
+                over_time = [t.swapaxes(0, 1) for t in (q, k, v, a, b)]
+                state, o = jax.lax.scan(
+                    token, state, (*over_time, token_valid.swapaxes(0, 1)))
+                o = o.swapaxes(0, 1)  # [B, S, H, dv]
+
+        o = RMSNorm(eps=cfg.norm_eps, name="o_norm")(o)
+        out = (o.astype(f32).reshape(B, S, C) * g).astype(dtype)
+        out = _linear(cfg, cfg.d_model, None, "embed", "o_proj")(out)
+        new_cache = None if cache_layer is None else \
+            RowState(state=state, conv=new_conv)
+        return out, new_cache
+
+
 def route_tokens(x: jnp.ndarray, router: jnp.ndarray, cfg: ModelConfig):
     """``(chosen [T, k] int32 over ALL experts, weights [T, k] float32)``:
     sigmoid scores in float32 at the highest matmul precision, the k largest
@@ -990,19 +1166,25 @@ class SparseMLP(nn.Module):
 class Block(nn.Module):
     config: ModelConfig
     kind: str = "dense"  # this layer's MLP: config.layer_kinds[i]
+    mixer: str = "gqa"  # this layer's mixer: config.mixer_kinds[i]
 
     @nn.compact
     def __call__(self, x, positions, cache_layer, cache_index, key_valid, key_positions,
                  left_padded=False, shared_kv=None, write_offsets=None,
                  token_valid=None):
         """Returns ``(x, the layer's new cache, its counters or None)``."""
-        attention = LatentAttention if self.config.attention == "mla" else Attention
-        attn_out, new_cache = attention(self.config, name="attn")(
-            _norm(self.config, "attn_norm")(x),
-            positions, cache_layer, cache_index, key_valid, key_positions,
-            left_padded=left_padded, shared_kv=shared_kv,
-            write_offsets=write_offsets,
-        )
+        normed = _norm(self.config, "attn_norm")(x)
+        if self.mixer == "kda":
+            attn_out, new_cache = DeltaRuleAttention(self.config, name="attn")(
+                normed, cache_layer, token_valid, shared_kv=shared_kv,
+                write_offsets=write_offsets)
+        else:
+            attention = LatentAttention if self.mixer == "mla" else Attention
+            attn_out, new_cache = attention(self.config, name="attn")(
+                normed, positions, cache_layer, cache_index, key_valid,
+                key_positions, left_padded=left_padded, shared_kv=shared_kv,
+                write_offsets=write_offsets,
+            )
         x = x + attn_out
         counters = None
         if self.kind == "moe":
@@ -1082,9 +1264,11 @@ class Transformer(nn.Module):
 
         new_layers = []
         counters = cache.counters if cache is not None else None
-        for i, kind in enumerate(cfg.layer_kinds):
+        for i, (kind, mixer) in enumerate(zip(cfg.layer_kinds,
+                                              cfg.mixer_kinds)):
             layer_cache = cache.layers[i] if cache is not None else None
-            x, new_layer, counted = Block(cfg, kind=kind, name=f"layer_{i}")(
+            x, new_layer, counted = Block(cfg, kind=kind, mixer=mixer,
+                                          name=f"layer_{i}")(
                 x, positions,
                 layer_cache, cache.index if cache is not None else None,
                 key_valid, key_positions, left_padded=left_padded,

@@ -111,15 +111,29 @@ def held_experts(num_experts: int, shards: int, rank: int) -> Tuple[int, int]:
     return rank * count, count
 
 
+def held_heads(num_heads: int, shards: int, rank: int) -> Tuple[int, int]:
+    """(first, count) of a mixer's heads that rank ``rank`` of ``shards``
+    holds, as tensor parallelism cuts them: the same equal contiguous ranges
+    as the experts'. Nothing here exchanges between the ranks: a
+    configuration that holds a share (``LinearHybridConfig``) computes its
+    own heads' part of ``o_proj``'s sum, and the other ranks' is left out."""
+    return held_experts(num_heads, shards, rank)
+
+
 def require_gqa(model_config: ModelConfig, what: str) -> None:
     """Raise for a layer kind ``what`` reckons GQA's sizes for and does not
     know: a wrong answer in GQA's terms is worse than none."""
+    # Asked of every layer: a model of which only some layers are GQA (the
+    # rest keep a per-row state) is not a GQA model.
     if model_config.attention != "gqa" or set(model_config.layer_kinds) != {
             "dense"}:
         raise NotImplementedError(
             f"{what} knows dense GQA layers only; {model_config.name} has "
-            f"{model_config.attention} attention and "
-            f"{sorted(set(model_config.layer_kinds))} layers")
+            f"{sorted(set(model_config.mixer_kinds))} mixers and "
+            f"{sorted(set(model_config.layer_kinds))} layers"
+            + ("; a per-row state has no head axis sharded here and its "
+               "heads' exchange is not built"
+               if model_config.row_state_bytes else ""))
 
 
 def make_axis_rules(model_config: ModelConfig, mesh: Mesh) -> AxisRules:
@@ -297,10 +311,12 @@ def per_device_kv_cache_bytes(model_config: ModelConfig, mesh: Mesh, batch: int,
         rules = make_axis_rules(model_config, mesh)
     dp = mesh.shape.get("dp", 1)
     if model_config.attention != "gqa":
-        # The layer's own count: a latent cache has no head axis to shard.
+        # Each layer's own count: a latent cache has no head axis to shard,
+        # and a per-row state no position axis to multiply by.
         item = 2 if model_config.dtype == "bfloat16" else 4
-        return (model_config.num_layers * -(-batch // dp) * max_len
-                * model_config.cache_values_per_token * item)
+        return -(-batch // dp) * (
+            max_len * sum(model_config.layer_cache_values) * item
+            + model_config.row_state_bytes)
     kv_axis = dict(rules).get("kv_heads")
     kv_div = mesh.shape.get(kv_axis, 1) if kv_axis else 1
     # ceil, matching the engine's batch padding to a dp multiple — floor would

@@ -305,7 +305,10 @@ class DecodeEngine:
         # Latent attention's prefill builds [.., S, S] scores in XLA: a key
         # axis of 192 is padded to 256 lanes on the chip anyway, and one
         # bucket for a sweep's 175-195 tokens means one prefill program.
-        lane_scores = self.config.attention == "mla" and lowering_for_tpu()
+        # The same holds for any model whose prefill attends through XLA
+        # over the pool (latent layers, or softmax layers beside recurrent
+        # ones).
+        lane_scores = self.config.attention != "gqa" and lowering_for_tpu()
         return 128 if flash_eligible or lane_scores else 64
 
     # -- compiled program ---------------------------------------------------
@@ -488,9 +491,11 @@ class DecodeEngine:
         from fairness_llm_tpu.models.tokenizer import _left_pad
 
         if self.config.attention != "gqa":
-            # A shared prefix is [Pc, Hkv, D] keys and values; a latent cache
-            # has no such form yet (stepbuilder.build_prefix refuses it), so
-            # every row prefills its whole prompt.
+            # A shared prefix is [Pc, Hkv, D] keys and values, asked of every
+            # layer: a latent cache has no such form yet, and a per-row
+            # state after the prefix would have to be restored per row
+            # (stepbuilder.build_prefix refuses both), so every row prefills
+            # its whole prompt.
             share_prefix, prefix_ids = False, None
         rows = [self.tokenizer.encode(p) for p in prompts]
         shared_ids: Optional[list] = None

@@ -66,7 +66,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from fairness_llm_tpu.models.transformer import init_cache
+from fairness_llm_tpu.models.transformer import RowState, init_cache
 from fairness_llm_tpu.parallel.sharding import require_gqa
 from fairness_llm_tpu.runtime.sampling import (
     SamplerSettings,
@@ -312,6 +312,11 @@ def build_spec_decode(cfg, model, pad_id: int, eos_id: int, *, batch: int,
     window always overwrites them. The cache carries ``draft_len`` spare
     slots so the last verify window of a nearly-finished row still fits.
     """
+    if cfg.row_state_bytes:
+        raise NotImplementedError(
+            f"speculation rolls rejected positions back by invalidating "
+            f"their slots; {cfg.name} keeps a per-row state, which a verify "
+            f"window has already moved past them")
     k = draft_len
     masked_finite = _masked_finite() if guard else None
     S = k + 1
@@ -553,10 +558,21 @@ def build_serve_step(cfg, model, sampler: SamplerSettings, pad_id: int,
 EXTEND_WINDOW = 64
 
 
+def extend_window(cfg, P: int) -> int:
+    """New tokens a ``serve_prefill`` call of bucket ``P`` computes per row.
+    A window narrower than the bucket pays where a donor saves a row its
+    leading windows. A model of which a layer keeps a fixed state per row
+    takes no donor (``scheduler._plan_prefill``) and would run every window
+    of every row, streaming its weights once a window: its window is the
+    whole bucket (on the chip: ``PERF.md`` section 6, PR 33)."""
+    return P if cfg.row_state_bytes else EXTEND_WINDOW
+
+
 def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
                         num_slots: int):
-    """Extend ``nb`` rows of the slot pool by one window of ``EXTEND_WINDOW``
-    tokens: prefill appends to what a row's ``[:P]`` extent already holds.
+    """Extend ``nb`` rows of the slot pool by one window of
+    ``extend_window(cfg, P)`` tokens: prefill appends to what a row's
+    ``[:P]`` extent already holds.
 
     A row's prompt is left-padded to ``P`` (``pad`` slots of padding, real
     token ``t`` at slot ``pad + t`` and position ``t``). A call computes the
@@ -584,9 +600,19 @@ def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
        the tail ``[P, cache_len)`` is invalidated, and the window's last
        position lands in the carried logits: the row's next-token logits
        once its last window (``w + S == P``) has run.
+
+    A layer that keeps a fixed state per row (``RowState``: no position
+    axis) is gathered and scattered by slot alone. Such a model's window is
+    its whole bucket, so every row a call names begins in it: its leaves
+    start from zero, and a recycled slot never shows its previous tenant's
+    state. They are zeroed here, inside the program, not by the host. Padding
+    leaves them as they were (the forward's ``token_valid``); a slot the call
+    does not name (a batch-bucket pad row, every other row of the pool) is
+    neither read nor written. No donor is taken for such a model (the plan's
+    ``copied`` is all zeros): a donor's state stands at its own last token.
     """
     masked_finite = _masked_finite() if guard else None
-    S = EXTEND_WINDOW
+    S = extend_window(cfg, P)
     if P % S:
         raise ValueError(f"prompt bucket {P} is not a multiple of the "
                          f"extend window {S}")
@@ -626,8 +652,22 @@ def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
             return jnp.where(mask, theirs,
                              rows_of(big, own[:, None], P, (0,)))
 
+        # Every row a call names begins in it where a state is kept: the
+        # window of such a model is its whole bucket.
+        fresh = slots < num_slots
+
+        def take_row_state(big):
+            rows = big[own]
+            return jnp.where(fresh.reshape((nb,) + (1,) * (big.ndim - 1)),
+                             jnp.zeros_like(rows), rows)
+
+        def take_layer(layer):
+            return jax.tree.map(
+                take_row_state if isinstance(layer, RowState) else take,
+                layer)
+
         view = cache.replace(
-            layers=jax.tree.map(take, cache.layers),
+            layers=tuple(map(take_layer, cache.layers)),
             key_positions=take(cache.key_positions),
             key_valid=(j >= lead) & (j < w),
             lengths=jnp.maximum(w - pad, 0),
@@ -645,10 +685,17 @@ def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
         def scat(big, rows):
             return big.at[slots, :P].set(rows, mode="drop")
 
+        def scat_layer(layer, rows):
+            if isinstance(layer, RowState):
+                return jax.tree.map(
+                    lambda big, r: big.at[slots].set(r, mode="drop"),
+                    layer, rows)
+            return jax.tree.map(scat, layer, rows)
+
         key_valid = scat(cache.key_valid, view.key_valid)
         key_valid = key_valid.at[slots, P:].set(False, mode="drop")
         new_cache = cache.replace(
-            layers=jax.tree.map(scat, cache.layers, view.layers),
+            layers=tuple(map(scat_layer, cache.layers, view.layers)),
             counters=(None if cache.counters is None
                       else cache.counters + view.counters),
             key_valid=key_valid,

@@ -143,13 +143,15 @@ def family_of(cfg: ModelConfig) -> str:
 
 def hf_name_map(cfg: ModelConfig, family: Optional[str] = None) -> Dict[str, Tuple[str, Transform]]:
     if cfg.attention != "gqa" or "moe" in cfg.layer_kinds:
-        # Latent attention's low-rank pairs and stacked expert kernels have
-        # leaves of their own (models/transformer.py: q_a_proj .. kv_b_proj,
+        # Latent attention's low-rank pairs, the delta rule's gates and
+        # convolutions and stacked expert kernels have leaves of their own
+        # (models/transformer.py: q_a_proj .. kv_b_proj, conv_*, A_log,
         # router, shared, experts_*); no published layout is mapped to them
         # yet, and guessing one would load a wrong model without complaint.
         raise NotImplementedError(
             f"no checkpoint loader for {cfg.name}'s kind of layer "
-            f"({cfg.attention} attention, {sorted(set(cfg.layer_kinds))})")
+            f"({sorted(set(cfg.mixer_kinds))} mixers, "
+            f"{sorted(set(cfg.layer_kinds))})")
     family = family or family_of(cfg)
     return _FAMILY_MAPS[family](cfg)
 

@@ -91,7 +91,11 @@ from fairness_llm_tpu.config import (
     ResilienceConfig,
     ServingConfig,
 )
-from fairness_llm_tpu.models.transformer import MOE_COUNTERS, init_cache
+from fairness_llm_tpu.models.transformer import (
+    MOE_COUNTERS,
+    RowState,
+    init_cache,
+)
 from fairness_llm_tpu.parallel import sharding as shd
 from fairness_llm_tpu.resilience.breaker import BreakerBoard
 from fairness_llm_tpu.resilience.drain import (
@@ -102,10 +106,10 @@ from fairness_llm_tpu.resilience.drain import (
 from fairness_llm_tpu.resilience.watchdog import StepWatchdog
 from fairness_llm_tpu.runtime.sampling import SamplerSettings
 from fairness_llm_tpu.runtime.stepbuilder import (
-    EXTEND_WINDOW,
     build_serve_prefill,
     build_serve_step,
     compile_key,
+    extend_window,
     program_label,
 )
 from fairness_llm_tpu.serving.overload import (
@@ -296,6 +300,9 @@ class ContinuousScheduler:
         # slot) + each slot's carried next-token logits (f32 — what the
         # sampler consumes).
         self._cache = init_cache(cfg, self.num_slots, self.cache_len)
+        # Bytes of per-row state a slot holds whatever its length (0 unless
+        # a layer is recurrent): the plan takes no donor for such a model.
+        self._row_state_bytes = cfg.row_state_bytes
         self._prev_logits = jnp.zeros(
             (self.num_slots, cfg.vocab_size), jnp.float32
         )
@@ -408,8 +415,18 @@ class ContinuousScheduler:
         )
 
         mem = get_memory_ledger()
+        # Two kinds of leaf, two pools: what a layer keeps per position
+        # (and the pool's own masks), and what it keeps per row whatever
+        # the positions (a recurrent state), which no length bounds.
+        per_row = [l for l in self._cache.layers if isinstance(l, RowState)]
         mem.register("kv_contiguous", f"{self._mem_handle}:cache",
-                     self._cache, replica=self.replica)
+                     self._cache.replace(layers=tuple(
+                         l for l in self._cache.layers
+                         if not isinstance(l, RowState))),
+                     replica=self.replica)
+        if per_row:
+            mem.register("row_state", f"{self._mem_handle}:row_state",
+                         per_row, replica=self.replica)
         mem.register("logits_carry", f"{self._mem_handle}:logits",
                      self._prev_logits, replica=self.replica)
 
@@ -495,7 +512,8 @@ class ContinuousScheduler:
         # ledger (telemetry/costmodel.py) on every compiled program.
         fn = instrument_jit(
             run, program,
-            collectives=self._collectives(nb, EXTEND_WINDOW, "call"))
+            collectives=self._collectives(
+                nb, extend_window(self.engine.config, P), "call"))
         self._compiled[key] = fn
         return fn
 
@@ -1150,6 +1168,9 @@ class ContinuousScheduler:
             plan_span.set(rows=len(admitted), nb=plan["nb"], P=plan["P"],
                           donors=plan["donors"],
                           windows=len(plan["calls"]))
+            if self._row_state_bytes:
+                # No donor was looked for: a layer keeps a per-row state.
+                plan_span.set(donorless="row_state")
         return self._dispatch_prefill(plan, stats, injected_hang)
 
     def _plan_admission(self, n: int, stats: ServingStats):
@@ -1235,8 +1256,8 @@ class ContinuousScheduler:
     def _plan_prefill(self, admitted) -> dict:
         """What one contiguous admission batch has left to compute, as the
         arguments of its ``serve_prefill`` calls (one per window of
-        ``EXTEND_WINDOW`` slots, in order), on the device before the first
-        call is made.
+        ``extend_window(config, P)`` slots, in order), on the device before
+        the first call is made.
 
         A row whose prompt starts like a live row's copies what it can and
         extends over its last windows only: with ``m`` ids shared and ``k``
@@ -1244,9 +1265,16 @@ class ContinuousScheduler:
         come from the donor. A row with no such donor (or fewer than ``S``
         ids) runs every window that holds one of its tokens. Donors are rows
         resident before this call: never a slot it writes.
+
+        A model of which a layer keeps a fixed state per row
+        (``config.row_state_bytes``) takes no donor at all: a resident row's
+        state stands at that row's own last token, not at the point where
+        the prompts part, so there is nothing to copy. Its rows carry no
+        ``ids`` and so are never donors (``LiveRowPrefixes`` finds none). Its
+        window is the whole bucket, so a batch is one call, in which
+        ``serve_prefill`` zeroes each row's state.
         """
         tok = self.engine.tokenizer
-        S = EXTEND_WINDOW
         # ONE bucket per admission batch, the largest of its rows'. Shorter
         # rows pad up to it: numerically free (pad slots are masked,
         # contributing exact zeros to every reduction; parity tests pin
@@ -1255,6 +1283,7 @@ class ContinuousScheduler:
         # is therefore the bucket it was PREFILLED at, which its decode
         # write offsets continue from.
         P = max(item[2] for item in admitted)
+        S = extend_window(self.engine.config, P)
         n_rows = len(admitted)
         nb = _bucket_pow2(n_rows, max(self.serving.prefill_group, n_rows))
         live = LiveRowPrefixes(self.pool)
@@ -1285,7 +1314,7 @@ class ContinuousScheduler:
         for r, req in enumerate(reqs):
             slot = self.pool.alloc(SlotState(
                 request=req, base=P, real_len=int(P - lead[r]),
-                ids=tokens[r, lead[r]:],
+                ids=None if self._row_state_bytes else tokens[r, lead[r]:],
             ))
             assert slot is not None  # admission is free-count bounded
             slots.append(slot)
@@ -1412,6 +1441,9 @@ class ContinuousScheduler:
             self.breakers.record_success("prefill")
         stats.prefill_tokens += plan["prompt_tokens"]
         stats.prefill_tokens_reused += plan["reused_tokens"]
+        if self._row_state_bytes:
+            # Each admitted row's state was zeroed in the batch's call.
+            stats.row_state_resets += len(reqs)
         stats.admitted += len(reqs)
         return True
 
@@ -1624,6 +1656,10 @@ class ContinuousScheduler:
         # summed over layers, steps and the prefills in between).
         for name, value in zip(MOE_COUNTERS, counters[2:]):
             setattr(stats, name, getattr(stats, name) + int(value))
+        # Every step reads and writes EVERY slot's per-row state, live or
+        # idle (the step program runs over the whole pool): from shapes.
+        stats.row_state_bytes_stepped += \
+            steps * self.num_slots * self._row_state_bytes
         # Performance attribution (telemetry/): the chunk's span on this
         # scheduler's timeline track (the gap to the previous chunk feeds
         # the step_gap_s histogram — the per-step host sync ROADMAP item 3

@@ -40,7 +40,10 @@ class SlotState:
     tokens: List[int] = dataclasses.field(default_factory=list)
     # The prompt's ids as prefilled: real token t sits at slot
     # ``base - real_len + t``, which is what lets a later row with the same
-    # leading ids copy them (``LiveRowPrefixes``). None: never a donor.
+    # leading ids copy them (``LiveRowPrefixes``). None: never a donor, as
+    # every row of a model that keeps a fixed state per row: its state
+    # stands at the row's own last token, which is nothing a shorter
+    # shared prefix could be copied from.
     ids: Optional[np.ndarray] = None
 
 

@@ -123,19 +123,19 @@ def decode_step_bytes(config, stats) -> int:
         params = config.approx_param_count * model_item
     # What a layer keeps for one token is the configuration's own count
     # (k and v per KV head, or one latent): never 2 x n_kv x head_dim here.
-    per_token = config.cache_values_per_token
+    # A layer that keeps a state per row instead reads and writes it whole.
+    per_token = sum(config.layer_cache_values)
     if config.kv_cache_quant:
         # int8 values + the per-(slot, head) f32 scale the step also reads —
         # same accounting as parallel/sharding.per_device_kv_cache_bytes.
-        per_slot = (per_token + 2 * config.num_kv_heads * 4) * config.num_layers
+        per_slot = per_token + 2 * config.num_kv_heads * 4 * config.num_layers
     else:
-        per_slot = per_token * model_item * config.num_layers
-    kv = stats["batch"] * stats["cache_slots"] * per_slot
+        per_slot = per_token * model_item
+    kv = stats["batch"] * (stats["cache_slots"] * per_slot
+                           + 2 * config.row_state_bytes)
     # _prefix_fn dequantizes the shared prefix to the model dtype, so its
     # per-step read is model-dtype-wide even under kv_cache_quant.
-    prefix = stats["prefix_len"] * (
-        per_token * model_item * config.num_layers
-    )
+    prefix = stats["prefix_len"] * per_token * model_item
     return params + kv + prefix
 
 

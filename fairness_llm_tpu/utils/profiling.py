@@ -310,6 +310,11 @@ class ServingStats:
       layers, decode steps and prefills: real tokens routed, the (token,
       expert) pairs whose expert this chip holds, and the pairs of the
       fullest held expert (x held experts / local pairs = load max over mean)
+    - ``row_state_bytes_stepped`` / ``row_state_resets``: models with a
+      layer that keeps a fixed state per row only. Bytes of that state the
+      decode steps read and wrote (steps x slots x bytes a row, from shapes:
+      the step program streams every slot's state, live or idle), and rows
+      whose state ``serve_prefill`` zeroed on admission
     """
 
     num_slots: int = 0
@@ -333,6 +338,8 @@ class ServingStats:
     moe_tokens: int = 0
     moe_local_pairs: int = 0
     moe_fullest_expert_pairs: int = 0
+    row_state_bytes_stepped: int = 0
+    row_state_resets: int = 0
 
     @property
     def tokens_per_step(self) -> float:

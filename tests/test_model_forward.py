@@ -100,5 +100,8 @@ def test_all_registered_configs_are_consistent():
     for name, cfg in MODEL_CONFIGS.items():
         assert cfg.num_heads % cfg.num_kv_heads == 0, name
         assert cfg.q_dim == cfg.num_heads * cfg.head_dim
-        assert cfg.pos_emb in ("rope", "learned")
+        # "none": no positions at all, only beside recurrent layers, which
+        # carry the order themselves
+        assert cfg.pos_emb in ("rope", "learned") or (
+            cfg.pos_emb == "none" and cfg.row_state_bytes > 0), name
         assert cfg.norm in ("rmsnorm", "layernorm")

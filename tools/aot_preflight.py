@@ -55,9 +55,9 @@ def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
     from fairness_llm_tpu.parallel import sharding as shd
     from fairness_llm_tpu.runtime.sampling import SamplerSettings
     from fairness_llm_tpu.runtime.stepbuilder import (
-        EXTEND_WINDOW,
         build_serve_prefill,
         build_serve_step,
+        extend_window,
     )
     from fairness_llm_tpu.telemetry.memory import count_hlo_ops
 
@@ -106,10 +106,11 @@ def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
         tok.pad_id, tok.eos_id, num_slots=slots, chunk=8, guard=True,
         fuse=1)
     row = lambda dt: host((slots,), dt)  # noqa: E731
+    window = extend_window(cfg, prompt_budget)
     programs = [
         ("serve_prefill", jax.jit(prefill),
-         (params, cache, logits, host((nb, EXTEND_WINDOW), jnp.int32),
-          host((nb, EXTEND_WINDOW), jnp.bool_))
+         (params, cache, logits, host((nb, window), jnp.int32),
+          host((nb, window), jnp.bool_))
          + (host((nb,), jnp.int32),) * 5 + (host((), jnp.int32),)),
         ("serve_step", jax.jit(step, donate_argnums=(1, 2)),
          (params, cache, logits, row(jnp.uint32), row(jnp.int32),
