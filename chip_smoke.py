@@ -41,10 +41,10 @@ MODEL = "mistral-7b"
 MAX_NEW_TOKENS = 128
 # The study's own prompts run to 1128 byte-tokens (phase 3's fairness-aware
 # prompt); the serving default of 512 would truncate every one. The budget
-# and the slot pool are set through Config. serve_prefill is not donated, so
-# admission holds the pool twice: tools/aot_preflight.py puts 8 slots x
-# (1152 + 128) positions at 11.3 GB live beside the 7.4 GB of int8 weights
-# (16 slots: 15.66 of 15.75 GB, no room for anything else).
+# and the slot pool are set through Config. serve_prefill is donated the
+# pool, so admission holds it once: tools/aot_preflight.py puts 8 slots x
+# (1152 + 128) positions at 11.07 GB live, 2.35 of them the call's
+# temporaries, beside the 7.4 GB of int8 weights (16 slots: 12.4 of 15.75 GB).
 PROMPT_BUDGET = 1152
 NUM_SLOTS = 8
 # --rehearse: the same model family flags at a width a CPU decodes in seconds.

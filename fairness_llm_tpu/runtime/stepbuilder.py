@@ -599,7 +599,10 @@ def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
        batch-bucket pad rows and the rows that sit this window out, drop),
        the tail ``[P, cache_len)`` is invalidated, and the window's last
        position lands in the carried logits: the row's next-token logits
-       once its last window (``w + S == P``) has run.
+       once its last window (``w + S == P``) has run. The scheduler donates
+       the pool and the logits, so these are writes in place; under
+       ``guard`` a batch whose logits are not finite drops every row, and
+       the pool it returns is the pool it was given.
 
     A layer that keeps a fixed state per row (``RowState``: no position
     axis) is gathered and scattered by slot alone. Such a model's window is
@@ -627,6 +630,13 @@ def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
         # The donor's row rolled so that its slot donor_pad meets pad. The
         # copied range never wraps: it lies inside the donor's own extent.
         shift = jnp.mod(donor_pad - pad, L)
+        # Each rider's donor row is sliced out of the pool by its slot. As
+        # ONE gather of whole rows XLA stages the whole leaf for it in every
+        # call, and where the leaf's tiling is not the gather's (4 KV
+        # heads) copies the leaf into another layout first: a copy of the
+        # pool a call, donated or not. A slice reads its row.
+        donor_slot = [jax.lax.index_in_dim(donor, r, keepdims=False)
+                      for r in range(nb)]
 
         def take(big):
             # ``lax.gather`` spelled out: NumPy-style indexing and ``vmap``
@@ -642,7 +652,8 @@ def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
                         start_index_map=index_map),
                     (1, length) + tail, mode="promise_in_bounds")
 
-            theirs = rows_of(big, donor[:, None], L, (0,))
+            theirs = jnp.concatenate([
+                jax.lax.dynamic_index_in_dim(big, d, 0) for d in donor_slot])
             theirs = jnp.concatenate([theirs, theirs[:, :P]], axis=1)
             theirs = rows_of(
                 theirs, jnp.stack([jnp.arange(nb, dtype=jnp.int32), shift],
@@ -681,6 +692,15 @@ def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
             {"params": params}, tokens, positions, valid, view,
             last_only=True,
         )
+        if guard:
+            # Real rows of this window only (the others scatter-drop and
+            # may hold anything): one reduced flag for the batch. A batch
+            # that trips it writes NOTHING: the donated pool has no earlier
+            # copy to fall back to, and a NaN left in a released slot would
+            # reach its next tenant through masked positions (a weight of
+            # zero times NaN).
+            finite = masked_finite(logits[:, -1, :], slots < num_slots)
+            slots = jnp.where(finite, slots, num_slots)
 
         def scat(big, rows):
             return big.at[slots, :P].set(rows, mode="drop")
@@ -706,11 +726,7 @@ def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
             logits[:, -1, :], mode="drop"
         )
         if guard:
-            # Real rows of this window only (the others scatter-drop and
-            # may hold anything): one reduced flag for the batch.
-            return new_cache, new_logits, masked_finite(
-                logits[:, -1, :], slots < num_slots
-            )
+            return new_cache, new_logits, finite
         return new_cache, new_logits
 
     run.__name__ = f"serve_prefill_{nb}x{P}"

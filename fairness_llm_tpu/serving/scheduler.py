@@ -454,10 +454,11 @@ class ContinuousScheduler:
     # -- compiled programs --------------------------------------------------
 
     def _donate(self):
-        # Donate the cache + carried logits so each decode chunk updates
-        # in-place instead of copying the whole pool per call (donation is
-        # implemented on CPU too). The decode failure path must then
-        # REBUILD device state, which _decode's except branch does.
+        # Donate the cache + carried logits, so that a decode chunk and a
+        # prefill window update the pool in place instead of copying all of
+        # it per call (donation is implemented on CPU too). A call that
+        # raised may have consumed them: the failure paths then build the
+        # device state anew (_rebuild_device_state).
         return (1, 2)
 
     def _guard(self) -> bool:
@@ -506,12 +507,12 @@ class ContinuousScheduler:
             self.engine.config, self.engine.model, nb=nb, P=P, guard=guard,
             num_slots=self.num_slots,
         )
-        # No donation here even on TPU: a prefill failure must leave the
-        # OTHER live slots' cache rows intact, and a donated input buffer
-        # doesn't survive a raised call. instrument_jit = jax.jit + the cost
-        # ledger (telemetry/costmodel.py) on every compiled program.
+        # Donated like the step: the extent's scatter writes the pool in
+        # place, and the call's outputs are sound for every row it did not
+        # name (it scatters at ``slots`` alone). instrument_jit = jax.jit +
+        # the cost ledger (telemetry/costmodel.py) on every compiled program.
         fn = instrument_jit(
-            run, program,
+            run, program, donate_argnums=self._donate(),
             collectives=self._collectives(
                 nb, extend_window(self.engine.config, P), "call"))
         self._compiled[key] = fn
@@ -1361,13 +1362,16 @@ class ContinuousScheduler:
         window, each as a ``sched.prefill.dispatch`` span that names the
         requests it admits. On an asynchronous backend a call returns once
         the work is queued: the span, ``prefill_wall_s`` and
-        ``note_invocation`` are the DISPATCH; the device is known to be
-        done with a batch's last window only at the next
-        ``sched.decode.sync`` (the windows before it are waited for, so
-        that no two of a batch are in flight). A call that fails
-        releases the whole batch: a row some earlier window wrote stays
-        out of every decode step and is no donor, and its slot is reset
-        or prefilled anew before anything reads it."""
+        ``note_invocation`` are the DISPATCH, a batch's windows queue
+        behind one another on the device (each is handed the pool the one
+        before it returns, donated, so none holds a copy), and the device
+        is known to be done with them only at the next
+        ``sched.decode.sync``. A call that fails releases the whole batch:
+        a row some earlier window wrote stays out of every decode step and
+        is no donor, and its slot is reset or prefilled anew before
+        anything reads it. Where the call that raised had consumed the
+        pool, every live row goes with the batch and the pool is built
+        anew (``_rebuild_device_state``)."""
         nb, P, reqs, slots = plan["nb"], plan["P"], plan["reqs"], plan["slots"]
         fn, guard, first_compile = plan["fn"], plan["guard"], \
             plan["first_compile"]
@@ -1382,30 +1386,22 @@ class ContinuousScheduler:
                 with get_timeline().span(
                         "sched.prefill.dispatch", "prefill", self._track,
                         program=plan["program"], nb=nb, P=P, w=call["w"],
-                        rows=call["rows"],
+                        rows=call["rows"], donated=True,
                         request_ids=[r.id for r in reqs]):
                     self._note_dispatch(t0)
-                    if call is not plan["calls"][0]:
-                        # This window reads what the one before wrote.
-                        # Dispatched behind it unfinished, it would hold a
-                        # third copy of the undonated pool (that call's
-                        # input and output, and its own output); waiting
-                        # costs one host round trip.
-                        jax.block_until_ready(self._prev_logits)
                     out = self._run_compiled(
                         fn, self.engine.params, self._cache,
                         self._prev_logits, *call["args"],
                     )
+                    # Swapped BEFORE the finite check: the call consumed
+                    # its inputs, and what it returns is sound for every
+                    # row it did not name (the program scatters at
+                    # ``slots`` alone, and at none where the flag is
+                    # down), so a poisoned batch still releases only
+                    # itself in the containment branch.
+                    self._cache, self._prev_logits = out[0], out[1]
                     if guard:
-                        new_cache, new_logits, finite = out
-                        # Checked BEFORE the state swap: prefill isn't
-                        # donated, so a poisoned call leaves the previous
-                        # cache/logits untouched (the containment branch
-                        # releases the new slots).
-                        check_finite(finite, "serving", "prefill")
-                    else:
-                        new_cache, new_logits = out
-                self._cache, self._prev_logits = new_cache, new_logits
+                        check_finite(out[2], "serving", "prefill")
                 if self.watchdog is not None:
                     # Post-hoc hang classification (see
                     # resilience/watchdog.py): an over-budget prefill raises
@@ -1432,6 +1428,14 @@ class ContinuousScheduler:
             self._note_fault("prefill", kind, [r.id for r in reqs], e)
             if self.breakers is not None:
                 self.breakers.record_failure("prefill")
+            if self._device_state_deleted():
+                # The call that raised had consumed the pool: the rows that
+                # were live before the batch went with it.
+                self._rebuild_device_state(
+                    "prefill", f"prefill failed: {e}", stats, cause=kind)
+                return True
+            # The state is whole (the call raised before it launched, or
+            # its outputs were swapped in): the batch alone goes.
             for slot, req in zip(slots, reqs):
                 self.pool.release(slot)
                 self._requeue_or_fail(req, f"prefill failed: {e}", stats,
@@ -1446,6 +1450,39 @@ class ContinuousScheduler:
             stats.row_state_resets += len(reqs)
         stats.admitted += len(reqs)
         return True
+
+    def _device_state_deleted(self) -> bool:
+        """Whether a donated call took the pool or the carried logits with
+        it when it raised: asked of the arrays themselves."""
+        return any(x.is_deleted() for x in jax.tree.leaves(
+            (self._cache, self._prev_logits)))
+
+    def _rebuild_device_state(self, stage: str, error: str,
+                              stats: ServingStats, cause: str) -> None:
+        """Containment where a donated call may have consumed its inputs (a
+        decode chunk that failed; a prefill window that raised after it
+        launched): every live row is released and requeued for a fresh
+        prefill, so nothing in the pool is still needed, and the pool and
+        the carried logits are built anew, placed and accounted."""
+        for slot in self.pool.live_slots():
+            req = self.pool.release(slot).request
+            self._requeue_or_fail(req, error, stats, cause=cause)
+        self._cache = init_cache(
+            self.engine.config, self.num_slots, self.cache_len
+        )
+        self._prev_logits = jnp.zeros(
+            (self.num_slots, self.engine.config.vocab_size), jnp.float32
+        )
+        # Fresh host-side buffers: re-pin them to the mesh, or the next
+        # compiled call would recompile against replicated layouts.
+        self._place_device_state()
+        self._account_device_state()
+        self.pool.take_invalidations()
+        self._busy_from = None
+        get_registry().counter(
+            "device_state_rebuilds_total", component="serving",
+            stage=stage, **self.labels,
+        ).inc()
 
     def _note_dispatch(self, t0: float) -> None:
         """The earliest dispatch since the last ``sched.decode.sync``: the
@@ -1611,25 +1648,8 @@ class ContinuousScheduler:
             )
             if self.breakers is not None:
                 self.breakers.record_failure("decode")
-            for slot in live_ids:
-                req = self.pool.release(slot).request
-                self._requeue_or_fail(req, f"decode failed: {e}", stats,
-                                      cause=kind)
-            # Every live slot was just released, so nothing in the cache is
-            # still needed — rebuild device state from scratch (with TPU
-            # buffer donation, a raised call may have consumed the inputs).
-            self._cache = init_cache(
-                self.engine.config, self.num_slots, self.cache_len
-            )
-            self._prev_logits = jnp.zeros(
-                (self.num_slots, self.engine.config.vocab_size), jnp.float32
-            )
-            # Fresh host-side buffers: re-pin them to the mesh, or the next
-            # compiled call would recompile against replicated layouts.
-            self._place_device_state()
-            self._account_device_state()
-            self.pool.take_invalidations()
-            self._busy_from = None
+            self._rebuild_device_state(
+                "decode", f"decode failed: {e}", stats, cause=kind)
             return True
         with tl.span("sched.decode.emit", "decode", self._track,
                      rows=len(live_ids)):

@@ -489,6 +489,23 @@ def count_hlo_ops(hlo_text: str) -> Dict[str, int]:
     return counts
 
 
+def count_pool_copies(hlo_text: str, slots: int, cache_len: int) -> int:
+    """Copies of a whole ``[slots, cache_len, ...]`` leaf of the slot pool
+    that a compiled module makes as instructions of their own: what a
+    program pays per call where the pool's donation did not take, or where
+    a read of the leaf made XLA change its layout first. A copy inside a
+    fused computation is that fusion's read of the leaf in another layout,
+    not a second leaf, and is left out."""
+    copy = re.compile(rf"= \w+\[{slots},{cache_len}[,\]][^=]* copy\(")
+    count, fused = 0, False
+    for line in hlo_text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            fused = "fused_computation" in line.split("(", 1)[0]
+        elif not fused and copy.search(line):
+            count += 1
+    return count
+
+
 def publish_program_hlo_ops(program: str, hlo_text: str) -> None:
     """``program_hlo_ops{program, op}`` gauges from :func:`count_hlo_ops` —
     the witness that a kernel was not replaced by an XLA expression and that

@@ -27,6 +27,8 @@ from toys import (
     IdPrompts,
     alone,
     assert_same_tokens,
+    prefill_aliasing,
+    prefill_fault,
     toy_engine,
     whole_prompt_logits as _whole_prompt_logits,
 )
@@ -49,6 +51,7 @@ from fairness_llm_tpu.serving.slots import (  # noqa: E402
     SlotState,
 )
 from fairness_llm_tpu.telemetry import use_registry  # noqa: E402
+from fairness_llm_tpu.telemetry.timeline import use_timeline  # noqa: E402
 from fairness_llm_tpu.utils.failures import ScriptedFaultInjector  # noqa: E402
 from fairness_llm_tpu.utils.profiling import ServingStats  # noqa: E402
 
@@ -135,6 +138,20 @@ def test_extended_and_copied_rows_carry_the_whole_prompts_logits(toy):
             res = sched.take_result(req.id)
             assert res.ok and res.prompt_tokens == len(ids)
             _assert_same_greedy(engine, req.id, res.tokens, ids)
+
+
+@pytest.mark.parametrize("guard", [False, True], ids=["plain", "guarded"])
+def test_the_compiled_prefill_writes_the_pool_in_place(toy, guard):
+    """``serve_prefill`` is donated as ``serve_step`` is: the compiled module
+    aliases every leaf of the pool and the carried logits to an output and
+    copies no whole leaf, so the extent's scatter writes where the pool is
+    (ISSUE 34; the same question of the described v5e:
+    ``tools/aot_preflight.py``'s ``pool_copies``)."""
+    engine, _ = toy
+    sched = _scheduler(engine)
+    aliased, donated, copies = prefill_aliasing(sched, 2, 192, guard)
+    assert aliased == donated and len(donated) >= 9
+    assert copies == 0
 
 
 # -- the plan's edges ---------------------------------------------------------------
@@ -241,12 +258,14 @@ def test_the_plan_computes_only_what_no_live_row_holds(dense, case):
 
 
 def test_a_failed_extend_call_releases_its_group_and_nothing_else(dense):
-    """The second window of a batch raises: the batch's slots are released
-    and its requests retried; the row that was live before keeps decoding
-    the engine's tokens, and the retried rows come out right too (their
-    half-written slots were prefilled anew)."""
+    """The second window of a batch raises before the call is made (what a
+    trace or compile error does): the donated pool was never handed over,
+    so the batch's slots are released and its requests retried; the row
+    that was live before keeps decoding the engine's tokens, and the
+    retried rows come out right too (their half-written slots were
+    prefilled anew)."""
     engine, cut, sched = dense
-    with jax.default_matmul_precision("highest"):
+    with jax.default_matmul_precision("highest"), use_registry() as reg:
         prompts = _Prompts(engine, cut, seed=7)
         ids = [prompts.draw(150), prompts.draw(140), prompts.draw(130)]
         reqs = [prompts.request(x) for x in ids]
@@ -255,26 +274,16 @@ def test_a_failed_extend_call_releases_its_group_and_nothing_else(dense):
         sched._admit(stats)
         live_before = sched.pool.live_slots()
 
-        inner, calls = sched._run_compiled, []
-
-        def failing(fn, *args):
-            if getattr(fn, "program", "") == "serve_prefill":
-                calls.append(1)
-                if len(calls) == 2:
-                    raise RuntimeError("scripted device fault")
-            return inner(fn, *args)
-
-        sched._run_compiled = failing
-        try:
+        with prefill_fault(sched, at=2) as calls:
             for r in reqs[1:]:
                 assert sched.submit(r)
             sched._admit_once(stats)
-        finally:
-            sched._run_compiled = inner
         assert len(calls) == 2 and stats.requeued == 2
         assert sched.pool.live_slots() == live_before
         assert stats.prefill_batches == 3 + 1  # the call that ran is counted
         assert stats.prefill_tokens == 150  # the failed batch admitted none
+        assert reg.peek("device_state_rebuilds_total", component="serving",
+                        stage="prefill") is None
 
         sched._run_loop(stats)
         assert stats.completed == 3 and stats.failed == 0
@@ -282,6 +291,70 @@ def test_a_failed_extend_call_releases_its_group_and_nothing_else(dense):
             res = sched.take_result(req.id)
             assert res.ok
             _assert_same_greedy(engine, req.id, res.tokens, x)
+
+
+def test_a_call_that_fails_after_launch_takes_every_live_row_with_it(dense):
+    """The second window of a batch raises once the call has consumed the
+    donated pool: the scheduler asks the arrays, finds them deleted, and
+    does what a failed decode chunk does: the row that was live before
+    goes back to the queue with the batch, the pool and the carried logits
+    are built anew, and every request still finishes with the engine's
+    tokens."""
+    engine, cut, sched = dense
+    with jax.default_matmul_precision("highest"), use_registry() as reg:
+        prompts = _Prompts(engine, cut, seed=8, tag="late")
+        ids = [prompts.draw(150), prompts.draw(140), prompts.draw(130)]
+        reqs = [prompts.request(x) for x in ids]
+        stats = ServingStats(num_slots=sched.num_slots)
+        assert sched.submit(reqs[0])
+        sched._admit(stats)
+
+        with prefill_fault(sched, at=2, how="launched") as calls:
+            for r in reqs[1:]:
+                assert sched.submit(r)
+            sched._admit_once(stats)
+        assert len(calls) == 2 and stats.requeued == 3
+        assert sched.pool.live_slots() == [] and len(sched.queue) == 3
+        assert not sched._device_state_deleted()
+        assert not np.asarray(sched._cache.key_valid).any()
+        assert reg.peek("device_state_rebuilds_total", component="serving",
+                        stage="prefill").value == 1
+        assert reg.peek("faults_total", component="serving", kind="device",
+                        stage="prefill").value == 1
+
+        sched._run_loop(stats)
+        assert stats.completed == 3 and stats.failed == 0
+        for req, x in zip(reqs, ids):
+            res = sched.take_result(req.id)
+            assert res.ok and res.retries == 1
+            _assert_same_greedy(engine, req.id, res.tokens, x)
+
+
+def test_a_batchs_windows_are_dispatched_without_a_wait_between_them(
+        dense, monkeypatch):
+    """Three windows of one donorless batch: each call is handed the pool
+    the one before it returned, so the host waits for none of them (no
+    ``block_until_ready`` between the dispatches), and each dispatch span
+    says the call was donated."""
+    engine, cut, sched = dense
+    waits = []
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: waits.append(1) or x)
+    with jax.default_matmul_precision("highest"), use_timeline() as tl:
+        prompts = _Prompts(engine, cut, seed=9, tag="win")
+        ids = prompts.draw(150)
+        req = prompts.request(ids)
+        stats = ServingStats(num_slots=sched.num_slots)
+        assert sched.submit(req)
+        sched._admit_once(stats)
+        spans = [e for e in tl.events() if e["type"] == "span"
+                 and e["name"] == "sched.prefill.dispatch"]
+        assert stats.prefill_batches == 3 and waits == []
+        assert [e["args"]["w"] for e in spans] == [0, S, 2 * S]
+        assert all(e["args"]["donated"] is True for e in spans)
+        _drained(sched, stats, 1)
+        _assert_same_greedy(engine, req.id, sched.take_result(req.id).tokens,
+                            ids)
 
 
 def test_admission_runs_no_program_but_its_own(dense, caplog):
@@ -582,6 +655,17 @@ def test_a_poisoned_donor_trips_the_guard_for_its_readers_only(shared):
             assert stats.requeued == 2 and sched.pool.live_slots() == live
             assert reg.peek("faults_total", component="serving",
                             kind="numerics", stage="prefill").value == 1
+            # the poisoned call's outputs were swapped in before its flag
+            # was read: the pool is whole, and nothing was built anew; the
+            # guarded program wrote nothing, so no released slot keeps a
+            # NaN that its next tenant would meet behind a mask
+            assert not sched._device_state_deleted()
+            clean_slots = [s for s in range(sched.num_slots) if s != slot]
+            for x in jax.tree.leaves(sched._cache):
+                if jnp.issubdtype(x.dtype, jnp.floating) and x.ndim >= 3:
+                    assert np.isfinite(np.asarray(x)[clean_slots]).all()
+            assert reg.peek("device_state_rebuilds_total",
+                            component="serving", stage="prefill") is None
             # the row heals; the requeued readers find it whole
             sched._cache = _float_rows(
                 lambda now, was: now.at[slot].set(was[slot]),

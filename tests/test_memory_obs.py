@@ -31,6 +31,7 @@ from fairness_llm_tpu.telemetry import (
 )
 from fairness_llm_tpu.telemetry.memory import (
     MemoryLedger,
+    count_pool_copies,
     use_memory_ledger,
 )
 
@@ -215,6 +216,35 @@ def test_headroom_math():
         # CPU reports no memory_stats, so no measured delta and no alerts.
         assert reg.read_value("hbm_reconciliation_alerts_total",
                               component="memory") == 0
+
+
+_HLO = """\
+HloModule jit_serve_prefill_8x256, input_output_alias={ {0}: (2, {}, may-alias) }
+
+%fused_computation.7 (param_0.1: bf16[48,384,8,128]) -> bf16[48,384,8,128] {
+  %param_0.1 = bf16[48,384,8,128]{3,2,1,0:T(8,128)(2,1)} parameter(0)
+  ROOT %copy.9 = bf16[48,384,8,128]{3,1,2,0:T(8,128)(2,1)} copy(%param_0.1)
+}
+
+ENTRY %main.3 (k.1: bf16[48,384,8,128], pos.1: s32[48,384]) -> bf16[48,384,8,128] {
+  %k.1 = bf16[48,384,8,128]{3,2,1,0:T(8,128)(2,1)} parameter(0)
+  %pos.1 = s32[48,384]{1,0:T(8,128)} parameter(1)
+  %copy.1 = bf16[48,384,8,128]{3,2,1,0:T(8,128)(2,1)S(1)} copy(%k.1), metadata={op_name="a=b"}
+  %copy.2 = s32[48,384]{1,0:T(8,128)} copy(%pos.1)
+  %copy.3 = bf16[8,384,8,128]{3,2,1,0:T(8,128)(2,1)} copy(%rows)
+  ROOT %fusion.7 = bf16[48,384,8,128]{3,2,1,0} fusion(%copy.1), kind=kLoop, calls=%fused_computation.7
+}
+"""
+
+
+@pytest.mark.parametrize("slots, cache_len, want", [
+    (48, 384, 2),  # a leaf and the positions; not the fusion's own read
+    (8, 384, 1),   # the gathered rows are a pool of 8 slots' leaf
+    (96, 384, 0),
+])
+def test_pool_copies_are_whole_leaves_copied_outside_fusions(
+        slots, cache_len, want):
+    assert count_pool_copies(_HLO, slots, cache_len) == want
 
 
 def test_attribution_off_records_nothing():

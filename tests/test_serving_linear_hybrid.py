@@ -44,7 +44,14 @@ from fairness_llm_tpu.utils.profiling import ServingStats  # noqa: E402
 from perfbench.families import linear_hybrid_moe  # noqa: E402
 from perfbench.lib import correct, reference  # noqa: E402
 from perfbench.lib.traffic import RequestSpec  # noqa: E402
-from toys import IdPrompts, alone, assert_same_tokens, toy_engine  # noqa: E402
+from toys import (  # noqa: E402
+    IdPrompts,
+    alone,
+    assert_same_tokens,
+    prefill_aliasing,
+    prefill_fault,
+    toy_engine,
+)
 
 TOL = 1e-4
 SEED = 33
@@ -364,49 +371,123 @@ def test_a_call_moves_only_the_rows_it_names(toy):
     assert unnamed["serve_prefill"] == 1 and unnamed["serve_step"] >= 3
 
 
+def _one_live_then_a_batch(engine, cut, seed, tag):
+    """A scheduler with one row admitted, and two more requests to come."""
+    sched = _scheduler(engine)
+    prompts = IdPrompts(engine, cut, seed, GREEDY, tag=tag)
+    ids = [prompts.draw(150), prompts.draw(140), prompts.draw(130)]
+    reqs = [prompts.request(x) for x in ids]
+    stats = ServingStats(num_slots=sched.num_slots)
+    assert sched.submit(reqs[0])
+    sched._admit(stats)
+    return sched, stats, reqs, ids
+
+
+def _all_decode_the_engines_tokens(engine, sched, stats, reqs, ids, retries):
+    sched._run_loop(stats)
+    assert stats.completed == len(reqs) and stats.failed == 0
+    for req, x, n in zip(reqs, ids, retries):
+        res = sched.take_result(req.id)
+        assert res.ok and res.retries == n
+        assert_same_tokens(engine, req.id, x, res.tokens,
+                           alone(engine, req.id, GREEDY, NEW))
+
+
 def test_a_failed_prefill_call_leaves_the_other_rows_states_intact(toy):
-    """A batch's call raises: prefill is not donated, so the live row's
+    """A batch's call raises before it is made (what a trace or compile
+    error does): the donated pool was never handed over, so the live row's
     state is what it was, the batch's slots are released and its requests
     retried, and every request still decodes the engine's tokens."""
     engine, cut = toy
-    with jax.default_matmul_precision("highest"):
-        sched = _scheduler(engine)
-        prompts = IdPrompts(engine, cut, SEED + 4, GREEDY, tag="f")
-        ids = [prompts.draw(150), prompts.draw(140), prompts.draw(130)]
-        reqs = [prompts.request(x) for x in ids]
-        stats = ServingStats(num_slots=sched.num_slots)
-        assert sched.submit(reqs[0])
-        sched._admit(stats)
+    with jax.default_matmul_precision("highest"), use_registry() as reg:
+        sched, stats, reqs, ids = _one_live_then_a_batch(
+            engine, cut, SEED + 4, "f")
         live = _slot_of(sched, reqs[0].id)
         before = _row_state(sched)
-        inner, calls = sched._run_compiled, []
-
-        def failing(fn, *args):
-            if getattr(fn, "program", "") == "serve_prefill":
-                calls.append(1)
-                if len(calls) == 1:
-                    raise RuntimeError("scripted device fault")
-            return inner(fn, *args)
-
-        sched._run_compiled = failing
-        try:
+        with prefill_fault(sched, at=1) as calls:
             for r in reqs[1:]:
                 assert sched.submit(r)
             sched._admit_once(stats)
-        finally:
-            sched._run_compiled = inner
         assert len(calls) == 1 and stats.requeued == 2
         assert sched.pool.live_slots() == [live]
         for b, a in zip(before, _row_state(sched)):
             assert np.array_equal(b[live], a[live])
         assert stats.row_state_resets == 1  # the failed batch admitted none
-        sched._run_loop(stats)
-        assert stats.completed == 3 and stats.failed == 0
-        for req, x in zip(reqs, ids):
-            res = sched.take_result(req.id)
-            assert res.ok
-            assert_same_tokens(engine, req.id, x, res.tokens,
-                               alone(engine, req.id, GREEDY, NEW))
+        assert reg.peek("device_state_rebuilds_total", component="serving",
+                        stage="prefill") is None
+        _all_decode_the_engines_tokens(engine, sched, stats, reqs, ids,
+                                       (0, 1, 1))
+
+
+def test_a_call_that_fails_after_launch_rebuilds_every_rows_state(toy):
+    """The batch's call raises once it has consumed the donated pool: the
+    live row's state went with it, so every live row is requeued, the pool
+    (per-row states too) is built anew from zeros, the counter says the
+    wider path ran once, and every request still decodes the engine's
+    tokens."""
+    engine, cut = toy
+    with jax.default_matmul_precision("highest"), use_registry() as reg:
+        sched, stats, reqs, ids = _one_live_then_a_batch(
+            engine, cut, SEED + 5, "g")
+        assert any(np.abs(x).max() > 0 for x in _row_state(sched))
+        with prefill_fault(sched, at=1, how="launched") as calls:
+            for r in reqs[1:]:
+                assert sched.submit(r)
+            sched._admit_once(stats)
+        assert len(calls) == 1 and stats.requeued == 3
+        assert sched.pool.live_slots() == []
+        assert not sched._device_state_deleted()
+        assert all(np.abs(x).max() == 0 for x in _row_state(sched))
+        assert reg.peek("device_state_rebuilds_total", component="serving",
+                        stage="prefill").value == 1
+        _all_decode_the_engines_tokens(engine, sched, stats, reqs, ids,
+                                       (1, 1, 1))
+
+
+def test_a_poisoned_batch_is_released_alone_and_the_live_row_decodes_on(toy):
+    """Guard on, and a batch's call comes back with its finite flag down:
+    the call consumed the pool and its outputs are already the scheduler's
+    when the flag is read, sound for the row the call did not name. Only the
+    batch is released; the live row's state is bit for bit what it was and
+    it decodes the engine's tokens without a retry."""
+    engine, cut = toy
+    engine.numerics_guards = True
+    try:
+        with jax.default_matmul_precision("highest"), use_registry() as reg:
+            sched, stats, reqs, ids = _one_live_then_a_batch(
+                engine, cut, SEED + 6, "p")
+            live = _slot_of(sched, reqs[0].id)
+            before = _row_state(sched)
+            with prefill_fault(sched, at=1, how="flag_down") as calls:
+                for r in reqs[1:]:
+                    assert sched.submit(r)
+                sched._admit_once(stats)
+            assert len(calls) == 1 and stats.requeued == 2
+            assert sched.pool.live_slots() == [live]
+            assert not sched._device_state_deleted()
+            for b, a in zip(before, _row_state(sched)):
+                assert np.array_equal(b[live], a[live])
+            assert reg.peek("faults_total", component="serving",
+                            kind="numerics", stage="prefill").value == 1
+            assert reg.peek("device_state_rebuilds_total",
+                            component="serving", stage="prefill") is None
+            _all_decode_the_engines_tokens(engine, sched, stats, reqs, ids,
+                                           (0, 1, 1))
+    finally:
+        engine.numerics_guards = False
+
+
+@pytest.mark.parametrize("guard", [False, True], ids=["plain", "guarded"])
+def test_the_compiled_prefill_writes_the_per_row_states_in_place(toy, guard):
+    """Donated like ``serve_step``: keys, values, per-row states, masks and
+    the carried logits are each aliased to an output, and no whole leaf of
+    the pool is copied (ISSUE 34)."""
+    engine, _ = toy
+    sched = _scheduler(engine)
+    aliased, donated, copies = prefill_aliasing(sched, 2, 192, guard)
+    assert aliased == donated and copies == 0
+    per_row = sum(isinstance(l, RowState) for l in sched._cache.layers)
+    assert per_row == 3 and len(donated) >= 2 * per_row + 1
 
 
 # -- what is refused, with a message ---------------------------------------------------

@@ -4,8 +4,10 @@ per KV head) from Mistral's file and ``latent_moe`` (one latent a token)
 from A.X-K1's. Float32 on int8 weights made from a seed, prompts as token
 ids the tokenizer hands back by name."""
 
+import contextlib
 import dataclasses
 import os
+import re
 import sys
 
 import jax
@@ -17,7 +19,9 @@ sys.path.insert(0, ROOT)
 
 from fairness_llm_tpu.config import ModelSettings  # noqa: E402
 from fairness_llm_tpu.runtime.engine import DecodeEngine  # noqa: E402
+from fairness_llm_tpu.runtime.stepbuilder import extend_window  # noqa: E402
 from fairness_llm_tpu.serving import Request  # noqa: E402
+from fairness_llm_tpu.telemetry.memory import count_pool_copies  # noqa: E402
 from perfbench import families  # noqa: E402
 from perfbench.lib import modelcfg  # noqa: E402
 from perfbench.lib import weights as W  # noqa: E402
@@ -93,3 +97,56 @@ def alone(engine, name, settings: ModelSettings, n: int):
     """The first ``n`` tokens ``DecodeEngine`` alone decodes for the
     registered prompt ``name``."""
     return np.asarray(engine.generate([name], settings).tokens[0])[:n]
+
+
+def prefill_aliasing(sched, nb: int, P: int, guard: bool = False):
+    """The scheduler's own ``serve_prefill`` of bucket ``(nb, P)`` compiled
+    for its pool: (the parameters the module aliases to an output, the
+    parameters that hold the pool and the carried logits, the copies of a
+    whole pool leaf it makes)."""
+    fn = sched._prefill_fn(nb, P, guard)
+    S = extend_window(sched.engine.config, P)
+    row = jnp.zeros((nb,), jnp.int32)
+    hlo = fn._jit.lower(
+        sched.engine.params, sched._cache, sched._prev_logits,
+        jnp.zeros((nb, S), jnp.int32), jnp.zeros((nb, S), bool),
+        row, row, row, row, row, jnp.zeros((), jnp.int32),
+    ).compile().as_text()
+    header = hlo[:hlo.index("\n")]
+    aliased = sorted(int(i) for i in re.findall(
+        r"\((\d+), \{\}, (?:may|must)-alias\)", header))
+    held = len(jax.tree.leaves(sched.engine.params))
+    donated = len(jax.tree.leaves((sched._cache, sched._prev_logits)))
+    return aliased, list(range(held, held + donated)), \
+        count_pool_copies(hlo, sched.num_slots, sched.cache_len)
+
+
+@contextlib.contextmanager
+def prefill_fault(sched, at: int, how: str = "unlaunched"):
+    """The ``at``-th ``serve_prefill`` call from here on fails. ``how``:
+    ``unlaunched`` raises before the compiled call is made (what a trace or
+    compile error does: the inputs live on); ``launched`` raises after it
+    returned (the donated inputs are consumed); ``flag_down`` lets the call
+    through and hands back its finite flag as False (a guarded program).
+    Yields the list that counts the calls."""
+    inner, calls = sched._run_compiled, []
+
+    def failing(fn, *args):
+        if getattr(fn, "program", "") != "serve_prefill":
+            return inner(fn, *args)
+        calls.append(1)
+        hit = len(calls) == at
+        if hit and how == "unlaunched":
+            raise RuntimeError("scripted device fault")
+        out = inner(fn, *args)
+        if hit and how == "launched":
+            raise RuntimeError("scripted device fault after launch")
+        if hit and how == "flag_down":
+            return out[:2] + (jnp.zeros((), bool),)
+        return out
+
+    sched._run_compiled = failing
+    try:
+        yield calls
+    finally:
+        sched._run_compiled = inner

@@ -5,11 +5,13 @@ that has none — the cheap check before spending chip time.
 With libtpu installed, ``jax.experimental.topologies`` describes a v5e slice
 without one being attached. This tool lowers ``serve_prefill`` and
 ``serve_step`` (the programs ``ContinuousScheduler`` builds, from the same
-``stepbuilder`` compositions, guard on, the step donated) for a model at
-full width against that descriptor under ``force_pallas()``, and reports
-per program: compile wall, the Mosaic custom calls and all-reduces in the
-compiled module, and ``memory_analysis()`` against the chip's HBM. The
-defaults are ``chip_smoke.py``'s configuration.
+``stepbuilder`` compositions, guard on, both donated the pool and the
+carried logits as the scheduler donates them) for a model at full width
+against that descriptor under ``force_pallas()``, and reports per program:
+compile wall, the Mosaic custom calls and all-reduces in the compiled
+module, the copies of a whole pool leaf it holds (a donation that did not
+take), and ``memory_analysis()`` against the chip's HBM. The defaults are
+``chip_smoke.py``'s configuration.
 
 It proves the compiler accepts the programs and that they fit. It does not
 run them: execution, run-time HBM, init and host code are only seen on the
@@ -59,7 +61,10 @@ def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
         build_serve_step,
         extend_window,
     )
-    from fairness_llm_tpu.telemetry.memory import count_hlo_ops
+    from fairness_llm_tpu.telemetry.memory import (
+        count_hlo_ops,
+        count_pool_copies,
+    )
 
     if bench_config:
         # A benchmark configuration, as its family hands it to the program.
@@ -108,7 +113,7 @@ def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
     row = lambda dt: host((slots,), dt)  # noqa: E731
     window = extend_window(cfg, prompt_budget)
     programs = [
-        ("serve_prefill", jax.jit(prefill),
+        ("serve_prefill", jax.jit(prefill, donate_argnums=(1, 2)),
          (params, cache, logits, host((nb, window), jnp.int32),
           host((nb, window), jnp.bool_))
          + (host((nb,), jnp.int32),) * 5 + (host((), jnp.int32),)),
@@ -123,6 +128,7 @@ def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
             compiled = fn.lower(*args).compile()
         wall = time.perf_counter() - t0
         ma = compiled.memory_analysis()
+        hlo = compiled.as_text()
         live = (ma.argument_size_in_bytes + ma.output_size_in_bytes
                 + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
         rec = {
@@ -130,7 +136,8 @@ def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
             "tp": tp, "slots": slots, "cache_len": cache_len,
             "device_kind": topo.devices[0].device_kind,
             "compile_s": round(wall, 1),
-            **count_hlo_ops(compiled.as_text()),
+            **count_hlo_ops(hlo),
+            "pool_copies": count_pool_copies(hlo, slots, cache_len),
             "argument_gb": round(ma.argument_size_in_bytes / 1e9, 2),
             "output_gb": round(ma.output_size_in_bytes / 1e9, 2),
             "alias_gb": round(ma.alias_size_in_bytes / 1e9, 2),
