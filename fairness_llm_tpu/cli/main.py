@@ -436,6 +436,12 @@ def config_from_args(args: argparse.Namespace) -> Config:
                             f"{sorted(set(mc.mixer_kinds))} mixers, whose "
                             "caches (a latent, a per-row state) have no "
                             "head axis sharded yet")
+                    if mc is not None and "moe" in mc.layer_kinds:
+                        raise SystemExit(
+                            f"--tp has no expert axis; {args.model} has "
+                            "routed experts, whose exchange across chips "
+                            "is not built (its GQA layers' rings and rows "
+                            "would shard by KV head as a dense model's do)")
                     if mc is not None and (mc.num_heads % args.tp != 0 or
                                            mc.num_kv_heads % args.tp != 0):
                         raise SystemExit(

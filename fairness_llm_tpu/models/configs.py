@@ -15,8 +15,13 @@ A model whose layers are not all one kind is a subclass: latent attention
 with routed experts after leading dense layers is ``SparseLatentConfig``;
 delta-rule linear-attention layers that keep a fixed state per row beside
 gated softmax layers, every one with routed experts, is
-``LinearHybridConfig``. What each layer is and keeps is asked of the
-configuration (``layer_kinds`` for the MLP, ``mixer_kinds`` for the mixer,
+``LinearHybridConfig``; GQA layers that see a window (and keep a ring of it)
+beside GQA layers that see and keep every position, with QK-norm, post-norms
+and routed experts after leading dense layers, is ``WindowHybridConfig``. What
+each layer is and keeps is asked of the configuration (``layer_kinds`` for
+the MLP, ``mixer_kinds`` for the mixer, ``layer_windows`` and
+``layer_rotary`` for what a GQA layer sees and whether it rotates,
+``retained_len`` for the positions the slot pool keeps of it,
 ``layer_cache_values`` and ``row_state_bytes`` for what the cache holds, the
 parameter counts), and the dense answers stay on ``ModelConfig``, whose
 fields do not change.
@@ -27,6 +32,29 @@ from __future__ import annotations
 import dataclasses
 import math
 from typing import Optional, Tuple
+
+#: New tokens a ``serve_prefill`` call computes per row
+#: (``runtime/stepbuilder.extend_window``). Prompt buckets are multiples of
+#: the sequence bucket (64 or 128), so 64 divides every ``P``; it is the
+#: smallest such width that covers the study's divergent tails (17-37 ids) in
+#: one window. 128 measured on the chip: ``PERF.md`` section 6, PR 29. It
+#: lives here because a window layer's ring is sized against it.
+EXTEND_WINDOW = 64
+
+
+def ring_len(window: int) -> int:
+    """Positions the slot pool keeps of a layer that sees ``window`` back:
+    the window and a slack of half a window (at least one prefill window),
+    rounded up to whole prefill windows. The slack buys two things. A call
+    that writes ``EXTEND_WINDOW`` positions must leave its FIRST query its
+    whole window (``ring >= window + EXTEND_WINDOW - 1``). And a resident row
+    stays a donor until it has moved ``slack`` positions past the point where
+    a new row's prompt parts from it (``scheduler._plan_prefill``): half a
+    window covers a divergent tail and a whole answer of the study's traffic
+    many times over, at half the bytes a second window would cost. Whole
+    prefill windows, so that a call's write never wraps inside the call."""
+    slack = max(window // 2, EXTEND_WINDOW)
+    return -(-(window + slack) // EXTEND_WINDOW) * EXTEND_WINDOW
 
 
 @dataclasses.dataclass(frozen=True)
@@ -134,6 +162,40 @@ class ModelConfig:
         return False
 
     @property
+    def layer_windows(self) -> Tuple[Optional[int], ...]:
+        """Per layer, how far back a GQA layer's query sees: the query at
+        position ``p`` sees the key at ``q <= p`` iff ``p - q < window``;
+        None: every earlier position. ``sliding_window`` is the every-layer
+        case (Mistral); whoever has a layer in hand asks this, not the
+        field."""
+        return (self.sliding_window,) * self.num_layers
+
+    @property
+    def layer_rotary(self) -> Tuple[bool, ...]:
+        """Per layer, whether a GQA layer rotates its queries and keys."""
+        return (self.pos_emb == "rope",) * self.num_layers
+
+    def retained_len(self, cache_len: int) -> Tuple[int, ...]:
+        """Per layer, the positions the slot pool keeps of a row of
+        ``cache_len``: all of them, or a ring of ``ring_len(window)`` where
+        the layer sees a window and that is shorter. It follows from the
+        configuration and the pool alone."""
+        return tuple(cache_len if w is None else min(cache_len, ring_len(w))
+                     for w in self.layer_windows)
+
+    @property
+    def qk_norm(self) -> bool:
+        """An RMSNorm with a learned scale over each query and key head,
+        before any rotation."""
+        return False
+
+    @property
+    def post_norms(self) -> bool:
+        """A norm on the mixer's and on the MLP's OUTPUT as well, before
+        each is added to the stream (four norms a layer)."""
+        return False
+
+    @property
     def cache_values_per_token(self) -> int:
         """Values one position-keeping layer's cache keeps for one token of
         one row."""
@@ -203,6 +265,12 @@ class RoutedExpertsConfig(ModelConfig):
     norm_topk_prob: bool = True
     expert_shards: int = 1
     expert_rank: int = 0
+
+    @property
+    def router_bias(self) -> bool:
+        """A per-expert bias added to the scores to CHOOSE the experts and
+        never to weigh them (a trained load-balancing buffer)."""
+        return False
 
     def __post_init__(self):
         if self.num_experts % max(self.expert_shards, 1):
@@ -289,19 +357,54 @@ class SparseLatentConfig(RoutedExpertsConfig):
 
 
 @dataclasses.dataclass(frozen=True)
-class LinearHybridConfig(RoutedExpertsConfig):
+class HeadShareConfig(RoutedExpertsConfig):
+    """Mixer heads held as one rank's share, beside the experts':
+    ``num_heads`` and ``num_kv_heads`` (and whatever further kinds of head a
+    subclass names in ``_head_counts``) are the PUBLISHED counts, and this
+    chip holds rank ``head_rank`` of ``head_shards`` equal ranges of each
+    (``heads_held``), as tensor parallelism would cut them. It computes its
+    own heads' part of ``o_proj``'s sum; the other ranks' is left out."""
+
+    head_shards: int = 1
+    head_rank: int = 0
+
+    def __post_init__(self):
+        super().__post_init__()
+        for n in self._head_counts:
+            if n % max(self.head_shards, 1):
+                raise ValueError(f"{n} heads do not divide over "
+                                 f"{self.head_shards} ranks")
+        if not 0 <= self.head_rank < self.head_shards:
+            raise ValueError("head_rank outside head_shards")
+
+    @property
+    def _head_counts(self) -> Tuple[int, int, int]:
+        """Published (query, key/value, linear-attention) heads."""
+        return self.num_heads, self.num_kv_heads, 0
+
+    @property
+    def heads_held(self) -> Tuple[int, int, int]:
+        from fairness_llm_tpu.parallel.sharding import held_heads
+
+        return tuple(held_heads(n, self.head_shards, self.head_rank)[1]
+                     for n in self._head_counts)
+
+    @property
+    def cache_values_per_token(self) -> int:
+        return 2 * self.heads_held[1] * self.head_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class LinearHybridConfig(HeadShareConfig):
     """Delta-rule linear-attention layers ("kda": Kimi Delta Attention,
     arXiv 2510.26692) beside gated softmax GQA layers without rotary
     positions, every layer with routed experts (``RoutedExpertsConfig``),
-    held as one rank's share of heads and experts.
+    held as one rank's share of heads and experts (``HeadShareConfig``).
 
     Layer ``i`` is softmax GQA where ``i`` is in ``softmax_layers`` and a
-    linear layer otherwise. ``num_heads``, ``num_kv_heads`` and
-    ``linear_heads`` are the PUBLISHED counts; this chip holds rank
-    ``head_rank`` of ``head_shards`` equal ranges of each (``heads_held``),
-    as tensor parallelism would cut them. A linear layer keeps, per row and
-    held head, a ``linear_head_dim x linear_head_dim`` float32 state and the
-    last ``conv_width - 1`` inputs of its short convolution; a softmax layer
+    linear layer otherwise. A linear layer keeps, per row and held head, a
+    ``linear_head_dim x linear_head_dim`` float32 state and the last
+    ``conv_width - 1`` inputs of its short convolution; a softmax layer
     keeps k and v per held KV head and position. ``gate_rank`` is the width
     of the two low-rank gate projections (decay and output).
     """
@@ -311,17 +414,10 @@ class LinearHybridConfig(RoutedExpertsConfig):
     linear_head_dim: int = 128
     conv_width: int = 4
     gate_rank: int = 128
-    head_shards: int = 1
-    head_rank: int = 0
 
-    def __post_init__(self):
-        super().__post_init__()
-        for n in (self.num_heads, self.num_kv_heads, self.linear_heads):
-            if n % max(self.head_shards, 1):
-                raise ValueError(f"{n} heads do not divide over "
-                                 f"{self.head_shards} ranks")
-        if not 0 <= self.head_rank < self.head_shards:
-            raise ValueError("head_rank outside head_shards")
+    @property
+    def _head_counts(self) -> Tuple[int, int, int]:
+        return self.num_heads, self.num_kv_heads, self.linear_heads
 
     @property
     def mixer_kinds(self) -> Tuple[str, ...]:
@@ -333,20 +429,8 @@ class LinearHybridConfig(RoutedExpertsConfig):
         return ("moe",) * self.num_layers
 
     @property
-    def heads_held(self) -> Tuple[int, int, int]:
-        from fairness_llm_tpu.parallel.sharding import held_heads
-
-        return tuple(held_heads(n, self.head_shards, self.head_rank)[1]
-                     for n in (self.num_heads, self.num_kv_heads,
-                               self.linear_heads))
-
-    @property
     def attention_gate(self) -> bool:
         return True
-
-    @property
-    def cache_values_per_token(self) -> int:
-        return 2 * self.heads_held[1] * self.head_dim
 
     @property
     def layer_cache_values(self) -> Tuple[int, ...]:
@@ -369,6 +453,73 @@ class LinearHybridConfig(RoutedExpertsConfig):
         c = hl * self.linear_head_dim
         return (4 * d * c + 2 * (d + c) * self.gate_rank + d * hl
                 + 3 * c * self.conv_width + c + hl + self.linear_head_dim)
+
+
+@dataclasses.dataclass(frozen=True)
+class WindowHybridConfig(HeadShareConfig):
+    """GQA layers that see a window beside GQA layers that see everything
+    (the ``afmoe`` block of Arcee's Trinity models), held as one rank's
+    share of heads and experts (``HeadShareConfig``).
+
+    Layer ``i`` is ``layer_types[i]`` (the list may run past a depth that was
+    cut): a "sliding_attention" layer rotates
+    its queries and keys and sees ``sliding_window`` back, so the slot pool
+    keeps a ring of it (``retained_len``); a "full_attention" layer rotates
+    nothing and sees, and keeps, every position. Every layer norms each
+    query and key head before any rotation (``qk_norm``), gates its
+    attention's output (``attention_gate``) and norms what its mixer and its
+    MLP give before adding it (``post_norms``). The first
+    ``first_dense_layers`` have a dense MLP of ``d_ff``, the rest routed
+    experts whose choice, and only whose choice, takes a per-expert bias
+    (``router_bias``).
+    """
+
+    layer_types: Tuple[str, ...] = ()
+    first_dense_layers: int = 0
+
+    def __post_init__(self):
+        super().__post_init__()
+        if len(self.layer_types) < self.num_layers or set(
+                self.layer_types) - {"sliding_attention", "full_attention"}:
+            raise ValueError("layer_types must name sliding_attention or "
+                             "full_attention for each of the layers")
+        if "sliding_attention" in self.layer_types and not self.sliding_window:
+            raise ValueError("a sliding_attention layer needs sliding_window")
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        return tuple("dense" if i < self.first_dense_layers else "moe"
+                     for i in range(self.num_layers))
+
+    @property
+    def layer_windows(self) -> Tuple[Optional[int], ...]:
+        return tuple(self.sliding_window if rotates else None
+                     for rotates in self.layer_rotary)
+
+    @property
+    def layer_rotary(self) -> Tuple[bool, ...]:
+        return tuple(t == "sliding_attention"
+                     for t in self.layer_types[:self.num_layers])
+
+    @property
+    def attention_gate(self) -> bool:
+        return True
+
+    @property
+    def qk_norm(self) -> bool:
+        return True
+
+    @property
+    def post_norms(self) -> bool:
+        return True
+
+    @property
+    def router_bias(self) -> bool:
+        return True
+
+    def mixer_param_count(self, kind: str) -> int:
+        hq, hkv, _ = self.heads_held
+        return self.d_model * self.head_dim * (3 * hq + 2 * hkv)
 
 
 MODEL_CONFIGS = {
@@ -428,6 +579,19 @@ MODEL_CONFIGS = {
         pos_emb="none", softmax_layers=(0,), linear_heads=4,
         linear_head_dim=16, gate_rank=8, num_experts=16,
         experts_per_token=4, expert_d_ff=32, num_shared_experts=1,
+    ),
+    # Tiny window/full hybrid (tests): two periods of three window layers
+    # (rotary, window 16, a ring of 128 in the slot pool) and one full layer
+    # (no rotary); layers 0-1 dense, then 16 experts top-4, all held.
+    "tiny-window-moe": WindowHybridConfig(
+        name="tiny-window-moe", vocab_size=512, num_layers=8, num_heads=4,
+        num_kv_heads=2, d_model=64, d_ff=128, head_dim=16, max_seq_len=1024,
+        eos_token_id=1, dtype="float32", use_flash_attention=False,
+        embed_scale=True, sliding_window=16,
+        layer_types=("sliding_attention",) * 3 + ("full_attention",)
+        + ("sliding_attention",) * 3 + ("full_attention",),
+        first_dense_layers=2, num_experts=16, experts_per_token=4,
+        expert_d_ff=32, num_shared_experts=1, routed_scaling=2.5,
     ),
     "gpt2-small": ModelConfig(
         name="gpt2-small", vocab_size=50257, num_layers=12, num_heads=12,

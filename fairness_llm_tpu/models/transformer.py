@@ -90,6 +90,33 @@ def _cache_write(buf: jnp.ndarray, upd: jnp.ndarray, index, write_offsets):
     return jax.vmap(one)(buf, upd, write_offsets)
 
 
+def _ring_view(rows: jnp.ndarray, last: jnp.ndarray, R: int) -> jnp.ndarray:
+    """A row-long ``[B, L]`` array by slot, as a ring of ``R`` that has been
+    written through slot ``last`` (``[B, 1]`` or a scalar) shows it: entry
+    ``r`` is ``rows[:, last - (last - r) mod R]``, and zero where that slot
+    is negative (the ring has not come round yet). The ring holds the ``R``
+    slots up to ``last`` rotated by ``last + 1``, so this is two gathers of
+    whole slices a row; one index an element costs 2 ms a step at 64 x 3072
+    on the chip (``PERF.md`` section 6, PR 35)."""
+    B, L = rows.shape
+    nxt = jnp.broadcast_to(jnp.reshape(last, (-1,)) + 1, (B,))  # [B]
+
+    def slices(operand, starts):
+        return jax.lax.gather(
+            operand, jnp.stack([jnp.arange(B, dtype=jnp.int32), starts],
+                               axis=1),
+            jax.lax.GatherDimensionNumbers(
+                offset_dims=(1,), collapsed_slice_dims=(0,),
+                start_index_map=(0, 1)),
+            (1, R), mode="promise_in_bounds")
+
+    # R zeros in front: slot ``last - R + 1`` sits at ``last + 1``
+    front = jnp.concatenate([jnp.zeros((B, R), rows.dtype), rows], axis=1)
+    newest = slices(front, nxt.astype(jnp.int32))  # slots last-R+1 .. last
+    turn = jnp.mod(-nxt, R).astype(jnp.int32)  # rotate right by nxt mod R
+    return slices(jnp.concatenate([newest, newest], axis=1), turn)
+
+
 @flax.struct.dataclass
 class LatentCache:
     """What a latent-attention (MLA) layer keeps per token: the normed
@@ -122,13 +149,22 @@ class KVCache:
     ``lengths`` counts real tokens per row (the next RoPE position).
 
     ``layers`` holds, per layer, whatever that layer keeps per request, and
-    the layer's class says which of two kinds its leaves are. A
-    ``LayerCache`` (k and v per KV head) or a ``LatentCache`` keeps something
-    per position: every leaf is ``[B, max_len, ...]``. A ``RowState`` keeps a
-    fixed state per row: every leaf is ``[B, ...]`` with no position axis.
+    the layer's class and its leaves' length say which of three kinds they
+    are. A ``LayerCache`` (k and v per KV head) or a ``LatentCache`` keeps
+    something per position: every leaf is ``[B, max_len, ...]``. A
+    ``LayerCache`` of a layer that sees only a window may be a RING: every
+    leaf is ``[B, R, ...]`` with ``R < max_len``, slot ``j`` of the row lives
+    at ``j mod R``, and a ring slot holds the newest slot written there.
+    Which slot that is, whether it is valid and at what position its key
+    stands are not stored per ring: they follow from where the call writes
+    and from the one ``key_valid`` / ``key_positions`` of the row
+    (``Attention``), so a recycled slot shows its last tenant in a ring no
+    more than in a full leaf. A ``RowState`` keeps a fixed state per row:
+    every leaf is ``[B, ...]`` with no position axis.
     That is all the step programs rely on (``runtime/stepbuilder.py``): a
-    per-position leaf is gathered over its ``[:P]`` extent, may take a
-    donor's positions, and is scattered back by slot and extent; a per-row
+    per-position leaf is gathered over the ``[:P]`` extent of its own length,
+    may take a donor's positions (rolled within its own length), and is
+    scattered back by slot and extent; a per-row
     leaf is gathered and scattered by slot alone, starts from zero in the
     prefill call that names its row (one call, as wide as the bucket), and
     can be copied from no other row, whose state stands at that row's own
@@ -154,10 +190,18 @@ class KVCache:
 MOE_COUNTERS = ("moe_tokens", "moe_local_pairs", "moe_fullest_expert_pairs")
 
 
-def init_cache(config: ModelConfig, batch_size: int, max_len: int, dtype=None) -> KVCache:
+def init_cache(config: ModelConfig, batch_size: int, max_len: int, dtype=None,
+               ring: bool = False) -> KVCache:
+    """A fresh cache of ``max_len`` positions a row. ``ring``: the slot
+    pool's form, in which a layer that sees a window keeps
+    ``config.retained_len(max_len)`` of them; otherwise (the static engine,
+    the parity tests' oracle) every layer keeps every position and a window
+    is a mask. An int8 cache keeps every position too (no int8 ring)."""
     dtype = dtype or _dtype_of(config)
     _, kv_heads, linear_heads = config.heads_held
     shape = (batch_size, max_len, kv_heads, config.head_dim)
+    kept = config.retained_len(max_len) if ring and not config.kv_cache_quant \
+        else (max_len,) * config.num_layers
     if config.row_state_bytes:
         if config.kv_cache_quant:
             raise NotImplementedError("no int8 form of a per-row state")
@@ -192,10 +236,10 @@ def init_cache(config: ModelConfig, batch_size: int, max_len: int, dtype=None) -
             for _ in range(config.num_layers)
         )
     else:
-        layers = tuple(
-            LayerCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
-            for _ in range(config.num_layers)
-        )
+        def leaf(n):
+            return jnp.zeros((batch_size, n) + shape[2:], dtype)
+
+        layers = tuple(LayerCache(k=leaf(n), v=leaf(n)) for n in kept)
     return KVCache(
         layers=layers,
         key_valid=jnp.zeros((batch_size, max_len), jnp.bool_),
@@ -404,7 +448,36 @@ class QuantDense(nn.Module):
 
 
 class Attention(nn.Module):
+    """GQA softmax attention. The window and the rotation are this LAYER's
+    (``config.layer_windows[layer]``, ``config.layer_rotary[layer]``), not
+    the model's: a query at position ``p`` sees a key at ``q <= p`` iff ``p -
+    q < window`` (None: every earlier key), and queries and keys are rotated
+    or not.
+
+    A cache leaf shorter than the row (``[B, R, ...]`` against ``key_valid``
+    ``[B, L]``, ``R < L``) is a RING, which only a window layer of the slot
+    pool has: slot ``j`` of the row lives at ``j mod R``. After this call has
+    written through slot ``last``, ring slot ``r`` holds slot ``j_r = last -
+    (last - r) mod R`` (nothing while that is negative); its validity and
+    its key's position are the row's ``key_valid`` / ``key_positions`` at
+    ``j_r``, gathered here, and the causal rule is over ``j_r``. What a ring
+    no longer holds lies a window or more behind every query the call makes,
+    provided the call writes no more than ``R - window + 1`` slots
+    (``configs.ring_len``); a ring slot whose claimed slot was never written
+    by this tenant (a donor that had moved on: ``scheduler._plan_prefill``)
+    claims a position outside every later query's window and is overwritten
+    when the ring comes round."""
+
     config: ModelConfig
+    layer: int = 0  # which layer's answers to ask of the configuration
+
+    @property
+    def window(self) -> Optional[int]:
+        return self.config.layer_windows[self.layer]
+
+    @property
+    def rotary(self) -> bool:
+        return self.config.layer_rotary[self.layer]
 
     def _flash_ok(self, seq_len: int, left_padded: bool) -> bool:
         """Static gate for the Pallas fast path.
@@ -472,7 +545,7 @@ class Attention(nn.Module):
         from fairness_llm_tpu.ops import flash_attention
         from fairness_llm_tpu.parallel.sharding import current_mesh
 
-        window = self.config.sliding_window
+        window = self.window
 
         def call(q, k, v, lengths):
             return flash_attention(q, k, v, lengths, causal=True, window=window)
@@ -521,7 +594,7 @@ class Attention(nn.Module):
         q_ok = seq_len == 1 or (multi_q and seq_len <= 16)
         if not (cfg.use_decode_attention_kernel and q_ok and cache_layer is not None):
             return False
-        if cfg.sliding_window is not None:
+        if self.window is not None:
             return False
         if cfg.attention_impl != "xla" or jax.default_backend() != "tpu":
             return False
@@ -593,12 +666,47 @@ class Attention(nn.Module):
         k = dense(kv_dim, "kv_heads", "k_proj")(x).reshape(B, S, Hkv, cfg.head_dim)
         v = dense(kv_dim, "kv_heads", "v_proj")(x).reshape(B, S, Hkv, cfg.head_dim)
 
-        if cfg.pos_emb == "rope":
+        if cfg.qk_norm:
+            # Per head, over head_dim, a learned scale; before any rotation.
+            with jax.named_scope("attn.qk_norm"):
+                q = RMSNorm(eps=cfg.norm_eps, name="q_norm")(q)
+                k = RMSNorm(eps=cfg.norm_eps, name="k_norm")(k)
+
+        if self.rotary:
             q = rope(q, positions, cfg.rope_theta)
             k = rope(k, positions, cfg.rope_theta)
 
         if cfg.attention_impl == "ring" and cache_layer is not None:
             raise ValueError("attention_impl='ring' supports only the no-cache path")
+
+        # A leaf shorter than the row is a ring (see the class docstring):
+        # the write lands at the slot modulo the ring's length.
+        ring = cache_layer is not None and \
+            cache_layer.k.shape[1] < key_valid.shape[1]
+        if ring:
+            R = cache_layer.k.shape[1]
+            if self.window is None or cfg.kv_cache_quant:
+                raise ValueError("only a bfloat16/float32 window layer "
+                                 "keeps a ring")
+            if shared_kv is not None:
+                raise NotImplementedError(
+                    "a shared prefix (the static engine's prefix cache) is "
+                    "not built over a ring: the engine keeps every position")
+            if write_offsets is not None and S > 1:
+                raise NotImplementedError(
+                    "a multi-token verify step over a ring is not built "
+                    "(its rejected slots have already overwritten what they "
+                    "would roll back to): no speculation over a ring")
+            if write_offsets is None and (R % S or R < self.window + S - 1):
+                raise ValueError(
+                    f"a call of {S} positions into a ring of {R} (window "
+                    f"{self.window}) would wrap inside the call or lose a "
+                    "key its first query still sees")
+            last = (cache_index if write_offsets is None
+                    else write_offsets[:, None]) + (S - 1)  # [] or [B, 1]
+            cache_index = None if cache_index is None else cache_index % R
+            write_offsets = None if write_offsets is None \
+                else write_offsets % R
 
         # Shared cache write (prefill records the prompt for later decode steps).
         if cache_layer is not None:
@@ -629,9 +737,9 @@ class Attention(nn.Module):
 
             out = ring_attention(
                 q, k, v, positions, positions, key_valid,
-                axis_name="sp", window=cfg.sliding_window,
+                axis_name="sp", window=self.window,
             ).astype(dtype)
-        elif shared_kv is None and self._flash_ok(S, left_padded):
+        elif shared_kv is None and not ring and self._flash_ok(S, left_padded):
             # Training (no cache) or first prefill (cache present but empty —
             # S > 1 is the engine's static marker; a chunked-prefill caller
             # must set use_flash_attention=False). In both cases the NEW k/v
@@ -683,55 +791,67 @@ class Attention(nn.Module):
                     shared_kv=sh, q_offsets=write_offsets,
                 ).reshape(B, S, H, cfg.head_dim)
         else:
-            if cache_layer is not None:
-                K = keys.shape[1]
-                j_idx = jnp.arange(K)[None, :]
-                if write_offsets is None:
-                    # causal: new query i (global slot index+i) sees key slot
-                    # j iff j <= index+i
-                    q_idx = cache_index + jnp.arange(S)[:, None]
-                    causal = (j_idx <= q_idx)[None, :, :]  # [1, S, K]
+            with jax.named_scope(
+                    "attn.full" if self.window is None else "attn.window"):
+                kv_valid, kv_positions = key_valid, key_positions
+                if ring:
+                    # What each ring slot holds once this call has written
+                    # through slot ``last``, and whether and where it counts.
+                    held = last - jnp.mod(last - jnp.arange(R)[None, :], R)
+                    held = jnp.broadcast_to(held, (B, R))
+                    kv_valid = _ring_view(key_valid, last, R)
+                    kv_positions = _ring_view(key_positions, last, R)
+                    q_idx = last[..., None] - (S - 1) + jnp.arange(S)[:, None]
+                    causal = held[:, None, :] <= q_idx  # [B, S, R]
+                elif cache_layer is not None:
+                    K = keys.shape[1]
+                    j_idx = jnp.arange(K)[None, :]
+                    if write_offsets is None:
+                        # causal: new query i (global slot index+i) sees key slot
+                        # j iff j <= index+i
+                        q_idx = cache_index + jnp.arange(S)[:, None]
+                        causal = (j_idx <= q_idx)[None, :, :]  # [1, S, K]
+                    else:
+                        # per-row window: query i of row b wrote slot offsets[b]+i
+                        q_idx = (
+                            write_offsets[:, None, None]
+                            + jnp.arange(S)[None, :, None]
+                        )  # [B, S, 1]
+                        causal = j_idx[None, :, :] <= q_idx  # [B, S, K]
                 else:
-                    # per-row window: query i of row b wrote slot offsets[b]+i
-                    q_idx = (
-                        write_offsets[:, None, None]
-                        + jnp.arange(S)[None, :, None]
-                    )  # [B, S, 1]
-                    causal = j_idx[None, :, :] <= q_idx  # [B, S, K]
-            else:
-                K = S
-                causal = jnp.tril(jnp.ones((S, S), jnp.bool_))[None, :, :]
+                    K = S
+                    causal = jnp.tril(jnp.ones((S, S), jnp.bool_))[None, :, :]
 
-            allowed = causal & key_valid[:, None, :]  # [B, S, K]
-            if cfg.sliding_window is not None:
-                delta = positions[:, :, None] - key_positions[:, None, :]
-                allowed = allowed & (delta < cfg.sliding_window)
+                allowed = causal & kv_valid[:, None, :]  # [B, S, K]
+                if self.window is not None:
+                    delta = positions[:, :, None] - kv_positions[:, None, :]
+                    allowed = allowed & (delta < self.window)
 
-            # GQA: query head h reads kv head h // rep, so q splits into
-            # [Hkv, rep] and both contractions keep the kv-head axis: the
-            # cache is read once, never written out rep times.
-            rep = H // Hkv
-            q5 = q.reshape(B, S, Hkv, rep, cfg.head_dim)
+                # GQA: query head h reads kv head h // rep, so q splits into
+                # [Hkv, rep] and both contractions keep the kv-head axis: the
+                # cache is read once, never written out rep times.
+                rep = H // Hkv
+                q5 = q.reshape(B, S, Hkv, rep, cfg.head_dim)
 
-            scale = cfg.head_dim ** -0.5
-            scores = jnp.einsum("bqgrd,bkgd->bgrqk", q5, keys).astype(jnp.float32) * scale
-            scores = jnp.where(allowed[:, None, None, :, :], scores, -1e30)
-            if shared_kv is not None:
-                sk, sv = shared_kv  # [Pc, Hkv, D]
-                # [B,Hkv,rep,S,Pc] — note sk has no batch dim: read once, not B times
-                s_sh = jnp.einsum("bqgrd,kgd->bgrqk", q5, sk.astype(q.dtype)).astype(jnp.float32) * scale
-                if cfg.sliding_window is not None:
-                    sh_pos = jnp.arange(sk.shape[0])
-                    sh_allowed = (positions[:, :, None] - sh_pos[None, None, :]) < cfg.sliding_window
-                    s_sh = jnp.where(sh_allowed[:, None, None, :, :], s_sh, -1e30)
-                joint = jnp.concatenate([s_sh, scores], axis=-1)
-                probs = jax.nn.softmax(joint, axis=-1).astype(dtype)
-                p_sh, p_own = probs[..., : sk.shape[0]], probs[..., sk.shape[0]:]
-                out = jnp.einsum("bgrqk,kgd->bqgrd", p_sh, sv.astype(dtype))
-                out = out + jnp.einsum("bgrqk,bkgd->bqgrd", p_own, values)
-            else:
-                probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
-                out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, values)
+                scale = cfg.head_dim ** -0.5
+                scores = jnp.einsum("bqgrd,bkgd->bgrqk", q5, keys).astype(jnp.float32) * scale
+                scores = jnp.where(allowed[:, None, None, :, :], scores, -1e30)
+                if shared_kv is not None:
+                    sk, sv = shared_kv  # [Pc, Hkv, D]
+                    # [B,Hkv,rep,S,Pc] — note sk has no batch dim: read once, not B times
+                    s_sh = jnp.einsum("bqgrd,kgd->bgrqk", q5, sk.astype(q.dtype)).astype(jnp.float32) * scale
+                    if self.window is not None:
+                        sh_pos = jnp.arange(sk.shape[0])
+                        sh_allowed = (positions[:, :, None] - sh_pos[None, None, :]) < self.window
+                        s_sh = jnp.where(sh_allowed[:, None, None, :, :], s_sh, -1e30)
+                    joint = jnp.concatenate([s_sh, scores], axis=-1)
+                    probs = jax.nn.softmax(joint, axis=-1).astype(dtype)
+                    p_sh, p_own = probs[..., : sk.shape[0]], probs[..., sk.shape[0]:]
+                    out = jnp.einsum("bgrqk,kgd->bqgrd", p_sh, sv.astype(dtype))
+                    out = out + jnp.einsum("bgrqk,bkgd->bqgrd", p_own, values)
+                else:
+                    probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
+                    out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, values)
 
         out = out.reshape(B, S, q_dim)
         if cfg.attention_gate:
@@ -1097,15 +1217,22 @@ class DeltaRuleAttention(nn.Module):
         return out, new_cache
 
 
-def route_tokens(x: jnp.ndarray, router: jnp.ndarray, cfg: ModelConfig):
+def route_tokens(x: jnp.ndarray, router: jnp.ndarray, cfg: ModelConfig,
+                 bias: Optional[jnp.ndarray] = None):
     """``(chosen [T, k] int32 over ALL experts, weights [T, k] float32)``:
     sigmoid scores in float32 at the highest matmul precision, the k largest
     (ties to the lower index), normalised over the chosen, times
-    ``routed_scaling``."""
+    ``routed_scaling``. ``bias`` [experts], where the configuration has one,
+    is added to the scores to CHOOSE and never to weigh."""
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    top, chosen = jax.lax.top_k(scores, cfg.experts_per_token)
+    if bias is None:
+        top, chosen = jax.lax.top_k(scores, cfg.experts_per_token)
+    else:
+        _, chosen = jax.lax.top_k(scores + bias.astype(jnp.float32),
+                                  cfg.experts_per_token)
+        top = jnp.take_along_axis(scores, chosen, axis=-1)
     if cfg.norm_topk_prob:
         top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
     return chosen.astype(jnp.int32), top * cfg.routed_scaling
@@ -1116,7 +1243,8 @@ class SparseMLP(nn.Module):
 
     ``p = sigmoid(x W_g)`` over ALL experts, in float32 (with random or
     trained weights a near-tie picks another expert in bfloat16); the
-    ``experts_per_token`` largest; ``w = routed_scaling * p_sel / sum(p_sel)``
+    ``experts_per_token`` largest (of ``p + b`` where the configuration has
+    a selection bias ``b``: ``router_bias``); ``w = routed_scaling * p_sel / sum(p_sel)``
     over all the chosen, held here or not; ``y = Shared(x) + sum_i w_i
     E_i(x)`` over the chosen experts this rank holds
     (``config.experts_held``). What the other ranks' experts would add is
@@ -1146,9 +1274,11 @@ class SparseMLP(nn.Module):
                 ("experts_up", (d, ff), ("embed", "ff")),
                 ("experts_down", (ff, d), ("ff", "embed")))
         ]
+        bias = self.param("router_bias", nn.initializers.zeros,
+                          (cfg.num_experts,)) if cfg.router_bias else None
         x2 = x.reshape(B * S, d)
         with jax.named_scope("moe.route"):
-            chosen, top = route_tokens(x2, router, cfg)
+            chosen, top = route_tokens(x2, router, cfg, bias)
         # Kept only by an apply that asks for "intermediates" (a diagnostic:
         # perfbench/tools/router_flips.py); otherwise not even traced.
         self.sow("intermediates", "chosen", chosen.reshape(B, S, -1))
@@ -1167,6 +1297,7 @@ class Block(nn.Module):
     config: ModelConfig
     kind: str = "dense"  # this layer's MLP: config.layer_kinds[i]
     mixer: str = "gqa"  # this layer's mixer: config.mixer_kinds[i]
+    layer: int = 0  # i: a GQA layer asks the configuration its window by it
 
     @nn.compact
     def __call__(self, x, positions, cache_layer, cache_index, key_valid, key_positions,
@@ -1179,20 +1310,28 @@ class Block(nn.Module):
                 normed, cache_layer, token_valid, shared_kv=shared_kv,
                 write_offsets=write_offsets)
         else:
-            attention = LatentAttention if self.mixer == "mla" else Attention
-            attn_out, new_cache = attention(self.config, name="attn")(
+            attention = LatentAttention(self.config, name="attn") \
+                if self.mixer == "mla" else Attention(
+                    self.config, layer=self.layer, name="attn")
+            attn_out, new_cache = attention(
                 normed, positions, cache_layer, cache_index, key_valid,
                 key_positions, left_padded=left_padded, shared_kv=shared_kv,
                 write_offsets=write_offsets,
             )
+        # ``post_norms``: h = x + N2(Mixer(N1(x))), y = h + N4(MLP(N3(h))).
+        post = self.config.post_norms
+        if post:
+            attn_out = _norm(self.config, "attn_post_norm")(attn_out)
         x = x + attn_out
         counters = None
         if self.kind == "moe":
             y, counters = SparseMLP(self.config, name="mlp")(
                 _norm(self.config, "mlp_norm")(x), token_valid)
-            x = x + y
         else:
-            x = x + MLP(self.config, name="mlp")(_norm(self.config, "mlp_norm")(x))
+            y = MLP(self.config, name="mlp")(_norm(self.config, "mlp_norm")(x))
+        if post:
+            y = _norm(self.config, "mlp_post_norm")(y)
+        x = x + y
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         return x, new_cache, counters
 
@@ -1268,7 +1407,7 @@ class Transformer(nn.Module):
                                               cfg.mixer_kinds)):
             layer_cache = cache.layers[i] if cache is not None else None
             x, new_layer, counted = Block(cfg, kind=kind, mixer=mixer,
-                                          name=f"layer_{i}")(
+                                          layer=i, name=f"layer_{i}")(
                 x, positions,
                 layer_cache, cache.index if cache is not None else None,
                 key_valid, key_positions, left_padded=left_padded,

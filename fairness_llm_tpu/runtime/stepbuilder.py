@@ -66,6 +66,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from fairness_llm_tpu.models.configs import EXTEND_WINDOW
 from fairness_llm_tpu.models.transformer import RowState, init_cache
 from fairness_llm_tpu.parallel.sharding import require_gqa
 from fairness_llm_tpu.runtime.sampling import (
@@ -551,11 +552,17 @@ def build_serve_step(cfg, model, sampler: SamplerSettings, pad_id: int,
 # -- serving prefill programs --------------------------------------------------
 
 
-#: New tokens a ``serve_prefill`` call computes per row. Prompt buckets are
-#: multiples of the sequence bucket (64 or 128), so 64 divides every ``P``; it
-#: is the smallest such width that covers the study's divergent tails (17-37
-#: ids) in one window. 128 measured on the chip: ``PERF.md`` section 6, PR 29.
-EXTEND_WINDOW = 64
+# ``EXTEND_WINDOW``, the new tokens a ``serve_prefill`` call computes per row,
+# is ``models/configs.py``'s: a window layer's ring is sized against it.
+
+
+#: Windows in a bucket from which ``serve_prefill`` builds its riders'
+#: donor rows under a ``lax.cond`` on "a row of this call copies". A static
+#: choice on the bucket: the rolled rows cost a call ``(L + P) / S`` of its
+#: window's own traffic, 3% at the 3-5 windows of a 192-320 bucket and two
+#: fifths at 126 (measured at those two ends alone: ``PERF.md`` section 6,
+#: PR 35); 16 windows is a bucket of 1024 positions.
+DONOR_COND_WINDOWS = 16
 
 
 def extend_window(cfg, P: int) -> int:
@@ -604,6 +611,16 @@ def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
        ``guard`` a batch whose logits are not finite drops every row, and
        the pool it returns is the pool it was given.
 
+    A per-position leaf has its OWN length: ``L`` slots, or a window layer's
+    ring of ``R < L`` (``KVCache``), in which slot ``j`` lives at ``j mod R``.
+    Steps 1 and 4 take each leaf at its own length: its extent is its first
+    ``min(P, R)`` slots (the whole ring once the bucket outgrows it), the
+    donor's row is rolled within ``R``, and a ring slot takes the donor's
+    where the slot it holds just before this window, ``j_r = (w - 1) - (w -
+    1 - r) mod R``, is one of the copied ones. Validity needs no ring form:
+    the forward derives a ring slot's from the row's ``key_valid`` at
+    ``j_r``.
+
     A layer that keeps a fixed state per row (``RowState``: no position
     axis) is gathered and scattered by slot alone. Such a model's window is
     its whole bucket, so every row a call names begins in it: its leaves
@@ -626,10 +643,6 @@ def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
         own = jnp.minimum(slots, num_slots - 1)
         j = jnp.arange(P, dtype=jnp.int32)[None, :]
         lead = pad[:, None]
-        from_donor = (j >= lead) & (j < lead + copied[:, None])
-        # The donor's row rolled so that its slot donor_pad meets pad. The
-        # copied range never wraps: it lies inside the donor's own extent.
-        shift = jnp.mod(donor_pad - pad, L)
         # Each rider's donor row is sliced out of the pool by its slot. As
         # ONE gather of whole rows XLA stages the whole leaf for it in every
         # call, and where the leaf's tiling is not the gather's (4 KV
@@ -638,12 +651,16 @@ def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
         donor_slot = [jax.lax.index_in_dim(donor, r, keepdims=False)
                       for r in range(nb)]
 
-        def take(big):
+        def take(big, with_donor):
             # ``lax.gather`` spelled out: NumPy-style indexing and ``vmap``
             # cost several times as much to trace, per leaf, in every
             # prefill bucket's set-up.
             tail = big.shape[2:]
             offsets = tuple(range(1, big.ndim))
+            # The leaf's own length and extent; ``n < L`` is a ring, whose
+            # slot r holds the row's slot ``held`` just before this window.
+            n = big.shape[1]
+            extent = min(P, n)
 
             def rows_of(operand, starts, length, index_map):
                 return jax.lax.gather(
@@ -652,16 +669,25 @@ def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
                         start_index_map=index_map),
                     (1, length) + tail, mode="promise_in_bounds")
 
+            mine = rows_of(big, own[:, None], extent, (0,))
+            if not with_donor:
+                return mine
+            held = j[:, :extent] if n == L else \
+                (w - 1) - jnp.mod(w - 1 - j[:, :extent], n)
+            from_donor = (held >= lead) & (held < lead + copied[:, None])
+            # The donor's row rolled so that its slot donor_pad meets pad.
+            # The copied range never wraps a full leaf (it lies inside the
+            # donor's own extent); a ring is read round its end.
+            shift = jnp.mod(donor_pad - pad, n)
             theirs = jnp.concatenate([
                 jax.lax.dynamic_index_in_dim(big, d, 0) for d in donor_slot])
-            theirs = jnp.concatenate([theirs, theirs[:, :P]], axis=1)
+            theirs = jnp.concatenate([theirs, theirs[:, :extent]], axis=1)
             theirs = rows_of(
                 theirs, jnp.stack([jnp.arange(nb, dtype=jnp.int32), shift],
-                                  axis=1), P, (0, 1))
+                                  axis=1), extent, (0, 1))
             mask = from_donor.reshape(from_donor.shape
                                       + (1,) * (big.ndim - 2))
-            return jnp.where(mask, theirs,
-                             rows_of(big, own[:, None], P, (0,)))
+            return jnp.where(mask, theirs, mine)
 
         # Every row a call names begins in it where a state is kept: the
         # window of such a model is its whole bucket.
@@ -672,14 +698,33 @@ def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
             return jnp.where(fresh.reshape((nb,) + (1,) * (big.ndim - 1)),
                              jnp.zeros_like(rows), rows)
 
-        def take_layer(layer):
-            return jax.tree.map(
-                take_row_state if isinstance(layer, RowState) else take,
-                layer)
+        def extents(with_donor):
+            """Every layer's leaves and ``key_positions`` over the rows'
+            extents, with the donors' positions laid over them or not."""
+            def per_position(big):
+                return take(big, with_donor)
 
+            return tuple(
+                jax.tree.map(take_row_state if isinstance(layer, RowState)
+                             else per_position, layer)
+                for layer in cache.layers), per_position(cache.key_positions)
+
+        # A row copies in the call of its first window alone, so in a long
+        # bucket most calls (every window of a batch that found no donor,
+        # every later window of a rider) name no copying row: those build no
+        # donor's rolled rows, which at 126 windows a bucket were two fifths
+        # of a call's time on the chip (``PERF.md`` section 6, PR 35). In a
+        # bucket of a few windows they are a few hundredths, fused into the
+        # extent's own gather, and the program stays the one it was.
+        if P // S >= DONOR_COND_WINDOWS:
+            gathered, own_positions = jax.lax.cond(
+                jnp.any(copied > 0), lambda: extents(True),
+                lambda: extents(False))
+        else:
+            gathered, own_positions = extents(True)
         view = cache.replace(
-            layers=tuple(map(take_layer, cache.layers)),
-            key_positions=take(cache.key_positions),
+            layers=gathered,
+            key_positions=own_positions,
             key_valid=(j >= lead) & (j < w),
             lengths=jnp.maximum(w - pad, 0),
             index=w,
@@ -703,7 +748,7 @@ def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
             slots = jnp.where(finite, slots, num_slots)
 
         def scat(big, rows):
-            return big.at[slots, :P].set(rows, mode="drop")
+            return big.at[slots, :rows.shape[1]].set(rows, mode="drop")
 
         def scat_layer(layer, rows):
             if isinstance(layer, RowState):

@@ -299,10 +299,24 @@ class ContinuousScheduler:
         # Persistent device state: the shared KV cache (one private row a
         # slot) + each slot's carried next-token logits (f32 — what the
         # sampler consumes).
-        self._cache = init_cache(cfg, self.num_slots, self.cache_len)
+        self._cache = init_cache(cfg, self.num_slots, self.cache_len,
+                                 ring=True)
         # Bytes of per-row state a slot holds whatever its length (0 unless
         # a layer is recurrent): the plan takes no donor for such a model.
         self._row_state_bytes = cfg.row_state_bytes
+        # What a row's per-position leaves hold, from shapes: the bytes all
+        # its layers keep (every step reads every slot's), the lengths kept,
+        # and the (window, length) of each ring, a window layer's leaf
+        # shorter than the row, which bounds how long a resident row stays a
+        # donor (``_ring_holds``).
+        kept = [(w, jax.tree.leaves(layer)) for w, layer in zip(
+            cfg.layer_windows, self._cache.layers)
+            if not isinstance(layer, RowState)]
+        self._kv_row_bytes = sum(
+            x.nbytes for _, leaves in kept for x in leaves) // self.num_slots
+        self._retained = sorted({leaves[0].shape[1] for _, leaves in kept})
+        self._rings = sorted({(w, leaves[0].shape[1]) for w, leaves in kept
+                              if leaves[0].shape[1] < self.cache_len})
         self._prev_logits = jnp.zeros(
             (self.num_slots, cfg.vocab_size), jnp.float32
         )
@@ -415,15 +429,22 @@ class ContinuousScheduler:
         )
 
         mem = get_memory_ledger()
-        # Two kinds of leaf, two pools: what a layer keeps per position
-        # (and the pool's own masks), and what it keeps per row whatever
-        # the positions (a recurrent state), which no length bounds.
+        # Three kinds of leaf, three pools: what a layer keeps of every
+        # position (and the pool's own masks), a window layer's ring,
+        # shorter than the row, and what a layer keeps per row whatever the
+        # positions (a recurrent state), which no length bounds.
         per_row = [l for l in self._cache.layers if isinstance(l, RowState)]
+        rings = [l for l in self._cache.layers
+                 if not isinstance(l, RowState)
+                 and jax.tree.leaves(l)[0].shape[1] < self.cache_len]
         mem.register("kv_contiguous", f"{self._mem_handle}:cache",
                      self._cache.replace(layers=tuple(
                          l for l in self._cache.layers
-                         if not isinstance(l, RowState))),
+                         if not any(l is x for x in per_row + rings))),
                      replica=self.replica)
+        if rings:
+            mem.register("kv_window", f"{self._mem_handle}:rings", rings,
+                         replica=self.replica)
         if per_row:
             mem.register("row_state", f"{self._mem_handle}:row_state",
                          per_row, replica=self.replica)
@@ -1172,6 +1193,11 @@ class ContinuousScheduler:
             if self._row_state_bytes:
                 # No donor was looked for: a layer keeps a per-row state.
                 plan_span.set(donorless="row_state")
+            if self._rings:
+                # What each kind of layer keeps of a row, and the rows whose
+                # best donor's ring had moved past what they needed of it.
+                plan_span.set(retained=self._retained,
+                              ring_refused=plan["ring_refused"])
         return self._dispatch_prefill(plan, stats, injected_hang)
 
     def _plan_admission(self, n: int, stats: ServingStats):
@@ -1265,7 +1291,11 @@ class ContinuousScheduler:
         the fewest windows for which ``n - k S <= m``, ``n - k S`` positions
         come from the donor. A row with no such donor (or fewer than ``S``
         ids) runs every window that holds one of its tokens. Donors are rows
-        resident before this call: never a slot it writes.
+        resident before this call: never a slot it writes. Where a window
+        layer keeps a ring, the donor is the resident row with the longest
+        shared prefix among those whose rings still hold what the rider
+        needs of them (``_ring_holds``), and ``ring_refused`` counts the
+        riders whose longest-sharing row did not.
 
         A model of which a layer keeps a fixed state per row
         (``config.row_state_bytes``) takes no donor at all: a resident row's
@@ -1296,19 +1326,25 @@ class ContinuousScheduler:
         copied = np.zeros((nb,), np.int32)
         # The window a row starts in; batch-bucket pad rows start in none.
         first = np.full((nb,), P // S, np.int32)
+        ring_refused = 0
         for r, (req, ids, _) in enumerate(admitted):
             ids = np.asarray(ids[-P:], np.int32)
             n = len(ids)
             tokens[r, P - n:] = ids
             lead[r] = P - n
             first[r] = min((P - n) // S, P // S - 1)
-            src, m = live.longest(ids)
-            k = max(1, -(-(n - m) // S))
-            if src is not None and n - k * S > 0:
+            for at, (src, m) in enumerate(live.by_shared(ids)):
+                k = max(1, -(-(n - m) // S))
+                if n - k * S <= 0:
+                    break  # a shorter prefix saves no window either
                 st = self.pool.get(src)
+                if not self._ring_holds(st, n - k * S):
+                    ring_refused += at == 0
+                    continue
                 donor[r], donor_pad[r] = src, st.base - st.real_len
                 copied[r] = n - k * S
                 first[r] = P // S - k
+                break
             reqs.append(req)
         # Slots are claimed after every row has looked for its donor, so
         # that no row of this batch can be another's.
@@ -1354,7 +1390,19 @@ class ContinuousScheduler:
             prompt_tokens=int((P - lead).sum()),
             reused_tokens=int(copied.sum()),
             donors=int((copied > 0).sum()), calls=calls,
+            ring_refused=int(ring_refused),
         )
+
+    def _ring_holds(self, st: SlotState, copied: int) -> bool:
+        """Whether a resident row's rings still hold what a rider that
+        copies ``copied`` positions needs of them. The rider's first query
+        stands at position ``copied`` and sees ``window`` back, so it needs
+        the positions from ``copied - window + 1`` (or 0) on; a ring of
+        ``R`` holds the last ``R`` of the ``real_len + emitted`` positions
+        the row has written. True where no layer keeps a ring."""
+        written = st.real_len + st.emitted
+        return all(written - length <= max(0, copied - window + 1)
+                   for window, length in self._rings)
 
     def _dispatch_prefill(self, plan: dict, stats: ServingStats,
                           injected_hang: float) -> bool:
@@ -1445,6 +1493,7 @@ class ContinuousScheduler:
             self.breakers.record_success("prefill")
         stats.prefill_tokens += plan["prompt_tokens"]
         stats.prefill_tokens_reused += plan["reused_tokens"]
+        stats.prefill_donors_refused_ring += plan["ring_refused"]
         if self._row_state_bytes:
             # Each admitted row's state was zeroed in the batch's call.
             stats.row_state_resets += len(reqs)
@@ -1468,7 +1517,7 @@ class ContinuousScheduler:
             req = self.pool.release(slot).request
             self._requeue_or_fail(req, error, stats, cause=cause)
         self._cache = init_cache(
-            self.engine.config, self.num_slots, self.cache_len
+            self.engine.config, self.num_slots, self.cache_len, ring=True
         )
         self._prev_logits = jnp.zeros(
             (self.num_slots, self.engine.config.vocab_size), jnp.float32
@@ -1680,6 +1729,9 @@ class ContinuousScheduler:
         # idle (the step program runs over the whole pool): from shapes.
         stats.row_state_bytes_stepped += \
             steps * self.num_slots * self._row_state_bytes
+        # Likewise every slot's keys and values, as far as each layer
+        # retains them: the full layers' rows and the window layers' rings.
+        stats.kv_bytes_stepped += steps * self.num_slots * self._kv_row_bytes
         # Performance attribution (telemetry/): the chunk's span on this
         # scheduler's timeline track (the gap to the previous chunk feeds
         # the step_gap_s histogram — the per-step host sync ROADMAP item 3
@@ -1715,7 +1767,7 @@ class ContinuousScheduler:
         observe_decode(
             self.engine.config,
             {"batch": self.num_slots, "cache_slots": self.cache_len,
-             "prefix_len": 0},
+             "prefix_len": 0, "ring": True},
             steps, dc_wall, program=prog, labels=self.labels,
         )
         # Gap attribution (telemetry/costmodel.py): the chunk's measured

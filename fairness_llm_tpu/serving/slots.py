@@ -62,19 +62,17 @@ class LiveRowPrefixes:
         for i, ids in enumerate(rows):
             self.ids[i, :len(ids)] = ids
 
-    def longest(self, ids: np.ndarray) -> Tuple[Optional[int], int]:
-        """(slot, shared ids) of the live row that shares most leading ids
-        with ``ids``, the lowest slot among equals; (None, 0) when no live
-        row shares the first."""
+    def by_shared(self, ids: np.ndarray) -> List[Tuple[int, int]]:
+        """(slot, shared ids) of every live row that shares the first id
+        with ``ids``, most shared first, the lower slot first among
+        equals."""
         if not self.slots:
-            return None, 0
+            return []
         n = min(len(ids), self.ids.shape[1])
         differ = self.ids[:, :n] != np.asarray(ids[:n])[None, :]
         shared = np.where(differ.any(axis=1), differ.argmax(axis=1), n)
-        best = int(shared.argmax())
-        if shared[best] == 0:
-            return None, 0
-        return self.slots[best], int(shared[best])
+        return [(self.slots[i], int(shared[i]))
+                for i in np.argsort(-shared, kind="stable") if shared[i]]
 
 
 class SlotPool:

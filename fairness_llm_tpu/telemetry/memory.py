@@ -71,6 +71,7 @@ logger = logging.getLogger(__name__)
 POOLS = (
     "params",         # engine parameter tree (per engine instance)
     "kv_contiguous",  # slot KV cache (scheduler._cache): per-position leaves
+    "kv_window",      # its window layers' rings: leaves shorter than the row
     "row_state",      # its per-row leaves: recurrent state, whatever the length
     "prefix_cache",   # engine prefix-KV LRU entries
     "logits_carry",   # per-slot carried next-token logits
@@ -489,14 +490,18 @@ def count_hlo_ops(hlo_text: str) -> Dict[str, int]:
     return counts
 
 
-def count_pool_copies(hlo_text: str, slots: int, cache_len: int) -> int:
+def count_pool_copies(hlo_text: str, slots: int, cache_len) -> int:
     """Copies of a whole ``[slots, cache_len, ...]`` leaf of the slot pool
     that a compiled module makes as instructions of their own: what a
     program pays per call where the pool's donation did not take, or where
-    a read of the leaf made XLA change its layout first. A copy inside a
-    fused computation is that fusion's read of the leaf in another layout,
-    not a second leaf, and is left out."""
-    copy = re.compile(rf"= \w+\[{slots},{cache_len}[,\]][^=]* copy\(")
+    a read of the leaf made XLA change its layout first. ``cache_len``: the
+    row's length, or every length a leaf of the pool has (a window layer's
+    ring is shorter than the row). A copy inside a fused computation is that
+    fusion's read of the leaf in another layout, not a second leaf, and is
+    left out."""
+    lengths = "|".join(str(n) for n in (
+        (cache_len,) if isinstance(cache_len, int) else cache_len))
+    copy = re.compile(rf"= \w+\[{slots},(?:{lengths})[,\]][^=]* copy\(")
     count, fused = 0, False
     for line in hlo_text.splitlines():
         if line.endswith("{") and not line.startswith(" "):

@@ -104,7 +104,8 @@ def decode_step_bytes(config, stats) -> int:
     KV cache (its remainder-prompt + generated slots), and the shared
     prefix KV once per step (read once for the whole batch — the
     prefix-cache win). ``stats`` carries ``batch`` / ``cache_slots`` /
-    ``prefix_len`` (the ``GenerateOutput.stats`` shape).
+    ``prefix_len`` (the ``GenerateOutput.stats`` shape), and ``ring`` where
+    the cache is the slot pool's.
 
     Param width: the COMPUTE dtype, not the storage dtype — the round-3
     device trace shows XLA hoists the f32->bf16 cast of a bf16-config
@@ -125,14 +126,22 @@ def decode_step_bytes(config, stats) -> int:
     # (k and v per KV head, or one latent): never 2 x n_kv x head_dim here.
     # A layer that keeps a state per row instead reads and writes it whole.
     per_token = sum(config.layer_cache_values)
+    # ``stats["ring"]``: the slot pool's form, in which a window layer keeps
+    # a ring shorter than ``cache_slots`` (``config.retained_len``; an int8
+    # cache keeps every position: ``models/transformer.init_cache``).
+    lengths = config.retained_len(stats["cache_slots"]) \
+        if stats.get("ring") and not config.kv_cache_quant \
+        else (stats["cache_slots"],) * config.num_layers
+    row_values = sum(n * v for n, v in zip(lengths,
+                                           config.layer_cache_values))
     if config.kv_cache_quant:
         # int8 values + the per-(slot, head) f32 scale the step also reads —
         # same accounting as parallel/sharding.per_device_kv_cache_bytes.
-        per_slot = per_token + 2 * config.num_kv_heads * 4 * config.num_layers
+        row = row_values + stats["cache_slots"] * \
+            2 * config.num_kv_heads * 4 * config.num_layers
     else:
-        per_slot = per_token * model_item
-    kv = stats["batch"] * (stats["cache_slots"] * per_slot
-                           + 2 * config.row_state_bytes)
+        row = row_values * model_item
+    kv = stats["batch"] * (row + 2 * config.row_state_bytes)
     # _prefix_fn dequantizes the shared prefix to the model dtype, so its
     # per-step read is model-dtype-wide even under kv_cache_quant.
     prefix = stats["prefix_len"] * per_token * model_item

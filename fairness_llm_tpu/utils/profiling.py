@@ -315,6 +315,13 @@ class ServingStats:
       decode steps read and wrote (steps x slots x bytes a row, from shapes:
       the step program streams every slot's state, live or idle), and rows
       whose state ``serve_prefill`` zeroed on admission
+    - ``kv_bytes_stepped``: bytes of keys and values (or latents) the decode
+      steps read (steps x slots x the bytes a row's layers RETAIN, from
+      shapes: every slot's, live or idle; a window layer's ring counts its
+      own length)
+    - ``prefill_donors_refused_ring``: admitted rows whose longest-sharing
+      resident row could not be their donor because a window layer's ring
+      had moved past what they needed of it
     """
 
     num_slots: int = 0
@@ -340,6 +347,8 @@ class ServingStats:
     moe_fullest_expert_pairs: int = 0
     row_state_bytes_stepped: int = 0
     row_state_resets: int = 0
+    kv_bytes_stepped: int = 0
+    prefill_donors_refused_ring: int = 0
 
     @property
     def tokens_per_step(self) -> float:

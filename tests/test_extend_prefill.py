@@ -386,6 +386,11 @@ def test_admission_runs_no_program_but_its_own(dense, caplog):
 # -- the host's match --------------------------------------------------------------
 
 
+def _longest(live, ids):
+    """The row the plan tries first: ``by_shared``'s head."""
+    return next(iter(live.by_shared(ids)), (None, 0))
+
+
 def test_live_row_prefixes_take_the_longest_share_and_the_lowest_slot():
     pool = SlotPool(4)
     rows = [np.arange(10, 30), np.arange(10, 40), np.arange(10, 40),
@@ -394,13 +399,15 @@ def test_live_row_prefixes_take_the_longest_share_and_the_lowest_slot():
         pool.alloc(SlotState(request=Request(prompt=f"p{i}"), base=64,
                              real_len=len(ids), ids=ids))
     match = LiveRowPrefixes(pool)
-    assert match.longest(np.arange(10, 35)) == (1, 25)  # slots 1 and 2 tie
-    assert match.longest(np.arange(10, 20)) == (0, 10)  # capped by the row
-    assert match.longest(np.arange(10, 60)) == (1, 30)  # capped by the donor
-    assert match.longest(np.arange(90, 99)) == (None, 0)
-    assert match.longest(np.asarray([10, 11, 99, 13])) == (0, 2)
+    assert _longest(match, np.arange(10, 35)) == (1, 25)  # slots 1 and 2 tie
+    assert _longest(match, np.arange(10, 20)) == (0, 10)  # capped by the row
+    assert _longest(match, np.arange(10, 60)) == (1, 30)  # capped by the donor
+    assert _longest(match, np.arange(90, 99)) == (None, 0)
+    assert _longest(match, np.asarray([10, 11, 99, 13])) == (0, 2)
+    # every sharing row, most shared first, the lower slot first among equals
+    assert match.by_shared(np.arange(10, 35)) == [(1, 25), (2, 25), (0, 20)]
     pool.release(1)
-    assert LiveRowPrefixes(pool).longest(np.arange(10, 35)) == (2, 25)
+    assert _longest(LiveRowPrefixes(pool), np.arange(10, 35)) == (2, 25)
 
 
 def _pool_of(*rows):
@@ -423,7 +430,7 @@ def test_live_row_prefixes_invariants(case):
     if case == "the_copy_stops_a_window_short_of_the_whole_prompt":
         # an identical resident prompt is matched whole; what the plan copies
         # of it leaves the last window to run, so that its logits exist
-        assert LiveRowPrefixes(_pool_of(ids)).longest(ids) == (0, len(ids))
+        assert _longest(LiveRowPrefixes(_pool_of(ids)), ids) == (0, len(ids))
         assert _tool("prefix_stats").expected_reuse([ids, ids]) == \
             [0, len(ids) - S]
     elif case == "never_a_slot_the_call_writes":
@@ -431,16 +438,16 @@ def test_live_row_prefixes_invariants(case):
         live = LiveRowPrefixes(pool)  # built before the batch claims slots
         assert pool.alloc(SlotState(request=Request(prompt="new"), base=192,
                                     real_len=len(ids), ids=ids)) == 1
-        assert live.longest(ids) == (0, 80)
+        assert _longest(live, ids) == (0, 80)
     elif case == "never_a_row_without_ids":
-        assert LiveRowPrefixes(_pool_of(None)).longest(ids) == (None, 0)
-        assert LiveRowPrefixes(_pool_of(None, ids[:30])).longest(ids) == \
+        assert _longest(LiveRowPrefixes(_pool_of(None)), ids) == (None, 0)
+        assert _longest(LiveRowPrefixes(_pool_of(None, ids[:30])), ids) == \
             (1, 30)
     else:
         pool = _pool_of(ids, ids[:40])
         pool.release(0)
         assert LiveRowPrefixes(pool).slots == [1]
-        assert LiveRowPrefixes(pool).longest(ids) == (1, 40)
+        assert _longest(LiveRowPrefixes(pool), ids) == (1, 40)
 
 
 # -- what prefix sharing is asked of, for both kinds of cache -------------------
@@ -742,7 +749,8 @@ def test_the_reuse_share_files_load_and_read_the_counters(metric, cells):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     entry = next(m for m in bench["per_layer"] if m["name"] == metric)
-    assert entry["workloads"] == cells and entry["unit"] == "%"
+    # what later PRs appended (PR 35's cell) comes after
+    assert entry["workloads"][:len(cells)] == cells and entry["unit"] == "%"
     assert entry["source"] == "program_counter"
     read, args = bench_run._reader("perfbench", metric)
 

@@ -241,6 +241,8 @@ ENTRY %main.3 (k.1: bf16[48,384,8,128], pos.1: s32[48,384]) -> bf16[48,384,8,128
     (48, 384, 2),  # a leaf and the positions; not the fusion's own read
     (8, 384, 1),   # the gathered rows are a pool of 8 slots' leaf
     (96, 384, 0),
+    (48, (128, 384), 2),  # every length a leaf has: a ring beside the rows
+    (48, (128, 38), 0),   # a length is matched whole, not as a prefix
 ])
 def test_pool_copies_are_whole_leaves_copied_outside_fusions(
         slots, cache_len, want):

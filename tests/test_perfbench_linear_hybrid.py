@@ -233,18 +233,21 @@ def test_the_two_layer_metrics_name_their_readers():
 def test_the_entries_name_the_cell_as_the_issue_does():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    # appended, each at the end of its list
-    assert bench["workloads"][-1]["name"] == CELL
-    assert bench["configs"][-1]["name"] == "solar-open2-int8"
-    assert [m["name"] for m in bench["per_layer"][-2:]] == [
+    # appended, each at the end of its list; what PR 35 appended comes after
+    later = "trinity-mini-int8"
+    cell = [w for w in bench["workloads"] if w["config"] != later][-1]
+    conf = [c for c in bench["configs"] if c["name"] != later][-1]
+    own_metrics = [m for m in bench["per_layer"]
+                   if m.get("workloads") != [later + ".catalog8k"]][-2:]
+    assert cell["name"] == CELL and conf["name"] == "solar-open2-int8"
+    assert [m["name"] for m in own_metrics] == [
         "kda_step_roofline.req", "row_state_kb_per_token.req"]
     for metric in bench["end_to_end"] + bench["per_layer"]:
         if CELL in metric.get("workloads", ()):
-            assert metric["workloads"][-1] == CELL
-    cell = bench["workloads"][-1]
+            assert [w for w in metric["workloads"]
+                    if not w.startswith(later)][-1] == CELL
     assert (cell["config"], cell["traffic"], cell["chips"]) \
         == ("solar-open2-int8", "sweep180", 1) and len(cell["why"]) <= 200
-    conf = bench["configs"][-1]
     config = _raw()
     assert conf["source"] == config["source"]
     assert sorted(conf["reduced"]) == sorted(config["reduced"])
@@ -269,5 +272,5 @@ def test_the_entries_name_the_cell_as_the_issue_does():
         "setup_compile_load_s", "kda_step_roofline.req",
         "row_state_kb_per_token.req"}
     assert "prefill_reuse_share.req" not in named  # nothing is copied
-    for metric in bench["per_layer"][-2:]:
+    for metric in own_metrics:
         assert metric["workloads"] == [CELL] and metric["moves"] == "req_per_s"

@@ -118,7 +118,8 @@ def prefill_aliasing(sched, nb: int, P: int, guard: bool = False):
     held = len(jax.tree.leaves(sched.engine.params))
     donated = len(jax.tree.leaves((sched._cache, sched._prev_logits)))
     return aliased, list(range(held, held + donated)), \
-        count_pool_copies(hlo, sched.num_slots, sched.cache_len)
+        count_pool_copies(hlo, sched.num_slots,
+                          {sched.cache_len, *sched._retained})
 
 
 @contextlib.contextmanager

@@ -96,7 +96,12 @@ def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
     params = jax.eval_shape(
         lambda: init_params_lowmem(cfg, jax.random.key(0), jnp.bfloat16))
     params = placed(params, shd.param_shardings(cfg, mesh, rules))
-    cache = jax.eval_shape(lambda: init_cache(cfg, slots, cache_len))
+    # The slot pool's form: a window layer's leaf is its ring.
+    cache = jax.eval_shape(lambda: init_cache(cfg, slots, cache_len,
+                                              ring=True))
+    lengths = sorted({x.shape[1] for layer in cache.layers
+                      for x in jax.tree.leaves(layer) if x.ndim > 2}
+                     | {cache_len})
     cache = placed(cache, shd.kv_tree_shardings(cfg, mesh, cache))
     logits = jax.ShapeDtypeStruct((slots, cfg.vocab_size), jnp.float32,
                                   sharding=shd.logits_sharding(cfg, mesh))
@@ -137,7 +142,8 @@ def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
             "device_kind": topo.devices[0].device_kind,
             "compile_s": round(wall, 1),
             **count_hlo_ops(hlo),
-            "pool_copies": count_pool_copies(hlo, slots, cache_len),
+            "leaf_lengths": lengths,
+            "pool_copies": count_pool_copies(hlo, slots, lengths),
             "argument_gb": round(ma.argument_size_in_bytes / 1e9, 2),
             "output_gb": round(ma.output_size_in_bytes / 1e9, 2),
             "alias_gb": round(ma.alias_size_in_bytes / 1e9, 2),
