@@ -111,7 +111,7 @@ def compile_key(program: str, *, batch: Optional[int] = None,
                 draft_len: Optional[int] = None,
                 chunk: Optional[int] = None, fuse: int = 1,
                 nb: Optional[int] = None, P: Optional[int] = None,
-                tp: int = 1) -> Tuple:
+                copies: bool = True, tp: int = 1) -> Tuple:
     """The one compile-key scheme for every step program.
 
     Axes are per-program-shape (batch/prompt buckets, decode caps), plus
@@ -139,6 +139,11 @@ def compile_key(program: str, *, batch: Optional[int] = None,
                guard, ngram_max, draft_len)
     elif program == "serve_prefill":
         key = ("serve_prefill", nb, P, guard)
+        if not copies:
+            # The program of a batch in which no row copies (a tagged
+            # element, as the mesh axis': the keys that were stay as they
+            # were).
+            key += (("copies", False),)
     elif program == "serve_step":
         key = ("serve_step", chunk, guard, fuse)
     else:
@@ -565,21 +570,62 @@ def build_serve_step(cfg, model, sampler: SamplerSettings, pad_id: int,
 DONOR_COND_WINDOWS = 16
 
 
-def extend_window(cfg, P: int) -> int:
+#: The widest window of a batch in which no row copies: the kernels' row
+#: tile (``ops/quant_matmul.matmul_blocks``: one row tile up to 512).
+ALONE_WINDOW_MAX = 512
+
+
+def cache_rings(cfg, cache) -> list:
+    """The ``(window, length)`` of each ring a cache holds: a window layer's
+    leaf shorter than the row (``KVCache``). From shapes."""
+    kept = ((w, jax.tree.leaves(layer)[0].shape[1])
+            for w, layer in zip(cfg.layer_windows, cache.layers)
+            if not isinstance(layer, RowState))
+    return sorted({(w, n) for w, n in kept if n < cache.max_len})
+
+
+def long_bucket(cfg, P: int) -> bool:
+    """Whether a bucket has ``DONOR_COND_WINDOWS`` windows and more of a
+    model that takes donors: there a row's leading windows are most of its
+    prefill, so ``serve_prefill`` builds donor rows only in a call that
+    copies, and the scheduler sends a donorless row ahead of the rows of its
+    batch that could copy from it (``scheduler._plan_prefill``)."""
+    return not cfg.row_state_bytes and \
+        P // EXTEND_WINDOW >= DONOR_COND_WINDOWS
+
+
+def extend_window(cfg, P: int, copies: bool = True, rings=()) -> int:
     """New tokens a ``serve_prefill`` call of bucket ``P`` computes per row.
     A window narrower than the bucket pays where a donor saves a row its
     leading windows. A model of which a layer keeps a fixed state per row
     takes no donor (``scheduler._plan_prefill``) and would run every window
     of every row, streaming its weights once a window: its window is the
-    whole bucket (on the chip: ``PERF.md`` section 6, PR 33)."""
-    return P if cfg.row_state_bytes else EXTEND_WINDOW
+    whole bucket (on the chip: ``PERF.md`` section 6, PR 33).
+
+    ``copies`` false: the batch is of rows that copy nothing, in a long
+    bucket (``long_bucket``; elsewhere the argument changes nothing). Such a
+    row runs every window that holds one of its tokens, and each call
+    streams the weights again, so its window is the widest multiple of
+    ``EXTEND_WINDOW`` up to ``ALONE_WINDOW_MAX`` that divides ``P`` and the
+    length of every ring of the pool (``rings``: ``cache_rings``), so that a
+    call's write never wraps inside the call, and that leaves the call's
+    first query its whole window in every ring (``S <= R - window + 1``:
+    ``configs.ring_len`` explains the slack)."""
+    if cfg.row_state_bytes:
+        return P
+    if copies or not long_bucket(cfg, P):
+        return EXTEND_WINDOW
+    return max((
+        S for S in range(EXTEND_WINDOW, ALONE_WINDOW_MAX + 1, EXTEND_WINDOW)
+        if P % S == 0 and all(R % S == 0 and S <= R - window + 1
+                              for window, R in rings)), default=EXTEND_WINDOW)
 
 
 def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
-                        num_slots: int):
+                        num_slots: int, copies: bool = True, rings=()):
     """Extend ``nb`` rows of the slot pool by one window of
-    ``extend_window(cfg, P)`` tokens: prefill appends to what a row's
-    ``[:P]`` extent already holds.
+    ``extend_window(cfg, P, copies, rings)`` tokens: prefill appends to what
+    a row's ``[:P]`` extent already holds.
 
     A row's prompt is left-padded to ``P`` (``pad`` slots of padding, real
     token ``t`` at slot ``pad + t`` and position ``t``). A call computes the
@@ -630,9 +676,18 @@ def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
     does not name (a batch-bucket pad row, every other row of the pool) is
     neither read nor written. No donor is taken for such a model (the plan's
     ``copied`` is all zeros): a donor's state stands at its own last token.
+
+    ``copies`` false, in a long bucket (``long_bucket``; elsewhere it
+    changes nothing): the program of a batch in which no row copies. Step 1
+    is left out (no donor's rows are built, ``copied`` is not read) and the
+    window is the wide one ``extend_window`` gives over the pool's
+    ``rings``; the rest is the same, so its rows stand in the pool as any
+    row's and are donors from the next plan on.
     """
     masked_finite = _masked_finite() if guard else None
-    S = extend_window(cfg, P)
+    long = long_bucket(cfg, P)
+    copies = copies or not long
+    S = extend_window(cfg, P, copies, rings)
     if P % S:
         raise ValueError(f"prompt bucket {P} is not a multiple of the "
                          f"extend window {S}")
@@ -716,7 +771,9 @@ def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
         # of a call's time on the chip (``PERF.md`` section 6, PR 35). In a
         # bucket of a few windows they are a few hundredths, fused into the
         # extent's own gather, and the program stays the one it was.
-        if P // S >= DONOR_COND_WINDOWS:
+        if not copies:
+            gathered, own_positions = extents(False)
+        elif long:
             gathered, own_positions = jax.lax.cond(
                 jnp.any(copied > 0), lambda: extents(True),
                 lambda: extents(False))

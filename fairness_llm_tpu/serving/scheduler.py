@@ -108,8 +108,10 @@ from fairness_llm_tpu.runtime.sampling import SamplerSettings
 from fairness_llm_tpu.runtime.stepbuilder import (
     build_serve_prefill,
     build_serve_step,
+    cache_rings,
     compile_key,
     extend_window,
+    long_bucket,
     program_label,
 )
 from fairness_llm_tpu.serving.overload import (
@@ -160,6 +162,13 @@ def _bucket_pow2(n: int, cap: int) -> int:
     while b < n:
         b *= 2
     return min(b, cap)
+
+
+def _copyable(n: int, m: int, S: int) -> int:
+    """Positions a row of ``n`` ids copies from a row that shares its first
+    ``m``: all but the fewest whole windows of ``S`` that hold the rest (one
+    at the least, for the row's own logits). Zero or less: nothing."""
+    return n - max(1, -(-(n - m) // S)) * S
 
 
 class ContinuousScheduler:
@@ -315,8 +324,7 @@ class ContinuousScheduler:
         self._kv_row_bytes = sum(
             x.nbytes for _, leaves in kept for x in leaves) // self.num_slots
         self._retained = sorted({leaves[0].shape[1] for _, leaves in kept})
-        self._rings = sorted({(w, leaves[0].shape[1]) for w, leaves in kept
-                              if leaves[0].shape[1] < self.cache_len})
+        self._rings = cache_rings(cfg, self._cache)
         self._prev_logits = jnp.zeros(
             (self.num_slots, cfg.vocab_size), jnp.float32
         )
@@ -513,20 +521,22 @@ class ContinuousScheduler:
         return tp_collective_costs(self.engine.config, self.tp, rows,
                                    tokens=tokens, scope=scope)
 
-    def _prefill_fn(self, nb: int, P: int, guard: bool):
+    def _prefill_fn(self, nb: int, P: int, guard: bool, copies: bool = True):
         """One window's extend of [nb] rows' ``[:P]`` extents of the shared
         cache: the builder's ``serve_prefill`` composition (see
-        ``stepbuilder.build_serve_prefill`` for the program semantics). The
-        collectives row counts the window's tokens."""
+        ``stepbuilder.build_serve_prefill`` for the program semantics;
+        ``copies`` false is the program of a long bucket's batch in which no
+        row copies, with its wide window). The collectives row counts the
+        window's tokens."""
         key = compile_key("serve_prefill", nb=nb, P=P, guard=guard,
-                          tp=self.tp)
+                          copies=copies, tp=self.tp)
         program = program_label("serve_prefill", tp=self.tp)
         fn = self._compiled.get(key)
         if fn is not None:
             return fn
         run = build_serve_prefill(
             self.engine.config, self.engine.model, nb=nb, P=P, guard=guard,
-            num_slots=self.num_slots,
+            num_slots=self.num_slots, copies=copies, rings=self._rings,
         )
         # Donated like the step: the extent's scatter writes the pool in
         # place, and the call's outputs are sound for every row it did not
@@ -535,7 +545,8 @@ class ContinuousScheduler:
         fn = instrument_jit(
             run, program, donate_argnums=self._donate(),
             collectives=self._collectives(
-                nb, extend_window(self.engine.config, P), "call"))
+                nb, extend_window(self.engine.config, P, copies, self._rings),
+                "call"))
         self._compiled[key] = fn
         return fn
 
@@ -1182,13 +1193,19 @@ class ContinuousScheduler:
         with tl.span("sched.admit.plan", "sched", self._track,
                      queue_depth=len(self.queue),
                      live_rows=self.pool.occupancy) as plan_span:
-            popped = self._plan_admission(n, stats)
-            if popped is None:
+            admitted = self._plan_admission(n, stats)
+            if admitted is None:
                 return False
-            admitted, injected_hang = popped
             plan = self._plan_prefill(admitted)
-            plan_span.set(rows=len(admitted), nb=plan["nb"], P=plan["P"],
-                          donors=plan["donors"],
+            # Rows that wait a batch for a row ahead of them go back to the
+            # front in their order: before every later arrival, with their
+            # deadline and their ``queue_wait`` running on.
+            for req in reversed(plan["deferred"]):
+                self.queue.requeue(req)
+            stats.prefill_rows_deferred += len(plan["deferred"])
+            plan_span.set(rows=len(plan["reqs"]), nb=plan["nb"], P=plan["P"],
+                          S=plan["S"], donors=plan["donors"],
+                          deferred=len(plan["deferred"]),
                           windows=len(plan["calls"]))
             if self._row_state_bytes:
                 # No donor was looked for: a layer keeps a per-row state.
@@ -1198,18 +1215,20 @@ class ContinuousScheduler:
                 # best donor's ring had moved past what they needed of it.
                 plan_span.set(retained=self._retained,
                               ring_refused=plan["ring_refused"])
+            # A scripted stall is consumed by the batch its request goes with.
+            hang_fn = getattr(self.fault_injector, "maybe_hang", None)
+            injected_hang = 0.0 if hang_fn is None else sum(
+                hang_fn(req.id, "prefill") for req in plan["reqs"])
         return self._dispatch_prefill(plan, stats, injected_hang)
 
     def _plan_admission(self, n: int, stats: ServingStats):
         """Pop ``n`` requests and settle each one's fate before any device
         work: expired, shed, faulted by script, or admitted with its token
-        ids and prompt bucket. Returns ``([(request, ids, P)],
-        injected_hang)``, or None when nothing is left to admit."""
+        ids and prompt bucket. Returns ``[(request, ids, P)]``, or None when
+        nothing is left to admit."""
         popped = self.queue.pop(n)
         tok = self.engine.tokenizer
         now = time.monotonic()
-        hang_fn = getattr(self.fault_injector, "maybe_hang", None)
-        injected_hang = 0.0
         admitted = []  # (request, row ids, P)
         for req in popped:
             if req.expired(now):
@@ -1254,8 +1273,6 @@ class ContinuousScheduler:
                         self.breakers.record_failure("prefill")
                     self._requeue_or_fail(req, str(e), stats, cause="injected")
                     continue
-                if hang_fn is not None:
-                    injected_hang += hang_fn(req.id, "prefill")
             ids = tok.encode(req.prompt)
             if len(ids) > self.prompt_budget:
                 # Keep recency, like the engine's truncation — but the
@@ -1276,26 +1293,41 @@ class ContinuousScheduler:
                 self.max_prompt_bucket,
             )
             admitted.append((req, ids, P))
-        if not admitted:
-            return None
-        return admitted, injected_hang
+        return admitted or None
 
     def _plan_prefill(self, admitted) -> dict:
         """What one contiguous admission batch has left to compute, as the
-        arguments of its ``serve_prefill`` calls (one per window of
-        ``extend_window(config, P)`` slots, in order), on the device before
-        the first call is made.
+        arguments of its ``serve_prefill`` calls (one per window, in
+        order), on the device before the first call is made.
 
         A row whose prompt starts like a live row's copies what it can and
-        extends over its last windows only: with ``m`` ids shared and ``k``
-        the fewest windows for which ``n - k S <= m``, ``n - k S`` positions
-        come from the donor. A row with no such donor (or fewer than ``S``
-        ids) runs every window that holds one of its tokens. Donors are rows
-        resident before this call: never a slot it writes. Where a window
-        layer keeps a ring, the donor is the resident row with the longest
-        shared prefix among those whose rings still hold what the rider
-        needs of them (``_ring_holds``), and ``ring_refused`` counts the
-        riders whose longest-sharing row did not.
+        extends over its last windows only: with ``m`` ids shared, ``S`` the
+        window ``extend_window(config, P)`` and ``k`` the fewest windows for
+        which ``n - k S <= m``, ``n - k S`` positions come from the donor. A
+        row with no such donor (or fewer than ``S`` ids) runs every window
+        that holds one of its tokens. Donors are rows resident before this
+        call: never a slot it writes. Where a window layer keeps a ring, the
+        donor is the resident row with the longest shared prefix among those
+        whose rings still hold what the rider needs of them
+        (``_ring_holds``), and ``ring_refused`` counts the riders whose
+        longest-sharing row did not.
+
+        In a long bucket (``stepbuilder.long_bucket``: there a row's leading
+        windows are most of a batch's work) a row that found no donor looks,
+        with the same arithmetic, among the rows AHEAD of it that this batch
+        takes. Where one of them would save it a window the row is
+        ``deferred``: it claims no slot, and ``_admit_once`` puts it back at
+        the front of the queue, so the next plan of the same ``_admit`` loop
+        finds the row that went ahead resident (its calls are queued on the
+        device before the riders', as a batch's windows already are). Rows
+        that share nothing wait for nobody, and nothing is remembered: if
+        the batch of the row ahead fails, that row is requeued in front of
+        the rows that waited for it and the next plan is made as if the
+        batch had never been. A batch of such a bucket in which no row
+        copies runs the program of that case, with its wide window
+        (``stepbuilder.extend_window``: the plan's ``S``), and a batch there
+        is one row or pads to ``prefill_group``: a compiled shape of a long
+        bucket costs more than a padded row.
 
         A model of which a layer keeps a fixed state per row
         (``config.row_state_bytes``) takes no donor at all: a resident row's
@@ -1306,6 +1338,7 @@ class ContinuousScheduler:
         ``serve_prefill`` zeroes each row's state.
         """
         tok = self.engine.tokenizer
+        cfg = self.engine.config
         # ONE bucket per admission batch, the largest of its rows'. Shorter
         # rows pad up to it: numerically free (pad slots are masked,
         # contributing exact zeros to every reduction; parity tests pin
@@ -1314,11 +1347,39 @@ class ContinuousScheduler:
         # is therefore the bucket it was PREFILLED at, which its decode
         # write offsets continue from.
         P = max(item[2] for item in admitted)
-        S = extend_window(self.engine.config, P)
-        n_rows = len(admitted)
-        nb = _bucket_pow2(n_rows, max(self.serving.prefill_group, n_rows))
+        S = extend_window(cfg, P)  # a rider's window
+        long = long_bucket(cfg, P)
         live = LiveRowPrefixes(self.pool)
-        reqs, slots = [], []
+        rows, deferred = [], []  # (request, ids, donor, donor_pad, copied)
+        ring_refused = 0
+        for req, ids, _ in admitted:
+            ids = np.asarray(ids[-P:], np.int32)
+            n = len(ids)
+            found = (0, 0, 0)
+            for at, (src, m) in enumerate(live.by_shared(ids)):
+                can_copy = _copyable(n, m, S)
+                if can_copy <= 0:
+                    break  # a shorter prefix saves no window either
+                st = self.pool.get(src)
+                if not self._ring_holds(st, can_copy):
+                    ring_refused += at == 0
+                    continue
+                found = (src, st.base - st.real_len, can_copy)
+                break
+            if long and not found[2] and self._waits_for(ids, rows, S):
+                deferred.append(req)
+                continue
+            rows.append((req, ids) + found)
+        n_rows = len(rows)
+        cap = max(self.serving.prefill_group, n_rows)
+        copies = not long or any(row[4] for row in rows)
+        if not copies:
+            S = extend_window(cfg, P, False, self._rings)
+        if long:
+            nb = 1 if n_rows == 1 else cap
+        else:
+            nb = _bucket_pow2(n_rows, cap)
+        reqs = [row[0] for row in rows]
         tokens = np.full((nb, P), tok.pad_id, np.int32)
         lead = np.full((nb,), P, np.int32)  # left padding; P: no real token
         donor = np.zeros((nb,), np.int32)
@@ -1326,28 +1387,18 @@ class ContinuousScheduler:
         copied = np.zeros((nb,), np.int32)
         # The window a row starts in; batch-bucket pad rows start in none.
         first = np.full((nb,), P // S, np.int32)
-        ring_refused = 0
-        for r, (req, ids, _) in enumerate(admitted):
-            ids = np.asarray(ids[-P:], np.int32)
+        for r, (_, ids, src, src_pad, n_copied) in enumerate(rows):
             n = len(ids)
             tokens[r, P - n:] = ids
             lead[r] = P - n
-            first[r] = min((P - n) // S, P // S - 1)
-            for at, (src, m) in enumerate(live.by_shared(ids)):
-                k = max(1, -(-(n - m) // S))
-                if n - k * S <= 0:
-                    break  # a shorter prefix saves no window either
-                st = self.pool.get(src)
-                if not self._ring_holds(st, n - k * S):
-                    ring_refused += at == 0
-                    continue
-                donor[r], donor_pad[r] = src, st.base - st.real_len
-                copied[r] = n - k * S
-                first[r] = P // S - k
-                break
-            reqs.append(req)
+            donor[r], donor_pad[r], copied[r] = src, src_pad, n_copied
+            # A rider runs the whole windows after what it copied; any other
+            # row starts in its first token's window.
+            first[r] = P // S - (n - n_copied) // S if n_copied else \
+                min((P - n) // S, P // S - 1)
         # Slots are claimed after every row has looked for its donor, so
         # that no row of this batch can be another's.
+        slots = []
         for r, req in enumerate(reqs):
             slot = self.pool.alloc(SlotState(
                 request=req, base=P, real_len=int(P - lead[r]),
@@ -1381,26 +1432,46 @@ class ContinuousScheduler:
         # exempt from hang classification (injected stalls still classify).
         guard = self._guard()
         pf_key = compile_key("serve_prefill", nb=nb, P=P, guard=guard,
-                             tp=self.tp)
+                             copies=copies, tp=self.tp)
         return dict(
-            nb=nb, P=P, reqs=reqs, slots=slots, guard=guard, key=pf_key,
+            nb=nb, P=P, S=S, reqs=reqs, slots=slots, guard=guard, key=pf_key,
             first_compile=pf_key not in self._compiled,
-            fn=self._prefill_fn(nb, P, guard),
+            fn=self._prefill_fn(nb, P, guard, copies),
             program=program_label("serve_prefill", tp=self.tp),
             prompt_tokens=int((P - lead).sum()),
             reused_tokens=int(copied.sum()),
             donors=int((copied > 0).sum()), calls=calls,
-            ring_refused=int(ring_refused),
+            ring_refused=int(ring_refused), deferred=deferred,
+            alone=not copies,
         )
+
+    def _waits_for(self, ids: np.ndarray, ahead, S: int) -> bool:
+        """Whether a row that found no donor waits a batch for one of the
+        rows ``ahead`` of it that this batch takes: one that, once resident,
+        saves it a window."""
+        n = len(ids)
+        for _, theirs, *_ in ahead:
+            width = min(n, len(theirs))
+            differ = np.flatnonzero(ids[:width] != theirs[:width])
+            m = int(differ[0]) if differ.size else width
+            can_copy = _copyable(n, m, S)
+            if can_copy > 0 and self._rings_hold(len(theirs), can_copy):
+                return True
+        return False
 
     def _ring_holds(self, st: SlotState, copied: int) -> bool:
         """Whether a resident row's rings still hold what a rider that
-        copies ``copied`` positions needs of them. The rider's first query
-        stands at position ``copied`` and sees ``window`` back, so it needs
-        the positions from ``copied - window + 1`` (or 0) on; a ring of
-        ``R`` holds the last ``R`` of the ``real_len + emitted`` positions
-        the row has written. True where no layer keeps a ring."""
-        written = st.real_len + st.emitted
+        copies ``copied`` positions needs of them: ``_rings_hold`` of the
+        ``real_len + emitted`` positions the row has written."""
+        return self._rings_hold(st.real_len + st.emitted, copied)
+
+    def _rings_hold(self, written: int, copied: int) -> bool:
+        """Whether the rings of a row that has written ``written`` positions
+        hold what a rider that copies ``copied`` needs of them. The rider's
+        first query stands at position ``copied`` and sees ``window`` back,
+        so it needs the positions from ``copied - window + 1`` (or 0) on; a
+        ring of ``R`` holds the last ``R`` written. True where no layer
+        keeps a ring."""
         return all(written - length <= max(0, copied - window + 1)
                    for window, length in self._rings)
 
@@ -1494,6 +1565,7 @@ class ContinuousScheduler:
         stats.prefill_tokens += plan["prompt_tokens"]
         stats.prefill_tokens_reused += plan["reused_tokens"]
         stats.prefill_donors_refused_ring += plan["ring_refused"]
+        stats.prefill_leader_batches += plan["alone"]
         if self._row_state_bytes:
             # Each admitted row's state was zeroed in the batch's call.
             stats.row_state_resets += len(reqs)

@@ -322,6 +322,12 @@ class ServingStats:
     - ``prefill_donors_refused_ring``: admitted rows whose longest-sharing
       resident row could not be their donor because a window layer's ring
       had moved past what they needed of it
+    - ``prefill_leader_batches`` / ``prefill_rows_deferred``: in a bucket
+      of ``stepbuilder.DONOR_COND_WINDOWS`` windows and more, admission
+      batches in which no row copied (they ran the wide-window program),
+      and the times a row that found no resident donor went back to the
+      front of the queue to wait one batch for a row ahead of it
+      (``scheduler._plan_prefill``); both 0 in shorter buckets
     """
 
     num_slots: int = 0
@@ -349,6 +355,8 @@ class ServingStats:
     row_state_resets: int = 0
     kv_bytes_stepped: int = 0
     prefill_donors_refused_ring: int = 0
+    prefill_leader_batches: int = 0
+    prefill_rows_deferred: int = 0
 
     @property
     def tokens_per_step(self) -> float:

@@ -425,14 +425,15 @@ def test_kda_step_compiles_in_place_on_v5e_topology():
 
 
 def test_the_ring_cells_two_programs_compile_in_place_on_v5e_topology():
-    """``serve_prefill`` and ``serve_step`` of ``trinity-mini-int8.catalog8k``
-    at the cell's real pool (64 slots x 8192 positions, a bucket of 8064, 8
-    rows a call) and every published width, one period deep (two dense and
+    """Both ``serve_prefill`` programs (8 rows that copy; one row alone in
+    its wide window) and ``serve_step`` of ``trinity-mini-int8.catalog8k``
+    at the cell's real pool (64 slots x 8192 positions, a bucket of 8064)
+    and every published width, one period deep (two dense and
     two expert layers; three window layers that keep a ring of 3072 and one
     full layer), through the REAL v5e compiler as ``tools/aot_preflight.py``
     builds them: the Mosaic kernels are there, every leaf of the pool, ring
     or row, is written in place (no whole-leaf copy of either length), and
-    both programs fit the chip."""
+    all three programs fit the chip."""
     try:
         from jax.experimental import topologies
 
@@ -453,7 +454,12 @@ def test_the_ring_cells_two_programs_compile_in_place_on_v5e_topology():
                          bench_config="trinity-mini-int8")
     finally:
         sys.path.remove(tools)
-    assert [r["program"] for r in recs] == ["serve_prefill", "serve_step"]
+    assert [r["program"] for r in recs] == ["serve_prefill"] * 2 \
+        + ["serve_step"]
+    # a batch that copies: 8 rows x 64 positions; the row sent ahead alone:
+    # 384 positions a call (21 calls; 8 to a ring; 2048 + 383 <= 3072)
+    assert [(r["nb"], r["window"], r["copies"]) for r in recs[:2]] \
+        == [(8, 64, True), (1, 384, False)]
     for r in recs:
         assert r["leaf_lengths"] == [3072, 8192] and r["cache_len"] == 8192
         assert r["pool_copies"] == 0, r

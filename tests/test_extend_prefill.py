@@ -15,6 +15,7 @@ logits do not call it a near-tie (ROADMAP D9)."""
 import importlib.util
 import json
 import os
+import time
 import zlib
 
 import jax
@@ -39,7 +40,11 @@ from fairness_llm_tpu.config import (  # noqa: E402
     ServingConfig,
 )
 from fairness_llm_tpu.models.tokenizer import ByteTokenizer  # noqa: E402
-from fairness_llm_tpu.runtime.stepbuilder import EXTEND_WINDOW  # noqa: E402
+from fairness_llm_tpu.runtime import stepbuilder  # noqa: E402
+from fairness_llm_tpu.runtime.stepbuilder import (  # noqa: E402
+    EXTEND_WINDOW,
+    extend_window,
+)
 from fairness_llm_tpu.serving import (  # noqa: E402
     ContinuousScheduler,
     ReplicaSet,
@@ -381,6 +386,368 @@ def test_admission_runs_no_program_but_its_own(dense, caplog):
         assert stats.completed == 2 and stats.prefill_tokens_reused == 140 - S
     compiled = set(re.findall(r"Compiling jit\((\w+)\)", caplog.text))
     assert compiled == {"serve_prefill_1x192", "serve_step"}, compiled
+
+
+# -- a long bucket: one row ahead, alone, in a wide window (ISSUE 36) -----------------
+
+LONG = 1024  # 16 windows: the first bucket ``stepbuilder.long_bucket`` names
+WIDE = 512
+
+
+@pytest.fixture(scope="module")
+def long_dense():
+    """The dense toy behind a pool of 8 slots x a bucket of 1024, four rows
+    to a batch: its programs are ``1 x 1024`` and ``4 x 1024``, each for a
+    batch that copies (window 64) and for one that does not (window 512)."""
+    with jax.default_matmul_precision("highest"):
+        engine, cut = _toy("mistral-7b-int8")
+        yield engine, cut, _scheduler(engine, num_slots=8, prefill_group=4,
+                                      max_prompt_len=LONG)
+
+
+def _plans(tl):
+    return [e["args"] for e in tl.events() if e["type"] == "span"
+            and e["name"] == "sched.admit.plan"]
+
+
+def _plan_rows(tl):
+    return [(p["rows"], p["nb"], p["S"], p["donors"], p["deferred"],
+             p["windows"]) for p in _plans(tl)]
+
+
+def _near_duplicates(p):
+    # six prompts of 1000-1005 ids of which 990 are common: the first goes
+    # ahead in two windows of 512, the five others copy all but their last
+    # window from it, four and then one to a batch
+    rows = _family(p, 6, stem=990, tails=(10, 15))
+    return rows, dict(
+        plans=[(1, 1, WIDE, 0, 3, 2), (4, 4, S, 4, 0, 1), (1, 1, S, 1, 0, 1)],
+        leaders=1, deferred=3, batches=4,
+        reused=sum(len(r) - S for r in rows[1:]))
+
+
+def _unrelated(p):
+    # nothing shared: nobody waits, and the batch of three that copies
+    # nothing runs the wide window in the group's shape
+    rows = [p.draw(n) for n in (1000, 990, 980)]
+    return rows, dict(plans=[(3, 4, WIDE, 0, 0, 2)], leaders=1, deferred=0,
+                      batches=2, reused=0)
+
+
+def _two_groups(p):
+    # two families in one batch: a row of each goes ahead, in one batch
+    a, b = (_family(p, 2, stem=990, tails=(10, 12)) for _ in range(2))
+    rows = [a[0], b[0], a[1], b[1]]
+    return rows, dict(plans=[(2, 4, WIDE, 0, 2, 2), (2, 4, S, 2, 0, 1)],
+                      leaders=1, deferred=2, batches=3,
+                      reused=len(a[1]) + len(b[1]) - 2 * S)
+
+
+def _a_stem_too_short_to_save_a_window(p):
+    # 30 ids shared of 1000: the second row could copy none of its windows
+    # from the first, so it does not wait for it
+    a = p.draw(1000)
+    rows = [a, np.concatenate([a[:30], p.draw(970)])]
+    return rows, dict(plans=[(2, 4, WIDE, 0, 0, 2)], leaders=1, deferred=0,
+                      batches=2, reused=0)
+
+
+def _a_short_fellow(p):
+    # the fellow shares 200 ids of its 230 with the row ahead: it waits,
+    # copies 166 and runs one window; the batch's bucket is the leader's
+    a = p.draw(1000)
+    rows = [a, np.concatenate([a[:200], p.draw(30)])]
+    return rows, dict(plans=[(1, 1, WIDE, 0, 1, 2), (1, 1, S, 1, 0, 1)],
+                      leaders=1, deferred=1, batches=3, reused=230 - S)
+
+
+@pytest.mark.parametrize("case", [
+    _near_duplicates, _unrelated, _two_groups,
+    _a_stem_too_short_to_save_a_window, _a_short_fellow,
+], ids=lambda f: f.__name__.strip("_"))
+def test_in_a_long_bucket_a_donorless_row_goes_ahead_and_its_fellows_copy(
+        long_dense, case):
+    """A pass arrives at once in an empty pool. What the plans, the span and
+    the counters say; then every row carries the whole prompt's logits and
+    decodes the engine's tokens."""
+    engine, cut, sched = long_dense
+    with jax.default_matmul_precision("highest"), use_timeline() as tl:
+        prompts = _Prompts(engine, cut, tag=case.__name__,
+                           seed=zlib.crc32(case.__name__.encode()))
+        rows, want = case(prompts)
+        reqs = [prompts.request(ids) for ids in rows]
+        stats = ServingStats(num_slots=sched.num_slots)
+        for r in reqs:
+            assert sched.submit(r)
+        sched._admit(stats)
+        assert _plan_rows(tl) == want["plans"]
+        assert (stats.prefill_leader_batches, stats.prefill_rows_deferred,
+                stats.prefill_batches, stats.prefill_tokens_reused) == (
+            want["leaders"], want["deferred"], want["batches"],
+            want["reused"])
+        assert stats.admitted == len(rows) and len(sched.queue) == 0
+        assert stats.prefill_tokens == sum(map(len, rows))
+        for req, ids in zip(reqs, rows):
+            carried = np.asarray(sched._prev_logits[_slot_of(sched, req.id)])
+            assert np.abs(carried - _whole_prompt_logits(engine, ids)).max() \
+                < TOL, req.id
+        _drained(sched, stats, len(rows))
+        for req, ids in zip(reqs, rows):
+            _assert_same_greedy(engine, req.id,
+                                sched.take_result(req.id).tokens, ids)
+
+
+def test_rows_that_wait_keep_their_place_their_deadline_and_their_wait(
+        long_dense):
+    """Six fellows and four to a batch: the three that wait for the first go
+    back in front of the two that were not popped, in their order, not
+    admitted and without a slot; their ``queue_wait`` runs until their own
+    batch; one whose deadline passes while it waits expires, unprefilled."""
+    engine, cut, sched = long_dense
+    with jax.default_matmul_precision("highest"):
+        prompts = _Prompts(engine, cut, seed=36, tag="wait")
+        rows = _family(prompts, 6, stem=990, tails=(10, 15))
+        reqs = [prompts.request(ids) for ids in rows]
+        reqs[2].deadline_s = 0.2
+        stats = ServingStats(num_slots=sched.num_slots)
+        for r in reqs:
+            assert sched.submit(r)
+        stamps = [r.submitted_at for r in reqs]
+        sched._admit_once(stats)
+        assert [r.id for r in sched.queue._q] == [r.id for r in reqs[1:]]
+        assert [r.submitted_at for r in reqs] == stamps
+        assert stats.admitted == 1 and sched.pool.occupancy == 1
+        assert (stats.prefill_leader_batches, stats.prefill_rows_deferred) \
+            == (1, 3)
+        events = {r.id: [e.event for e in sched.tracer.events(r.id)]
+                  for r in reqs}
+        assert "admitted" in events[reqs[0].id]
+        assert all("admitted" not in events[r.id] for r in reqs[1:])
+        time.sleep(0.25)
+        sched._admit_once(stats)
+        assert stats.expired == 1 and stats.admitted == 4
+        assert [r.id for r in sched.queue._q] == [reqs[5].id]
+        sched._run_loop(stats)
+        assert stats.completed == 5 and stats.prefill_rows_deferred == 3
+        gone = sched.take_result(reqs[2].id)
+        assert not gone.ok and gone.finish_reason == "deadline"
+        waits = [sched.take_result(r.id).queue_wait_s
+                 for r in reqs[:2] + reqs[3:]]
+        # the row that went ahead, then its fellows in their order
+        assert waits[0] < waits[1] <= waits[4] and waits[1] >= 0.25
+
+
+@pytest.mark.parametrize("how", ["unlaunched", "launched"])
+def test_a_failed_call_of_the_row_ahead_loses_none_of_its_fellows(
+        long_dense, how):
+    """The second wide window of the row that went ahead fails: that row is
+    requeued in front of the fellows that waited for it, goes ahead again,
+    and all of them finish with the engine's tokens. Where the call had
+    consumed the pool it is built anew, and nothing else changes."""
+    engine, cut, sched = long_dense
+    with jax.default_matmul_precision("highest"):
+        prompts = _Prompts(engine, cut, seed=37, tag=how)
+        rows = _family(prompts, 4, stem=990, tails=(10, 13))
+        reqs = [prompts.request(ids) for ids in rows]
+        stats = ServingStats(num_slots=sched.num_slots)
+        for r in reqs:
+            assert sched.submit(r)
+        with prefill_fault(sched, at=2, how=how) as calls:
+            sched._admit_once(stats)
+        assert len(calls) == 2 and stats.requeued == 1
+        assert [r.id for r in sched.queue._q] == [r.id for r in reqs]
+        assert sched.pool.occupancy == 0 and stats.admitted == 0
+        assert (stats.prefill_leader_batches, stats.prefill_rows_deferred) \
+            == (0, 3)
+        sched._admit(stats)
+        assert (stats.prefill_leader_batches, stats.prefill_rows_deferred) \
+            == (1, 6)
+        assert stats.prefill_tokens_reused == sum(
+            len(r) - S for r in rows[1:])
+        _drained(sched, stats, 4)
+        for req, ids in zip(reqs, rows):
+            res = sched.take_result(req.id)
+            assert res.ok and res.retries == (req is reqs[0])
+            _assert_same_greedy(engine, req.id, res.tokens, ids)
+
+
+@pytest.mark.parametrize("rings, P, copies, want", [
+    ([(2048, 3072)], 8064, False, 384),  # the cell: 21 calls, 8 to a ring
+    ([(2048, 3072)], 8064, True, S),
+    ([], 8064, False, 448),              # 18 x 448; 512 does not divide it
+    ([], 1024, False, 512),
+    ([], 1024, True, S),
+    ([], 960, False, S),                 # 15 windows: not a long bucket
+    ([(16, 128)], 1024, False, S),       # 128 - 16 + 1 holds one window
+    ([(256, 384)], 1024, False, 128),
+    ([(256, 384)], 1152, False, 128),    # 384 divides both; too wide a write
+    ([(4096, 6144)], 6144, False, 512),
+    ([(256, 384), (2048, 3072)], 3072, False, 128),
+], ids=str)
+def test_the_window_of_a_batch_that_copies_nothing(rings, P, copies, want):
+    """The widest multiple of 64 up to 512 that divides the bucket and every
+    ring and leaves a call's first query its whole window; 64 where a row
+    copies and in every bucket under ``DONOR_COND_WINDOWS`` windows."""
+    from fairness_llm_tpu.models.configs import get_model_config
+
+    got = extend_window(get_model_config("tiny-test"), P, copies, rings)
+    assert got == want and P % got == 0
+    assert all(R % got == 0 and got <= R - w + 1 for w, R in rings)
+
+
+def test_the_program_of_a_batch_that_copies_nothing_builds_no_donor_rows(
+        long_dense):
+    """The two programs of the long bucket as lowered: the one that copies
+    branches on "a row of this call copies" and takes 64 positions a row;
+    the other takes 512, has no branch, and still writes the pool in
+    place. Their compile keys differ; a short bucket's key is as it was."""
+    engine, cut, sched = long_dense
+    row = jnp.zeros((1,), jnp.int32)
+
+    def lowered(copies, width):
+        return sched._prefill_fn(1, LONG, False, copies)._jit.lower(
+            engine.params, sched._cache, sched._prev_logits,
+            jnp.zeros((1, width), jnp.int32), jnp.zeros((1, width), bool),
+            row, row, row, row, row, jnp.zeros((), jnp.int32)).as_text()
+
+    assert "stablehlo.case" in lowered(True, S)
+    assert "stablehlo.case" not in lowered(False, WIDE)
+    aliased, donated, copies = prefill_aliasing(sched, 1, LONG, copies=False)
+    assert aliased == donated and copies == 0
+    key = stepbuilder.compile_key
+    assert key("serve_prefill", nb=1, P=LONG, guard=False) \
+        == ("serve_prefill", 1, LONG, False)
+    assert key("serve_prefill", nb=1, P=LONG, guard=False, copies=False) \
+        == ("serve_prefill", 1, LONG, False, ("copies", False))
+
+
+# -- the bypass: under 16 windows, and where a row keeps a state -----------------------
+
+
+def _parents_plan(sched, admitted):
+    """``_plan_prefill`` as it stood before ISSUE 36, from the same pool:
+    (nb, P, [(w, rows, the call's eight arguments)]). Claims no slot: the
+    rows take the lowest free slots in their order."""
+    tok = sched.engine.tokenizer
+    P = max(item[2] for item in admitted)
+    W = P if sched._row_state_bytes else EXTEND_WINDOW
+    n_rows = len(admitted)
+    nb = 1
+    while nb < n_rows:
+        nb *= 2
+    nb = min(nb, max(sched.serving.prefill_group, n_rows))
+    live = LiveRowPrefixes(sched.pool)
+    tokens = np.full((nb, P), tok.pad_id, np.int32)
+    lead = np.full((nb,), P, np.int32)
+    donor, donor_pad, copied = (np.zeros((nb,), np.int32) for _ in range(3))
+    first = np.full((nb,), P // W, np.int32)
+    for r, (_, ids, _) in enumerate(admitted):
+        ids = np.asarray(ids[-P:], np.int32)
+        n = len(ids)
+        tokens[r, P - n:] = ids
+        lead[r] = P - n
+        first[r] = min((P - n) // W, P // W - 1)
+        for src, m in live.by_shared(ids):
+            k = max(1, -(-(n - m) // W))
+            if n - k * W <= 0:
+                break
+            st = sched.pool.get(src)
+            if not sched._ring_holds(st, n - k * W):
+                continue
+            donor[r], donor_pad[r] = src, st.base - st.real_len
+            copied[r], first[r] = n - k * W, P // W - k
+            break
+    slot_ids = np.full((nb,), sched.num_slots, np.int32)
+    slot_ids[:n_rows] = sorted(sched.pool._free)[:n_rows]
+    valid = np.arange(P)[None, :] >= lead[:, None]
+    calls = []
+    for i in range(int(first.min()), P // W):
+        w = i * W
+        rides = first <= i
+        calls.append((w, int(rides.sum()), (
+            np.where(rides[:, None], tokens[:, w:w + W], tok.pad_id),
+            valid[:, w:w + W] & rides[:, None],
+            np.where(rides, slot_ids, sched.num_slots),
+            np.where(rides, lead, P), donor, donor_pad,
+            np.where(first == i, copied, 0), np.asarray(w, np.int32))))
+    return nb, P, calls
+
+
+def _assert_the_parents_plan(sched, rows, prompts):
+    """The plan of a batch of ``rows`` is the parent's, argument for
+    argument, and nobody waits. Leaves the pool as it found it."""
+    reqs = [prompts.request(ids) for ids in rows]
+    admitted = [(r, list(ids), min(
+        sched._bucket_len(len(ids), sched.engine.seq_bucket),
+        sched.max_prompt_bucket)) for r, ids in zip(reqs, rows)]
+    nb, P, calls = _parents_plan(sched, admitted)
+    plan = sched._plan_prefill(admitted)
+    try:
+        assert (plan["nb"], plan["P"]) == (nb, P)
+        assert plan["deferred"] == [] and not plan["alone"]
+        assert plan["S"] == extend_window(sched.engine.config, P)
+        assert plan["key"] == ("serve_prefill", nb, P, False)
+        assert [(c["w"], c["rows"]) for c in plan["calls"]] \
+            == [c[:2] for c in calls]
+        for mine, (_, _, theirs) in zip(plan["calls"], calls):
+            assert len(mine["args"]) == len(theirs) == 8
+            for a, b in zip(mine["args"], theirs):
+                a = np.asarray(a)
+                assert a.dtype == b.dtype and np.array_equal(a, b)
+    finally:
+        for slot in plan["slots"]:
+            sched.pool.release(slot)
+        sched.pool.take_invalidations()
+    return plan
+
+
+@pytest.mark.parametrize("case", [
+    "fellows_and_no_resident_row", "riders_of_a_resident_row",
+    "three_rows_in_a_batch_of_four", "one_row", "two_buckets"])
+def test_under_sixteen_windows_the_plan_is_the_parents(dense, case):
+    """Buckets of 64-192 (every older cell's are 3-5 windows): fellows of
+    one batch all compute, riders copy from the resident row, the batch
+    pads to a power of two, and no row waits."""
+    engine, cut, sched = dense
+    with jax.default_matmul_precision("highest"):
+        prompts = _Prompts(engine, cut, seed=zlib.crc32(case.encode()),
+                           tag=case)
+        a = prompts.draw(150)
+        fellow = np.concatenate([a[:120], prompts.draw(30)])
+        stats = ServingStats(num_slots=sched.num_slots)
+        if case == "riders_of_a_resident_row":
+            assert sched.submit(prompts.request(a))
+            sched._admit(stats)
+        rows = {"fellows_and_no_resident_row": [a, fellow],
+                "riders_of_a_resident_row": [fellow, a.copy()],
+                "three_rows_in_a_batch_of_four": [a, fellow, a[:100]],
+                "one_row": [a],
+                "two_buckets": [a[:60], fellow]}[case]
+        if len(rows) > 2:
+            sched = _scheduler(engine, prefill_group=4)
+        plan = _assert_the_parents_plan(sched, rows, prompts)
+        assert plan["donors"] == 2 * (case == "riders_of_a_resident_row")
+        if stats.admitted:
+            _drained(sched, stats, 1)
+        assert (stats.prefill_leader_batches, stats.prefill_rows_deferred) \
+            == (0, 0)
+
+
+@pytest.mark.parametrize("budget", [256, LONG])
+def test_a_model_that_keeps_a_state_per_row_plans_as_the_parents(budget):
+    """Such a model takes no donor, so nobody can go ahead for anybody: its
+    fellows run their whole bucket in one call, at 4 windows a bucket and
+    at 16."""
+    with jax.default_matmul_precision("highest"):
+        engine, cut = _toy("solar-open2-int8")
+        assert engine.config.row_state_bytes
+        assert not stepbuilder.long_bucket(engine.config, LONG)
+        sched = _scheduler(engine, num_slots=8, prefill_group=4,
+                           max_prompt_len=budget)
+        prompts = _Prompts(engine, cut, seed=budget, tag=f"state{budget}")
+        rows = _family(prompts, 3, stem=budget - 60, tails=(10, 30))
+        plan = _assert_the_parents_plan(sched, rows, prompts)
+        assert len(plan["calls"]) == 1 and plan["S"] == plan["P"] == budget
 
 
 # -- the host's match --------------------------------------------------------------

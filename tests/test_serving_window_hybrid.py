@@ -392,10 +392,11 @@ def test_a_long_buckets_calls_build_donor_rows_only_where_a_row_copies(
     its riders' donor rows under a ``lax.cond`` on "a row of this call
     copies"; below it (every bucket the benchmark's cells have) it is the
     unconditional program it was. With the threshold at one window the same
-    two admissions go through the conditional program: the donorless row's
-    six calls take the branch that builds none, the rider's one call the
-    branch that copies, and both give the reference's logits and the
-    engine's tokens."""
+    two admissions meet a long bucket: the donorless row goes through the
+    program that builds no donor's rows at all (six calls: a ring of 128
+    holds no window wider than 64), the rider's one call through the
+    conditional program's branch that copies, and both give the reference's
+    logits and the engine's tokens."""
     (engine, cut) = toy
     args = dict(nb=2, P=384, guard=False)
     with jax.default_matmul_precision("highest"):
@@ -569,6 +570,123 @@ def test_a_prefill_call_moves_only_the_rows_it_names(toy, pool):
     moved = seen[-1][0][seen[-1][0] < sched.num_slots]
     assert any(not np.array_equal(b[moved], a[moved])
                for b, a in zip(seen[-1][1], seen[-1][2]))
+
+
+# -- a long bucket: one row ahead in a window wider than 64 (ISSUE 36) -----------------
+
+WIDE_WINDOW, WIDE_RING, LONG = 256, 384, 1024
+
+
+@pytest.fixture(scope="module")
+def wide():
+    """The toy with a window of 256: a ring of 384 holds a call of 128
+    positions (128 <= 384 - 256 + 1), so in the bucket of 1024 a row that
+    copies nothing runs 8 calls of 128 and its writes come round the ring
+    twice. (engine, cut, a scheduler of 8 slots, four rows to a batch.)"""
+    with jax.default_matmul_precision("highest"):
+        engine, cut = toy_engine("trinity-mini-int8", SEED,
+                                 sliding_window=WIDE_WINDOW)
+        assert ring_len(WIDE_WINDOW) == WIDE_RING
+        sched = _scheduler(engine, num_slots=8, prefill_group=4,
+                           max_prompt_len=LONG)
+        assert sched._rings == [(WIDE_WINDOW, WIDE_RING)]
+        assert stepbuilder.extend_window(
+            engine.config, LONG, False, sched._rings) == 128
+        yield engine, cut, sched
+
+
+def test_a_pass_of_near_duplicates_is_the_references_and_the_engines(wide):
+    """Six prompts of 1000-1005 ids, 990 of them common, at once in an empty
+    pool: the first goes ahead alone in 8 calls of 128 positions, round the
+    ring of 384 twice; the others wait a batch, copy all but their last 64
+    positions from it (rows and rolled rings) and run one window. Every row
+    carries the reference's logits after its prompt, and serves the
+    reference's and the static engine's tokens."""
+    engine, cut, sched = wide
+    with jax.default_matmul_precision("highest"), use_timeline() as tl:
+        prompts = IdPrompts(engine, cut, SEED + 36, GREEDY, tag="nd")
+        stem = prompts.draw(990)
+        rows = [np.concatenate([stem, prompts.draw(10 + i)])
+                for i in range(6)]
+        reqs = [prompts.request(ids) for ids in rows]
+        stats = ServingStats(num_slots=sched.num_slots)
+        for r in reqs:
+            assert sched.submit(r)
+        sched._admit(stats)
+        plans = _plans(tl)
+        assert [(p["rows"], p["nb"], p["S"], p["donors"], p["deferred"],
+                 p["windows"]) for p in plans] == [
+            (1, 1, 128, 0, 3, 8), (4, 4, EXTEND_WINDOW, 4, 0, 1),
+            (1, 1, EXTEND_WINDOW, 1, 0, 1)]
+        assert all(p["ring_refused"] == 0 for p in plans)
+        spans = [e["args"] for e in tl.events() if e["type"] == "span"
+                 and e["name"] == "sched.prefill.dispatch"]
+        assert [s["w"] for s in spans] == list(range(0, LONG, 128)) \
+            + [LONG - EXTEND_WINDOW] * 2
+        assert {s["program"] for s in spans} == {"serve_prefill"}
+        assert (stats.prefill_leader_batches, stats.prefill_rows_deferred,
+                stats.prefill_batches, stats.prefill_donors_refused_ring) \
+            == (1, 3, 10, 0)
+        assert stats.prefill_tokens_reused == sum(
+            len(r) - EXTEND_WINDOW for r in rows[1:])
+        carried = np.asarray(sched._prev_logits)
+        want = _reference_logits(cut, rows)
+        for req, w in zip(reqs, want):
+            assert np.abs(carried[_slot_of(sched, req.id)] - w).max() < TOL
+        sched._run_loop(stats)
+        assert stats.completed == 6 and stats.failed == 0
+        tokens = [np.asarray(sched.take_result(r.id).tokens) for r in reqs]
+        sample = [correct.Served(RequestSpec(
+            id=r.id, ids=ids, max_new=NEW, row_seed=0), t, True)
+            for r, ids, t in zip(reqs, rows, tokens)]
+        got = correct.gaps(cut, SEED, sample)
+        assert got["served_tokens"] == 6 * NEW and got["widest_gap"] < TOL
+        for req, ids, toks in list(zip(reqs, rows, tokens))[:2]:
+            assert_same_tokens(engine, req.id, ids, toks,
+                               alone(engine, req.id, GREEDY, NEW))
+
+
+def test_a_recycled_slot_shows_no_tenant_of_before_after_a_wide_window(wide):
+    """Slot 0 held a row of 1000 ids and 24 tokens (the test before left the
+    pool drained, every leaf full of old keys). A prompt of 965 ids takes it
+    alone, in 8 calls of 128: its rings come round twice over the old
+    tenant's, and past the bucket the row-long leaves keep the old keys
+    until the row decodes over them. The reference's logits after prefill
+    and the engine's tokens out."""
+    engine, cut, sched = wide
+    with jax.default_matmul_precision("highest"), use_timeline() as tl:
+        assert sched.pool.occupancy == 0
+        old = [x[0].copy() for x in _leaves(sched)]
+        assert all(np.abs(x).max() > 0 for x in old)
+        prompts = IdPrompts(engine, cut, SEED + 37, GREEDY, tag="rc")
+        ids = prompts.draw(965)
+        req = prompts.request(ids)
+        stats = ServingStats(num_slots=sched.num_slots)
+        assert sched.submit(req)
+        sched._admit(stats)
+        assert _slot_of(sched, req.id) == 0
+        (plan,) = _plans(tl)
+        assert (plan["S"], plan["windows"], plan["nb"]) == (128, 8, 1)
+        carried = np.asarray(sched._prev_logits[0])
+        assert np.abs(carried - _reference_logits(cut, [ids])[0]).max() < TOL
+        # past the bucket a row-long leaf still holds the old tenant's keys
+        now = [x[0] for x in _leaves(sched)]
+        assert any(x.shape[0] > LONG and np.abs(x[LONG:]).max() > 0
+                   and np.array_equal(x[LONG:], o[LONG:])
+                   for x, o in zip(now, old))
+        _all_decode_the_engines_tokens(engine, sched, stats, (req, ids))
+
+
+@pytest.mark.parametrize("guard", [False, True], ids=["plain", "guarded"])
+def test_the_wide_program_writes_rings_and_rows_in_place(wide, guard):
+    """The compiled program of a batch that copies nothing, over rings of
+    384 in a bucket of 1024: every leaf of the pool and the carried logits
+    aliased to an output, no copy of either length."""
+    engine, _, sched = wide
+    aliased, donated, copies = prefill_aliasing(sched, 1, LONG, guard,
+                                                copies=False)
+    assert aliased == donated and len(donated) == 8 * 2 + 4 + 1 + 1
+    assert copies == 0
 
 
 # -- what is refused, and what is as it was -------------------------------------------

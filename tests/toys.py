@@ -32,11 +32,13 @@ KINDS = {"dense": "mistral-7b-int8", "latent": "ax-k1-int8"}
 NEAR_TIE = 1e-4
 
 
-def toy_engine(config: str, seed: int):
-    """(engine, cut) for one family's toy, float32, ids as prompts."""
+def toy_engine(config: str, seed: int, **overrides):
+    """(engine, cut) for one family's toy, float32, ids as prompts.
+    ``overrides`` replace keys of the cut (a wider window, say)."""
     cut = modelcfg.rehearsal_cut(modelcfg.load_config(
         os.path.join(ROOT, "perfbench", "configs", config + ".json")))
     cut["eos_token_id"] = 1  # never drawn; a row may still end on it
+    cut.update(overrides)
     family = families.of(cut)
     make_layer, make_globals = W.jitted_makers(cut)
     words = W.seed_words(seed)
@@ -99,13 +101,15 @@ def alone(engine, name, settings: ModelSettings, n: int):
     return np.asarray(engine.generate([name], settings).tokens[0])[:n]
 
 
-def prefill_aliasing(sched, nb: int, P: int, guard: bool = False):
-    """The scheduler's own ``serve_prefill`` of bucket ``(nb, P)`` compiled
-    for its pool: (the parameters the module aliases to an output, the
+def prefill_aliasing(sched, nb: int, P: int, guard: bool = False,
+                     copies: bool = True):
+    """The scheduler's own ``serve_prefill`` of bucket ``(nb, P)`` (of a
+    batch that copies or not: ``stepbuilder.extend_window``) compiled for
+    its pool: (the parameters the module aliases to an output, the
     parameters that hold the pool and the carried logits, the copies of a
     whole pool leaf it makes)."""
-    fn = sched._prefill_fn(nb, P, guard)
-    S = extend_window(sched.engine.config, P)
+    fn = sched._prefill_fn(nb, P, guard, copies)
+    S = extend_window(sched.engine.config, P, copies, sched._rings)
     row = jnp.zeros((nb,), jnp.int32)
     hlo = fn._jit.lower(
         sched.engine.params, sched._cache, sched._prev_logits,

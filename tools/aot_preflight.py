@@ -59,7 +59,9 @@ def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
     from fairness_llm_tpu.runtime.stepbuilder import (
         build_serve_prefill,
         build_serve_step,
+        cache_rings,
         extend_window,
+        long_bucket,
     )
     from fairness_llm_tpu.telemetry.memory import (
         count_hlo_ops,
@@ -109,25 +111,37 @@ def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
     def host(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=replicated)
 
-    prefill = build_serve_prefill(cfg, model, nb=nb, P=prompt_budget,
-                                  guard=True, num_slots=slots)
     step = build_serve_step(
         cfg, model, SamplerSettings(temperature=0.7, top_k=0, top_p=1.0),
         tok.pad_id, tok.eos_id, num_slots=slots, chunk=8, guard=True,
         fuse=1)
     row = lambda dt: host((slots,), dt)  # noqa: E731
-    window = extend_window(cfg, prompt_budget)
-    programs = [
-        ("serve_prefill", jax.jit(prefill, donate_argnums=(1, 2)),
-         (params, cache, logits, host((nb, window), jnp.int32),
-          host((nb, window), jnp.bool_))
-         + (host((nb,), jnp.int32),) * 5 + (host((), jnp.int32),)),
-        ("serve_step", jax.jit(step, donate_argnums=(1, 2)),
+    rings = cache_rings(cfg, cache)
+
+    def prefill(rows, copies):
+        """``serve_prefill`` of ``rows`` rows at the budget's bucket, of a
+        batch that copies or of one that does not (its wide window)."""
+        window = extend_window(cfg, prompt_budget, copies, rings)
+        run = build_serve_prefill(cfg, model, nb=rows, P=prompt_budget,
+                                  guard=True, num_slots=slots, copies=copies,
+                                  rings=rings)
+        return ("serve_prefill", dict(nb=rows, window=window, copies=copies),
+                jax.jit(run, donate_argnums=(1, 2)),
+                (params, cache, logits, host((rows, window), jnp.int32),
+                 host((rows, window), jnp.bool_))
+                + (host((rows,), jnp.int32),) * 5 + (host((), jnp.int32),))
+
+    programs = [prefill(nb, True)]
+    if long_bucket(cfg, prompt_budget):
+        # The scheduler's other program of such a bucket: one row that
+        # copies nothing, sent ahead of the rows that copy from it.
+        programs.append(prefill(1, False))
+    programs.append(
+        ("serve_step", {}, jax.jit(step, donate_argnums=(1, 2)),
          (params, cache, logits, row(jnp.uint32), row(jnp.int32),
-          row(jnp.int32), row(jnp.int32), row(jnp.bool_), row(jnp.bool_))),
-    ]
+          row(jnp.int32), row(jnp.int32), row(jnp.bool_), row(jnp.bool_))))
     out = []
-    for name, fn, args in programs:
+    for name, shape, fn, args in programs:
         t0 = time.perf_counter()
         with force_pallas(), mesh, nn.logical_axis_rules(rules):
             compiled = fn.lower(*args).compile()
@@ -137,7 +151,8 @@ def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
         live = (ma.argument_size_in_bytes + ma.output_size_in_bytes
                 + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
         rec = {
-            "program": name, "model": cfg.name, "layers": cfg.num_layers,
+            "program": name, **shape, "model": cfg.name,
+            "layers": cfg.num_layers,
             "tp": tp, "slots": slots, "cache_len": cache_len,
             "device_kind": topo.devices[0].device_kind,
             "compile_s": round(wall, 1),
