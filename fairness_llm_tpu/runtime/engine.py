@@ -57,7 +57,7 @@ from fairness_llm_tpu.runtime.stepbuilder import (
     compile_key,
 )
 from fairness_llm_tpu.telemetry import get_registry
-from fairness_llm_tpu.telemetry.compilestats import record_compile
+from fairness_llm_tpu.telemetry.compilestats import record_compile, startup_stage
 from fairness_llm_tpu.telemetry.costmodel import instrument_jit, note_invocation
 from fairness_llm_tpu.telemetry.roofline import observe_decode
 from fairness_llm_tpu.telemetry.timeline import get_timeline
@@ -145,6 +145,7 @@ class DecodeEngine:
     # instance would silently replace the first's params entry).
     _mem_seq = 0
 
+    @startup_stage("engine")
     def __init__(
         self,
         model_config: ModelConfig,

@@ -133,7 +133,7 @@ from fairness_llm_tpu.telemetry import (
     emit_event,
     get_registry,
 )
-from fairness_llm_tpu.telemetry.compilestats import record_compile
+from fairness_llm_tpu.telemetry.compilestats import record_compile, startup_stage
 from fairness_llm_tpu.telemetry.costmodel import (
     instrument_jit,
     note_invocation,
@@ -186,6 +186,7 @@ class ContinuousScheduler:
     # process (a fleet holds several; handles must not collide).
     _mem_seq = 0
 
+    @startup_stage("scheduler")
     def __init__(
         self,
         engine,

@@ -27,8 +27,11 @@ attribution, occupancy over time. This package is the shared substrate:
 - ``compilestats`` — compile observability: ``compiles_total{program,
   reason}``, and from JAX's own monitoring events
   ``compile_stage_seconds_total{program, stage}``,
-  ``compile_cache_persistent_total{program, outcome}``, ``compile_seconds``
-  (the stages' sum) and ``compile.<program>`` spans.
+  ``compile_cache_persistent_total{program, outcome}``,
+  ``compile_trace_fn_seconds_total{program, fn, outer}`` (tracing by the
+  function traced), ``startup_seconds_total{stage}`` (the constructors and
+  each program's first call beyond its stages) and ``compile.<program>`` /
+  ``startup.<stage>`` spans.
 - ``roofline``  — the bytes-per-step model as LIVE gauges
   (``decode_step_bytes`` / ``achieved_hbm_gbps`` /
   ``achieved_over_achievable`` per program/replica).

@@ -63,6 +63,7 @@ Gated, like the whole attribution layer, on ``timeline.attribution_on()``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 import re
 import time
@@ -504,10 +505,9 @@ def note_invocation(program: str, wall_s: float, steps: int = 0,
     reg.gauge("cost_steps_total", **lbl).add(float(steps))
     reg.gauge("cost_calls_total", **lbl).add(1.0)
     if compiling:
-        # The whole compiling call's wall (compile_seconds' upper-bound
-        # convention) — it includes the call's own floor-charged work, so
-        # the residual on a compile-only program can read slightly
-        # negative; compile dominates in practice.
+        # The whole compiling call's wall, an upper bound — it includes the
+        # call's own floor-charged work, so the residual on a compile-only
+        # program can read slightly negative; compile dominates in practice.
         reg.gauge("cost_compile_s_total", **lbl).add(
             max(float(wall_s), 0.0))
     reg.gauge("cost_dispatch_s", component="costmodel").set(
@@ -556,11 +556,15 @@ class InstrumentedJit:
         self._memory_done = False
         self._memory_failed = False
         self._collectives = list(collectives or ())
+        self._fn = _fun_name(pyfn)
+        self._called = False
 
     def __call__(self, *args):
         # Every compile stage JAX reports inside this call is booked to
-        # this program (telemetry/compilestats.py), whenever it happens.
-        with program_scope(self.program):
+        # this program (telemetry/compilestats.py), whenever it happens;
+        # on the first call, what the call takes beyond them as well.
+        first, self._called = not self._called, True
+        with program_scope(self.program, self._fn, first):
             self._first_call_instrumentation(args)
             return self._jit(*args)
 
@@ -608,6 +612,16 @@ class InstrumentedJit:
                     logger.warning(
                         "AOT memory analysis for %s unavailable: %s: %s",
                         self.program, type(e).__name__, e)
+
+
+def _fun_name(fn) -> str:
+    """The name JAX reports ``fn``'s tracing under (``jax._src.util``)."""
+    name = getattr(fn, "__name__", None)
+    if name is not None:
+        return name
+    if isinstance(fn, functools.partial):
+        return _fun_name(fn.func)
+    return "<unnamed function>"
 
 
 def instrument_jit(pyfn, program: str, collectives=None,

@@ -238,7 +238,7 @@ def test_the_entries_name_the_cell_as_the_issue_does():
     cell = [w for w in bench["workloads"] if w["config"] != later][-1]
     conf = [c for c in bench["configs"] if c["name"] != later][-1]
     own_metrics = [m for m in bench["per_layer"]
-                   if m.get("workloads") != [later + ".catalog8k"]][-2:]
+                   if m.get("workloads") == [CELL]][-2:]
     assert cell["name"] == CELL and conf["name"] == "solar-open2-int8"
     assert [m["name"] for m in own_metrics] == [
         "kda_step_roofline.req", "row_state_kb_per_token.req"]
@@ -270,7 +270,8 @@ def test_the_entries_name_the_cell_as_the_issue_does():
         "moe_expert_load_max_over_mean.req", "sched_host_ms_per_iter.req",
         "dispatch_ms_per_call.req", "hbm_peak_pct.req", "setup_trace_lower_s",
         "setup_compile_load_s", "kda_step_roofline.req",
-        "row_state_kb_per_token.req"}
+        "row_state_kb_per_token.req", "setup_build_s", "setup_first_call_s",
+        "setup_trace_body_s"}
     assert "prefill_reuse_share.req" not in named  # nothing is copied
     for metric in own_metrics:
         assert metric["workloads"] == [CELL] and metric["moves"] == "req_per_s"

@@ -158,21 +158,38 @@ def report(events, window, kind: str, out=sys.stdout) -> dict:
 
 
 def print_setup_split(warm_s: float, out=sys.stdout) -> None:
-    """The warm-up's seconds by program and compile stage, from the
-    registry (``compile_stage_seconds_total``), beside its wall."""
+    """Set-up by stage, from the registry, beside the warm-up's wall: the
+    constructors' and the first calls' own seconds
+    (``startup_seconds_total``), each program's compile stages
+    (``compile_stage_seconds_total``), and the five functions whose
+    tracing took longest (``compile_trace_fn_seconds_total``)."""
     from fairness_llm_tpu.telemetry import get_registry
 
+    instruments = get_registry().instruments()
     rows = [(m.labels["program"], m.labels["stage"], m.value)
-            for m in get_registry().instruments()
-            if m.name == "compile_stage_seconds_total"]
+            for m in instruments if m.name == "compile_stage_seconds_total"]
     hits = {(m.labels["program"], m.labels["outcome"]): m.value
-            for m in get_registry().instruments()
+            for m in instruments
             if m.name == "compile_cache_persistent_total"}
     booked = sum(v for p, _, v in rows if p != "unscoped")
     print(f"SETUP warm-up {warm_s:.2f} s, of it booked to the step programs' "
           f"compile stages {booked:.2f} s; persistent cache {hits}", file=out)
+    startup = {}
+    for m in instruments:
+        if m.name == "startup_seconds_total":
+            startup[m.labels["stage"]] = startup.get(m.labels["stage"],
+                                                     0.0) + m.value
+    for stage, value in sorted(startup.items()):
+        print(f"  SETUP startup {stage} {value:.3f} s", file=out)
     for program, stage, value in sorted(rows):
         print(f"  SETUP {program} {stage} {value:.3f} s", file=out)
+    fns = sorted(((m.value, m.labels["program"], m.labels["fn"],
+                   m.labels["outer"]) for m in instruments
+                  if m.name == "compile_trace_fn_seconds_total"),
+                 reverse=True)
+    for value, program, fn, outer in fns[:5]:
+        print(f"  SETUP traced {program} {fn} (outer {outer}) {value:.3f} s",
+              file=out)
 
 
 class Heartbeat:
