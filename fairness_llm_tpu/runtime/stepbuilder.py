@@ -561,12 +561,13 @@ def build_serve_step(cfg, model, sampler: SamplerSettings, pad_id: int,
 # is ``models/configs.py``'s: a window layer's ring is sized against it.
 
 
-#: Windows in a bucket from which ``serve_prefill`` builds its riders'
-#: donor rows under a ``lax.cond`` on "a row of this call copies". A static
-#: choice on the bucket: the rolled rows cost a call ``(L + P) / S`` of its
-#: window's own traffic, 3% at the 3-5 windows of a 192-320 bucket and two
-#: fifths at 126 (measured at those two ends alone: ``PERF.md`` section 6,
-#: PR 35); 16 windows is a bucket of 1024 positions.
+#: Windows in a bucket from which ``serve_prefill`` takes its riders' donor
+#: rows as one rotated slice of the pool each, under a ``lax.cond`` on "a
+#: row of this call copies", and not as rows rolled over the whole extent.
+#: A static choice on the bucket: the rolled rows cost a call ``(L + P) /
+#: S`` of its window's own traffic, 3% at the 3-5 windows of a 192-320
+#: bucket and two fifths at 126 (measured at those two ends alone:
+#: ``PERF.md`` section 6); 16 windows is a bucket of 1024 positions.
 DONOR_COND_WINDOWS = 16
 
 
@@ -667,6 +668,19 @@ def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
     the forward derives a ring slot's from the row's ``key_valid`` at
     ``j_r``.
 
+    In a long bucket (``long_bucket``) step 1 is a branch on "a row of this
+    call copies": where none does, the rows' own extents; where one does,
+    each row of the call is one slice of one row of the pool, a rider's
+    donor's whole row rotated by ``donor_pad - pad`` within the leaf's
+    length (ring slot ``r`` takes the donor's ``(r + donor_pad - pad) mod
+    R``, the rule above), any other row its own. No position of the rider's
+    own is lost (its slot is new to it, and it copies in its first window
+    only), and every position the call shows it is a copied one (its first
+    window starts where the copied range ends). A short bucket's form rolls
+    the donors' rows over ``[nb, n + extent]`` and masks them in; at 126
+    windows that was a third of a rider call on the chip (``PERF.md``
+    section 5).
+
     A layer that keeps a fixed state per row (``RowState``: no position
     axis) is gathered and scattered by slot alone. Such a model's window is
     its whole bucket, so every row a call names begins in it: its leaves
@@ -744,6 +758,45 @@ def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
                                       + (1,) * (big.ndim - 2))
             return jnp.where(mask, theirs, mine)
 
+        # A long bucket's call in which a row copies: each row it names is one
+        # slice of one row of the pool (the docstring's step 1). The rest of
+        # a rider's rotated row lies in its padding (dark for good), in this
+        # window (written by it) or past it (dark until a later window
+        # writes it). The rows reach the pool by the extent's own scatter, so
+        # a batch the guard drops writes no copy either.
+        def rotated():
+            rider = copied > 0
+            src = jnp.where(rider, donor, own)
+            shift = jnp.where(rider, donor_pad - pad, 0)
+            row_of = [jax.lax.index_in_dim(src, r, keepdims=False)
+                      for r in range(nb)]
+            shift_of = [jax.lax.index_in_dim(shift, r, keepdims=False)
+                        for r in range(nb)]
+
+            def per_position(big):
+                # The row doubled over the extent and sliced at the shift:
+                # read round the leaf's end (a ring's); a full leaf's copied
+                # range never wraps (it lies inside the donor's own extent).
+                n = big.shape[1]
+                extent = min(P, n)
+                tail = big.shape[2:]
+                # One axis after the position's, so that a row's slice keeps
+                # the pool's tiling (a head axis of one would make XLA lay
+                # the whole leaf out anew for the slices).
+                flat = big.reshape(big.shape[:2] + (-1,))
+
+                def row(r):
+                    whole = jax.lax.dynamic_index_in_dim(flat, row_of[r], 0)
+                    whole = jnp.concatenate([whole, whole[:, :extent]],
+                                            axis=1)
+                    return jax.lax.dynamic_slice_in_dim(
+                        whole, jnp.mod(shift_of[r], n), extent, axis=1)
+
+                return jnp.concatenate([row(r) for r in range(nb)]).reshape(
+                    (nb, extent) + tail)
+
+            return extents(per_position)
+
         # Every row a call names begins in it where a state is kept: the
         # window of such a model is its whole bucket.
         fresh = slots < num_slots
@@ -753,32 +806,30 @@ def build_serve_prefill(cfg, model, *, nb: int, P: int, guard: bool,
             return jnp.where(fresh.reshape((nb,) + (1,) * (big.ndim - 1)),
                              jnp.zeros_like(rows), rows)
 
-        def extents(with_donor):
+        def extents(per_position):
             """Every layer's leaves and ``key_positions`` over the rows'
-            extents, with the donors' positions laid over them or not."""
-            def per_position(big):
-                return take(big, with_donor)
-
+            extents, each per-position leaf taken by ``per_position``."""
             return tuple(
                 jax.tree.map(take_row_state if isinstance(layer, RowState)
                              else per_position, layer)
                 for layer in cache.layers), per_position(cache.key_positions)
 
+        def own_rows(big):
+            return take(big, False)
+
         # A row copies in the call of its first window alone, so in a long
         # bucket most calls (every window of a batch that found no donor,
-        # every later window of a rider) name no copying row: those build no
-        # donor's rolled rows, which at 126 windows a bucket were two fifths
-        # of a call's time on the chip (``PERF.md`` section 6, PR 35). In a
-        # bucket of a few windows they are a few hundredths, fused into the
-        # extent's own gather, and the program stays the one it was.
+        # every later window of a rider) name no copying row, and take the
+        # rows' own extents. In a bucket of a few windows the donors' rolled
+        # rows are a few hundredths of a call, fused into the extent's own
+        # gather, and the program stays the one it was.
         if not copies:
-            gathered, own_positions = extents(False)
+            gathered, own_positions = extents(own_rows)
         elif long:
             gathered, own_positions = jax.lax.cond(
-                jnp.any(copied > 0), lambda: extents(True),
-                lambda: extents(False))
+                jnp.any(copied > 0), rotated, lambda: extents(own_rows))
         else:
-            gathered, own_positions = extents(True)
+            gathered, own_positions = extents(lambda big: take(big, True))
         view = cache.replace(
             layers=gathered,
             key_positions=own_positions,

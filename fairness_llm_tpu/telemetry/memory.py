@@ -501,12 +501,37 @@ def count_pool_copies(hlo_text: str, slots: int, cache_len) -> int:
     left out."""
     lengths = "|".join(str(n) for n in (
         (cache_len,) if isinstance(cache_len, int) else cache_len))
-    copy = re.compile(rf"= \w+\[{slots},(?:{lengths})[,\]][^=]* copy\(")
+    return count_unfused(
+        hlo_text, rf"= \w+\[{slots},(?:{lengths})[,\]][^=]* copy\(")
+
+
+def count_rolled_rows(hlo_text: str, rows: int, P: int, pool,
+                      lengths) -> int:
+    """Instructions of their own that make a ``[rows, n + min(P, n), ...]``
+    value of a leaf of ``pool`` whose row is ``[n, ...]`` (``n`` one of
+    ``lengths``: a per-position leaf): a batch's rows of the leaf doubled over
+    a bucket's extent of ``P``, the temporary that rolls donor rows by one
+    gather (``stepbuilder.build_serve_prefill`` in a short bucket)."""
+    import jax
+
+    doubled = "|".join(sorted({re.escape(",".join(map(str, (
+        rows, x.shape[1] + min(P, x.shape[1])) + tuple(x.shape[2:]))))
+        for x in jax.tree_util.tree_leaves(pool)
+        if len(x.shape) > 1 and x.shape[1] in lengths}))
+    return count_unfused(
+        hlo_text, rf"= \w+\[(?:{doubled})\](?:\{{[^}}]*\}})? "
+        r"(?!get-tuple-element\(|bitcast\(|parameter\()[\w-]+\(")
+
+
+def count_unfused(hlo_text: str, pattern: str) -> int:
+    """Lines of a compiled module outside its fused computations that match
+    ``pattern``: instructions that XLA materializes."""
+    found = re.compile(pattern)
     count, fused = 0, False
     for line in hlo_text.splitlines():
         if line.endswith("{") and not line.startswith(" "):
             fused = "fused_computation" in line.split("(", 1)[0]
-        elif not fused and copy.search(line):
+        elif not fused and found.search(line):
             count += 1
     return count
 

@@ -22,8 +22,10 @@ says so of each.
 """
 
 import dataclasses
+import functools
 import inspect
 import os
+import re
 import sys
 
 import jax
@@ -59,6 +61,8 @@ from toys import (  # noqa: E402
     alone,
     assert_same_tokens,
     prefill_aliasing,
+    prefill_hlo,
+    rolled_rows,
     toy_engine,
 )
 
@@ -386,31 +390,142 @@ def _prefill_text(sched, nb, P, guard):
         row, row, row, row, row, jnp.zeros((), jnp.int32)).as_text()
 
 
+#: What a short bucket's copying program rolls over the toy's bucket of 384
+#: (rows of 408 and rings of 128, two rows a batch): each donor's row
+#: doubled over the extent, as a value of its own.
+ROLLED = re.compile(r"tensor<2x(?:792|256)x2x32xf32>|tensor<2x792xi32>")
+
+
+def _bits(tree, slots=4):
+    """Every leaf of a tree that has a slot axis, as host arrays of its raw
+    bits (NaN equals NaN): the pool's rows and the carried logits."""
+    return [np.asarray(x).view(np.uint8) for x in jax.tree.leaves(tree)
+            if np.ndim(x) and np.shape(x)[0] == slots]
+
+
+def _watch_prefill(sched, rolled, poison=None):
+    """Wraps the scheduler's calls: every ``serve_prefill`` call first runs
+    through ``rolled(nb)`` (the same call in a short bucket's program, not
+    donated) and is recorded as (slots of its rows that copy, its pool and
+    logits before, its outputs, ``rolled``'s outputs). ``poison`` replaces
+    the parameters of the first call in which a row copies."""
+    inner, seen = sched._run_compiled, []
+
+    def watching(fn, *args):
+        if getattr(fn, "program", "") != "serve_prefill":
+            return inner(fn, *args)
+        slots, copied = np.asarray(args[5]), np.asarray(args[9])
+        if poison is not None and copied.any() and not seen:
+            args = (poison(args[0]),) + args[1:]
+        before = _bits(args[1:3])
+        theirs = jax.device_get(rolled(len(slots))(*args))
+        out = inner(fn, *args)
+        seen.append((slots[copied > 0], before, jax.device_get(out), theirs))
+        return out
+
+    sched._run_compiled = watching
+    return seen
+
+
+def _nan_final_norm(params):
+    return jax.tree_util.tree_map_with_path(
+        lambda path, x: jnp.full_like(x, jnp.nan)
+        if "final_norm" in jax.tree_util.keystr(path) else x, params)
+
+
+@pytest.mark.parametrize("case", [
+    "rider_at_another_padding", "a_fellow_a_rider_and_a_pad_row",
+    "guarded_batch_trips_the_guard"])
 def test_a_long_buckets_calls_build_donor_rows_only_where_a_row_copies(
-        toy, monkeypatch):
-    """From ``DONOR_COND_WINDOWS`` windows a bucket, ``serve_prefill`` builds
-    its riders' donor rows under a ``lax.cond`` on "a row of this call
-    copies"; below it (every bucket the benchmark's cells have) it is the
-    unconditional program it was. With the threshold at one window the same
-    two admissions meet a long bucket: the donorless row goes through the
-    program that builds no donor's rows at all (six calls: a ring of 128
-    holds no window wider than 64), the rider's one call through the
-    conditional program's branch that copies, and both give the reference's
-    logits and the engine's tokens."""
+        toy, monkeypatch, case):
+    """From ``DONOR_COND_WINDOWS`` windows a bucket, a call of
+    ``serve_prefill`` in which a row copies takes each row as one slice of
+    the pool (a rider's donor's row rotated by the difference of their
+    paddings), under a ``lax.cond`` on "a row of this call copies", and
+    builds no rolled rows over the whole extent; below it (every bucket but
+    Trinity's cell's) it is the program it was. With the threshold at one
+    window a resident row of 370 ids (padding 14) has decoded 8 tokens when
+    a rider of 380 (padding 4, so every ring slot takes the donor's ten
+    further on, round the ring's end) copies 316 positions. Beside it, a
+    fellow of 90 ids that copies nothing and a pad row (a batch of three),
+    or the guard on and the rider's first call made not finite. In every
+    call every slot but a rider's is, bit for bit, what the short bucket's
+    rolled rows give; a call the guard drops writes nothing at all. The
+    riders carry the reference's logits and every row decodes the engine's
+    tokens."""
     (engine, cut) = toy
     args = dict(nb=2, P=384, guard=False)
+    guard = case == "guarded_batch_trips_the_guard"
+    group = 3 if case == "a_fellow_a_rider_and_a_pad_row" else 2
     with jax.default_matmul_precision("highest"):
         plain = _scheduler(engine)
         assert 384 // EXTEND_WINDOW < stepbuilder.DONOR_COND_WINDOWS
-        assert "stablehlo.case" not in _prefill_text(plain, **args)
+        text = _prefill_text(plain, **args)
+        assert "stablehlo.case" not in text and ROLLED.search(text)
+        rolled = functools.lru_cache()(lambda nb: jax.jit(
+            stepbuilder.build_serve_prefill(
+                engine.config, engine.model, nb=nb, P=384, guard=guard,
+                num_slots=4, rings=plain._rings)))
         monkeypatch.setattr(stepbuilder, "DONOR_COND_WINDOWS", 1)
-        sched = _scheduler(engine)
-        assert "stablehlo.case" in _prefill_text(sched, **args)
-        stats, a, b = _admit_after(sched, engine, cut, 43, 370, 2, 350, 30)
+        monkeypatch.setattr(engine, "numerics_guards", guard)
+        sched = _scheduler(engine, prefill_group=group)
+        text = _prefill_text(sched, **args)
+        assert "stablehlo.case" in text and not ROLLED.search(text)
+        prompts = IdPrompts(engine, cut, 43, GREEDY, tag=case[:6])
+        stats = ServingStats(num_slots=sched.num_slots)
+        first = prompts.draw(370)
+        a = (prompts.request(first), first)
+        assert sched.submit(a[0])
+        sched._admit(stats)
+        for _ in range(2):
+            sched._decode(stats)
+        rows = [np.concatenate([first[:350], prompts.draw(30)])]
+        if group == 3:
+            rows.append(prompts.draw(90))
+        batch = [(prompts.request(ids), ids) for ids in rows]
+        seen = _watch_prefill(sched, rolled,
+                              _nan_final_norm if guard else None)
+        try:
+            for req, _ in batch:
+                assert sched.submit(req)
+            sched._admit(stats)
+        finally:
+            del sched._run_compiled
         assert stats.prefill_tokens_reused == 380 - EXTEND_WINDOW
-        carried = np.asarray(sched._prev_logits[_slot_of(sched, b[0].id)])
-        assert np.abs(carried - _reference_logits(cut, [b[1]])[0]).max() < TOL
-        _all_decode_the_engines_tokens(engine, sched, stats, a, b)
+        assert [len(riders) for riders, *_ in seen] == \
+            [1] * guard + [0] * (group == 3) + [1]
+        for riders, before, out, theirs in seen:
+            others = np.setdiff1d(np.arange(sched.num_slots), riders)
+            mine = _bits(out[:2])
+            for m, t in zip(mine, _bits(theirs[:2])):
+                assert np.array_equal(m[others], t[others])
+            if len(out) == 3 and not out[2]:  # the guard dropped the call
+                assert all(map(np.array_equal, mine, before))
+        assert stats.requeued == guard
+        for req, ids in batch:
+            carried = np.asarray(sched._prev_logits[_slot_of(sched, req.id)])
+            assert np.abs(carried - _reference_logits(cut, [ids])[0]).max() \
+                < TOL
+        _all_decode_the_engines_tokens(engine, sched, stats, a, *batch)
+
+
+@pytest.mark.parametrize("guard", [False, True], ids=["plain", "guarded"])
+def test_a_long_buckets_copying_program_writes_in_place_and_rolls_no_row(
+        toy, monkeypatch, guard):
+    """The compiled program of a long bucket's call in which rows copy
+    (``DONOR_COND_WINDOWS`` at one window): every leaf of the pool and the
+    carried logits aliased to an output, no copy of either length, and no
+    value of a rolled shape (``[2, 408 + 384, ...]``, ``[2, 2 x 128, ...]``),
+    which a short bucket's copying program holds."""
+    engine, _ = toy
+    short = _scheduler(engine)
+    assert rolled_rows(short, 2, 384, prefill_hlo(short, 2, 384, guard)) > 0
+    monkeypatch.setattr(stepbuilder, "DONOR_COND_WINDOWS", 1)
+    sched = _scheduler(engine)
+    hlo = prefill_hlo(sched, 2, 384, guard)
+    aliased, donated, copies = prefill_aliasing(sched, 2, 384, hlo=hlo)
+    assert aliased == donated and len(donated) == 8 * 2 + 4 + 1 + 1
+    assert copies == 0 and rolled_rows(sched, 2, 384, hlo) == 0
 
 
 def test_a_donor_whose_ring_moved_on_is_refused_and_the_row_computes(

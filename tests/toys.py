@@ -21,7 +21,10 @@ from fairness_llm_tpu.config import ModelSettings  # noqa: E402
 from fairness_llm_tpu.runtime.engine import DecodeEngine  # noqa: E402
 from fairness_llm_tpu.runtime.stepbuilder import extend_window  # noqa: E402
 from fairness_llm_tpu.serving import Request  # noqa: E402
-from fairness_llm_tpu.telemetry.memory import count_pool_copies  # noqa: E402
+from fairness_llm_tpu.telemetry.memory import (  # noqa: E402
+    count_pool_copies,
+    count_rolled_rows,
+)
 from perfbench import families  # noqa: E402
 from perfbench.lib import modelcfg  # noqa: E402
 from perfbench.lib import weights as W  # noqa: E402
@@ -101,21 +104,36 @@ def alone(engine, name, settings: ModelSettings, n: int):
     return np.asarray(engine.generate([name], settings).tokens[0])[:n]
 
 
-def prefill_aliasing(sched, nb: int, P: int, guard: bool = False,
-                     copies: bool = True):
+def prefill_hlo(sched, nb: int, P: int, guard: bool = False,
+                copies: bool = True) -> str:
     """The scheduler's own ``serve_prefill`` of bucket ``(nb, P)`` (of a
     batch that copies or not: ``stepbuilder.extend_window``) compiled for
-    its pool: (the parameters the module aliases to an output, the
-    parameters that hold the pool and the carried logits, the copies of a
-    whole pool leaf it makes)."""
+    its pool, as text."""
     fn = sched._prefill_fn(nb, P, guard, copies)
     S = extend_window(sched.engine.config, P, copies, sched._rings)
     row = jnp.zeros((nb,), jnp.int32)
-    hlo = fn._jit.lower(
+    return fn._jit.lower(
         sched.engine.params, sched._cache, sched._prev_logits,
         jnp.zeros((nb, S), jnp.int32), jnp.zeros((nb, S), bool),
         row, row, row, row, row, jnp.zeros((), jnp.int32),
     ).compile().as_text()
+
+
+def rolled_rows(sched, nb: int, P: int, hlo: str) -> int:
+    """``count_rolled_rows`` of a compiled ``serve_prefill`` of bucket
+    ``(nb, P)`` over the scheduler's pool."""
+    return count_rolled_rows(hlo, nb, P, sched._cache,
+                             {sched.cache_len, *sched._retained})
+
+
+def prefill_aliasing(sched, nb: int, P: int, guard: bool = False,
+                     copies: bool = True, hlo: str = None):
+    """The scheduler's own ``serve_prefill`` of bucket ``(nb, P)`` (of a
+    batch that copies or not: ``stepbuilder.extend_window``) compiled for
+    its pool (or ``hlo``, that program's text): (the parameters the module
+    aliases to an output, the parameters that hold the pool and the carried
+    logits, the copies of a whole pool leaf it makes)."""
+    hlo = hlo or prefill_hlo(sched, nb, P, guard, copies)
     header = hlo[:hlo.index("\n")]
     aliased = sorted(int(i) for i in re.findall(
         r"\((\d+), \{\}, (?:may|must)-alias\)", header))

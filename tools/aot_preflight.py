@@ -37,7 +37,8 @@ sys.path.insert(0, ROOT)
 
 def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
               tp: int, layers: int | None, nb: int,
-              bench_config: str | None = None) -> list:
+              bench_config: str | None = None,
+              hlo_out: str | None = None) -> list:
     import flax.linen as nn
     import jax
     import jax.numpy as jnp
@@ -66,6 +67,7 @@ def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
     from fairness_llm_tpu.telemetry.memory import (
         count_hlo_ops,
         count_pool_copies,
+        count_rolled_rows,
     )
 
     if bench_config:
@@ -148,6 +150,12 @@ def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
         wall = time.perf_counter() - t0
         ma = compiled.memory_analysis()
         hlo = compiled.as_text()
+        if hlo_out:
+            os.makedirs(hlo_out, exist_ok=True)
+            tag = "".join(f"_{k}{v}" for k, v in shape.items())
+            with open(os.path.join(hlo_out, f"{name}{tag}.hlo.txt"),
+                      "w") as f:
+                f.write(hlo)
         live = (ma.argument_size_in_bytes + ma.output_size_in_bytes
                 + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
         rec = {
@@ -159,6 +167,9 @@ def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
             **count_hlo_ops(hlo),
             "leaf_lengths": lengths,
             "pool_copies": count_pool_copies(hlo, slots, lengths),
+            "rolled_rows": (count_rolled_rows(
+                hlo, shape["nb"], prompt_budget, cache, lengths)
+                if name == "serve_prefill" else 0),
             "argument_gb": round(ma.argument_size_in_bytes / 1e9, 2),
             "output_gb": round(ma.output_size_in_bytes / 1e9, 2),
             "alias_gb": round(ma.alias_size_in_bytes / 1e9, 2),
@@ -188,12 +199,15 @@ if __name__ == "__main__":
                          "understates the full model)")
     ap.add_argument("--nb", type=int, default=8,
                     help="prefill batch rows (the scheduler's prefill_group)")
+    ap.add_argument("--hlo-out", default=None,
+                    help="a directory to write each compiled program's text "
+                         "to (compare two trees' programs with it)")
     a = ap.parse_args()
     from fairness_llm_tpu.utils.compile_cache import place_compile_cache
 
     place_compile_cache()
     recs = preflight(a.model, a.slots, a.prompt_budget, a.max_new, a.tp,
-                     a.layers, a.nb, a.bench_config)
+                     a.layers, a.nb, a.bench_config, a.hlo_out)
     bad = [r["program"] for r in recs
            if not r["fits"] or not r["int8_matmul"]
            or (r["wants_grouped"] and not r["grouped_int8_matmul"])
