@@ -626,7 +626,12 @@ class Attention(nn.Module):
         left_padded: bool = False,
         shared_kv: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
         write_offsets: Optional[jnp.ndarray] = None,
+        pool=None,
     ):
+        # ``pool``: the serving step's plan over the slot pool
+        # (``ops/pool_attention.PoolPlan``): its one token attends through
+        # the pool kernels, each position read once.
+        #
         # ``shared_kv``: (k, v) each [Pc, Hkv, D] — a prompt prefix COMMON to
         # every batch row, computed once and read once per step instead of
         # B times (prefix caching; decode is KV-read-bound). Shared keys sit
@@ -739,6 +744,11 @@ class Attention(nn.Module):
                 q, k, v, positions, positions, key_valid,
                 axis_name="sp", window=self.window,
             ).astype(dtype)
+        elif pool is not None:
+            from fairness_llm_tpu.ops.pool_attention import pool_attention
+
+            out = pool_attention(q[:, 0], keys, values, pool,
+                                 window=self.window)[:, None]
         elif shared_kv is None and not ring and self._flash_ok(S, left_padded):
             # Training (no cache) or first prefill (cache present but empty —
             # S > 1 is the engine's static marker; a chunked-prefill caller
@@ -1302,8 +1312,9 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, positions, cache_layer, cache_index, key_valid, key_positions,
                  left_padded=False, shared_kv=None, write_offsets=None,
-                 token_valid=None):
-        """Returns ``(x, the layer's new cache, its counters or None)``."""
+                 token_valid=None, pool=None):
+        """Returns ``(x, the layer's new cache, its counters or None)``.
+        ``pool``: the serving step's plan, for a softmax layer."""
         normed = _norm(self.config, "attn_norm")(x)
         if self.mixer == "kda":
             attn_out, new_cache = DeltaRuleAttention(self.config, name="attn")(
@@ -1313,10 +1324,11 @@ class Block(nn.Module):
             attention = LatentAttention(self.config, name="attn") \
                 if self.mixer == "mla" else Attention(
                     self.config, layer=self.layer, name="attn")
+            extra = {} if self.mixer == "mla" else dict(pool=pool)
             attn_out, new_cache = attention(
                 normed, positions, cache_layer, cache_index, key_valid,
                 key_positions, left_padded=left_padded, shared_kv=shared_kv,
-                write_offsets=write_offsets,
+                write_offsets=write_offsets, **extra,
             )
         # ``post_norms``: h = x + N2(Mixer(N1(x))), y = h + N4(MLP(N3(h))).
         post = self.config.post_norms
@@ -1363,6 +1375,7 @@ class Transformer(nn.Module):
         last_only: bool = False,  # return logits for the final position only
         shared_layers: Optional[Tuple] = None,  # per-layer (k, v) [Pc, Hkv, D] prefix KV
         write_offsets: Optional[jnp.ndarray] = None,  # [B] per-row cache slots
+        pool_spans: Optional[Tuple] = None,  # (lead, shared) [B]: the pool's plan
     ) -> Tuple[jnp.ndarray, Optional[KVCache]]:
         cfg = self.config
         dtype = _dtype_of(cfg)
@@ -1401,6 +1414,21 @@ class Transformer(nn.Module):
             key_valid = token_valid
             key_positions = positions
 
+        pool = None
+        if pool_spans is not None:
+            # The serving step over the slot pool (``ops/pool_attention``):
+            # one token a row, written at ``write_offsets``.
+            if S != 1 or cache is None or write_offsets is None:
+                raise ValueError("pool_spans is the serving step's: one "
+                                 "token a row, per-row write offsets")
+            from fairness_llm_tpu.ops.pool_attention import PoolPlan
+
+            # A model that keeps a per-row state takes no donor, so its
+            # plan names no lead (``scheduler._plan_prefill``).
+            pool = PoolPlan(*pool_spans, live=token_valid[:, 0],
+                            pos=positions[:, 0], last=write_offsets,
+                            max_len=cache.max_len,
+                            leads=not cfg.row_state_bytes)
         new_layers = []
         counters = cache.counters if cache is not None else None
         for i, (kind, mixer) in enumerate(zip(cfg.layer_kinds,
@@ -1413,6 +1441,7 @@ class Transformer(nn.Module):
                 key_valid, key_positions, left_padded=left_padded,
                 shared_kv=shared_layers[i] if shared_layers is not None else None,
                 write_offsets=write_offsets, token_valid=token_valid,
+                pool=pool,
             )
             new_layers.append(new_layer)
             if counted is not None and counters is not None:

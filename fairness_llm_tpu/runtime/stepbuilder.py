@@ -203,7 +203,8 @@ def make_greedy_loop(model, sample, pad_id: int, eos_id: int, *, batch: int,
     without it the cache writes at its own lengths (the engine layout).
 
     Returns ``loop(params, cache, prev_logits, row_seeds, emitted0, base,
-    caps, live0, shared_layers) -> final carry`` with layout
+    caps, live0, shared_layers, pool_spans=None) -> final carry`` with
+    layout
     ``(t, cache, prev_logits, done, emitted, toks, counters[, finite])``
     — ``toks`` is the ``[batch, steps]`` pad-filled emit buffer,
     ``counters`` is ``[steps_run, live_row_steps]``.
@@ -212,7 +213,7 @@ def make_greedy_loop(model, sample, pad_id: int, eos_id: int, *, batch: int,
     masked_finite = _masked_finite() if guard else None
 
     def loop(params, cache, prev_logits, row_seeds, emitted0, base, caps,
-             live0, shared_layers):
+             live0, shared_layers, pool_spans=None):
         row_keys = jax.vmap(jax.random.key)(row_seeds)
         toks0 = jnp.full((B, T), pad_id, jnp.int32)
         done0 = ~live0
@@ -237,6 +238,8 @@ def make_greedy_loop(model, sample, pad_id: int, eos_id: int, *, batch: int,
             apply_kwargs = dict(shared_layers=shared_layers)
             if per_row_offsets:
                 apply_kwargs["write_offsets"] = base + emitted
+            if pool_spans is not None:
+                apply_kwargs["pool_spans"] = pool_spans
             logits, cache = model.apply(
                 {"params": params}, tok[:, None], pos, live[:, None],
                 cache, **apply_kwargs,
@@ -503,9 +506,15 @@ def build_prefix(cfg, model, *, prefix_len: int):
 
 def build_serve_step(cfg, model, sampler: SamplerSettings, pad_id: int,
                      eos_id: int, *, num_slots: int, chunk: int,
-                     guard: bool, fuse: int = 1):
+                     guard: bool, fuse: int = 1, pool_spans: bool = False):
     """The serving decode program: the shared greedy loop over the slot
     pool.
+
+    ``pool_spans``: the program takes the scheduler's plan, ``lead`` and
+    ``shared`` ``[num_slots]`` int32 after ``reset``, and each softmax layer
+    attends through ``ops/pool_attention`` (``pool_attention.available``):
+    a row's shared positions from its lead's row, its own from itself, an
+    idle row nothing.
 
     Released-slot invalidation rides on the program entry's reset mask
     (rows in ``reset`` lose their key_valid/lengths before any attention
@@ -528,7 +537,7 @@ def build_serve_step(cfg, model, sampler: SamplerSettings, pad_id: int,
                             steps=T, guard=guard, per_row_offsets=True)
 
     def run(params, cache, prev_logits, row_seeds, emitted0, base, caps,
-            live0, reset):
+            live0, reset, *plan):
         # Fold released-slot invalidation into the step entry: rows in
         # ``reset`` lose their key_valid/lengths before any attention can
         # touch them.
@@ -538,7 +547,7 @@ def build_serve_step(cfg, model, sampler: SamplerSettings, pad_id: int,
             lengths=cache.lengths * keep.astype(cache.lengths.dtype),
         )
         c = loop(params, cache, prev_logits, row_seeds, emitted0, base,
-                 caps, live0, None)
+                 caps, live0, None, plan if pool_spans else None)
         cache, counters = c[1], c[6]
         if cache.counters is not None:
             # What the model counted on the device since the last chunk

@@ -96,6 +96,7 @@ from fairness_llm_tpu.models.transformer import (
     RowState,
     init_cache,
 )
+from fairness_llm_tpu.ops import pool_attention
 from fairness_llm_tpu.parallel import sharding as shd
 from fairness_llm_tpu.resilience.breaker import BreakerBoard
 from fairness_llm_tpu.resilience.drain import (
@@ -314,18 +315,38 @@ class ContinuousScheduler:
         # Bytes of per-row state a slot holds whatever its length (0 unless
         # a layer is recurrent): the plan takes no donor for such a model.
         self._row_state_bytes = cfg.row_state_bytes
-        # What a row's per-position leaves hold, from shapes: the bytes all
-        # its layers keep (every step reads every slot's), the lengths kept,
-        # and the (window, length) of each ring, a window layer's leaf
+        # What a row's per-position leaves hold, from shapes: the lengths
+        # kept, and the (window, length) of each ring, a window layer's leaf
         # shorter than the row, which bounds how long a resident row stays a
         # donor (``_ring_holds``).
-        kept = [(w, jax.tree.leaves(layer)) for w, layer in zip(
-            cfg.layer_windows, self._cache.layers)
-            if not isinstance(layer, RowState)]
-        self._kv_row_bytes = sum(
-            x.nbytes for _, leaves in kept for x in leaves) // self.num_slots
-        self._retained = sorted({leaves[0].shape[1] for _, leaves in kept})
+        kept = [jax.tree.leaves(layer) for layer in self._cache.layers
+                if not isinstance(layer, RowState)]
+        self._retained = sorted({leaves[0].shape[1] for leaves in kept})
         self._rings = cache_rings(cfg, self._cache)
+        # The step reads the pool through ``ops/pool_attention`` (each
+        # position once, an idle row not at all) where that is available,
+        # and the plan (``_decode_plan``) names each row's lead. Per kind of
+        # leaf that keeps something per position: (window, slots a row,
+        # layers, bytes a row-position of all its leaves, the kernels'
+        # blocks of a softmax leaf).
+        self._pool_spans = pool_attention.available(cfg, self.tp)
+        self._next_root = 0
+        _, kv_heads, _ = cfg.heads_held
+        kinds: Dict[tuple, int] = {}
+        for mixer, w, layer in zip(cfg.mixer_kinds, cfg.layer_windows,
+                                   self._cache.layers):
+            if mixer in ("gqa", "mla"):
+                leaves = jax.tree.leaves(layer)
+                n, L = leaves[0].shape[:2]
+                key = (w if mixer == "gqa" else None, L,
+                       sum(x.nbytes for x in leaves) // (n * L),
+                       mixer == "gqa" and leaves[0].dtype.itemsize)
+                kinds[key] = kinds.get(key, 0) + 1
+        self._attn_kinds = [
+            (w, L, n, row_bytes, pool_attention.blocks(
+                self.num_slots, L, kv_heads * cfg.head_dim, itemsize,
+                cfg.heads_held[0] // kv_heads) if itemsize else None)
+            for (w, L, row_bytes, itemsize), n in kinds.items()]
         self._prev_logits = jnp.zeros(
             (self.num_slots, cfg.vocab_size), jnp.float32
         )
@@ -565,7 +586,7 @@ class ContinuousScheduler:
             self.engine.config, self.engine.model, self.sampler,
             self.engine.tokenizer.pad_id, self.engine.tokenizer.eos_id,
             num_slots=self.num_slots, chunk=self.decode_chunk, guard=guard,
-            fuse=self.fuse_steps,
+            fuse=self.fuse_steps, pool_spans=self._pool_spans,
         )
         fn = instrument_jit(
             run, program, donate_argnums=self._donate(),
@@ -1401,9 +1422,16 @@ class ContinuousScheduler:
         # that no row of this batch can be another's.
         slots = []
         for r, req in enumerate(reqs):
+            if copied[r]:
+                src = self.pool.get(int(donor[r]))
+                root, held = src.root, min(int(copied[r]), src.root_shared)
+            else:
+                root, held = self._next_root, int(P - lead[r])
+                self._next_root += 1
             slot = self.pool.alloc(SlotState(
                 request=req, base=P, real_len=int(P - lead[r]),
                 ids=None if self._row_state_bytes else tokens[r, lead[r]:],
+                root=root, root_shared=held,
             ))
             assert slot is not None  # admission is free-count bounded
             slots.append(slot)
@@ -1691,11 +1719,53 @@ class ContinuousScheduler:
         prog = self._step_program()
         first_compile = step_key not in self._compiled
         fn = self._step_fn()
+        plan = self._decode_plan(live_ids) if self._pool_spans else ()
         args = (self._cache, self._prev_logits) + tuple(
-            jnp.asarray(x) for x in (seeds, emitted, base, caps, live, reset))
+            jnp.asarray(x) for x in (seeds, emitted, base, caps, live, reset)
+            + plan)
         return dict(live_ids=live_ids, injected_hang=injected_hang, fn=fn,
                     args=args, prog=prog, step_key=step_key, guard=guard,
-                    first_compile=first_compile)
+                    first_compile=first_compile, plan=plan)
+
+    def _decode_plan(self, live_ids) -> tuple:
+        """The chunk's ``(lead, shared)``, int32 a slot, for
+        ``ops/pool_attention``: which row each row reads its leading
+        positions from, and how many. Live rows of one ``root`` hold the
+        fewer of their ``root_shared`` alike, bit for bit; a group of two
+        and more reads them once, from its lead: the member that stands at
+        the smallest position (the lower slot among equals), so that under a
+        ring its ring still holds what every member's window needs of it
+        (``_rings_hold``'s arithmetic, asked of each member). The lead reads
+        its whole ``root_shared`` as its group's. A finished lead keeps its
+        row until the next chunk's reset, and the next plan names another
+        member; a released or recycled slot is in no plan. At most
+        ``pool_attention.MAX_GROUPS`` groups, those that save the most
+        positions; every other row is its own lead with ``shared`` 0."""
+        B = self.num_slots
+        lead = np.arange(B, dtype=np.int32)
+        shared = np.zeros((B,), np.int32)
+        by_root: Dict[int, list] = {}
+        for slot in live_ids:
+            st = self.pool.get(slot)
+            if st.root >= 0:
+                by_root.setdefault(st.root, []).append((
+                    st.real_len + st.emitted, slot, st.root_shared))
+        groups = []
+        for rows in by_root.values():
+            if len(rows) < 2:
+                continue
+            at, head, held = min(rows)
+            members = [(slot, min(n, held)) for pos, slot, n in rows
+                       if slot != head and self._rings_hold(at, pos)]
+            if members:
+                groups.append((sum(n for _, n in members), head, held,
+                               members))
+        groups.sort(key=lambda g: (-g[0], g[1]))
+        for _, head, held, members in groups[:pool_attention.MAX_GROUPS]:
+            shared[head] = held
+            for slot, n in members:
+                lead[slot], shared[slot] = head, n
+        return lead, shared
 
     def _decode(self, stats: ServingStats) -> bool:
         """One compiled decode chunk over the live slots; evict finished
@@ -1778,13 +1848,14 @@ class ContinuousScheduler:
             self._emit_chunk(
                 stats, live_ids, toks, emitted_after, counters, fn=fn,
                 prog=prog, step_key=step_key, first_compile=first_compile,
-                dc_t0=dc_t0, now=synced, busy_from=busy_from)
+                dc_t0=dc_t0, now=synced, busy_from=busy_from,
+                plan=ready["plan"])
         return True
 
     def _emit_chunk(self, stats: ServingStats, live_ids, toks, emitted_after,
                     counters, *, fn, prog: str, step_key, first_compile: bool,
                     dc_t0: float, now: float,
-                    busy_from: Optional[float]) -> None:
+                    busy_from: Optional[float], plan: tuple = ()) -> None:
         """What follows a chunk whose results the host holds (``now`` is the
         end of its sync): the telemetry that reads its wall, then each
         row's new tokens, first-token stamp, and eviction when done."""
@@ -1802,9 +1873,7 @@ class ContinuousScheduler:
         # idle (the step program runs over the whole pool): from shapes.
         stats.row_state_bytes_stepped += \
             steps * self.num_slots * self._row_state_bytes
-        # Likewise every slot's keys and values, as far as each layer
-        # retains them: the full layers' rows and the window layers' rings.
-        stats.kv_bytes_stepped += steps * self.num_slots * self._kv_row_bytes
+        self._count_reads(stats, live_ids, emitted_after, steps, plan)
         # Performance attribution (telemetry/): the chunk's span on this
         # scheduler's timeline track (the gap to the previous chunk feeds
         # the step_gap_s histogram — the per-step host sync ROADMAP item 3
@@ -1876,6 +1945,47 @@ class ContinuousScheduler:
                 self._finish(slot, "length", stats)
             elif st.request.expired(now):
                 self._finish(slot, "deadline", stats)
+
+    def _count_reads(self, stats: ServingStats, live_ids, emitted_after,
+                     steps: int, plan: tuple) -> None:
+        """What the chunk's attention read of the pool, from the rows'
+        positions and the plan, over its steps and the layers that keep
+        something per position. ``kv_positions_attended``: the positions the
+        live rows attended (a window layer's window). ``kv_positions_from_
+        lead``: of those, the ones a row read through another row's lead
+        span. ``kv_bytes_stepped``: the bytes of keys and values fetched:
+        through ``ops/pool_attention`` every grid step's block of both
+        kernels (``pool_attention.fetched_positions``); on the XLA path
+        every slot's whole retained row, live or idle."""
+        B = self.num_slots
+        start = np.zeros((B,), np.int64)
+        ran = np.zeros((B,), np.int64)
+        pad = np.zeros((B,), np.int64)
+        for slot in live_ids:
+            st = self.pool.get(slot)
+            start[slot] = st.real_len + st.emitted
+            ran[slot] = int(emitted_after[slot]) - st.emitted
+            pad[slot] = st.base - st.real_len
+        t = np.arange(steps)[:, None]
+        live = ran[None, :] > t  # [steps, B]
+        pos = start[None, :] + t
+        member = live & (plan[0] != np.arange(B))[None, :] if plan \
+            else np.zeros_like(live)
+        for window, L, layers, row_bytes, blocks in self._attn_kinds:
+            lo = 0 if window is None else np.maximum(pos - window + 1, 0)
+            stats.kv_positions_attended += layers * int(
+                np.where(live, pos + 1 - lo, 0).sum())
+            if plan:
+                stats.kv_positions_from_lead += layers * int(np.where(
+                    member, np.maximum(plan[1][None, :] - lo, 0), 0).sum())
+            if plan and blocks:
+                stats.kv_bytes_stepped += layers * row_bytes * \
+                    pool_attention.fetched_positions(
+                        plan[0], plan[1], live, pos, pad + pos, L=L,
+                        window=window, own_blk=blocks[0], lead_blk=blocks[1],
+                        leads=not self._row_state_bytes)
+            else:
+                stats.kv_bytes_stepped += layers * steps * B * L * row_bytes
 
     def _iterate(self, stats: ServingStats) -> bool:
         """One iteration, as one ``sched.iterate`` span whose children are

@@ -45,6 +45,15 @@ class SlotState:
     # stands at the row's own last token, which is nothing a shorter
     # shared prefix could be copied from.
     ids: Optional[np.ndarray] = None
+    # Provenance of the prompt's leading positions: the row that computed
+    # them (``root``, a number no other admission gets) and how many of
+    # them this row holds bit for bit as that row computed them
+    # (``root_shared``): a row that copied nothing holds its whole prompt
+    # as its own root; a rider holds what it copied, no more than its donor
+    # held. Two rows of one root hold the fewer of their two counts alike
+    # (``scheduler._decode_plan``).
+    root: int = -1
+    root_shared: int = 0
 
 
 class LiveRowPrefixes:

@@ -316,9 +316,15 @@ class ServingStats:
       the step program streams every slot's state, live or idle), and rows
       whose state ``serve_prefill`` zeroed on admission
     - ``kv_bytes_stepped``: bytes of keys and values (or latents) the decode
-      steps read (steps x slots x the bytes a row's layers RETAIN, from
+      steps fetched. Through ``ops/pool_attention``: the blocks of every
+      grid step of its two kernels (block-rounded; an idle row none).
+      Otherwise steps x slots x the bytes a row's layers RETAIN, from
       shapes: every slot's, live or idle; a window layer's ring counts its
-      own length)
+      own length
+    - ``kv_positions_attended`` / ``kv_positions_from_lead``: row-positions
+      the live rows attended over decode steps and layers (a window layer's
+      window), and of those the ones a row read through another row's lead
+      span (``scheduler._decode_plan``; 0 where the step takes the XLA path)
     - ``prefill_donors_refused_ring``: admitted rows whose longest-sharing
       resident row could not be their donor because a window layer's ring
       had moved past what they needed of it
@@ -354,6 +360,8 @@ class ServingStats:
     row_state_bytes_stepped: int = 0
     row_state_resets: int = 0
     kv_bytes_stepped: int = 0
+    kv_positions_attended: int = 0
+    kv_positions_from_lead: int = 0
     prefill_donors_refused_ring: int = 0
     prefill_leader_batches: int = 0
     prefill_rows_deferred: int = 0

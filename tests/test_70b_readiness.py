@@ -467,3 +467,54 @@ def test_the_ring_cells_two_programs_compile_in_place_on_v5e_topology():
         assert r["fits"] and r["all-reduce"] == 0
         # the pool is aliased: what goes in comes out in place
         assert r["alias_gb"] > 0.5
+
+
+@pytest.mark.parametrize("slots,L,hkv,heads,max_len,window", [
+    (64, 8192, 1, 8, 8192, None),   # Trinity's full layer
+    (64, 3072, 1, 8, 8192, 2048),   # Trinity's ring under its window
+    (48, 384, 8, 32, 384, 4096),    # Mistral's sweep
+    (96, 384, 4, 28, 384, None),    # Qwen2's replay
+])
+def test_the_pool_kernels_compile_in_place_on_v5e_topology(
+        slots, L, hkv, heads, max_len, window):
+    """The serving step's attention over the slot pool at the cells' real
+    leaves goes through the REAL v5e compiler: both named kernels, the leaf
+    written and read in place (no copy of it, and its ``[B, L * Hkv, D]``
+    view a bitcast, not a relayout)."""
+    import re
+
+    try:
+        from jax.experimental import topologies
+
+        td = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this env
+        pytest.skip(f"TPU topology unavailable: {type(e).__name__}")
+    from fairness_llm_tpu.models.transformer import _cache_write
+    from fairness_llm_tpu.ops import pool_attention as pa
+    from fairness_llm_tpu.ops.quant_matmul import force_pallas
+
+    one = jax.sharding.SingleDeviceSharding(td.devices[0])
+
+    def shape(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one)
+
+    def step(k, v, q, new, lead, shared, live, pos, last):
+        at = last % L if L < max_len else last
+        k = _cache_write(k, new[:, None], None, at)
+        v = _cache_write(v, new[:, None], None, at)
+        plan = pa.PoolPlan(lead, shared, live, pos, last, max_len)
+        return k, v, pa.pool_attention(q, k, v, plan, window=window)
+
+    leaf = shape((slots, L, hkv, 128), jnp.bfloat16)
+    row = shape((slots,), jnp.int32)
+    with force_pallas():
+        compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+            leaf, leaf, shape((slots, heads, 128), jnp.bfloat16),
+            shape((slots, hkv, 128), jnp.bfloat16), row, row,
+            shape((slots,), jnp.bool_), row, row).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "pool_attention_own" in text and "pool_attention_lead" in text
+    assert not re.search(rf"\[{slots},{L},[^=]*\bcopy\(", text)
+    assert not re.search(rf"= \S+\[{slots},{L * hkv},128\]\S* "
+                         r"(?:copy|reshape|transpose)\(", text)

@@ -359,7 +359,8 @@ def test_the_entries_name_the_cell_as_the_issue_does():
         "setup_trace_lower_s", "setup_compile_load_s", "hbm_peak_pct.req",
         "moe_local_pairs_per_token.req", "moe_expert_load_max_over_mean.req",
         "prefill_reuse_share.req", "kv_read_kb_per_token.req",
-        "setup_build_s", "setup_first_call_s", "setup_trace_body_s"}
+        "setup_build_s", "setup_first_call_s", "setup_trace_body_s",
+        "decode_lead_read_share.req"}
     assert "flash_attention_roofline.req" not in named  # silent since PR 29
     metric = next(m for m in bench["per_layer"]
                   if m["name"] == "kv_read_kb_per_token.req")

@@ -278,7 +278,8 @@ def test_the_counters_count_what_each_kind_of_layer_retains(served, toy):
     # per position and layer: 2 held KV heads x 32 x (k, v) x float32
     per = 2 * 32 * 2 * 4
     row = 6 * RING * per + 2 * sched.cache_len * per
-    assert sched._kv_row_bytes == row
+    assert sum(n * L * row_bytes
+               for _, L, n, row_bytes, _ in sched._attn_kinds) == row
     assert stats.kv_bytes_stepped == stats.decode_steps * 4 * row
     assert stats.prefill_tokens == sum(LENGTHS)
     assert stats.prefill_donors_refused_ring == 0

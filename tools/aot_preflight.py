@@ -54,6 +54,7 @@ def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
         init_cache,
         init_params_lowmem,
     )
+    from fairness_llm_tpu.ops import pool_attention
     from fairness_llm_tpu.ops.quant_matmul import force_pallas
     from fairness_llm_tpu.parallel import sharding as shd
     from fairness_llm_tpu.runtime.sampling import SamplerSettings
@@ -113,10 +114,13 @@ def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
     def host(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=replicated)
 
+    with force_pallas():
+        # The scheduler's choice on the chip (``ContinuousScheduler``).
+        pool_spans = pool_attention.available(cfg, tp)
     step = build_serve_step(
         cfg, model, SamplerSettings(temperature=0.7, top_k=0, top_p=1.0),
         tok.pad_id, tok.eos_id, num_slots=slots, chunk=8, guard=True,
-        fuse=1)
+        fuse=1, pool_spans=pool_spans)
     row = lambda dt: host((slots,), dt)  # noqa: E731
     rings = cache_rings(cfg, cache)
 
@@ -141,7 +145,8 @@ def preflight(model_name: str, slots: int, prompt_budget: int, max_new: int,
     programs.append(
         ("serve_step", {}, jax.jit(step, donate_argnums=(1, 2)),
          (params, cache, logits, row(jnp.uint32), row(jnp.int32),
-          row(jnp.int32), row(jnp.int32), row(jnp.bool_), row(jnp.bool_))))
+          row(jnp.int32), row(jnp.int32), row(jnp.bool_), row(jnp.bool_))
+         + (row(jnp.int32),) * (2 * pool_spans)))
     out = []
     for name, shape, fn, args in programs:
         t0 = time.perf_counter()
