@@ -14,8 +14,9 @@ Flags rather than subclasses select family behavior:
 A model whose layers are not all one kind is a subclass: latent attention
 with routed experts after leading dense layers is ``SparseLatentConfig``;
 delta-rule linear-attention layers that keep a fixed state per row beside
-gated softmax layers, every one with routed experts, is
-``LinearHybridConfig``; GQA layers that see a window (and keep a ring of it)
+softmax layers (Kimi Delta Attention with routed experts, or Gated DeltaNet
+with dense MLPs in OLMo's post-norm block) is ``LinearHybridConfig``; GQA
+layers that see a window (and keep a ring of it)
 beside GQA layers that see and keep every position, with QK-norm, post-norms
 and routed experts after leading dense layers, is ``WindowHybridConfig``. What
 each layer is and keeps is asked of the configuration (``layer_kinds`` for
@@ -190,9 +191,22 @@ class ModelConfig:
         return False
 
     @property
+    def qk_norm_whole(self) -> bool:
+        """An RMSNorm with a learned scale over the WHOLE query projection
+        and the whole key projection (every head at once, OLMo 2's), before
+        any rotation."""
+        return False
+
+    @property
+    def pre_norms(self) -> bool:
+        """A norm on the mixer's and on the MLP's INPUT."""
+        return True
+
+    @property
     def post_norms(self) -> bool:
-        """A norm on the mixer's and on the MLP's OUTPUT as well, before
-        each is added to the stream (four norms a layer)."""
+        """A norm on the mixer's and on the MLP's OUTPUT, before each is
+        added to the stream: with ``pre_norms`` four norms a layer, without
+        them OLMo 2's block (``h = x + N(Mixer(x))``)."""
         return False
 
     @property
@@ -396,24 +410,53 @@ class HeadShareConfig(RoutedExpertsConfig):
 
 @dataclasses.dataclass(frozen=True)
 class LinearHybridConfig(HeadShareConfig):
-    """Delta-rule linear-attention layers ("kda": Kimi Delta Attention,
-    arXiv 2510.26692) beside gated softmax GQA layers without rotary
-    positions, every layer with routed experts (``RoutedExpertsConfig``),
-    held as one rank's share of heads and experts (``HeadShareConfig``).
+    """Delta-rule linear-attention layers beside softmax GQA layers without
+    rotary positions, held as one rank's share of heads and experts
+    (``HeadShareConfig``).
 
     Layer ``i`` is softmax GQA where ``i`` is in ``softmax_layers`` and a
     linear layer otherwise. A linear layer keeps, per row and held head, a
-    ``linear_head_dim x linear_head_dim`` float32 state and the last
-    ``conv_width - 1`` inputs of its short convolution; a softmax layer
-    keeps k and v per held KV head and position. ``gate_rank`` is the width
-    of the two low-rank gate projections (decay and output).
+    ``linear_head_dim x value_head_dim`` float32 state (``row_state_shape``)
+    and the last ``conv_width - 1`` inputs of its short convolution; a
+    softmax layer keeps k and v per held KV head and position.
+
+    ``linear_mixer`` names the linear layer:
+
+    - "kda", Kimi Delta Attention (arXiv 2510.26692; the default): the decay
+      per key channel through a low-rank pair of ``gate_rank``, the output
+      gate ``sigmoid`` of a low-rank pair, separate q, k and v projections.
+    - "gdn", Gated DeltaNet (arXiv 2412.06464, as OLMo Hybrid uses it): the
+      decay one scalar per head and token from one full-rank projection, the
+      output gate ``SiLU`` of a full-rank projection, and q, k and v as one
+      projection, so that key widths that are no multiple of 128 lanes
+      still tile. ``linear_value_head_dim`` may be wider than the key head.
+
+    ``block`` is what is around the mixers: "kimi" (Kimi Linear's), pre-norm
+    blocks with routed experts on every layer and a sigmoid gate on the
+    softmax layers' output; "olmo2", OLMo 2's post-norm block (``h = x +
+    N(Mixer(x))``) with a dense MLP, QK-norm over the whole projection and
+    no gate.
     """
 
     softmax_layers: Tuple[int, ...] = ()
     linear_heads: int = 0
     linear_head_dim: int = 128
+    linear_value_head_dim: int = 0  # 0: linear_head_dim, a square state
     conv_width: int = 4
     gate_rank: int = 128
+    linear_mixer: str = "kda"  # "kda" | "gdn"
+    block: str = "kimi"  # "kimi" | "olmo2"
+
+    def __post_init__(self):
+        super().__post_init__()
+        for field, value, allowed in (
+                ("linear_mixer", self.linear_mixer, ("kda", "gdn")),
+                ("block", self.block, ("kimi", "olmo2"))):
+            if value not in allowed:
+                raise ValueError(f"{field} {value!r}: one of {allowed}")
+        if self.qk_norm_whole and self.head_shards > 1:
+            raise ValueError("a norm over the whole projection needs every "
+                             "head's values: no share of heads")
 
     @property
     def _head_counts(self) -> Tuple[int, int, int]:
@@ -426,11 +469,49 @@ class LinearHybridConfig(HeadShareConfig):
 
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
-        return ("moe",) * self.num_layers
+        return ("dense" if self.block == "olmo2" else "moe",) \
+            * self.num_layers
 
     @property
     def attention_gate(self) -> bool:
-        return True
+        return self.block == "kimi"
+
+    @property
+    def qk_norm_whole(self) -> bool:
+        return self.block == "olmo2"
+
+    @property
+    def pre_norms(self) -> bool:
+        return self.block == "kimi"
+
+    @property
+    def post_norms(self) -> bool:
+        return self.block == "olmo2"
+
+    @property
+    def value_head_dim(self) -> int:
+        return self.linear_value_head_dim or self.linear_head_dim
+
+    @property
+    def conv_channels(self) -> int:
+        """The convolution's channels, ``[q | k | v]`` of the held heads."""
+        return self.heads_held[2] * (2 * self.linear_head_dim
+                                     + self.value_head_dim)
+
+    @property
+    def row_state_shape(self) -> Tuple[int, int, int]:
+        """One row's state leaf in one linear layer, in the slot pool's
+        layout. A per-channel decay keeps ``[heads, dk, dv]``. A per-head
+        decay keeps ``p`` heads side by side, ``[heads / p, dk, p * dv]``,
+        with ``p`` the fewest heads whose values fill whole 128-lane tiles
+        (``ops/delta_rule.heads_side_by_side``): a 192-wide state would be
+        padded to 256 lanes, two side by side fill 384 exactly."""
+        from fairness_llm_tpu.ops.delta_rule import heads_side_by_side
+
+        h, dk, dv = self.heads_held[2], self.linear_head_dim, \
+            self.value_head_dim
+        p = 1 if self.linear_mixer == "kda" else heads_side_by_side(h, dv)
+        return h // p, dk, p * dv
 
     @property
     def layer_cache_values(self) -> Tuple[int, ...]:
@@ -440,8 +521,8 @@ class LinearHybridConfig(HeadShareConfig):
     @property
     def row_state_bytes(self) -> int:
         item = 2 if self.dtype == "bfloat16" else 4
-        h, dk = self.heads_held[2], self.linear_head_dim
-        one = h * dk * dk * 4 + (self.conv_width - 1) * 3 * h * dk * item
+        one = (math.prod(self.row_state_shape) * 4
+               + (self.conv_width - 1) * self.conv_channels * item)
         return one * self.mixer_kinds.count("kda")
 
     def mixer_param_count(self, kind: str) -> int:
@@ -449,10 +530,17 @@ class LinearHybridConfig(HeadShareConfig):
         d = self.d_model
         hq, hkv, hl = self.heads_held
         if kind == "gqa":
-            return d * self.head_dim * (3 * hq + 2 * hkv)  # q, gate, o; k, v
-        c = hl * self.linear_head_dim
-        return (4 * d * c + 2 * (d + c) * self.gate_rank + d * hl
-                + 3 * c * self.conv_width + c + hl + self.linear_head_dim)
+            gate = hq if self.attention_gate else 0
+            return d * self.head_dim * (2 * hq + gate + 2 * hkv)  # q, o; k, v
+        ck, cv = hl * self.linear_head_dim, hl * self.value_head_dim
+        if self.linear_mixer == "kda":
+            decay = (d + ck) * self.gate_rank + ck  # the pair, dt_bias
+            gate = (d + cv) * self.gate_rank
+        else:
+            decay, gate = d * hl + hl, d * cv  # the projection, dt_bias
+        return (d * (2 * ck + cv) + cv * d + decay + gate + d * hl
+                + self.conv_width * self.conv_channels + hl
+                + self.value_head_dim)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -580,6 +668,18 @@ MODEL_CONFIGS = {
         linear_head_dim=16, gate_rank=8, num_experts=16,
         experts_per_token=4, expert_d_ff=32, num_shared_experts=1,
     ),
+    # Tiny Gated DeltaNet hybrid (tests): OLMo Hybrid's kinds of layer, one
+    # period (three delta-rule layers, a state 16 x 64 a head, then one full
+    # NoPE layer with QK-norm over the projections), post-norm blocks,
+    # dense MLPs.
+    "tiny-gdn": LinearHybridConfig(
+        name="tiny-gdn", vocab_size=512, num_layers=4, num_heads=4,
+        num_kv_heads=4, d_model=64, d_ff=128, head_dim=16, max_seq_len=256,
+        norm_eps=1e-6, eos_token_id=1, dtype="float32",
+        use_flash_attention=False, pos_emb="none", softmax_layers=(3,),
+        linear_heads=4, linear_head_dim=16, linear_value_head_dim=64,
+        linear_mixer="gdn", block="olmo2",
+    ),
     # Tiny window/full hybrid (tests): two periods of three window layers
     # (rotary, window 16, a ring of 128 in the slot pool) and one full layer
     # (no rotary); layers 0-1 dense, then 16 experts top-4, all held.
@@ -629,6 +729,19 @@ MODEL_CONFIGS = {
         num_kv_heads=8, d_model=4096, d_ff=14336, head_dim=128, max_seq_len=8192,
         rope_theta=1000000.0, sliding_window=4096, eos_token_id=2, pad_token_id=0,
     ),
+    # OLMo Hybrid 7B (allenai/Olmo-Hybrid-7B config.json): 24 Gated DeltaNet
+    # layers (30 heads, a 96 x 192 state each) and 8 full NoPE layers (30
+    # heads of 128, QK-norm over the projections), three linear to one;
+    # post-norm blocks, SwiGLU 11008, no experts.
+    "olmo-hybrid-7b": LinearHybridConfig(
+        name="olmo-hybrid-7b", vocab_size=100352, num_layers=32,
+        num_heads=30, num_kv_heads=30, d_model=3840, d_ff=11008,
+        head_dim=128, max_seq_len=65536, norm_eps=1e-6, pos_emb="none",
+        use_flash_attention=False,
+        softmax_layers=tuple(range(3, 32, 4)), linear_heads=30,
+        linear_head_dim=96, linear_value_head_dim=192, linear_mixer="gdn",
+        block="olmo2",
+    ),
     "gemma-7b": ModelConfig(
         name="gemma-7b", vocab_size=256000, num_layers=28, num_heads=16,
         num_kv_heads=16, d_model=3072, d_ff=24576, head_dim=256, max_seq_len=8192,
@@ -662,7 +775,8 @@ MODEL_CONFIGS = {
 #   mistral-7b-int8  ~7.4 GB, qwen2-7b-int8 ~8.2 GB, gemma-7b-int8 ~9.3 GB
 #                    — the configs[2] cross-model set, single chip each
 #                      (tied embeddings, e.g. gemma's 256k vocab, stay bf16)
-for _base in ("llama3-8b", "llama3-70b", "mistral-7b", "gemma-7b", "qwen2-7b"):
+for _base in ("llama3-8b", "llama3-70b", "mistral-7b", "gemma-7b", "qwen2-7b",
+              "olmo-hybrid-7b"):
     _cfg = MODEL_CONFIGS[_base]
     MODEL_CONFIGS[f"{_base}-int8"] = dataclasses.replace(
         _cfg, name=f"{_base}-int8", weight_quant="int8"

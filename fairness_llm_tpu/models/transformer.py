@@ -135,8 +135,8 @@ class RowState:
     ``conv_width - 1`` inputs (the q, k and v projections side by side,
     before the convolution). No leaf has a position axis."""
 
-    state: jnp.ndarray  # [B, heads, dk, dv] float32
-    conv: jnp.ndarray  # [B, conv_width - 1, 3 * heads * dk], the model dtype
+    state: jnp.ndarray  # [B, *config.row_state_shape] float32
+    conv: jnp.ndarray  # [B, conv_width - 1, config.conv_channels], the model dtype
 
 
 @flax.struct.dataclass
@@ -198,22 +198,20 @@ def init_cache(config: ModelConfig, batch_size: int, max_len: int, dtype=None,
     the parity tests' oracle) every layer keeps every position and a window
     is a mask. An int8 cache keeps every position too (no int8 ring)."""
     dtype = dtype or _dtype_of(config)
-    _, kv_heads, linear_heads = config.heads_held
+    kv_heads = config.heads_held[1]
     shape = (batch_size, max_len, kv_heads, config.head_dim)
     kept = config.retained_len(max_len) if ring and not config.kv_cache_quant \
         else (max_len,) * config.num_layers
     if config.row_state_bytes:
         if config.kv_cache_quant:
             raise NotImplementedError("no int8 form of a per-row state")
-        dk, channels = config.linear_head_dim, 3 * linear_heads * \
-            config.linear_head_dim
         layers = tuple(
             LayerCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
             if kind == "gqa" else RowState(
-                state=jnp.zeros((batch_size, linear_heads, dk, dk),
+                state=jnp.zeros((batch_size,) + config.row_state_shape,
                                 jnp.float32),
-                conv=jnp.zeros((batch_size, config.conv_width - 1, channels),
-                               dtype))
+                conv=jnp.zeros((batch_size, config.conv_width - 1,
+                                config.conv_channels), dtype))
             for kind in config.mixer_kinds)
     elif config.attention == "mla":
         if config.kv_cache_quant:
@@ -676,6 +674,13 @@ class Attention(nn.Module):
             with jax.named_scope("attn.qk_norm"):
                 q = RMSNorm(eps=cfg.norm_eps, name="q_norm")(q)
                 k = RMSNorm(eps=cfg.norm_eps, name="k_norm")(k)
+        elif cfg.qk_norm_whole:
+            # Over the whole projection, every head at once (OLMo 2).
+            with jax.named_scope("attn.qk_norm"):
+                q = RMSNorm(eps=cfg.norm_eps, name="q_norm")(
+                    q.reshape(B, S, q_dim)).reshape(q.shape)
+                k = RMSNorm(eps=cfg.norm_eps, name="k_norm")(
+                    k.reshape(B, S, kv_dim)).reshape(k.shape)
 
         if self.rotary:
             q = rope(q, positions, cfg.rope_theta)
@@ -1102,30 +1107,38 @@ def _conv_init(key, shape):
 
 
 class DeltaRuleAttention(nn.Module):
-    """A delta-rule linear-attention layer (Kimi Delta Attention, arXiv
-    2510.26692) over the heads this chip holds. Per token ``t``, with ``x``
-    the normed input:
+    """A delta-rule linear-attention layer over the heads this chip holds,
+    Kimi Delta Attention (arXiv 2510.26692) or Gated DeltaNet (arXiv
+    2412.06464) as ``config.linear_mixer`` says (``LinearHybridConfig``).
+    Per token ``t``, with ``x`` the layer's input:
 
-    - ``q~, k~, v~ = W x``; a causal depthwise convolution of ``conv_width``
-      taps over time on each channel (tap ``conv_width - 1`` on the current
-      token, zeros before a row's first real token), then SiLU;
+    - ``q~, k~, v~ = W x`` (``dk``, ``dk`` and ``dv`` a head; one ``[q | k |
+      v]`` projection for Gated DeltaNet); a causal depthwise convolution of
+      ``conv_width`` taps over time on each channel (tap ``conv_width - 1``
+      on the current token, zeros before a row's first real token), then
+      SiLU;
     - ``q = l2norm(q^) / sqrt(dk)``, ``k = l2norm(k^)``, ``v = v^``, per head;
-    - ``a = exp(-exp(A_log[h]) softplus(W_fb (W_fa x) + dt_bias))`` in
-      (0, 1)^dk, ``b = 2 sigmoid(w_beta . x)`` in (0, 2);
+    - the decay: per key channel, ``a = exp(-exp(A_log[h]) softplus(W_fb
+      (W_fa x) + dt_bias))`` in (0, 1)^dk; per head, ``a = exp(-exp(A_log[h])
+      softplus(w_a,h . x + dt_bias[h]))``, one scalar;
+    - ``b = 2 sigmoid(w_beta . x)`` in (0, 2);
     - ``S = (I - b k k^T) Diag(a) S + b k v^T``, ``o = S^T q``
-      (``ops/delta_rule.py``), ``S`` zero before the row's first token;
-    - ``y = W_o concat_h(RMSNorm(o) * sigmoid(W_gb (W_ga x)))``.
+      (``ops/delta_rule.py``), ``S`` ``[dk, dv]``, zero before the row's
+      first token;
+    - ``y = W_o concat_h(RMSNorm(o) * gate)``, the gate ``sigmoid(W_gb (W_ga
+      x))`` or ``SiLU(W_g x)``.
 
     Float32: the state, ``a``, ``b``, the l2 norms, the convolution's sum
     and the update. What the layer keeps between calls is a ``RowState``: the
-    state and the last ``conv_width - 1`` rows of ``[q~ | k~ | v~]``. A token
+    state (``config.row_state_shape``: a per-head decay's keeps heads side by
+    side) and the last ``conv_width - 1`` rows of ``[q~ | k~ | v~]``. A token
     whose ``token_valid`` is False (padding, an idle slot) moves neither. One
     promise is asked of the caller: within a call a row's valid tokens are
     contiguous (left- or right-padded), which every caller here keeps.
 
-    One token with a cache is the decode step (``kda_step``, a Pallas kernel
-    over the whole pool on the TPU); anything longer runs the recurrence as a
-    ``lax.scan`` over its positions.
+    One token with a cache is the decode step (``kda_step`` or
+    ``gdn_step``, a Pallas kernel over the whole pool on the TPU); anything
+    longer runs the recurrence as a ``lax.scan`` over its positions.
     """
 
     config: ModelConfig
@@ -1133,7 +1146,12 @@ class DeltaRuleAttention(nn.Module):
     @nn.compact
     def __call__(self, x, cache_layer: Optional[RowState], token_valid,
                  shared_kv=None, write_offsets=None):
-        from fairness_llm_tpu.ops.delta_rule import kda_step, kda_update
+        from fairness_llm_tpu.ops.delta_rule import (
+            gdn_step,
+            gdn_update,
+            kda_step,
+            kda_update,
+        )
 
         cfg = self.config
         B, S, d = x.shape
@@ -1149,19 +1167,28 @@ class DeltaRuleAttention(nn.Module):
                 "linear-attention layers")
         dtype, f32 = _dtype_of(cfg), jnp.float32
         H, dk, W = cfg.heads_held[2], cfg.linear_head_dim, cfg.conv_width
-        C = H * dk
+        dv = cfg.value_head_dim
+        C, Cv = H * dk, H * dv
+        widths = (C, C, Cv)
+        per_head = cfg.linear_mixer == "gdn"  # and a fused qkv, a SiLU gate
 
-        pre = jnp.concatenate(
-            [_linear(cfg, C, "embed", None, name)(x).astype(dtype)
-             for name in ("q_proj", "k_proj", "v_proj")], axis=-1)
+        if per_head:
+            pre = _linear(cfg, sum(widths), "embed", None, "qkv_proj")(
+                x).astype(dtype)
+        else:
+            pre = jnp.concatenate(
+                [_linear(cfg, n, "embed", None, name)(x).astype(dtype)
+                 for n, name in zip(widths, ("q_proj", "k_proj", "v_proj"))],
+                axis=-1)
         taps = jnp.concatenate(
-            [self.param(name, _conv_init, (W, C))
-             for name in ("conv_q", "conv_k", "conv_v")], axis=-1)
+            [self.param(name, _conv_init, (W, n))
+             for n, name in zip(widths, ("conv_q", "conv_k", "conv_v"))],
+            axis=-1)
 
         with jax.named_scope("kda.conv"):
-            history = (jnp.zeros((B, W - 1, 3 * C), dtype)
+            history = (jnp.zeros((B, W - 1, sum(widths)), dtype)
                        if cache_layer is None else cache_layer.conv)
-            ext = jnp.concatenate([history, pre], axis=1)  # [B, W-1+S, 3C]
+            ext = jnp.concatenate([history, pre], axis=1)  # [B, W-1+S, .]
             # The kept inputs must sit right before the row's first valid
             # token, whatever padding leads the call: entry j of the line
             # the convolution reads is the history while j - lead is under
@@ -1173,44 +1200,67 @@ class DeltaRuleAttention(nn.Module):
             line = jnp.take_along_axis(ext, src[:, :, None], axis=1)
             mixed = sum(line[:, i:i + S].astype(f32) * taps[i].astype(f32)
                         for i in range(W))
-            mixed = jax.nn.silu(mixed).reshape(B, S, 3, H, dk)
+            mixed = jax.nn.silu(mixed)
             new_conv = line[:, S:]  # unchanged for a row with no valid token
-            q, k, v = mixed[:, :, 0], mixed[:, :, 1], mixed[:, :, 2]
+            if dv == dk:  # slices would compile a square state's layers
+                # to another program than the one Solar's cell measures
+                mixed = mixed.reshape(B, S, 3, H, dk)
+                q, k, v = mixed[:, :, 0], mixed[:, :, 1], mixed[:, :, 2]
+            else:
+                q = mixed[..., :C].reshape(B, S, H, dk)
+                k = mixed[..., C:2 * C].reshape(B, S, H, dk)
+                v = mixed[..., 2 * C:].reshape(B, S, H, dv)
             q = q * jax.lax.rsqrt(
                 jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6) * dk ** -0.5
             k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
 
         with jax.named_scope("kda.gates"):
-            rank = cfg.gate_rank
             a_log = self.param("A_log", nn.initializers.zeros, (H,))
-            dt_bias = self.param("dt_bias", nn.initializers.zeros, (C,))
+            dt_bias = self.param("dt_bias", nn.initializers.zeros,
+                                 (H if per_head else C,))
             w_beta, _ = _Kernel((d, H), ("embed", None), False,
                                 name="beta_proj")()
-            f = _linear(cfg, C, None, None, "f_b_proj")(
-                _linear(cfg, rank, "embed", None, "f_a_proj")(x))
-            decay = jax.nn.softplus(f.astype(f32) + dt_bias.astype(f32))
-            a = jnp.exp(-jnp.exp(a_log.astype(f32))[:, None]
-                        * decay.reshape(B, S, H, dk))
+            if per_head:
+                w_a, _ = _Kernel((d, H), ("embed", None), False,
+                                 name="a_proj")()
+                f = jnp.einsum("bsd,dh->bsh", x.astype(dtype),
+                               w_a.astype(dtype), preferred_element_type=f32)
+                a = jnp.exp(-jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
+                    f + dt_bias.astype(f32)))  # [B, S, H]
+            else:
+                rank = cfg.gate_rank
+                f = _linear(cfg, C, None, None, "f_b_proj")(
+                    _linear(cfg, rank, "embed", None, "f_a_proj")(x))
+                decay = jax.nn.softplus(f.astype(f32) + dt_bias.astype(f32))
+                a = jnp.exp(-jnp.exp(a_log.astype(f32))[:, None]
+                            * decay.reshape(B, S, H, dk))
             b = 2.0 * jax.nn.sigmoid(jnp.einsum(
                 "bsd,dh->bsh", x.astype(dtype), w_beta.astype(dtype),
                 preferred_element_type=f32))
-            g = jax.nn.sigmoid(_linear(cfg, C, None, None, "g_b_proj")(
-                _linear(cfg, rank, "embed", None, "g_a_proj")(x)).astype(f32))
+            if per_head:
+                g = jax.nn.silu(_linear(cfg, Cv, "embed", None, "g_proj")(
+                    x).astype(f32))
+            else:
+                g = jax.nn.sigmoid(_linear(cfg, C, None, None, "g_b_proj")(
+                    _linear(cfg, cfg.gate_rank, "embed", None, "g_a_proj")(
+                        x)).astype(f32))
             # A token that is not valid decays nothing and writes nothing.
             tv = token_valid[:, :, None]
-            a = jnp.where(tv[..., None], a, 1.0)
+            a = jnp.where(tv if per_head else tv[..., None], a, 1.0)
             b = jnp.where(tv, b, 0.0)
 
+        step, update = (gdn_step, gdn_update) if per_head else \
+            (kda_step, kda_update)
         with jax.named_scope("kda.recurrence"):
-            state = (jnp.zeros((B, H, dk, dk), f32) if cache_layer is None
-                     else cache_layer.state)
+            state = (jnp.zeros((B,) + cfg.row_state_shape, f32)
+                     if cache_layer is None else cache_layer.state)
             if S == 1 and cache_layer is not None:
-                state, o = kda_step(state, q[:, 0], k[:, 0], v[:, 0],
-                                    a[:, 0], b[:, 0])
+                state, o = step(state, q[:, 0], k[:, 0], v[:, 0], a[:, 0],
+                                b[:, 0])
                 o = o[:, None]
             else:
                 def token(st, qkvab):
-                    new, out = kda_update(st, *qkvab[:5])
+                    new, out = update(st, *qkvab[:5])
                     keep = qkvab[5][:, None, None, None]
                     return jnp.where(keep, new, st), out
 
@@ -1220,7 +1270,7 @@ class DeltaRuleAttention(nn.Module):
                 o = o.swapaxes(0, 1)  # [B, S, H, dv]
 
         o = RMSNorm(eps=cfg.norm_eps, name="o_norm")(o)
-        out = (o.astype(f32).reshape(B, S, C) * g).astype(dtype)
+        out = (o.astype(f32).reshape(B, S, Cv) * g).astype(dtype)
         out = _linear(cfg, cfg.d_model, None, "embed", "o_proj")(out)
         new_cache = None if cache_layer is None else \
             RowState(state=state, conv=new_conv)
@@ -1315,7 +1365,10 @@ class Block(nn.Module):
                  token_valid=None, pool=None):
         """Returns ``(x, the layer's new cache, its counters or None)``.
         ``pool``: the serving step's plan, for a softmax layer."""
-        normed = _norm(self.config, "attn_norm")(x)
+        # ``pre_norms`` off (OLMo 2): the mixer and the MLP read the stream
+        # itself, and only their outputs are normed.
+        pre = self.config.pre_norms
+        normed = _norm(self.config, "attn_norm")(x) if pre else x
         if self.mixer == "kda":
             attn_out, new_cache = DeltaRuleAttention(self.config, name="attn")(
                 normed, cache_layer, token_valid, shared_kv=shared_kv,
@@ -1336,11 +1389,12 @@ class Block(nn.Module):
             attn_out = _norm(self.config, "attn_post_norm")(attn_out)
         x = x + attn_out
         counters = None
+        mlp_in = _norm(self.config, "mlp_norm")(x) if pre else x
         if self.kind == "moe":
             y, counters = SparseMLP(self.config, name="mlp")(
-                _norm(self.config, "mlp_norm")(x), token_valid)
+                mlp_in, token_valid)
         else:
-            y = MLP(self.config, name="mlp")(_norm(self.config, "mlp_norm")(x))
+            y = MLP(self.config, name="mlp")(mlp_in)
         if post:
             y = _norm(self.config, "mlp_post_norm")(y)
         x = x + y

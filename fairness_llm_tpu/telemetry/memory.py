@@ -476,14 +476,15 @@ def count_hlo_ops(hlo_text: str) -> Dict[str, int]:
     the all-reduces GSPMD inserts after partitioning (invisible to the jaxpr
     walk)."""
     counts = {"int8_matmul": 0, "grouped_int8_matmul": 0,
-              "flash_attention": 0, "kda_step": 0, "pool_attention_own": 0,
+              "flash_attention": 0, "kda_step": 0, "gdn_step": 0,
+              "pool_attention_own": 0,
               "pool_attention_lead": 0, "all-reduce": 0}
     for line in hlo_text.splitlines():
         if "tpu_custom_call" in line:
             # The grouped product's name ends in the plain one's: look for
             # it first, and count a call once.
             for kernel in ("grouped_int8_matmul", "int8_matmul",
-                           "flash_attention", "kda_step",
+                           "flash_attention", "kda_step", "gdn_step",
                            "pool_attention_own", "pool_attention_lead"):
                 if f"{kernel}/pallas_call" in line:
                     counts[kernel] += 1

@@ -518,3 +518,38 @@ def test_the_pool_kernels_compile_in_place_on_v5e_topology(
     assert not re.search(rf"\[{slots},{L},[^=]*\bcopy\(", text)
     assert not re.search(rf"= \S+\[{slots},{L * hkv},128\]\S* "
                          r"(?:copy|reshape|transpose)\(", text)
+
+
+def test_gdn_step_compiles_in_place_on_v5e_topology():
+    """``olmo-hybrid-7b-int8``'s decode update of the slot pool's state at
+    its real leaf (48 rows, 30 heads of 96 x 192, two side by side) goes
+    through the REAL v5e compiler: one named kernel, the leaf aliased in
+    place and never copied (a 192-lane leaf would be padded to 256)."""
+    import re
+
+    try:
+        from jax.experimental import topologies
+
+        td = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this env
+        pytest.skip(f"TPU topology unavailable: {type(e).__name__}")
+    from fairness_llm_tpu.ops.delta_rule import gdn_step
+    from fairness_llm_tpu.ops.quant_matmul import force_pallas
+
+    one = jax.sharding.SingleDeviceSharding(td.devices[0])
+
+    def shape(s):
+        return jax.ShapeDtypeStruct(s, jnp.float32, sharding=one)
+
+    with force_pallas():
+        compiled = jax.jit(gdn_step, donate_argnums=(0,)).lower(
+            shape((48, 15, 96, 384)), shape((48, 30, 96)),
+            shape((48, 30, 96)), shape((48, 30, 192)), shape((48, 30)),
+            shape((48, 30))).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "gdn_step" in text
+    assert not re.search(r"\[48,15,96,384\][^=]*\bcopy\(", text)
+    assert not re.search(r"= f32\[720,96,384\]\S* copy\(", text)
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= 48 * 15 * 96 * 384 * 4

@@ -117,3 +117,71 @@ def test_the_update_under_jit_and_scan_keeps_float32():
 
     new, o = run(state)
     assert new.dtype == jnp.float32 and o.shape == (3, 2, 2, 16)
+
+
+# -- Gated DeltaNet: one decay per head, a state that is not square -------------
+
+
+def _side_by_side(state, p):
+    """``[R, H, dk, dv]`` -> the pool's ``[R, H / p, dk, p * dv]``."""
+    r, h, dk, dv = state.shape
+    return state.reshape(r, h // p, p, dk, dv).transpose(
+        0, 1, 3, 2, 4).reshape(r, h // p, dk, p * dv)
+
+
+def _gdn_operands(rows, heads, dk, dv, seed=0):
+    rng = np.random.default_rng(seed)
+    f = np.float32
+    state = rng.standard_normal((rows, heads, dk, dv)).astype(f)
+    q = rng.standard_normal((rows, heads, dk)).astype(f) / np.sqrt(dk)
+    k = rng.standard_normal((rows, heads, dk)).astype(f)
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    v = rng.standard_normal((rows, heads, dv)).astype(f)
+    a = rng.uniform(0.2, 1.0, (rows, heads)).astype(f)
+    b = rng.uniform(0.0, 2.0, (rows, heads)).astype(f)
+    return tuple(map(jnp.asarray, (state, q, k, v, a, b)))
+
+
+@pytest.mark.parametrize("form", ["kernel", "plain"])
+@pytest.mark.parametrize("rows,heads,dk,dv", [(3, 4, 16, 64), (2, 4, 96, 192)])
+def test_gdn_forms_are_the_delta_rule_with_the_decay_per_head(
+        form, rows, heads, dk, dv):
+    """``gdn_step`` (Pallas, interpret mode) and ``gdn_update`` on the pool's
+    side-by-side layout against ``kda_update`` with each head's decay
+    broadcast over ``dk``, at value widths that are no multiple of 128: two
+    heads fill 128 or 384 lanes."""
+    state, q, k, v, a, b = _gdn_operands(rows, heads, dk, dv)
+    p = delta_rule.heads_side_by_side(heads, dv)
+    assert p == 2 and (p * dv) % 128 == 0
+    want_new, want_o = delta_rule.kda_update(
+        state, q, k, v, jnp.broadcast_to(a[..., None], q.shape), b)
+    packed = _side_by_side(state, p)
+    if form == "kernel":
+        new, o = delta_rule.gdn_step(packed, q, k, v, a, b, interpret=True)
+    else:
+        new, o = delta_rule.gdn_update(packed, q, k, v, a, b)
+    assert new.shape == packed.shape and o.shape == v.shape
+    assert float(jnp.max(jnp.abs(new - _side_by_side(want_new, p)))) < TOL
+    assert float(jnp.max(jnp.abs(o - want_o))) < TOL
+
+
+def test_gdn_step_is_witnessed_and_an_idle_row_moves_nothing():
+    def count(path):
+        return sum(m.value for m in get_registry().instruments()
+                   if m.name == COUNTER and m.labels.get("kernel") == "gdn_step"
+                   and m.labels.get("path") == path)
+
+    before = count("pallas"), count("xla")
+    state, q, k, v, a, b = _gdn_operands(4, 4, 16, 64, seed=1)
+    packed = _side_by_side(state, 2)
+    idle = jnp.arange(4) % 2 == 1
+    a = jnp.where(idle[:, None], 1.0, a)
+    b = jnp.where(idle[:, None], 0.0, b)
+    new, _ = delta_rule.gdn_step(packed, q, k, v, a, b, interpret=True)
+    assert bool(jnp.all(new[1::2] == packed[1::2]))
+    # 48 values a head fill no lane tile in any number of heads of 6
+    assert delta_rule.heads_side_by_side(6, 48) == 1
+    assert not delta_rule.gdn_step_tileable(16, 48)
+    odd = _gdn_operands(2, 6, 16, 48, seed=2)
+    delta_rule.gdn_step(*odd, interpret=True)
+    assert (count("pallas"), count("xla")) == (before[0] + 1, before[1] + 1)

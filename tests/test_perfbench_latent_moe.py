@@ -150,17 +150,21 @@ def test_model_flops_counts_local_pairs_from_the_programs_counter():
 def test_the_entries_name_the_cell_as_the_issue_does():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    # appended, each at the end of its list; what PRs 33 and 35 appended
-    # comes after
-    later = ("solar-open2-int8", "trinity-mini-int8")
-    assert [w["name"] for w in bench["workloads"]
-            if w["config"] not in later][-1] == CELL
-    assert [c["name"] for c in bench["configs"]
-            if c["name"] not in later][-1] == "ax-k1-int8"
+    # appended, each after every entry of its list that was there before;
+    # later PRs append after it, so each is found by name
+    earlier = ("mistral-7b-int8", "qwen2-7b-int8")
+
+    def after_earlier(names, item):
+        return names.count(item) == 1 and all(
+            names.index(item) > i for i, n in enumerate(names)
+            if n.startswith(earlier))
+
+    assert after_earlier([w["name"] for w in bench["workloads"]], CELL)
+    assert after_earlier([c["name"] for c in bench["configs"]],
+                         "ax-k1-int8")
     for metric in bench["end_to_end"] + bench["per_layer"]:
         if CELL in metric.get("workloads", ()):
-            assert [w for w in metric["workloads"]
-                    if not w.startswith(later)][-1] == CELL
+            assert after_earlier(metric["workloads"], CELL)
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) \
         == ("ax-k1-int8", "sweep180", 1) and len(cell["why"]) <= 200

@@ -233,19 +233,28 @@ def test_the_two_layer_metrics_name_their_readers():
 def test_the_entries_name_the_cell_as_the_issue_does():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    # appended, each at the end of its list; what PR 35 appended comes after
-    later = "trinity-mini-int8"
-    cell = [w for w in bench["workloads"] if w["config"] != later][-1]
-    conf = [c for c in bench["configs"] if c["name"] != later][-1]
+    # appended, each after every entry of its list that was there before;
+    # later PRs append after it, so each is found by name
+    earlier = ("mistral-7b-int8", "qwen2-7b-int8", "ax-k1-int8")
+
+    def after_earlier(names, item):
+        return names.count(item) == 1 and all(
+            names.index(item) > i for i, n in enumerate(names)
+            if n.startswith(earlier))
+
+    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
+    conf = next(c for c in bench["configs"]
+                if c["name"] == "solar-open2-int8")
+    assert after_earlier([w["name"] for w in bench["workloads"]], CELL)
+    assert after_earlier([c["name"] for c in bench["configs"]], conf["name"])
+    # the cell's own metrics list it first; later cells may append
     own_metrics = [m for m in bench["per_layer"]
-                   if m.get("workloads") == [CELL]][-2:]
-    assert cell["name"] == CELL and conf["name"] == "solar-open2-int8"
+                   if m.get("workloads", [None])[0] == CELL]
     assert [m["name"] for m in own_metrics] == [
         "kda_step_roofline.req", "row_state_kb_per_token.req"]
     for metric in bench["end_to_end"] + bench["per_layer"]:
         if CELL in metric.get("workloads", ()):
-            assert [w for w in metric["workloads"]
-                    if not w.startswith(later)][-1] == CELL
+            assert after_earlier(metric["workloads"], CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) \
         == ("solar-open2-int8", "sweep180", 1) and len(cell["why"]) <= 200
     config = _raw()
@@ -274,4 +283,4 @@ def test_the_entries_name_the_cell_as_the_issue_does():
         "setup_trace_body_s"}
     assert "prefill_reuse_share.req" not in named  # nothing is copied
     for metric in own_metrics:
-        assert metric["workloads"] == [CELL] and metric["moves"] == "req_per_s"
+        assert metric["workloads"][0] == CELL and metric["moves"] == "req_per_s"

@@ -321,18 +321,31 @@ def test_the_cell_is_in_the_benchmark_once_and_its_file_holds_no_entries():
 
 def test_the_entries_name_the_cell_as_the_issue_does():
     bench = _bench()
-    # appended, each at the end of its list
-    assert bench["workloads"][-1]["name"] == CELL
-    assert bench["configs"][-1]["name"] == "trinity-mini-int8"
+    # appended, each after every entry of its list that was there before;
+    # later PRs append after it, so each is found by name
+    earlier = ("mistral-7b-int8", "qwen2-7b-int8", "ax-k1-int8",
+               "solar-open2-int8")
+
+    def after_earlier(names, item):
+        return names.count(item) == 1 and all(
+            names.index(item) > i for i, n in enumerate(names)
+            if n.startswith(earlier))
+
+    assert after_earlier([w["name"] for w in bench["workloads"]], CELL)
+    assert after_earlier([c["name"] for c in bench["configs"]],
+                         "trinity-mini-int8")
+    # the cell's own metrics list it first; later cells may append
     assert [m["name"] for m in bench["per_layer"]
-            if m.get("workloads") == [CELL]][-1] == "kv_read_kb_per_token.req"
+            if m.get("workloads", [None])[0] == CELL][-1] \
+        == "kv_read_kb_per_token.req"
     for metric in bench["end_to_end"] + bench["per_layer"]:
         if CELL in metric.get("workloads", ()):
-            assert metric["workloads"][-1] == CELL
-    cell = bench["workloads"][-1]
+            assert after_earlier(metric["workloads"], CELL)
+    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) \
         == ("trinity-mini-int8", "catalog8k", 1) and len(cell["why"]) <= 200
-    conf = bench["configs"][-1]
+    conf = next(c for c in bench["configs"]
+                if c["name"] == "trinity-mini-int8")
     config = _raw()
     assert conf["source"] == config["source"] and len(conf["why"]) <= 200
     assert sorted(conf["reduced"]) == sorted(config["reduced"])
@@ -364,7 +377,8 @@ def test_the_entries_name_the_cell_as_the_issue_does():
     assert "flash_attention_roofline.req" not in named  # silent since PR 29
     metric = next(m for m in bench["per_layer"]
                   if m["name"] == "kv_read_kb_per_token.req")
-    assert metric == {
+    assert metric["workloads"][0] == CELL
+    assert dict(metric, workloads=[CELL]) == {
         "name": "kv_read_kb_per_token.req", "unit": "KiB/token",
         "better": "lower", "source": "program_counter",
         "layer": "step programs", "moves": "req_per_s", "workloads": [CELL]}

@@ -197,7 +197,8 @@ def test_the_lead_read_share_file_reads_the_counters():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    entry = bench["per_layer"][-1]
+    entry = next(m for m in bench["per_layer"]
+                 if m["name"] == "decode_lead_read_share.req")
     assert entry == {
         "name": "decode_lead_read_share.req", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "step programs",
